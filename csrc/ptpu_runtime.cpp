@@ -13,7 +13,8 @@
 //     reference's shared-memory DataLoader worker transport —
 //     python/paddle/io/dataloader/worker.py + fluid/framework/data_feed.h).
 //
-// Build: g++ -O2 -shared -fPIC -pthread ptpu_runtime.cpp -o libptpu_runtime.so
+// Build: paddle_tpu/lib does it on first use (g++ -O2 -shared -fPIC -pthread
+// ptpu_runtime.cpp -o libptpu_runtime.<source hash>.so); never tracked.
 #include <arpa/inet.h>
 #include <netinet/in.h>
 #include <netinet/tcp.h>

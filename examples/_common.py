@@ -1,10 +1,8 @@
 """Shared example bootstrap.
 
 `setup()` makes the repo importable and — when JAX_PLATFORMS=cpu is set —
-forces a virtual CPU mesh through jax.config BEFORE paddle_tpu initializes
-the backend (env vars alone don't stick when jax was pre-imported; same
-order-sensitive dance as tests/conftest.py). Call it before importing
-paddle_tpu or any model module.
+asks for a virtual CPU mesh (PADDLE_TPU_VIRTUAL_DEVICES, default 8) before
+anything starts the backend. Call it before the first jax operation.
 """
 import os
 import sys
@@ -16,12 +14,6 @@ def setup():
     if os.environ.get("JAX_PLATFORMS") == "cpu":
         import jax
 
-        jax.config.update("jax_platforms", "cpu")
-        try:
-            jax.config.update(
-                "jax_num_cpu_devices",
-                int(os.environ.get("PADDLE_TPU_VIRTUAL_DEVICES", "8")))
-        except (RuntimeError, AttributeError):
-            # backend already initialized, or an older jax with no
-            # jax_num_cpu_devices (XLA_FLAGS covers it) — keep what we have
-            pass
+        jax.config.update(
+            "jax_num_cpu_devices",
+            int(os.environ.get("PADDLE_TPU_VIRTUAL_DEVICES", "8")))

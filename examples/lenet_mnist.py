@@ -2,7 +2,7 @@
 
 Exercises the eager core end to end through the high-level `paddle.Model`
 API: autograd, optimizer, DataLoader, metric, checkpoint save/load.
-Real MNIST IDX files are picked up from ~/.cache/paddle_tpu/mnist when
+Real MNIST IDX files are picked up from <checkout>/.paddle_tpu_cache/mnist when
 present; otherwise the dataset synthesizes MNIST-shaped data so the example
 runs hermetically.
 

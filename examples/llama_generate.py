@@ -1,8 +1,8 @@
 """Llama text generation with the fused decode loop.
 
 `generate_fused` runs prefill + the whole decode loop as ONE compiled
-program (on-device sampling, EOS early exit) — the per-token-dispatch
-python loop costs ~30× more per step on remote-attached TPUs. Weights here
+program (on-device sampling, EOS early exit) — no host dispatch per
+token, as the python loop pays. Weights here
 are random (no checkpoint download in this environment); point
 `--load` at a `paddle.save`d params file to decode a trained model.
 

@@ -3,7 +3,8 @@
 Exercises the conv/BN kernel path under `paddle.jit.to_static` capture
 (one compiled program per train step, BN running stats threaded through
 capture) with bf16 autocast. Uses Cifar10 when its files are cached
-(~/.cache/paddle_tpu), otherwise synthetic image data — hermetic either way.
+(<checkout>/.paddle_tpu_cache), otherwise synthetic image data — hermetic
+either way.
 
 Run:  python examples/resnet_train.py [--arch resnet18] [--steps 50]
 """

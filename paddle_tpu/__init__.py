@@ -23,10 +23,18 @@ import jax as _jax
 if _os.environ.get("PADDLE_TPU_X64", "0") == "1":
     _jax.config.update("jax_enable_x64", True)
 
+# JAX's persistent compilation cache, at a fixed path inside the checkout
+# unless JAX_COMPILATION_CACHE_DIR places it from outside
+from .framework.cache_dirs import configure_compile_cache as _cc  # noqa: E402
+
+_cc()
+
 # Multi-process bootstrap (the PADDLE_* env contract from
-# distributed.launch) must run BEFORE anything touches the XLA backend —
-# importing this package initializes devices, so it happens here rather
-# than in init_parallel_env (which becomes a no-op confirmation).
+# distributed.launch) must run BEFORE anything touches the XLA backend, so
+# it happens here rather than in init_parallel_env (which becomes a no-op
+# confirmation). Importing this package itself initialises NO backend: a
+# chip belongs to one process, and a parent that only imports the package
+# (the launcher, a tool that spawns) must leave it to its children.
 if int(_os.environ.get("PADDLE_TRAINERS_NUM", "1")) > 1 and \
         _os.environ.get("PADDLE_MASTER"):
     try:

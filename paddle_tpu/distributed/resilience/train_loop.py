@@ -445,17 +445,14 @@ class ResilientTrainLoop:
             # one lowering of step_fn (a trace, not a compile) buys MFU
             # for the whole run; fns that don't trace opt out silently
             self._flops_derivable = False
-            # allow_compile=False: on jax versions with no pre-compile
-            # analysis, skip MFU rather than compile step_fn twice
             if self.rng_key is not None:
                 import jax
                 key = jax.random.fold_in(self.rng_key, self.step)
                 self._flops = _perf.flops_of(self.step_fn, self.state,
-                                             batch, key,
-                                             allow_compile=False)
+                                             batch, key)
             else:
                 self._flops = _perf.flops_of(self.step_fn, self.state,
-                                             batch, allow_compile=False)
+                                             batch)
         m = _perf.mfu(self._flops, dt)
         if m is not None:
             _M_MFU.set(m)

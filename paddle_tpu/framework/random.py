@@ -20,22 +20,30 @@ class Generator:
     python/paddle/framework/random.py get_rng_state)."""
 
     def __init__(self, seed: int = 0):
-        self._seed = seed
-        self._key = jax.random.key(seed)
         self._lock = threading.Lock()
+        self.manual_seed(seed)
 
     def manual_seed(self, seed: int):
+        # the key is built on first use: jax.random.key initialises the
+        # backend, and importing the package (which builds the default
+        # generator) must leave the chip to whichever process needs it
         self._seed = seed
-        self._key = jax.random.key(seed)
+        self._key = None
         return self
+
+    def _current(self):
+        if self._key is None:
+            self._key = jax.random.key(self._seed)
+        return self._key
 
     def next_key(self):
         with self._lock:
-            self._key, sub = jax.random.split(self._key)
+            self._key, sub = jax.random.split(self._current())
             return sub
 
     def get_state(self):
-        return jax.random.key_data(self._key)
+        with self._lock:
+            return jax.random.key_data(self._current())
 
     def set_state(self, state):
         self._key = jax.random.wrap_key_data(np.asarray(state))

@@ -83,17 +83,18 @@ class ArrayDataset(Dataset):
 
 
 def _native_gather(arr: np.ndarray, indices, nthreads: int = 4) -> np.ndarray:
-    """Batch-gather rows via the native runtime; numpy fallback."""
+    """Batch-gather rows via the native runtime; numpy where there is
+    neither a built library nor a compiler to build one."""
     import ctypes
+
+    from ..lib import native_available, native_lib
 
     idx = np.ascontiguousarray(indices, np.int64)
     out = np.empty((len(idx),) + arr.shape[1:], arr.dtype)
-    try:
-        from ..lib import native_lib
-        lib = native_lib()
-    except RuntimeError:
+    if not native_available():
         np.take(arr, idx, axis=0, out=out)
         return out
+    lib = native_lib()
     row_bytes = int(arr.dtype.itemsize * np.prod(arr.shape[1:], dtype=np.int64))
     lib.ptpu_gather_rows(
         arr.ctypes.data_as(ctypes.c_char_p),

@@ -51,7 +51,7 @@ from . import cache  # noqa: E402  (persistent compile-artifact store —
 from .segments import segment_scope  # noqa: E402  (public: eager code can
 # opt into lazy-segment batching directly — ops defer into cached compiled
 # segments, any .item()/numpy() materializes; avoids per-op dispatch and
-# compile storms through a remote-attached chip)
+# per-op compiles)
 
 _to_static_enabled = True
 

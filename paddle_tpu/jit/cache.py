@@ -8,7 +8,9 @@ runs. This module is the one place that knows where such artifacts
 live and how to write them without torn files:
 
 * ``cache_dir()`` — ``FLAGS_jit_cache_dir`` > ``$PADDLE_TPU_CACHE_DIR``
-  > ``$XDG_CACHE_HOME/paddle_tpu`` > ``~/.cache/paddle_tpu``;
+  > ``<checkout>/.paddle_tpu_cache`` (a fixed, gitignored directory
+  derived from the package's location — no run depends on ``$HOME``,
+  which a sealed machine does not keep);
 * ``load_json(name)`` / ``store_json(name, obj)`` — JSON documents
   committed with the resilience tier's temp+fsync+rename idiom
   (atomic_ckpt.py), so a crash mid-write leaves the previous version,
@@ -33,12 +35,13 @@ import os
 import tempfile
 from typing import Any, Dict
 
+from ..framework.cache_dirs import ARTIFACT_DIR
 from ..framework.flags import define_flag, get_flag
 
 define_flag("jit_cache_dir", "",
             "directory for persistent compile artifacts (tiling autotune "
             "winners etc.); empty = $PADDLE_TPU_CACHE_DIR or "
-            "$XDG_CACHE_HOME/paddle_tpu or ~/.cache/paddle_tpu")
+            "<checkout>/.paddle_tpu_cache")
 
 __all__ = ["cache_dir", "cache_path", "load_json", "store_json",
            "SCHEMA_KEY"]
@@ -47,12 +50,8 @@ SCHEMA_KEY = "__schema__"
 
 
 def cache_dir() -> str:
-    d = get_flag("jit_cache_dir") or os.environ.get("PADDLE_TPU_CACHE_DIR")
-    if not d:
-        xdg = os.environ.get("XDG_CACHE_HOME")
-        base = xdg if xdg else os.path.join(os.path.expanduser("~"), ".cache")
-        d = os.path.join(base, "paddle_tpu")
-    return d
+    return (get_flag("jit_cache_dir")
+            or os.environ.get("PADDLE_TPU_CACHE_DIR") or ARTIFACT_DIR)
 
 
 def cache_path(name: str) -> str:

@@ -19,11 +19,8 @@ bytecode translator:
   GradNode per segment — autograd composes across segments through the
   eager tape, so graph-broken models still train.
 
-Through a remote-attached chip this is also an eager-mode win: an N-op
-python region costs ~1 dispatch instead of N. Measured r4 on a 24-layer
-MLP: 18× vs a COLD eager pass (per-op compiles included — the compile
-storm segments avoid entirely), ~1.5-2× vs warm eager at ~30 ops,
-growing with region size.
+This is also an eager-mode win: an N-op python region costs ~1 dispatch
+and one compile instead of N (speedup on this chip: not measured).
 
 Anything the recorder cannot defer (data-dependent output shapes, ops
 whose abstract eval fails, nested already-compiled programs) flushes the

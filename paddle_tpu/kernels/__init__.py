@@ -19,8 +19,7 @@ from .paged_attention import (PagedKVCache, paged_append,  # noqa: F401
                               paged_decode_attention,
                               ragged_decode_partial, ragged_paged_decode)
 from .quant_matmul import (attn_pv, attn_qk, dequantize_kv,  # noqa: F401
-                           mixed_dot_supported, quantize_kv,
-                           weight_only_matmul)
+                           quantize_kv, weight_only_matmul)
 
 __all__ = [
     # fused decode megakernel (r18)
@@ -37,5 +36,5 @@ __all__ = [
     "heuristic_tilings", "get_tilings", "candidate_tilings",
     # int8 weight-only / KV quantized matmuls
     "weight_only_matmul", "quantize_kv", "dequantize_kv",
-    "attn_qk", "attn_pv", "mixed_dot_supported",
+    "attn_qk", "attn_pv",
 ]

@@ -40,6 +40,7 @@ from __future__ import annotations
 
 import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Dict, Optional, Tuple
 
 import numpy as _np
@@ -262,15 +263,37 @@ def _default_measure(m, k, n, E, dtype, full_rows):
     import jax.numpy as jnp
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
 
-    ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    lhs = jax.random.normal(ks[0], (m, k), jnp.float32).astype(dtype)
-    rhs = jax.random.normal(ks[1], (E, k, n), jnp.float32).astype(dtype)
-    grad = jax.random.normal(ks[2], (m, n), jnp.float32).astype(dtype)
-    # balanced groups summing to m — the load the aux loss maintains
-    gs = jnp.full((E,), m // E, jnp.int32).at[0].add(m - E * (m // E))
-    lhs_t = lhs.swapaxes(0, 1)
+    # get_tilings is usually reached while the model is being TRACED,
+    # where every jnp op yields a tracer and nothing can be timed (a
+    # tracer has no block_until_ready — every candidate "failed", and the
+    # skip in get_tilings turned that into the heuristic, silently, until
+    # PR 21's chip lane). Trace state is per thread, so the operands are
+    # built and the calls timed on a thread of their own, where they run
+    # for real. (jax.ensure_compile_time_eval does not do: under it the
+    # megablox index maps capture constants, which Pallas refuses.)
+    ops = {}
+
+    def operands():
+        if not ops:
+            ks = jax.random.split(jax.random.PRNGKey(0), 3)
+            ops["lhs"] = jax.random.normal(
+                ks[0], (m, k), jnp.float32).astype(dtype)
+            ops["rhs"] = jax.random.normal(
+                ks[1], (E, k, n), jnp.float32).astype(dtype)
+            ops["grad"] = jax.random.normal(
+                ks[2], (m, n), jnp.float32).astype(dtype)
+            # balanced groups summing to m — the load the aux loss
+            # maintains
+            ops["gs"] = jnp.full((E,), m // E, jnp.int32).at[0].add(
+                m - E * (m // E))
+        return ops["lhs"], ops["rhs"], ops["grad"], ops["gs"]
 
     def run(pass_: str, tiling: Tiling) -> float:
+        with ThreadPoolExecutor(1) as off_trace:
+            return off_trace.submit(timed, pass_, tiling).result()
+
+    def timed(pass_: str, tiling: Tiling) -> float:
+        lhs, rhs, grad, gs = operands()
         if pass_ == "fwd":
             f = jax.jit(functools.partial(
                 gmm, preferred_element_type=lhs.dtype, tiling=tiling))
@@ -284,7 +307,7 @@ def _default_measure(m, k, n, E, dtype, full_rows):
             f = jax.jit(functools.partial(
                 tgmm, preferred_element_type=rhs.dtype, tiling=tiling,
                 num_actual_groups=E))
-            args = (lhs_t, grad, gs)
+            args = (lhs.swapaxes(0, 1), grad, gs)
         f(*args).block_until_ready()          # compile + warm
         best = float("inf")
         for _ in range(3):

@@ -23,9 +23,10 @@ across layers) and each grid step fuses, for its layer,
 The ragged path launches ``n_steps × L`` attention kernels per decode
 call and round-trips the hidden state through HBM at every layer's XLA
 FFN boundary; the mega path launches ``n_steps`` kernels and the hidden
-state never leaves VMEM — at batch ≤ 4 decode is launch/latency-bound and
-this is the r18 win (serving/engine.py wires it as
-``decode_kernel="mega"``, ragged kept as the counted fallback).
+state never leaves VMEM (serving/engine.py wires it as
+``decode_kernel="mega"``). It has only ever run in the Pallas interpreter:
+the TPU compiler refuses it (``MEGA_TPU_REFUSAL`` below), so the engine
+selects it only by name and only off-TPU.
 
 Second fusion target (``mega_decode_loop``): the speculative DRAFT wave's
 ``k`` sequential tiny steps run as ONE persistent launch — the grid grows
@@ -55,12 +56,21 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .paged_attention import _interpret
-from .quant_matmul import is_quantized_weight, mixed_dot_supported
+from .quant_matmul import is_quantized_weight
 
 __all__ = ["mega_decode_step", "mega_decode_loop", "mega_supported",
-           "MEGA_VMEM_BUDGET"]
+           "MEGA_VMEM_BUDGET", "MEGA_TPU_REFUSAL"]
 
 _MATS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+
+# What the TPU compiler says to this kernel (jax 0.9.0 / libtpu 0.0.34,
+# tests/test_aot_chip_compile.py) once its per-layer vector blocks and its
+# value-level scatters are repaired: the ring combine's einsums carry two
+# batch dims (slot, kv head). The kernel was only ever run interpreted;
+# making Mosaic take it means re-laying-out its attention half, so the
+# serving engine no longer selects it on a TPU.
+MEGA_TPU_REFUSAL = ("'tpu.matmul' op Not implemented: Up to 1 batch dim "
+                    "supported")
 
 # same screening precedent as paged_decode_attention's staging-buffer
 # gate: past ~12 MiB the working set can't coexist in the ~16 MiB VMEM,
@@ -118,6 +128,7 @@ def mega_supported(params, config, *, n_slots: int, n_steps: int,
     if kv_int8:
         bytes_ += 2 * 2 * block_size * Hkv * 4           # walk scales
     bytes_ += 2 * n_slots * h * dt.itemsize              # xs + staging
+    bytes_ += n_slots * config.num_heads * (D + 2) * 4   # walk partials
     if multi_step:
         emb = params["embed"]
         bytes_ += n_slots * h * jnp.dtype(emb.dtype).itemsize   # ebuf
@@ -141,12 +152,12 @@ def _mega_kernel(*refs, meta):
     ``meta`` (dict of static shapes/flags) fixes the *refs layout; see
     the builder below for the exact operand order."""
     (n_kv, G, D, bs, MB, S, N, h, L, TW, eps, sm_scale, dt, kv_int8,
-     w_int8, multi, head_mode, TV, V, mixed_dot) = (
+     w_int8, multi, head_mode, TV, V) = (
         meta["n_kv"], meta["G"], meta["D"], meta["bs"], meta["MB"],
         meta["S"], meta["N"], meta["h"], meta["L"], meta["TW"],
         meta["eps"], meta["sm_scale"], meta["dt"], meta["kv_int8"],
         meta["w_int8"], meta["multi"], meta["head_mode"], meta["TV"],
-        meta["V"], meta["mixed_dot"])
+        meta["V"])
 
     it = iter(refs)
 
@@ -182,7 +193,7 @@ def _mega_kernel(*refs, meta):
     # scratch
     xs, rkb, rvb, kbuf, vbuf = take(5)
     ksbuf, vsbuf = take(2) if kv_int8 else (None, None)
-    wbuf = take()
+    wbuf, ms, ls, accs = take(4)
     ring_sem, rout_sem, walk_sem, w_sem = take(4)
     if multi:
         state, ebuf, hbuf, h_sem, e_sem = take(5)
@@ -236,8 +247,6 @@ def _mega_kernel(*refs, meta):
             cp(ti).wait()
             a, tw = ti * TW, min(TW, M - ti * TW)
             wt = wbuf[ti % 2, 0:K, 0:tw]
-            if w_int8 and not mixed_dot:
-                wt = wt.astype(xv.dtype)     # old jax: widen (exact)
             acc = jax.lax.dot_general(
                 xv, wt, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
@@ -308,7 +317,13 @@ def _mega_kernel(*refs, meta):
                         walk_sem.at[3, slot])]
         return cps
 
-    m_ps, l_ps, a_ps = [], [], []
+    # per-slot online-softmax partials live in VMEM scratch, one plane per
+    # slot, updated per kv head by ref stores — the ragged kernel's
+    # pattern (value-level ``.at[].set`` is a scatter, which Mosaic does
+    # not lower)
+    ms[...] = jnp.full(ms.shape, -1e30, jnp.float32)
+    ls[...] = jnp.zeros(ls.shape, jnp.float32)
+    accs[...] = jnp.zeros(accs.shape, jnp.float32)
     for n in range(N):                        # static slot unroll
         ln = wl_ref[n]
         nblk = jnp.minimum((ln + bs - 1) // bs, MB)
@@ -319,8 +334,7 @@ def _mega_kernel(*refs, meta):
             for cp in copies(n, 0, 0):
                 cp.start()
 
-        def walk(b, carry, n=n, ln=ln, nblk=nblk, qn=qn):
-            ms_c, ls_c, acc_c = carry
+        def walk(b, _, n=n, ln=ln, nblk=nblk, qn=qn):
             sl = jax.lax.rem(b, 2)
 
             @pl.when(b + 1 < nblk)
@@ -344,12 +358,11 @@ def _mega_kernel(*refs, meta):
                 if kv_int8:
                     sc = sc * ksbuf[sl][:, kh_i][None, :]
                 sc = jnp.where(live, sc, jnp.float32(-1e30))
-                m_prev = ms_c[kh_i]
+                m_prev = ms[n, kh_i]
                 m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1))
                 alpha = jnp.exp(m_prev - m_new)
                 p = jnp.exp(sc - m_new[:, None])
-                ls_c = ls_c.at[kh_i].set(
-                    ls_c[kh_i] * alpha + jnp.sum(p, axis=-1))
+                ls[n, kh_i] = ls[n, kh_i] * alpha + jnp.sum(p, axis=-1)
                 vh = vbuf[sl][:, kh_i]
                 if kv_int8:
                     p = p * vsbuf[sl][:, kh_i][None, :]
@@ -359,21 +372,14 @@ def _mega_kernel(*refs, meta):
                 pv = jax.lax.dot_general(
                     p, vh, (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)
-                acc_c = acc_c.at[kh_i].set(
-                    acc_c[kh_i] * alpha[:, None] + pv)
-                ms_c = ms_c.at[kh_i].set(m_new)
-            return ms_c, ls_c, acc_c
+                accs[n, kh_i] = accs[n, kh_i] * alpha[:, None] + pv
+                ms[n, kh_i] = m_new
+            return 0
 
-        init = (jnp.full((n_kv, G), -1e30, jnp.float32),
-                jnp.zeros((n_kv, G), jnp.float32),
-                jnp.zeros((n_kv, G, D), jnp.float32))
-        ms_n, ls_n, acc_n = jax.lax.fori_loop(0, nblk, walk, init)
-        m_ps.append(ms_n)
-        l_ps.append(ls_n)
-        a_ps.append(acc_n)
-    m_p = jnp.stack(m_ps)                                # [N, Hkv, G]
-    l_p = jnp.stack(l_ps)
-    acc_p = jnp.stack(a_ps)                              # [N, Hkv, G, D]
+        jax.lax.fori_loop(0, nblk, walk, 0)
+    m_p = ms[...]                                        # [N, Hkv, G]
+    l_p = ls[...]
+    acc_p = accs[...]                                    # [N, Hkv, G, D]
 
     # flash-decoding combine with the raw-dtype ring (j <= t live) —
     # _paged_decode's merge, verbatim
@@ -434,8 +440,6 @@ def _mega_kernel(*refs, meta):
                         preferred_element_type=jnp.float32)
                 else:
                     wt = hbuf[ti % 2, :, 0:tv]
-                    if head_mode == "int8" and not mixed_dot:
-                        wt = wt.astype(dt)
                     lg = jax.lax.dot_general(
                         xf, wt, (((1,), (0,)), ((), ())),
                         preferred_element_type=jnp.float32)
@@ -520,12 +524,21 @@ def _mega_call(params, config, *, x0, t0, block_table, walk_lens, lens,
     nxt_idx(8)                               # scalar prefetch operands
     freq = (config.rope_theta
             ** (-jnp.arange(0, D, 2, jnp.float32) / D)).reshape(1, -1)
-    inputs = [x0, freq, lay["attn_norm"], lay["mlp_norm"]]
+    # per-layer vectors ride as [L, 1, width] with the layer dim squeezed:
+    # Mosaic wants a block's last two dims tile-aligned or whole, and a
+    # (1, width) block of an [L, width] array is neither
+    def layer_row(v):
+        return (v.reshape(L, 1, -1),
+                pl.BlockSpec((None, 1, v.shape[-1]),
+                             lambda s, l, *_: (l, 0, 0)))
+
+    (an, an_spec), (mn, mn_spec) = (layer_row(lay["attn_norm"]),
+                                    layer_row(lay["mlp_norm"]))
+    inputs = [x0, freq, an, mn]
     in_specs = [
         pl.BlockSpec((N, h), lambda s, l, *_: (0, 0)),
         pl.BlockSpec((1, D // 2), lambda s, l, *_: (0, 0)),
-        pl.BlockSpec((1, h), lambda s, l, *_: (l, 0)),
-        pl.BlockSpec((1, h), lambda s, l, *_: (l, 0)),
+        an_spec, mn_spec,
     ]
     nxt_idx(4)
     for m in mats:
@@ -534,10 +547,9 @@ def _mega_call(params, config, *, x0, t0, block_table, walk_lens, lens,
     nxt_idx(7)
     if w_int8:
         for m in mats:
-            mdim = m["q"].shape[2]
-            inputs.append(m["s"])
-            in_specs.append(pl.BlockSpec(
-                (1, mdim), lambda s, l, *_: (l, 0)))
+            sc, sc_spec = layer_row(m["s"])
+            inputs.append(sc)
+            in_specs.append(sc_spec)
         nxt_idx(7)
     V = TV = 0
     if multi_step:
@@ -609,6 +621,9 @@ def _mega_call(params, config, *, x0, t0, block_table, walk_lens, lens,
         scratch += [pltpu.VMEM((2, bs, Hkv), jnp.float32),
                     pltpu.VMEM((2, bs, Hkv), jnp.float32)]
     scratch += [pltpu.VMEM((2, kmax, TW), wdt),            # wbuf
+                pltpu.VMEM((N, Hkv, G), jnp.float32),      # ms
+                pltpu.VMEM((N, Hkv, G), jnp.float32),      # ls
+                pltpu.VMEM((N, Hkv, G, D), jnp.float32),   # accs
                 pltpu.SemaphoreType.DMA((2,)),             # ring_sem
                 pltpu.SemaphoreType.DMA((2,)),             # rout_sem
                 pltpu.SemaphoreType.DMA((4 if kv_int8 else 2, 2)),
@@ -625,7 +640,7 @@ def _mega_call(params, config, *, x0, t0, block_table, walk_lens, lens,
                 TW=TW, eps=config.rms_eps,
                 sm_scale=1.0 / math.sqrt(D), dt=dt, kv_int8=kv_int8,
                 w_int8=w_int8, multi=multi_step, head_mode=head_mode,
-                TV=TV, V=V, mixed_dot=mixed_dot_supported())
+                TV=TV, V=V)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=8,
         grid=(n_steps if multi_step else 1, L),

@@ -92,21 +92,6 @@ _M_FALLBACKS = _instrument("moe_dispatch_fallbacks_total")
 _M_OVERLAP_BYPASS = _instrument("moe_overlap_bypass_total")
 
 
-def _shard_map(f, mesh, in_specs, out_specs, axis_names, check_vma=False):
-    """jax.shard_map across jax versions: the public API (axis_names/
-    check_vma) when present, else jax.experimental.shard_map (0.4.x —
-    partial-manual is spelled ``auto`` = the complement of axis_names,
-    replication checking is ``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=axis_names,
-                             check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma,
-               auto=frozenset(mesh.axis_names) - set(axis_names))
-
-
 def sort_by_expert(idx: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Flatten top-k assignments [T,k] → stable expert-sorted order.
 
@@ -827,7 +812,7 @@ def dropless_moe_ffn_ep(x, weights, idx, e_gate, e_up, e_down, mesh: Mesh,
     body = functools.partial(_ep_local, num_experts_local=E // ep,
                              compute_dtype=dt)
     if shared is None:
-        fn = _shard_map(
+        fn = jax.shard_map(
             lambda xl, wl, il, g, u, d: body(xl, wl, il, g, u, d),
             mesh=mesh,
             in_specs=(tok_spec, tok_spec, tok_spec, P("ep"), P("ep"),
@@ -837,7 +822,7 @@ def dropless_moe_ffn_ep(x, weights, idx, e_gate, e_up, e_down, mesh: Mesh,
             check_vma=False)
         return fn(x.astype(jnp.float32), weights, idx,
                   e_gate, e_up, e_down).astype(dt)
-    fn = _shard_map(
+    fn = jax.shard_map(
         lambda xl, wl, il, g, u, d, sg, su, sd: body(
             xl, wl, il, g, u, d, (sg, su, sd)),
         mesh=mesh,
@@ -985,7 +970,7 @@ def dropless_moe_ffn_a2a(x, weights, idx, e_gate, e_up, e_down, mesh: Mesh,
     body = functools.partial(_a2a_local, num_experts=E,
                              num_experts_local=E // ep, ep_size=ep)
     if shared is None:
-        fn = _shard_map(
+        fn = jax.shard_map(
             lambda xl, wl, il, g, u, d: body(xl, wl, il, g, u, d),
             mesh=mesh,
             in_specs=(tok_spec, tok_spec, tok_spec, P("ep"), P("ep"),
@@ -994,7 +979,7 @@ def dropless_moe_ffn_a2a(x, weights, idx, e_gate, e_up, e_down, mesh: Mesh,
             axis_names=set(tok_axes) | {"ep"},
             check_vma=False)
         return fn(x, weights, idx, e_gate, e_up, e_down)
-    fn = _shard_map(
+    fn = jax.shard_map(
         lambda xl, wl, il, g, u, d, sg, su, sd: body(
             xl, wl, il, g, u, d, (sg, su, sd)),
         mesh=mesh,

@@ -29,8 +29,11 @@ dispatch boundary. This module is that fusion, in two layers:
   VMEM *unconverted* (half the rhs bytes; dequantized in-register).
   Requires a per-group tile-padded row layout (built host-free in XLA int
   ops; padding rows carry combine weight 0, so they are exact no-ops in
-  both directions). Covered by the ``tests_tpu/`` lane; any failure to
-  build falls back to the XLA rewrite at trace time.
+  both directions). WITHDRAWN from selection: the TPU compiler refuses
+  the kernel's one-row gather DMA (``GATHER_GMM_TPU_REFUSAL`` below), so
+  ``FLAGS_moe_fused_kernel`` now defaults off and every backend runs the
+  XLA rewrite. Turning the flag on selects the kernel again, and a
+  failure to build it is an error, never a quiet other path.
 
 Expert weights may be plain arrays or int8 dicts ``{"q": int8, "s": f32}``
 from :func:`paddle_tpu.kernels.quant_matmul.quantize_grouped` — gate/up
@@ -38,7 +41,7 @@ scales ride the gu elementwise chain, down scales ride the combine-weight
 chain (:mod:`quant_matmul`'s output-scaling idiom, grouped).
 
 Path taken is visible as ``moe_gmm_fused_dispatch_total{path}`` with
-path ∈ {pallas, xla, xla_fallback}.
+path ∈ {pallas, xla}.
 """
 from __future__ import annotations
 
@@ -53,12 +56,21 @@ from ..observability import numerics as _numerics
 from ..observability.catalog import instrument as _instrument
 from .quant_matmul import is_quantized_weight
 
-define_flag("moe_fused_kernel", True,
+define_flag("moe_fused_kernel", False,
             "use the Pallas gather-fused grouped-GEMM kernel for the "
             "fused MoE dispatch on TPU (off = the portable XLA rewrite "
-            "everywhere)")
+            "everywhere). Off by default: the TPU compiler refuses the "
+            "kernel (moe_fused.GATHER_GMM_TPU_REFUSAL)")
 
-__all__ = ["fused_moe_ffn", "gather_gmm"]
+__all__ = ["fused_moe_ffn", "gather_gmm", "GATHER_GMM_TPU_REFUSAL"]
+
+# What the TPU compiler says to gather_gmm (jax 0.9.0 / libtpu 0.0.34,
+# tests/test_aot_chip_compile.py): the per-row gather DMA slices one row
+# of the bf16 activations, whose HBM layout tiles rows in groups of 8
+# (two to a sublane). Repairing it means another gather scheme.
+GATHER_GMM_TPU_REFUSAL = (
+    "Mosaic failed to compile TPU kernel: Slice shape along dimension 0 "
+    "must be aligned to tiling (8), but is 1")
 
 _M_FUSED = _instrument("moe_gmm_fused_dispatch_total")
 
@@ -181,10 +193,8 @@ def _kernel_tn(n: int, h: int = 0, rhs_itemsize: int = 2,
     residency inside the same ~15.5 MiB envelope gmm_autotune._fits is
     calibrated to: double-buffered rhs blocks (2*h*tn), the [tm, h] lhs
     gather scratch, and double-buffered [tm, tn] f32-accumulated output
-    blocks. The enclosing jit compiles the Mosaic kernel long after
-    trace time, where the try/except around the call site can no longer
-    catch it — so anything that would blow VMEM must be screened out
-    HERE (None = use the XLA rewrite)."""
+    blocks. Selection by shape: a shape that would blow VMEM is screened
+    out HERE (None = use the XLA rewrite), before the kernel is chosen."""
     for t in (512, 256, 128):
         if n % t:
             continue
@@ -253,7 +263,7 @@ def gather_gmm(x, idx, rhs, gid, *, tm: int = _KTM,
         num_scalar_prefetch=2,
         grid=grid,
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),        # x stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),           # x stays in HBM
             pl.BlockSpec((1, h, tn),
                          lambda i, j, idx_ref, gid_ref: (gid_ref[i], 0, j)),
         ],
@@ -267,7 +277,7 @@ def gather_gmm(x, idx, rhs, gid, *, tm: int = _KTM,
         kernel,
         out_shape=jax.ShapeDtypeStruct((A_pad, n), out_dtype),
         grid_spec=grid_spec,
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(idx, gid, x, rhs)
@@ -407,18 +417,12 @@ def fused_moe_ffn(x, weights, idx, e_gate, e_up, e_down,
                   and _kernel_tn(2 * f, h, Wcat.dtype.itemsize,
                                  x.dtype.itemsize) is not None
                   and A >= _KTM)
-    y = None
     if use_kernel:
-        try:
-            y = _fused_padded(x, ws, tok, esorted, gs, inv2d, Wcat, s_gu,
-                              Wd, s_down, E, f, dt)
-            _M_FUSED.labels(path="pallas").inc()
-        except Exception:
-            _M_FUSED.labels(path="xla_fallback").inc()
+        _M_FUSED.labels(path="pallas").inc()
+        y = _fused_padded(x, ws, tok, esorted, gs, inv2d, Wcat, s_gu,
+                          Wd, s_down, E, f, dt)
     else:
         _M_FUSED.labels(path="xla").inc()
-
-    if y is None:
         xs = _gather_rows(x, tok, inv2d)
         gu = _grouped(xs, Wcat, gs, full_rows=True)
         zw = _elementwise_core(gu, s_gu, ws, s_down, esorted, f, dt)
@@ -426,8 +430,7 @@ def fused_moe_ffn(x, weights, idx, e_gate, e_up, e_down,
         y = _combine_rows(ys, inv2d, tok)
     # routed-output health probe (trace-time gated, zero ops off): with
     # int8 experts this is where a blown scale or a saturating expert
-    # first becomes visible. Deliberately OUTSIDE the kernel try block:
-    # a probe failure must surface, not masquerade as a Pallas fallback.
+    # first becomes visible.
     # Lands in forward/serving programs and remat'd training bodies;
     # un-checkpointed grad drops in-scan probes (the models' ladder
     # covers training) — see numerics.record_stats.

@@ -46,6 +46,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["PagedKVCache", "paged_cache_init", "paged_append",
+           "RAGGED_INT8_KV_TPU_REFUSAL", "ragged_tpu_refusal",
            "paged_attention", "paged_append_token", "paged_append_blocks",
            "paged_decode_attention", "ragged_decode_partial",
            "ragged_paged_decode"]
@@ -54,6 +55,31 @@ __all__ = ["PagedKVCache", "paged_cache_init", "paged_append",
 def _interpret() -> bool:
     # off-TPU (CPU tests) the kernels run in the Pallas interpreter
     return jax.default_backend() != "tpu"
+
+
+# What the TPU compiler says to the ragged walk over int8 pools (jax 0.9.0
+# / libtpu 0.0.34, tests/test_aot_chip_compile.py): the [bs, Hkv] slice of
+# the f32 scale pools [L, NB, bs, Hkv] is narrower than the 128-lane tile
+# the pool is laid out in. Repairing it means a new scale-pool layout, so
+# the serving engine takes the bucketed path for int8 pools on a TPU.
+RAGGED_INT8_KV_TPU_REFUSAL = (
+    "Mosaic failed to compile TPU kernel: Slice shape along dimension 3 "
+    "must be aligned to tiling (128), but is 8")
+
+
+def ragged_tpu_refusal(head_dim: int, kv_int8: bool):
+    """The TPU compiler's message for a ragged walk it refuses, ``None``
+    for one it compiles — the engine's selection by shape. Besides int8
+    pools, the block DMA slices a pool whose minor dim is the head dim:
+    anything but a multiple of the 128-lane tile is refused (found on the
+    chip at head dims 8 and 64)."""
+    if kv_int8:
+        return RAGGED_INT8_KV_TPU_REFUSAL
+    if head_dim % 128:
+        return ("Mosaic failed to compile TPU kernel: Slice shape along "
+                "dimension 4 must be aligned to tiling (128), but is "
+                f"{head_dim}")
+    return None
 
 
 class PagedKVCache(NamedTuple):
@@ -507,7 +533,6 @@ def ragged_decode_partial(q, k_pool, v_pool, block_table, lengths, *,
         tp = dict(mesh.shape).get("tp", 1)
         if tp > 1:
             from jax.sharding import PartitionSpec as P
-            from .moe_dispatch import _shard_map
             Hkv_g = _as5d(k_pool).shape[3]
             assert Hkv_g % tp == 0, (Hkv_g, tp)
             pool_s = P(None, None, None, "tp", None) \
@@ -521,13 +546,13 @@ def ragged_decode_partial(q, k_pool, v_pool, block_table, lengths, *,
                 inner = lambda q_, k_, v_, t_, l_, ks_, vs_: \
                     ragged_decode_partial(q_, k_, v_, t_, l_, layer=layer,
                                           ks_pool=ks_, vs_pool=vs_)
-            fn = _shard_map(
-                inner, mesh,
+            fn = jax.shard_map(
+                inner, mesh=mesh,
                 in_specs=(P(None, "tp", None), pool_s, pool_s, P(), P())
                 + ((scale_s, scale_s) if ks_pool is not None else ()),
                 out_specs=(P(None, "tp", None, None), P(None, "tp", None),
                            P(None, "tp", None)),
-                axis_names=("tp",))
+                axis_names={"tp"}, check_vma=False)
             args = (q, k_pool, v_pool, block_table, lengths)
             if ks_pool is not None:
                 args += (ks_pool, vs_pool)

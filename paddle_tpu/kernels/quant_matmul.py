@@ -16,22 +16,15 @@ per-entry scale folded into the score, and the V scale is folded into the
 softmax probabilities BEFORE the PV contraction (the scale depends on the
 contracted position axis, so it must ride the probabilities, not the
 output).
-
-Older jax releases reject mixed-dtype dots; ``mixed_dot_supported()``
-probes once (shape-level, no compile) and every helper falls back to an
-inline dequant-then-dot that still skips the per-channel multiply on the
-weight (scales stay on the output) — slower, never wrong.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 
 __all__ = [
     "weight_only_matmul", "quantize_kv", "dequantize_kv",
-    "attn_qk", "attn_pv", "mixed_dot_supported",
+    "attn_qk", "attn_pv",
     "quantize_grouped", "is_quantized_weight", "dequantize_channels",
 ]
 
@@ -46,22 +39,6 @@ def dequantize_channels(q, scale, axis: int):
     measure against (observability.numerics.record_quant_error)."""
     return (q.astype(jnp.float32)
             * jnp.expand_dims(scale.astype(jnp.float32), axis))
-
-
-@functools.lru_cache(maxsize=1)
-def mixed_dot_supported() -> bool:
-    """True when this jax accepts a bf16 x int8 dot_general (type-level
-    probe via eval_shape — no device, no compile)."""
-    try:
-        jax.eval_shape(
-            lambda a, b: jax.lax.dot_general(
-                a, b, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32),
-            jax.ShapeDtypeStruct((2, 2), jnp.bfloat16),
-            jax.ShapeDtypeStruct((2, 2), jnp.int8))
-        return True
-    except Exception:
-        return False
 
 
 def _is_quantized(w) -> bool:
@@ -119,12 +96,7 @@ def weight_only_matmul(x, w, out_dtype):
         return x @ w.astype(out_dtype)
     q, s = w["q"], w["s"]
     dn = (((x.ndim - 1,), (0,)), ((), ()))
-    if mixed_dot_supported():
-        y = jax.lax.dot_general(x, q, dn,
-                                preferred_element_type=jnp.float32)
-    else:  # old jax: inline convert (XLA fuses it into the matmul read)
-        y = jax.lax.dot_general(x, q.astype(x.dtype), dn,
-                                preferred_element_type=jnp.float32)
+    y = jax.lax.dot_general(x, q, dn, preferred_element_type=jnp.float32)
     return (y * s.astype(jnp.float32)).astype(out_dtype)
 
 
@@ -166,8 +138,6 @@ def attn_qk(qg, kd, ks=None):
     """QK^T scores [N, Hkv, G, P] in f32. int8 K contracts directly; the
     per-entry scale multiplies the f32 score (it is constant over the
     contracted D axis, so it commutes out of the dot)."""
-    if kd.dtype == jnp.int8 and not mixed_dot_supported():
-        kd, ks = dequantize_kv(kd, ks, qg.dtype), None
     s = jax.lax.dot_general(qg, kd, _QK_DN,
                             preferred_element_type=jnp.float32)
     if ks is not None:
@@ -180,8 +150,6 @@ def attn_pv(p, vd, vs=None, *, out_dtype):
     probabilities [N, Hkv, G, P]. The V scale varies along the CONTRACTED
     P axis, so it is folded into the probabilities (a tensor that already
     exists at this size) and the int8 V feeds the dot unconverted."""
-    if vd.dtype == jnp.int8 and not mixed_dot_supported():
-        vd, vs = dequantize_kv(vd, vs, out_dtype), None
     if vs is not None:
         p = p * jnp.transpose(vs, (0, 2, 1))[:, :, None, :]
         out = jax.lax.dot_general(p, vd, _PV_DN,

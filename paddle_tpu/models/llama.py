@@ -429,23 +429,45 @@ def _apply_rope_at(x, cos, sin):
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
 
 
-def _attention(q, k, v, config: LlamaConfig):
+def _flash_attention(q, k, v, mesh: Optional[Mesh]):
+    """The Pallas flash kernel (GQA-native: no repeated K/V materialized),
+    run PER SHARD under a multi-device mesh: GSPMD cannot partition a
+    Mosaic kernel ("wrap the call in a shard_map"), so the call shard_maps
+    itself — batch over 'dp', heads over 'tp' (Megatron's column-parallel
+    qkv leaves them sharded that way, and contiguous head shards keep each
+    query head with its kv head while both head counts divide 'tp').
+    Attention mixes neither batch rows nor heads, so no collective is
+    added; any other mesh axis sees the inputs replicated."""
+    from ..kernels.pallas_attention import flash_attention_fwd
+    flash = functools.partial(flash_attention_fwd, causal=True)
+    if mesh is None or mesh.size == 1:
+        return flash(q, k, v)
+    sizes = dict(mesh.shape)
+    dp, tp = sizes.get("dp", 1), sizes.get("tp", 1)
+    batch = "dp" if dp > 1 and q.shape[0] % dp == 0 else None
+    heads = ("tp" if tp > 1 and q.shape[2] % tp == 0
+             and k.shape[2] % tp == 0 else None)
+    spec = P(batch, None, heads, None)
+    return jax.shard_map(flash, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
+
+
+def _attention(q, k, v, config: LlamaConfig, mesh: Optional[Mesh] = None):
     """Causal GQA attention. [B, S, H, D] layout. Uses the Pallas flash
-    kernel on TPU when shapes allow (kernels/pallas_attention.py — the
+    kernel when shapes allow (kernels/pallas_attention.py — the
     replacement for the reference's third_party/flashattn), else fused-XLA
-    reference math."""
+    reference math. Under a multi-device mesh the kernel needs the mesh
+    at trace time: ``mesh`` (the serving engine passes its own), else
+    the ambient :class:`activation_mesh` (the trainer's)."""
     B, S, H, D = q.shape
     groups = H // k.shape[2]
-    mesh = _ACT_MESH
+    if mesh is None:
+        mesh = _ACT_MESH
     use_ring = (config.context_parallel and mesh is not None
                 and dict(mesh.shape).get("sp", 1) > 1)
     if (not use_ring and config.use_flash and S >= 128 and D % 128 == 0):
-        try:
-            from ..kernels.pallas_attention import flash_attention_fwd
-            # GQA-native kernel: no repeated K/V materialized
-            return flash_attention_fwd(q, k, v, causal=True)
-        except Exception:
-            pass
+        # selected by shape; a kernel that then fails to build is an error
+        return _flash_attention(q, k, v, mesh)
     if use_ring:
         # GQA-native ring: unrepeated K/V blocks ride the ICI ring
         from ..kernels.ring_attention import ring_attention_sharded
@@ -1040,8 +1062,7 @@ def generate_fused(params, prompt_tokens, config: LlamaConfig,
                    eos_token_id=None, top_k: int = 0, top_p: float = 1.0):
     """Whole generation as ONE compiled program: prefill + a
     ``lax.while_loop`` decode with on-device sampling and EOS early exit.
-    The python-loop ``generate`` pays a host->device dispatch per token,
-    which dominates decode latency on remote-attached TPUs (~30x at 2.6B);
+    The python-loop ``generate`` pays a host->device dispatch per token;
     this is the analogue of the reference's fused block-decode path
     (block_multihead_attention + top_p_sampling ops in one graph).
     Same output contract as ``generate``; sampling VALUES (temperature /

@@ -68,12 +68,12 @@ CATALOG = {
                      "sampling-flags) set — test-enforced)"),
     "serving_mega_fallback_total": (
         "counter", ("reason",),
-        "decode dispatches that wanted the mega megakernel but fell "
-        "back to the ragged walk (vmem = the kernel's scratch envelope "
+        "decode dispatches that asked for the mega megakernel but took "
+        "the bucketed path (vmem = the kernel's scratch envelope "
         "exceeds the ~12 MiB budget, mixed_weights = partially "
-        "quantized layer stack, mesh = tp-sharded serving runs the "
-        "shard_mapped ragged walk instead; draft_* = the speculative "
-        "draft's own screen) — the fallback is counted, never silent"),
+        "quantized layer stack, mesh = one fused launch cannot be "
+        "tp-partitioned; draft_* = the speculative draft's own screen) "
+        "— the fallback is counted, never silent"),
     # -- serving speculative decoding (r13, draft-then-verify waves) -------
     "serving_spec_proposed_total": (
         "counter", (), "draft tokens proposed to the target's batched "
@@ -356,9 +356,8 @@ CATALOG = {
     "moe_gmm_fused_dispatch_total": (
         "counter", ("path",),
         "fused-dispatch entries by implementation path (pallas = "
-        "gather-fused TPU kernel, xla = portable scatter-free rewrite, "
-        "xla_fallback = kernel failed to build and the rewrite "
-        "answered)"),
+        "gather-fused TPU kernel, xla = portable scatter-free "
+        "rewrite)"),
     "moe_overlap_bypass_total": (
         "counter", (),
         "expert-parallel overlap bypasses: per-rank token slices below "

@@ -6,9 +6,10 @@ one-off benchmark:
 - **MFU** — FLOPs one call executes come from XLA's cost analysis of the
   LOWERED program (:func:`flops_of`; no second compile — ``lower()`` is
   a trace), divided by measured step time x the per-device-kind peak
-  from :data:`DEVICE_SPECS`. CPU reports against a nominal 1 TFLOP/s
-  peak so MFU stays defined on the CPU lane (same convention as
-  bench.py, which reuses this table).
+  from :data:`DEVICE_SPECS` (bench.py reuses this table). Off an
+  accelerator MFU is undefined: :func:`mfu` returns ``None`` on the CPU
+  and the gauges stay unset. A device kind the table does not list is
+  an error, never another chip's peak.
 - **HBM** — ``hbm_used_bytes`` / ``hbm_peak_bytes`` gauges from PJRT
   ``memory_stats()`` (:func:`update_hbm_gauges`); silently absent where
   the backend exposes none (CPU).
@@ -51,16 +52,20 @@ _M_HBM_PEAK = _instrument("hbm_peak_bytes")
 _M_SLO_TTFT = _instrument("serving_slo_ttft_attainment")
 _M_SLO_TPOT = _instrument("serving_slo_tpot_attainment")
 
-# per-device-kind spec sheet: bf16 peak FLOP/s, HBM bytes, HBM B/s —
-# matched by substring against jax's device_kind (moved here from
-# bench.py so serving/training MFU and the benchmark share one table)
+# per-chip published peaks: bf16 FLOP/s, HBM bytes, HBM bytes/s — matched
+# by substring against jax's device_kind; serving/training MFU and the
+# benchmark share this one table. Source: Google Cloud TPU documentation,
+# the "System architecture" page of each version ("TPU v4", "TPU v5p",
+# "TPU v5e", "TPU v6e"): 275 / 459 / 197 / 918 TFLOP/s bf16, 32 / 95 / 16 /
+# 32 GB of HBM at 1,200 / 2,765 / 819 / 1,640 GB/s.
 DEVICE_SPECS: Dict[str, Tuple[float, float, float]] = {
     #             flops    hbm    hbm B/s
     "v4":        (275e12, 32e9, 1.20e12),
     "v5p":       (459e12, 95e9, 2.77e12),
     "v5e":       (197e12, 16e9, 8.19e11),
-    "v5 lite":   (197e12, 16e9, 8.19e11),
+    "v5 lite":   (197e12, 16e9, 8.19e11),     # what a v5e chip reports
     "v6e":       (918e12, 32e9, 1.64e12),
+    "v6 lite":   (918e12, 32e9, 1.64e12),     # what a v6e chip reports
     "trillium":  (918e12, 32e9, 1.64e12),
 }
 
@@ -68,69 +73,50 @@ DEVICE_SPECS: Dict[str, Tuple[float, float, float]] = {
 def _device(device=None):
     if device is not None:
         return device
-    try:
-        import jax
+    import jax
 
-        return jax.devices()[0]
-    except Exception:
-        return None
+    return jax.devices()[0]
 
 
-def _lookup(dev, idx: int, default: float) -> float:
-    kind = (getattr(dev, "device_kind", "") or "").lower()
+def _spec(device, idx: int) -> float:
+    kind = (getattr(_device(device), "device_kind", "") or "").lower()
     for key, vals in DEVICE_SPECS.items():
         if key in kind:
             return vals[idx]
-    return default
+    raise ValueError(
+        f"device kind {kind!r} is not in observability.perf.DEVICE_SPECS: "
+        "add its published peaks and their source — a roofline against "
+        "another chip's peak is a wrong number, not an estimate")
 
 
 def peak_flops(device=None) -> float:
-    """bf16 peak FLOP/s of ``device`` (default: device 0). Unknown TPU
-    kinds assume v5p-class; CPU gets a nominal 1 TFLOP/s so MFU is
-    defined everywhere."""
-    dev = _device(device)
-    if dev is not None and getattr(dev, "platform", None) == "cpu":
-        return 1e12
-    return _lookup(dev, 0, 459e12)
+    """bf16 peak FLOP/s of ``device`` (default: device 0); raises
+    ``ValueError`` for a device kind the table does not list (the CPU
+    included — see :func:`mfu`)."""
+    return _spec(device, 0)
 
 
 def hbm_bytes(device=None) -> float:
-    return _lookup(_device(device), 1, 95e9)
+    return _spec(device, 1)
 
 
 def hbm_bandwidth(device=None) -> float:
-    return _lookup(_device(device), 2, 8.19e11)
+    return _spec(device, 2)
 
 
-def flops_of(fn, *args, allow_compile: bool = True, **kwargs
-             ) -> Optional[float]:
+def flops_of(fn, *args, **kwargs) -> Optional[float]:
     """FLOPs one ``fn(*args)`` call executes, from XLA cost analysis of
     the lowered program. ``fn`` may be a plain jittable or an existing
     ``jax.jit`` object (its AOT ``lower`` is reused — donation marks and
-    static partials survive). Lowering is a trace, not a compile; the
+    static partials survive). Lowering is a trace, never a compile; the
     caller should cache the result per executable (the train loop caches
-    per run, the serving engine per decode variant). On jax versions
-    whose pre-compile analysis is empty the fallback compiles the
-    program — pass ``allow_compile=False`` on hot paths where the same
-    program is about to compile anyway (the serving engine), trading a
-    possibly-missing MFU for never compiling twice. Returns ``None``
+    per run, the serving engine per decode variant). Returns ``None``
     when the fn doesn't trace or the backend offers no analysis."""
     try:
         import jax
 
         jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
-        lowered = jitted.lower(*args, **kwargs)
-        ca = None
-        try:
-            ca = lowered.cost_analysis()
-        except Exception:
-            pass
-        if not ca:                       # older jax: analysis post-compile
-            if not allow_compile:
-                return None
-            ca = lowered.compile().cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
+        ca = jitted.lower(*args, **kwargs).cost_analysis()
         f = float(ca.get("flops", -1.0)) if ca else -1.0
         return f if f > 0 else None
     except Exception:
@@ -139,13 +125,14 @@ def flops_of(fn, *args, allow_compile: bool = True, **kwargs
 
 def mfu(flops_per_step: Optional[float], step_seconds: float,
         device=None) -> Optional[float]:
-    """Model FLOP utilization: cost-model FLOPs / (wall x peak)."""
+    """Model FLOP utilization: cost-model FLOPs / (wall x peak).
+    ``None`` on the CPU, where no peak is defined."""
     if not flops_per_step or not step_seconds or step_seconds <= 0:
         return None
-    peak = peak_flops(device)
-    if peak <= 0:
+    dev = _device(device)
+    if getattr(dev, "platform", None) == "cpu":
         return None
-    return float(flops_per_step) / (float(step_seconds) * peak)
+    return float(flops_per_step) / (float(step_seconds) * peak_flops(dev))
 
 
 def token_count(batch) -> int:

@@ -12,11 +12,9 @@ memory hierarchy just as well. This module is the transfer engine that
 makes the host pool a true SECOND TIER of the paged block pool:
 
 - **Async spill (d2h).** A swap-out or a proactive cold-block spill
-  dispatches a non-blocking device→host copy (a ``pinned_host``
-  ``device_put`` where the backend has memory kinds — TPU — else
-  ``copy_to_host_async``, else nothing: the landing ``np.asarray``
-  blocks briefly, the version-shimmed fallback, same spirit as
-  moe_dispatch's ``_shard_map`` shim). The spilled blocks stay
+  dispatches a non-blocking device→host copy (a ``device_put`` into
+  the array's own sharding with ``memory_kind="pinned_host"``). The
+  spilled blocks stay
   device-resident and ACCOUNTED until the transfer lands: swap-out
   victims park their private blocks in this engine's custody (the
   ledger's transient ``in_flight`` term), proactively spilled cache
@@ -96,23 +94,11 @@ _M_PROACTIVE = _instrument("serving_kv_offload_proactive_spills_total")
 
 
 def _start_d2h(arr):
-    """Begin moving one device array to the host without blocking —
-    version-shimmed like moe_dispatch's ``_shard_map``: a
-    ``pinned_host`` ``device_put`` where the backend exposes memory
-    kinds (TPU), else ``copy_to_host_async`` (jax 0.4.x), else nothing
-    (the landing ``np.asarray`` then blocks briefly — the sync
-    fallback). Returns the array whose readiness marks the landing."""
-    try:
-        dev = next(iter(arr.devices()))
-        out = jax.device_put(arr, dev.memory("pinned_host"))
-        return out
-    except Exception:
-        pass
-    try:
-        arr.copy_to_host_async()
-    except Exception:
-        pass
-    return arr
+    """Begin moving one device array to the host without blocking: a
+    ``device_put`` into the same sharding in ``pinned_host`` memory (a
+    tp-sharded pool slice stays sharded the same way). Returns the new
+    host-memory array, whose readiness marks the landing."""
+    return jax.device_put(arr, arr.sharding.with_memory_kind("pinned_host"))
 
 
 def _is_ready(arr) -> bool:

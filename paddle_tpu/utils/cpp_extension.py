@@ -17,8 +17,10 @@ __all__ = ["load", "get_build_directory", "CppExtension", "CUDAExtension",
 
 
 def get_build_directory() -> str:
+    from ..framework.cache_dirs import ARTIFACT_DIR
+
     d = os.environ.get("PADDLE_EXTENSION_DIR",
-                       os.path.expanduser("~/.cache/paddle_tpu_extensions"))
+                       os.path.join(ARTIFACT_DIR, "extensions"))
     os.makedirs(d, exist_ok=True)
     return d
 

@@ -47,13 +47,16 @@ class FakeImageDataset(Dataset):
 class MNIST(Dataset):
     """parity: python/paddle/vision/datasets/mnist.py. Reads the standard IDX
     files from ``image_path``/``label_path`` if given or found under
-    ~/.cache/paddle_tpu/mnist; otherwise synthesizes MNIST-shaped data."""
+    ``<cache_dir>/mnist`` (:func:`paddle_tpu.jit.cache.cache_dir`);
+    otherwise synthesizes MNIST-shaped data."""
 
     def __init__(self, image_path=None, label_path=None, mode="train",
                  transform=None, download=True, backend=None):
         self.mode = mode
         self.transform = transform
-        base = os.path.expanduser("~/.cache/paddle_tpu/mnist")
+        from ...jit.cache import cache_dir
+
+        base = os.path.join(cache_dir(), "mnist")
         tag = "train" if mode == "train" else "t10k"
         image_path = image_path or os.path.join(base, f"{tag}-images-idx3-ubyte.gz")
         label_path = label_path or os.path.join(base, f"{tag}-labels-idx1-ubyte.gz")

@@ -1,0 +1,214 @@
+"""Compile the main path's Pallas kernels for a DESCRIBED TPU v5e.
+
+The sandbox has no accelerator, but the TPU compiler is installed and
+compiles for a topology that is described and not attached
+(``jax.experimental.topologies``). Interpret-mode tests cannot see what
+Mosaic refuses — a slice not aligned to the tiling, a block shape the
+lowering rejects, a removed Pallas name — so every kernel that ``auto``
+selection can reach on a TPU is compiled here at the widths
+``chip_smoke.py`` runs (Llama-3-8B: 32 heads / 8 KV heads, head_dim 128,
+hidden 4096, FFN 14336, KV block 16). Nothing executes: a pass says the
+chip's compiler accepts the kernel, never that a result or a time is
+right.
+
+The kernels pick interpret mode from ``jax.default_backend()``, which is
+the CPU here, so the tests steer ``_interpret`` themselves. Kernels
+withdrawn from selection keep their compile as ``xfail(strict=True)``
+with the compiler's message: the day Mosaic accepts one, the strict
+xfail fails and the withdrawal can be undone.
+"""
+import functools
+import importlib
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.models import llama
+
+# import_module: kernels/__init__ re-exports a FUNCTION named
+# paged_attention, which shadows the module on attribute access
+_mod = lambda name: importlib.import_module("paddle_tpu.kernels." + name)
+mega_decode, moe_fused = _mod("mega_decode"), _mod("moe_fused")
+paged_attention = _mod("paged_attention")
+pallas_attention = _mod("pallas_attention")
+
+# Llama-3-8B widths (models/llama.py LlamaConfig defaults), depth cut
+HQ, HKV, D, HID, FFN = 32, 8, 128, 4096, 14336
+BS, NB, LAYERS = 16, 1024, 2
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:       # no TPU compiler in this installation
+        pytest.skip(f"cannot describe v5e:2x2: {e}")
+
+
+@pytest.fixture(autouse=True)
+def _chip_lowering(monkeypatch):
+    """Mosaic lowering instead of the interpreter, and the persistent
+    compile cache off: a described-topology executable is written to the
+    cache but cannot be read back without a chip."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    for mod in (pallas_attention, paged_attention, mega_decode):
+        monkeypatch.setattr(mod, "_interpret", lambda: False)
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+class Refused(Exception):
+    """The chip's compiler refused a kernel with the recorded message."""
+
+
+def refused(message):
+    """Strict xfail for a kernel withdrawn from selection, held to the
+    compiler's message kept beside the kernel: the test errors if the
+    compile fails with anything else, and fails (XPASS) the day it
+    succeeds — the withdrawal can then be undone."""
+    def deco(test):
+        @functools.wraps(test)
+        def run(*a, **kw):
+            try:
+                test(*a, **kw)
+            except Exception as e:
+                if message not in str(e):
+                    raise
+                raise Refused(message) from e
+        return pytest.mark.xfail(strict=True, raises=Refused,
+                                 reason=message)(run)
+    return deco
+
+
+def _compile(fn, sharding, *specs):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in specs]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _one(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_flash_attention_fwd_bwd(topo):
+    def loss(q, k, v):
+        out = pallas_attention.flash_attention_fwd(q, k, v, causal=True)
+        return out.astype(jnp.float32).sum()
+
+    c = _compile(jax.grad(loss, argnums=(0, 1, 2)), _one(topo),
+                 ((2, 2048, HQ, D), BF16), ((2, 2048, HKV, D), BF16),
+                 ((2, 2048, HKV, D), BF16))
+    # forward (rebuilt for the residuals), dQ, dK/dV
+    assert c.as_text().count("tpu_custom_call") >= 3
+
+
+def test_paged_append_token_and_blocks(topo):
+    pool = ((LAYERS, NB, BS, HKV, D), BF16)
+    _compile(lambda kp, vp, kn, vn, b, o: paged_attention.paged_append_token(
+                 kp, vp, kn, vn, b, o, layer=1),
+             _one(topo), pool, pool, ((8, HKV, D), BF16), ((8, HKV, D), BF16),
+             ((8,), jnp.int32), ((8,), jnp.int32))
+    _compile(lambda kp, vp, kb, vb, ids: paged_attention.paged_append_blocks(
+                 kp, vp, kb, vb, ids, layer=1),
+             _one(topo), pool, pool, ((4, BS, HKV, D), BF16),
+             ((4, BS, HKV, D), BF16), ((4,), jnp.int32))
+
+
+def _ragged_specs(n, kv_dtype, d=D):
+    specs = [((n, HQ, d), BF16),
+             ((LAYERS, NB, BS, HKV, d), kv_dtype),
+             ((LAYERS, NB, BS, HKV, d), kv_dtype),
+             ((n, 128), jnp.int32), ((n,), jnp.int32)]
+    if kv_dtype == jnp.int8:
+        specs += [((LAYERS, NB, BS, HKV), jnp.float32)] * 2
+    return specs
+
+
+def _ragged(q, kp, vp, tbl, lens, ks=None, vs=None, mesh=None):
+    return paged_attention.ragged_decode_partial(
+        q, kp, vp, tbl, lens, layer=1, ks_pool=ks, vs_pool=vs, mesh=mesh)
+
+
+def test_ragged_walk_bf16(topo):
+    _compile(_ragged, _one(topo), *_ragged_specs(8, BF16))
+
+
+@refused(paged_attention.ragged_tpu_refusal(D, kv_int8=True))
+def test_ragged_walk_int8_kv(topo):
+    _compile(_ragged, _one(topo), *_ragged_specs(8, jnp.int8))
+
+
+@refused(paged_attention.ragged_tpu_refusal(64, kv_int8=False))
+def test_ragged_walk_head_dim_64(topo):
+    """Found on the chip, not by the planner: the walk compiles only for
+    head dims that fill the 128-lane tile, so the engine's auto selects it
+    by that shape."""
+    _compile(_ragged, _one(topo), *_ragged_specs(8, BF16, d=64))
+
+
+def test_ragged_walk_tp2(topo):
+    """The shard_mapped walk on two described devices: pools sharded on
+    the KV-head axis, tables and lengths replicated."""
+    mesh = Mesh(np.asarray(topo.devices[:2]), ("tp",))
+    specs = _ragged_specs(8, BF16)
+    shard = [P(None, "tp", None), P(None, None, None, "tp", None),
+             P(None, None, None, "tp", None), P(), P()]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=NamedSharding(mesh, p))
+            for (s, d), p in zip(specs, shard)]
+    compiled = jax.jit(
+        lambda *a: _ragged(*a, mesh=mesh)).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _mega_step(params, x0, tbl, wl, lens, rk, rv, kp, vp):
+    cfg = llama.LlamaConfig(num_layers=LAYERS)
+    return mega_decode.mega_decode_step(
+        params, cfg, x0=x0, t=0, block_table=tbl, walk_lens=wl, lens=lens,
+        ring_k=rk, ring_v=rv, k_pool=kp, v_pool=vp)
+
+
+@refused(mega_decode.MEGA_TPU_REFUSAL)
+def test_mega_decode_step(topo):
+    n = 4
+    cfg = llama.LlamaConfig(num_layers=LAYERS)
+    one = _one(topo)
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, BF16, sharding=one),
+        llama._abstract_params(cfg))
+    ok, why = mega_decode.mega_supported(
+        params, cfg, n_slots=n, n_steps=1, block_size=BS, kv_int8=False)
+    assert ok, why
+    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one)
+    ring = sds((LAYERS, n, 1, HKV, D), BF16)
+    pool = sds((LAYERS, NB, BS, HKV, D), BF16)
+    compiled = jax.jit(_mega_step).lower(
+        params, sds((n, HID), BF16), sds((n, 128), jnp.int32),
+        sds((n,), jnp.int32), sds((n,), jnp.int32), ring, ring, pool,
+        pool).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@refused(moe_fused.GATHER_GMM_TPU_REFUSAL)
+def test_moe_gather_gmm(topo):
+    tm = moe_fused._KTM
+    _compile(lambda x, idx, rhs, gid: moe_fused.gather_gmm(
+                 x, idx, rhs, gid, tm=tm),
+             _one(topo), ((4096, 2048), BF16), ((64 * tm,), jnp.int32),
+             ((64, 2048, 2048), BF16), ((64,), jnp.int32))

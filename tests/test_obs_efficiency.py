@@ -234,10 +234,12 @@ def test_device_specs_lookup():
     assert perf.peak_flops(_Dev("TPU v5 lite")) == 197e12
     assert perf.hbm_bytes(_Dev("TPU v4")) == 32e9
     assert perf.hbm_bandwidth(_Dev("tpu v5p")) == 2.77e12
-    # CPU: nominal 1 TFLOP/s so MFU stays defined on the CPU lane
-    assert perf.peak_flops(_Dev("cpu", platform="cpu")) == 1e12
-    # unknown TPU kind assumes v5p-class
-    assert perf.peak_flops(_Dev("TPU v9000")) == 459e12
+    # a kind the table does not list is an error, never another
+    # chip's peak — the CPU included
+    for unknown in (_Dev("cpu", platform="cpu"), _Dev("TPU v9000")):
+        for fn in (perf.peak_flops, perf.hbm_bytes, perf.hbm_bandwidth):
+            with pytest.raises(ValueError, match="DEVICE_SPECS"):
+                fn(unknown)
 
 
 def test_mfu_math():
@@ -246,6 +248,8 @@ def test_mfu_math():
     assert perf.mfu(197e12 / 2, 1.0, dev) == pytest.approx(0.5)
     assert perf.mfu(None, 1.0, dev) is None
     assert perf.mfu(1e12, 0.0, dev) is None
+    # no peak is defined for the CPU: MFU is undefined there
+    assert perf.mfu(1e12, 1.0, _Dev("cpu", platform="cpu")) is None
 
 
 def test_flops_of_jitted_matmul():
@@ -414,7 +418,10 @@ def test_train_loop_efficiency_gauges(tmp_path, obs_on):
                               rng_key=None)
     loop.run(4)
     reg = obs.get_registry()
-    assert reg.gauge("train_mfu").labels().value > 0
+    # the step's FLOPs come from the lowered program; on the CPU they
+    # divide by no peak, so the MFU gauge stays unset
+    assert loop._flops and loop._flops > 0
+    assert reg.gauge("train_mfu").labels().value == 0
     # 2x8 int32 ids per batch -> 16 tokens
     assert loop.tokens_per_batch == 16
     assert reg.gauge("train_tokens_per_second").labels().value > 0

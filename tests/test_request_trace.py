@@ -530,10 +530,35 @@ def _run_bench_diff(*argv):
         cwd=REPO)
 
 
-def test_bench_diff_flags_moe_regression_on_real_r04_r05():
+_TPS = "_pretrain_tokens_per_sec_per_chip"
+
+
+def _write_moe_rounds(tmp_path):
+    """Rounds 3-5 as the driver recorded them, written into ``tmp_path``
+    (the r03 record itself is deleted): a usable round, a failed one
+    (no parsed metrics), then one whose MoE row is 7.3% down."""
+    def round_(n, rc, values):
+        doc = {"n": n, "rc": rc, "parsed": values and {"metrics": [
+            {"metric": m + _TPS, "value": v, "unit": "tokens/s"}
+            for m, v in values.items()]}}
+        path = tmp_path / f"BENCH_r{n:02d}.json"
+        path.write_text(json.dumps(doc))
+        return str(path)
+
+    return (round_(3, 0, {"llama-5.2b-layerwise": 3594.0,
+                          "llama-2.6b@8k": 5472.5,
+                          "moe-dropless": 18268.5}),
+            round_(4, 1, None),
+            round_(5, 0, {"llama-5.2b-layerwise": 3595.5,
+                          "llama-2.6b@8k": 5450.5,
+                          "moe-dropless": 16937.6}))
+
+
+def test_bench_diff_flags_moe_regression_across_failed_round(tmp_path):
     """The sentinel that would have caught MoE 0.92x at r05: r04 failed
     (no parsed metrics), so it anchors on r03 and flags the -7.3%."""
-    proc = _run_bench_diff("BENCH_r04.json", "BENCH_r05.json")
+    _r03, r04, r05 = _write_moe_rounds(tmp_path)
+    proc = _run_bench_diff(r04, r05)
     out = proc.stdout.decode()
     assert proc.returncode == 1, out
     assert "moe-dropless_pretrain" in out
@@ -541,8 +566,9 @@ def test_bench_diff_flags_moe_regression_on_real_r04_r05():
     assert "BENCH_r03.json" in out            # the walk-back is explicit
 
 
-def test_bench_diff_auto_mode_latest_pair():
-    proc = _run_bench_diff("--dir", REPO)
+def test_bench_diff_auto_mode_latest_pair(tmp_path):
+    _write_moe_rounds(tmp_path)
+    proc = _run_bench_diff("--dir", str(tmp_path))
     out = proc.stdout.decode()
     assert proc.returncode == 1, out           # latest pair is r04/r05
     assert "moe-dropless_pretrain" in out
@@ -591,11 +617,12 @@ def test_bench_diff_check_next_committed_round_is_armed():
             or "first usable round" in out), out
 
 
-def test_bench_diff_check_flags_the_real_r05_regression():
-    """--check on the committed r05 anchors on the newest earlier
-    usable round and flags the MoE regression — proof the armed mode
-    actually bites once the round exists."""
-    proc = _run_bench_diff("--check", os.path.join(REPO, "BENCH_r05.json"))
+def test_bench_diff_check_flags_the_r05_regression(tmp_path):
+    """--check on r05 anchors on the newest earlier usable round and
+    flags the MoE regression — proof the armed mode actually bites once
+    the round exists."""
+    _r03, _r04, r05 = _write_moe_rounds(tmp_path)
+    proc = _run_bench_diff("--check", r05)
     out = proc.stdout.decode()
     assert proc.returncode == 1, out
     assert "moe-dropless_pretrain" in out and "REGRESSION" in out

@@ -16,12 +16,22 @@ import time
 import numpy as np
 import pytest
 
-pytestmark = pytest.mark.skipif(
-    os.environ.get("PADDLE_TPU_DEVICE_TESTS") != "1",
-    reason="real-device lane: set PADDLE_TPU_DEVICE_TESTS=1")
+import jax
+import jax.numpy as jnp
 
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
+from paddle_tpu.kernels.mega_decode import MEGA_TPU_REFUSAL
+
+# PR 21 withdrew the kernel from selection on a TPU: Mosaic refuses it
+# (tests/test_aot_chip_compile.py keeps the compile), so asking for it by
+# name raises and auto no longer picks it. Strict: the day the kernel
+# compiles and is selected again, these fail until the marks go.
+pytestmark = [
+    pytest.mark.skipif(
+        os.environ.get("PADDLE_TPU_DEVICE_TESTS") != "1",
+        reason="real-device lane: set PADDLE_TPU_DEVICE_TESTS=1"),
+    pytest.mark.xfail(strict=True, reason=MEGA_TPU_REFUSAL,
+                      raises=(NotImplementedError, AssertionError)),
+]
 
 
 @pytest.fixture(scope="module")

@@ -26,6 +26,23 @@ pytestmark = pytest.mark.skipif(
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
+from paddle_tpu.kernels.moe_fused import GATHER_GMM_TPU_REFUSAL  # noqa: E402
+
+# PR 21 withdrew the gather-GMM kernel from selection (FLAGS_moe_fused_kernel
+# now defaults off): Mosaic refuses its one-row gather DMA
+# (tests/test_aot_chip_compile.py keeps the compile). The tests of the
+# kernel turn the flag on and are strict xfails with the compiler's message.
+_refused = pytest.mark.xfail(strict=True, reason=GATHER_GMM_TPU_REFUSAL)
+
+
+@pytest.fixture
+def kernel_selected():
+    from paddle_tpu.framework.flags import set_flags
+
+    set_flags({"moe_fused_kernel": True})
+    yield
+    set_flags({"moe_fused_kernel": False})
+
 
 def _operands(T=2048, h=512, E=8, f=256, k=2, seed=0):
     from paddle_tpu.kernels import moe_dispatch as md
@@ -36,6 +53,7 @@ def _operands(T=2048, h=512, E=8, f=256, k=2, seed=0):
     return x, r, eg, eu, ed
 
 
+@_refused
 def test_gather_gmm_kernel_matches_take_plus_gmm_on_chip():
     from paddle_tpu.kernels import moe_dispatch as md
     from paddle_tpu.kernels import moe_fused as mf
@@ -64,7 +82,8 @@ def test_gather_gmm_kernel_matches_take_plus_gmm_on_chip():
     assert err.max() < 5e-2 * max(np.abs(ref).max(), 1.0)
 
 
-def test_fused_pallas_path_matches_xla_and_gmm_on_chip():
+@_refused
+def test_fused_pallas_path_matches_xla_and_gmm_on_chip(kernel_selected):
     import paddle_tpu.observability as obs
     from paddle_tpu.framework.flags import set_flags
     from paddle_tpu.kernels import moe_dispatch as md
@@ -112,7 +131,8 @@ def test_fused_pallas_path_matches_xla_and_gmm_on_chip():
         assert np.abs(p - q).max() < 5e-2 * max(np.abs(q).max(), 1e-3), name
 
 
-def test_int8_experts_through_kernel_on_chip():
+@_refused
+def test_int8_experts_through_kernel_on_chip(kernel_selected):
     from paddle_tpu.kernels import moe_fused as mf
     from paddle_tpu.kernels.quant_matmul import quantize_grouped
 

@@ -23,6 +23,9 @@ pytestmark = pytest.mark.skipif(
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
+from paddle_tpu.kernels.paged_attention import (  # noqa: E402
+    RAGGED_INT8_KV_TPU_REFUSAL)
+
 
 def _mk(rng, n, bs, hkv, g, d, mb, dtype, lens):
     from paddle_tpu.kernels.paged_attention import PagedKVCache
@@ -51,6 +54,9 @@ def test_ragged_kernel_matches_xla_oracle_on_chip():
     np.testing.assert_allclose(got, want, atol=5e-2, rtol=5e-2)
 
 
+# withdrawn from selection in PR 21: Mosaic refuses the int8 walk
+# (tests/test_aot_chip_compile.py keeps the compile)
+@pytest.mark.xfail(strict=True, reason=RAGGED_INT8_KV_TPU_REFUSAL)
 def test_ragged_kernel_int8_on_chip():
     """int8 pools: blocks stream unconverted, scales fold in-register —
     vs the dequantize-then-attend oracle."""
@@ -87,6 +93,10 @@ def model():
     return params, cfg
 
 
+# the acceptance below is the int8-KV engine's; with that walk withdrawn
+# auto no longer picks ragged for it, which is the first thing it asserts
+@pytest.mark.xfail(strict=True, reason=RAGGED_INT8_KV_TPU_REFUSAL,
+                   raises=AssertionError)
 def test_engine_ragged_one_variant_and_stream_parity_on_chip(model):
     """Acceptance: on TPU the default path IS ragged, greedy streams
     match the bucketed path, the compile cache holds exactly one

@@ -7,7 +7,7 @@ to remember last round's numbers. This tool is that diff:
 
     python tools/bench_diff.py                  # two latest rounds in .
     python tools/bench_diff.py --dir /path      # ... in /path
-    python tools/bench_diff.py BENCH_r04.json BENCH_r05.json
+    python tools/bench_diff.py rounds/BENCH_r04.json rounds/BENCH_r05.json
 
 Per-metric relative delta against a configurable noise band (default
 ±3%); any regression beyond the band prints a human table and exits

@@ -104,10 +104,10 @@ def run_bisect(cfg, batch, seq, out_path=None, levers="all"):
     import jax
     import jax.numpy as jnp
 
-    from bench import _peak_flops, _release, _time_train, \
-        moe_phase_breakdown
+    from bench import _release, _time_train, moe_phase_breakdown
     from paddle_tpu.framework.flags import get_flags, set_flags
     from paddle_tpu.models import moe
+    from paddle_tpu.observability.perf import mfu as mfu_of
 
     opt = {"optimizer": "adafactor", "param_dtype": jnp.bfloat16}
     dev = jax.devices()[0]
@@ -145,9 +145,11 @@ def run_bisect(cfg, batch, seq, out_path=None, levers="all"):
           f"backend={jax.default_backend()}")
     w = max(len(r[0]) for r in rows)
     for name, tps, delta in rows:
-        mfu = moe.flops_per_token(cfg, seq) * tps / _peak_flops(dev)
-        print(f"  {name.ljust(w)}  {tps:>10,.0f} tok/s  "
-              f"mfu={mfu:.3f}  {delta:+6.2f}% vs base")
+        # FLOPs of one second of tokens over one second: None off-chip
+        mfu = mfu_of(moe.flops_per_token(cfg, seq) * tps, 1.0, dev)
+        print(f"  {name.ljust(w)}  {tps:>10,.0f} tok/s  mfu="
+              + ("undefined on " + dev.platform if mfu is None
+                 else f"{mfu:.3f}") + f"  {delta:+6.2f}% vs base")
     print("  (moe_overlap_min_tokens lever: ep>1 meshes only — "
           "not timed on one chip)")
 
