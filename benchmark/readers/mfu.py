@@ -1,0 +1,18 @@
+"""Model FLOP/s utilization (%) of a training window: the FLOPs the forward
+and backward passes need per token (``benchmark/costs.py``; recomputation
+does not count) times tokens per second, over chips times the chip's
+published peak."""
+from benchmark import costs
+
+
+def read(rec):
+    sc = rec.get("scalars", {})
+    if ("tokens_per_s" not in sc or rec.get("kind") != "train"
+            or "peak" not in rec):          # no chip, no utilization
+        return None
+    flops = costs.train_flops_per_token(rec["model"], rec["plan"]["seq"])
+    share = 100.0 * flops * sc["tokens_per_s"] / (
+        rec["chips"] * rec["peak"].flops)
+    if share > 105.0:
+        raise ValueError(f"mfu {share:.1f}% > 105%")
+    return share
