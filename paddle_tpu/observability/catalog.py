@@ -38,7 +38,14 @@ CATALOG = {
         "host-visible generation throughput per engine step"),
     # ^ throughput, not a duration: gets its own bucket range below
     "serving_step_seconds": (
-        "histogram", (), "wall time of one LLMEngine.step call"),
+        "histogram", (), "wall time of one LLMEngine.step call, its "
+                         "blocking readback waits on the device "
+                         "included"),
+    "serving_step_host_seconds": (
+        "histogram", (), "the host's own share of one LLMEngine.step "
+                         "call: its wall time less the time inside the "
+                         "serving.readback_wait spans (one observation "
+                         "per serving_step_seconds observation)"),
     "serving_decode_prefix_bucket": (
         "gauge", (), "prefix horizon (tokens) of the decode dispatched "
                      "last — power-of-two bucket ceiling on the "
@@ -114,6 +121,11 @@ CATALOG = {
                      "(backpressure evidence; above "
                      "FLAGS_serve_send_queue_hwm the stall clock "
                      "runs)"),
+    "serving_http_emit_to_write_seconds": (
+        "histogram", (), "from the step thread posting a token to its "
+                         "stream (_route) to the loop thread having "
+                         "written that SSE frame to the socket — one "
+                         "observation per token frame"),
     "serving_http_drain_seconds": (
         "histogram", (), "graceful-drain duration: begin_drain/SIGTERM "
                          "to the last in-flight stream retiring "
@@ -386,10 +398,6 @@ CATALOG = {
                      "(PJRT memory_stats; 0 where unavailable)"),
     "hbm_peak_bytes": (
         "gauge", (), "device-0 HBM allocator high-water mark"),
-    "serving_mfu": (
-        "gauge", (), "decode-program FLOP utilization over the last "
-                     "engine step (cost-model FLOPs of the dispatched "
-                     "decode variant / step wall time / device peak)"),
     "serving_tpot_seconds": (
         "histogram", (), "per-request decode seconds per output token "
                          "(time-per-output-token, observed at finish; "
@@ -473,6 +481,25 @@ BUCKETS = {
 # Span names the framework emits (chrome-trace `name` field).
 SPANS = (
     "serving.step", "serving.prefill", "serving.decode", "serving.readback",
+    # the phases of a step (each names its parent in Span.parent):
+    # housekeeping (faults, deadlines, cancels, offload sweep), admit
+    # (chunk advance + admission; prefill_build and prefill nest in it),
+    # prefill_build (a wave's operands, bucket choice to the last h2d
+    # copy), decode_prepare (backing/preemption, carry refresh, table
+    # upload — everything before the decode call is enqueued),
+    # readback_wait (only the blocking device_get inside readback);
+    # telemetry is the post-step registry/timeline work, a sibling
+    # right after serving.step
+    "serving.housekeeping", "serving.admit", "serving.prefill_build",
+    "serving.decode_prepare", "serving.readback_wait",
+    "serving.telemetry",
+    # the front door's work on the step thread, before and after the
+    # engine step: queued submissions/cancellations; token fan-out,
+    # terminals, stall sweep
+    "serving.http.ops", "serving.http.route",
+    # one per device capture, ring only (never mirrored into the
+    # trace): the traced stretch on the perf_counter clock
+    "serving.profile_capture",
     "train.run", "train.step", "train.checkpoint", "train.resume",
     "jit.compile",
     # MoE hot path: moe.dispatch wraps one layer's routing+dispatch BUILD

@@ -16,6 +16,16 @@ step count, from a live job:
   ``jax.profiler.TraceAnnotation`` so the host-side spans land INSIDE
   the device trace — Perfetto shows which device ops ran under which
   engine phase;
+- a capture costs the run nothing it need not: it is taken WITHOUT the
+  profiler's Python tracer (the host tracer that ``TraceAnnotation``
+  needs stays on), and the step that counts the window down hands
+  ``stop_trace()`` to a helper thread and returns at once — collecting
+  and writing the trace no longer stalls the step thread;
+- right after the start one ``serving.clock_anchor`` annotation carries
+  the ``perf_counter`` reading as its argument, and at the stop one
+  ``serving.profile_capture`` span lands in the ring (only there): any
+  ring span can be put on the trace's timeline, and a reader knows the
+  traced stretch exactly;
 - each completed capture lands in the flight recorder
   (``profile_capture`` event) and bumps ``obs_profile_captures_total``.
 
@@ -43,7 +53,7 @@ import time
 from typing import Dict, Optional
 
 from ..framework.flags import get_flag
-from . import tracing
+from . import state, tracing
 from .catalog import instrument as _instrument
 
 __all__ = ["ProfileController", "get_controller",
@@ -64,17 +74,26 @@ class ProfileController:
     the SIGUSR2 deferral flag — the signal handler must not take the
     non-reentrant lock (the main thread may already hold it inside
     step_tick), so it only sets flags and the next step boundary arms
-    the capture on the handler's behalf."""
+    the capture on the handler's behalf.
+
+    The stop runs on a helper thread that is handed the lock and holds
+    it until the file is written. ``_stopping`` is read without the lock
+    so that a second ``request`` is refused at once; ``status()`` takes
+    the lock, so it waits while the profiler starts or stops and never
+    answers ``active: False`` before the capture is on disk (a poller's
+    clock reading taken BEFORE the call is when the stop was asked
+    for)."""
 
     def __init__(self):
         self._lock = threading.Lock()
         self._pending = False
+        self._stopping = False
+        self._t_started = 0.0       # perf_counter when the trace began
         self._sig_armed = False
         self._steps_left = 0
         self._armed_n = 0
         self._active = False
         self._dir: Optional[str] = None
-        self._started_unix: Optional[float] = None
         self._seq = 0
         self._last: Optional[Dict] = None
 
@@ -90,6 +109,10 @@ class ProfileController:
         if n <= 0:
             return {"ok": False, "bad_request": True,
                     "error": f"steps must be > 0, got {n}"}
+        if self._stopping:
+            # the helper thread holds the lock while it writes the file
+            return {"ok": False, "error": "capture already in flight",
+                    "status": self._status_locked()}
         with self._lock:
             if self._active or self._steps_left > 0:
                 return {"ok": False, "error": "capture already in flight",
@@ -115,12 +138,20 @@ class ProfileController:
     def step_tick(self) -> None:
         """One engine/train step boundary. Starts the armed capture,
         counts down, stops at zero. Called with ``_pending`` true only."""
+        if self._stopping:
+            return      # a SIGUSR2 during a stop waits for the next step
         if self._sig_armed:
             # a SIGUSR2 landed since the last boundary: arm the default
             # window HERE, outside signal context (see __init__ docstring)
             self._sig_armed = False
             self.request()
-        with self._lock:
+        # the lock is taken by hand: the step that ends the window does
+        # not give it back but hands it to the helper that writes the
+        # trace (a threading.Lock may be released by another thread), so
+        # no caller can slip in between the stop asked for and the file
+        self._lock.acquire()
+        handed = False
+        try:
             if not self._active:
                 if self._steps_left <= 0:
                     self._pending = False
@@ -129,15 +160,20 @@ class ProfileController:
                 return
             self._steps_left -= 1
             if self._steps_left <= 0:
-                self._stop_locked()
                 self._pending = False
+                self._stop_locked(wait=False)
+                handed = True
+        finally:
+            if not handed:
+                self._lock.release()
 
     def stop(self) -> Dict:
         """Force-stop (an idle job whose armed capture never saw a
-        step, or an operator cutting a window short)."""
+        step, or an operator cutting a window short). Waits for a stop
+        already under way: on return the capture is on disk."""
         with self._lock:
             if self._active:
-                self._stop_locked()
+                self._stop_locked(wait=True)
             self._steps_left = 0
             self._sig_armed = False
             self._pending = False
@@ -160,7 +196,12 @@ class ProfileController:
             import jax
 
             os.makedirs(self._dir, exist_ok=True)
-            jax.profiler.start_trace(self._dir)
+            # no Python tracer: it slows the host enough to double the
+            # traced stretch's idle share (PERF.md, PR 25); the host
+            # tracer (TraceAnnotation) keeps its default
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(self._dir, profiler_options=opts)
         except Exception as e:            # no backend / second profiler
             self._steps_left = 0
             self._last = {"ok": False, "error": repr(e), "dir": self._dir}
@@ -170,24 +211,53 @@ class ProfileController:
                                    dir=self._dir, error=repr(e))
             return
         self._active = True
-        self._started_unix = time.time()
+        # one event that is on both clocks: its start on the trace's,
+        # perf_counter (the span ring's) as its argument
+        self._t_started = time.perf_counter()
+        with jax.profiler.TraceAnnotation(
+                "serving.clock_anchor", perf_counter=self._t_started):
+            pass
         # host spans correlate with device ops only while capturing:
         # trace_span wraps its body in a TraceAnnotation via this hook
         tracing._set_annotation_factory(_annotation)
 
-    def _stop_locked(self) -> None:
+    def _stop_locked(self, wait: bool) -> None:
+        """End the traced stretch now; collect and write the trace on
+        this thread (``wait``) or on a helper thread, which then gives
+        back the lock the caller holds."""
         tracing._set_annotation_factory(None)
+        if state.enabled():
+            # ring only, on the perf_counter clock: the traced stretch
+            tracing.get_tracer().record(
+                "serving.profile_capture", self._t_started,
+                time.perf_counter(),
+                {"dir": self._dir, "steps": self._armed_n}, depth=0)
+        if wait:
+            self._write_trace()
+            return
+        self._stopping = True
+        threading.Thread(target=self._write_trace_and_release,
+                         name="obs-profile-stop", daemon=True).start()
+
+    def _write_trace_and_release(self) -> None:
+        try:
+            self._write_trace()
+        finally:
+            self._lock.release()
+
+    def _write_trace(self) -> None:
         steps = self._armed_n
         try:
             import jax
 
             jax.profiler.stop_trace()
         except Exception as e:
-            self._active = False
             self._last = {"ok": False, "error": repr(e), "dir": self._dir}
             return
-        self._active = False
-        dur = time.time() - (self._started_unix or time.time())
+        finally:
+            self._active = False
+            self._stopping = False
+        dur = time.perf_counter() - self._t_started
         self._last = {"ok": True, "dir": self._dir,
                       "seconds": dur, "unix_time": time.time()}
         _M_CAPTURES.inc()

@@ -384,11 +384,19 @@ class RequestTracer:
         from . import tracing
 
         p1 = time.perf_counter()
+        s = ctx.summary
+        queue_ms, ttft_ms = s.get("queue_ms"), s.get("ttft_ms")
         tracing.get_tracer().record(
             "serving.request", ctx._t0_perf, p1,
             {"request_id": ctx.request_id,
-             "tokens": ctx.summary.get("tokens", 0),
-             "preemptions": ctx.summary.get("preemptions", 0)},
+             "tokens": s.get("tokens", 0),
+             "preemptions": s.get("preemptions", 0),
+             # the engine's own timestamps, for whoever reads the ring:
+             # queued -> admitted, queued -> first token visible on the
+             # host, and the stretch between the two
+             "queue_ms": queue_ms, "ttft_ms": ttft_ms,
+             "prefill_ms": ttft_ms - queue_ms
+             if queue_ms is not None and ttft_ms is not None else None},
             depth=0)
 
     # -- SLO audit --------------------------------------------------------

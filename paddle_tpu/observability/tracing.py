@@ -75,15 +75,20 @@ def _json_safe(v):
 
 
 class Span:
-    __slots__ = ("name", "t0", "t1", "tid", "depth", "attrs")
+    __slots__ = ("name", "t0", "t1", "tid", "depth", "attrs", "parent")
 
-    def __init__(self, name, t0, t1, tid, depth, attrs):
+    def __init__(self, name, t0, t1, tid, depth, attrs, parent=None):
         self.name = name
         self.t0 = t0                 # perf_counter seconds
         self.t1 = t1
         self.tid = tid
         self.depth = depth
         self.attrs = attrs
+        # name of the span open just below it on its thread's stack
+        # (None at the top): a span's self time is its duration less
+        # that of the spans naming it as parent — no guessing from
+        # timestamps
+        self.parent = parent
 
     @property
     def duration(self) -> float:
@@ -118,7 +123,8 @@ class SpanTracer:
                 if st}
 
     def record(self, name: str, t0: float, t1: float,
-               attrs: Optional[Dict] = None, depth: Optional[int] = None):
+               attrs: Optional[Dict] = None, depth: Optional[int] = None,
+               parent: Optional[str] = None):
         """Append one completed span (deque append is GIL-atomic)."""
         ambient = getattr(_tls_attrs, "attrs", None)
         if ambient:
@@ -128,7 +134,8 @@ class SpanTracer:
             attrs = merged
         self._ring.append(Span(
             name, t0, t1, threading.get_ident(),
-            len(self._stack()) if depth is None else depth, attrs or {}))
+            len(self._stack()) if depth is None else depth, attrs or {},
+            parent))
 
     def spans(self) -> List[Span]:
         return list(self._ring)
@@ -154,6 +161,8 @@ class SpanTracer:
             # named "depth" wins over the synthetic nesting field
             args = {k: _json_safe(v) for k, v in s.attrs.items()}
             args.setdefault("depth", s.depth)
+            if s.parent is not None:
+                args.setdefault("parent", s.parent)
             events.append({
                 "name": s.name, "ph": "X", "cat": "obs",
                 "pid": pid, "tid": s.tid,
@@ -196,13 +205,21 @@ class trace_span:  # noqa: N801 — context manager, lowercase like the verb
     Near-zero when disabled (one enabled() check, no clock reads). The
     span records even when the body raises — a failing step is exactly
     the span you want on the timeline.
+
+    ``attrs`` may be added to until the span closes (``sp.attrs[...] =``
+    inside the body: a value only the body knows). :meth:`end` closes the
+    span before its ``with`` block does — the block's own exit is then a
+    no-op — and ``seconds`` holds the closed span's duration (0.0 when
+    nothing was recorded), so a caller that needs the interval pays no
+    second clock pair.
     """
 
-    __slots__ = ("name", "attrs", "_t0", "_stack", "_ann")
+    __slots__ = ("name", "attrs", "seconds", "_t0", "_stack", "_ann")
 
     def __init__(self, name: str, **attrs):
         self.name = name
         self.attrs = attrs
+        self.seconds = 0.0
         self._t0 = None
         self._stack = None
         self._ann = None
@@ -213,6 +230,7 @@ class trace_span:  # noqa: N801 — context manager, lowercase like the verb
         self._t0 = None
         self._stack = None
         self._ann = None
+        self.seconds = 0.0
         if not state.enabled():
             return self
         tr = _default_tracer
@@ -242,14 +260,21 @@ class trace_span:  # noqa: N801 — context manager, lowercase like the verb
             self._ann = None
         stack = self._stack
         depth = len(stack) - 1
+        parent = stack[-2] if depth > 0 else None
         if stack and stack[-1] == self.name:
             stack.pop()
         attrs = self.attrs if exc_type is None \
             else dict(self.attrs, error=exc_type.__name__)
-        _default_tracer.record(self.name, self._t0, t1, attrs, depth=depth)
+        _default_tracer.record(self.name, self._t0, t1, attrs, depth=depth,
+                               parent=parent)
         _feed_profiler_ledger(self.name, self._t0, t1)
+        self.seconds = t1 - self._t0
         self._t0 = None
         return False
+
+    def end(self) -> None:
+        """Close the span now, ahead of its ``with`` block."""
+        self.__exit__(None, None, None)
 
 
 def _feed_profiler_ledger(name: str, t0: float, t1: float) -> None:

@@ -140,11 +140,11 @@ _M_TOKENS = _instrument("serving_tokens_total")
 _M_TTFT = _instrument("serving_ttft_seconds")
 _M_TPS = _instrument("serving_tokens_per_second")
 _M_STEP_SECONDS = _instrument("serving_step_seconds")
+_M_STEP_HOST_SECONDS = _instrument("serving_step_host_seconds")
 _M_PREFIX_BUCKET = _instrument("serving_decode_prefix_bucket")
 _M_DECODE_RECOMPILES = _instrument("serving_decode_recompiles_total")
 _M_KV_READ_BYTES = _instrument("serving_decode_kv_read_bytes")
 _M_TPOT = _instrument("serving_tpot_seconds")
-_M_SERVING_MFU = _instrument("serving_mfu")
 _M_DEADLINE = _instrument("serving_deadline_exceeded_total")
 _M_SWAP_FALLBACK = _instrument("serving_kv_swap_fallback_total")
 _M_DECODE_KERNEL = _instrument("serving_decode_kernel_total")
@@ -190,6 +190,14 @@ class Request:
 # ---------------------------------------------------------------------------
 # device programs
 # ---------------------------------------------------------------------------
+def _named(name: str, fn):
+    """``fn`` under ``name`` for ``jax.jit``: a ``functools.partial`` has
+    no ``__name__``, and the program would be ``jit__unknown`` in every
+    trace and compile log."""
+    fn.__name__ = name
+    return fn
+
+
 def _sample_rows(logits, key, temps, top_ks, top_ps, any_sampled=True,
                  use_top_k=True, use_top_p=True):
     """Vectorized per-row sampling: every knob is a traced [N] vector, so
@@ -1230,10 +1238,10 @@ class LLMEngine:
         # first-token wall time per req still decoding, for TPOT at
         # finish; survives preemption (the decode clock keeps running)
         self._obs_t_first: Dict[int, float] = {}
-        # cost-model FLOPs per compiled decode variant (serving_mfu);
-        # None = analysis unavailable on this jax/backend
-        self._decode_flops: Dict = {}
-        self._last_decode_flops = None
+        # seconds this step spent inside serving.readback_wait (the
+        # blocking device_get calls): step wall time less this is the
+        # host's own share, serving_step_host_seconds
+        self._wait_s = 0.0
         # -- survivability layer (deadlines / shedding / swap / chaos) ----
         self.admission = (AdmissionController(admission)
                           if isinstance(admission, AdmissionConfig)
@@ -1454,7 +1462,7 @@ class LLMEngine:
             # probes are trace-time ops): variants compiled while
             # FLAGS_obs_numerics was off keep their compiled form —
             # flip the flag before the engine serves to instrument
-            fn = jax.jit(functools.partial(
+            fn = jax.jit(_named("paged_prefill", functools.partial(
                              _paged_prefill,
                              config=(self.draft_config if draft
                                      else self.config),
@@ -1464,7 +1472,7 @@ class LLMEngine:
                                        and _nm.active()),
                              prefix_nbk=prefix_nbk,
                              kv_prefix="d" if draft else "",
-                             mesh=self.mesh),
+                             mesh=self.mesh)),
                          donate_argnums=(4,))
             self._prefill[key] = fn
         return fn
@@ -2273,6 +2281,18 @@ class LLMEngine:
         if wave:
             _M_ADMISSIONS.inc(len(wave))
             self._dispatch_prefill(wave)
+        return len(wave)
+
+    def _admit_phase(self, chunks: bool = False) -> None:
+        """One ``serving.admit`` span around an admission attempt (and
+        the chunk advance that leads a step's first); a call with
+        nothing queued and no chunk due is not worth a span."""
+        if not self.queue and not (chunks and self._chunks):
+            return
+        with trace_span("serving.admit", queue=len(self.queue)) as sp:
+            if chunks:
+                self._advance_chunks()
+            sp.attrs["wave"] = self._admit()
 
     def _advance_chunks(self):
         """Feed every mid-prefill slot its next chunk — ONE chunk per
@@ -2306,6 +2326,35 @@ class LLMEngine:
         bucket) keeps the compiled family bounded — chunking and the
         cache extend the EXISTING (bucket, flags) cache with one
         log-bounded axis, not a new family."""
+        with trace_span("serving.prefill_build", wave=len(rows)) as sp:
+            bucket, B, flags, pnbk, args = self._prefill_operands(rows)
+            sp.attrs.update(bucket=bucket, batch=B)
+        wave_rids = [r.req_id for _s, r, _c, _h, _p, _f in rows]
+        with trace_span("serving.prefill", bucket=bucket, batch=B,
+                        wave=len(rows), prefix_bucket=pnbk * self.bs,
+                        request_ids=wave_rids):
+            tok_dev, self.pools = self._prefill_fn(
+                bucket, B, flags, pnbk)(*args)
+        if self._spec_on:
+            # the SAME wave through the draft model, right behind the
+            # target's call (pools chain through donation): both models'
+            # KV now cover every prefilled position, so these slots
+            # enter spec waves in sync. The draft's sampled token is
+            # discarded — the target owns the stream.
+            self._key, dsub = jax.random.split(self._key)
+            dargs = [self.draft_params] + args[1:8] + [dsub] + args[9:]
+            dargs[4] = self.pools
+            with trace_span("serving.prefill", bucket=bucket, batch=B,
+                            wave=len(rows), model="draft",
+                            request_ids=wave_rids):
+                _junk, self.pools = self._prefill_fn(
+                    bucket, B, flags, pnbk, draft=True)(*dargs)
+        self._prefill_dispatched(rows, bucket, B, tok_dev)
+
+    def _prefill_operands(self, rows):
+        """The wave's program variant and its operands, from the bucket
+        choice to the last host-to-device copy: ``(bucket, B, flags,
+        pnbk, args)``."""
         bucket = self._bucket_for(max(piece for *_x, piece, _f in rows))
         # two batch variants only: 1 (steady-state churn admits one slot
         # at a time — full-width padding would pay max_slots× the prefill
@@ -2345,32 +2394,17 @@ class LLMEngine:
                  sampled and any(r.top_p < 1.0 for r in finals
                                  if r.temperature > 0))
         self._key, sub = jax.random.split(self._key)
-        wave_rids = [r.req_id for _s, r, _c, _h, _p, _f in rows]
         args = [self.params, jnp.asarray(toks), jnp.asarray(blk_ids),
                 jnp.asarray(true_lens), self.pools,
                 jnp.asarray(temps), jnp.asarray(top_ks),
                 jnp.asarray(top_ps), sub]
         if pnbk:
             args += [jnp.asarray(hist_lens), jnp.asarray(ctx_tbl)]
-        with trace_span("serving.prefill", bucket=bucket, batch=B,
-                        wave=len(rows), prefix_bucket=pnbk * self.bs,
-                        request_ids=wave_rids):
-            tok_dev, self.pools = self._prefill_fn(
-                bucket, B, flags, pnbk)(*args)
-        if self._spec_on:
-            # the SAME wave through the draft model, right behind the
-            # target's call (pools chain through donation): both models'
-            # KV now cover every prefilled position, so these slots
-            # enter spec waves in sync. The draft's sampled token is
-            # discarded — the target owns the stream.
-            self._key, dsub = jax.random.split(self._key)
-            dargs = [self.draft_params] + args[1:8] + [dsub] + args[9:]
-            dargs[4] = self.pools
-            with trace_span("serving.prefill", bucket=bucket, batch=B,
-                            wave=len(rows), model="draft",
-                            request_ids=wave_rids):
-                _junk, self.pools = self._prefill_fn(
-                    bucket, B, flags, pnbk, draft=True)(*dargs)
+        return bucket, B, flags, pnbk, args
+
+    def _prefill_dispatched(self, rows, bucket, B, tok_dev):
+        """Host bookkeeping of a dispatched wave: lengths, pending first
+        tokens, chunk state, timelines, prefix-cache adoption."""
         tracer = _rt.get_request_tracer() if _obs.enabled() else None
         for i, (slot, req, ctx, hist, piece, final) in enumerate(rows):
             self.lengths[slot] = hist + piece
@@ -2695,11 +2729,12 @@ class LLMEngine:
         return sum(a.shape[0] * int(np.prod(a.shape[2:])) * a.dtype.itemsize
                    for n, a in self.pools.items() if n in want)
 
-    def _dispatch_decode(self, active_slots):
+    def _dispatch_decode(self, active_slots, prep=None):
         """Enqueue one multi-step decode call and record it as in-flight.
         rem_start tracks each slot's EXACT remaining budget at the start
         of the call (host bookkeeping lags; this chains from the previous
-        record when pipelined)."""
+        record when pipelined). ``prep``: the caller's open
+        ``serving.decode_prepare`` span, ended here right at the call."""
         prev = self._inflight
         pend = {s for s, _, _, _ in self._pending_adm}
         rem_start = {}
@@ -2746,14 +2781,13 @@ class LLMEngine:
             # stays ("mega"|"ragged"|bucket, flags): a mid-run flag flip
             # instruments new variants only — docs/observability.md)
             decode = self._decode_cache[vk] = jax.jit(
-                functools.partial(_paged_decode, config=self.config,
-                                  n_steps=self.decode_steps,
-                                  sample_flags=flags,
-                                  kv_int8=self.kv_int8,
-                                  numerics=self.kv_int8 and _nm.active(),
-                                  ragged=(path == "ragged"),
-                                  mega=(path == "mega"),
-                                  mesh=self.mesh),
+                _named("paged_decode", functools.partial(
+                    _paged_decode, config=self.config,
+                    n_steps=self.decode_steps, sample_flags=flags,
+                    kv_int8=self.kv_int8,
+                    numerics=self.kv_int8 and _nm.active(),
+                    ragged=(path == "ragged"), mega=(path == "mega"),
+                    mesh=self.mesh)),
                 donate_argnums=(8,))
             _M_DECODE_RECOMPILES.inc()
         # path + traffic accounting (host ints — kept whether or not the
@@ -2783,45 +2817,20 @@ class LLMEngine:
         else:
             # one dense gather (pool read + dense write) + one dense
             # read per scan step, all at the bucket ceiling
-            step_bytes = pb * self.N * nbk
+            walk = self.N * nbk
+            step_bytes = pb * walk
             kv_call_bytes = step_bytes * (2 + self.decode_steps)
             bucket_tokens = nbk * self.bs
         self.kv_read_bytes_total += kv_call_bytes
         if _obs.enabled():
             _M_PREFIX_BUCKET.set(bucket_tokens)
             _M_KV_READ_BYTES.set(step_bytes)
-            # cost-model FLOPs once per compiled variant (lower() is a
-            # trace, so MFU never compiles twice)
-            if vk not in self._decode_flops:
-                self._decode_flops[vk] = _perf.flops_of(
-                    decode, self.params, c_last, c_len, c_done, c_rem,
-                    c_key, v_act, tbl, self.pools, v_t, v_k, v_p, v_eos)
-            flops = self._decode_flops[vk]
-            if flops and ragged_like:
-                # the cost model can't see inside the Mosaic custom
-                # call, and the walk's FLOPs depend on runtime lengths
-                # anyway: add the prefix-attention term analytically —
-                # QK + PV = 4*Hq*D per walked token, per layer, per
-                # scan step (the ring/matmul/MLP terms are plain XLA
-                # ops the cost analysis already counted)
-                flops += (4 * self.config.num_heads * self.config.head_dim
-                          * walk * self.bs * self.config.num_layers
-                          * self.decode_steps)
-            if flops and path == "mega":
-                # the mega launch also swallows the hidden-state
-                # matmuls the ragged path left visible to XLA — add
-                # them analytically (2 FLOPs per weight element per
-                # row per step; L is already in the stacked shapes)
-                wels = sum(
-                    int(np.prod((m["q"] if isinstance(m, dict)
-                                 else m).shape))
-                    for m in (self.params["layers"][n]
-                              for n in ("wq", "wk", "wv", "wo",
-                                        "w_gate", "w_up", "w_down")))
-                flops += 2 * wels * self.N * self.decode_steps
-            self._last_decode_flops = flops
+        if prep is not None:
+            prep.attrs["slots"] = len(active_slots)
+            prep.end()
         with trace_span("serving.decode", slots=len(active_slots),
                         steps=self.decode_steps,
+                        walk_blocks=walk, kv_bytes=step_bytes,
                         # the true dispatched horizon (ragged: max real
                         # length; bucketed: the ceiling) — matches the
                         # serving_decode_prefix_bucket gauge, never the
@@ -2890,14 +2899,14 @@ class LLMEngine:
         fn = self._spec_draft_cache.get(key)
         if fn is None:
             fn = self._spec_draft_cache[key] = jax.jit(
-                functools.partial(
+                _named("spec_draft", functools.partial(
                     _paged_decode, config=self.draft_config,
                     n_steps=self.spec_k,
                     sample_flags=(False, False, False),
                     kv_int8=False, numerics=False,
                     ragged=(key == "ragged"), mega=(key == "mega"),
                     mega_multistep=(key == "mega"),
-                    kv_prefix="d"),
+                    kv_prefix="d")),
                 donate_argnums=(8,))
         return fn
 
@@ -2908,11 +2917,11 @@ class LLMEngine:
         fn = self._spec_verify_cache.get(nbk)
         if fn is None:
             fn = self._spec_verify_cache[nbk] = jax.jit(
-                functools.partial(
+                _named("spec_verify", functools.partial(
                     _spec_verify, config=self.config,
                     n_spec=self.spec_k, kv_int8=self.kv_int8,
                     numerics=self.kv_int8 and _nm.active(),
-                    max_model_len=self.max_model_len),
+                    max_model_len=self.max_model_len)),
                 donate_argnums=(6,))
         return fn
 
@@ -3035,8 +3044,8 @@ class LLMEngine:
                 f"{self._step_idx}")
         with guarded("serving-spec-readback"), \
                 trace_span("serving.readback"):
-            d_host = np.asarray(jax.device_get(demitted))   # [k, N]
-            v_host = np.asarray(jax.device_get(vtoks))      # [N, k+1]
+            d_host, v_host = self._device_get(
+                (demitted, vtoks))                  # [k, N], [N, k+1]
         wave_prop = wave_acc = wave_commit = 0
         for i in active:
             req = self.slot_req[i]
@@ -3117,6 +3126,16 @@ class LLMEngine:
                 trace_span("serving.readback"):
             return self._process_guarded(rec)
 
+    def _device_get(self, tree):
+        """The engine's blocking host sync: ``tree``'s arrays on the
+        host. The wait is a ``serving.readback_wait`` span of its own
+        inside ``serving.readback`` and adds to ``_wait_s``, which
+        ``serving_step_host_seconds`` takes off the step's wall time."""
+        with trace_span("serving.readback_wait") as sp:
+            host = jax.device_get(tree)
+        self._wait_s += sp.seconds
+        return host
+
     def _flush_adm(self, adm):
         """Read back a list of pending-admission first tokens
         ((slot, rid, wave_array, row) tuples) and commit them host-side
@@ -3126,8 +3145,8 @@ class LLMEngine:
         for slot, rid, arr, i in adm:
             uniq.setdefault(id(arr), (arr, []))[1].append(
                 (slot, rid, i))
-        host = {aid: np.asarray(jax.device_get(arr))
-                for aid, (arr, _) in uniq.items()}
+        host = self._device_get({aid: arr for aid, (arr, _)
+                                 in uniq.items()})
         first = [int(host[id(arr)][i]) for _, _, arr, i in adm]
         for (slot, rid, _, _), tok in zip(adm, first):
             req = self.slot_req[slot]
@@ -3146,7 +3165,7 @@ class LLMEngine:
         emitted = []
         if rec["adm"]:
             emitted += self._flush_adm(rec["adm"])
-        toks_host = np.asarray(jax.device_get(rec["toks"]))  # [K, N]
+        toks_host = self._device_get(rec["toks"])            # [K, N]
         for slot, rid in rec["snapshot"]:
             req = self.slot_req[slot]
             if req is None or req.req_id != rid:
@@ -3196,12 +3215,21 @@ class LLMEngine:
         _profiling.step_tick()
         if not _obs.enabled():
             return self._step_inner()
+        self._wait_s = 0.0
         t0 = time.perf_counter()
         with trace_span("serving.step"):
             emitted = self._step_inner()
         now = time.perf_counter()
-        dt = now - t0
+        with trace_span("serving.telemetry"):
+            self._step_telemetry(emitted, now, now - t0)
+        return emitted
+
+    def _step_telemetry(self, emitted, now: float, dt: float) -> None:
+        """What a step owes the registry and the request timelines once
+        its work is done (the ``serving.telemetry`` span)."""
         _M_STEP_SECONDS.observe(dt)
+        # the host's own share: the step less its waits on the device
+        _M_STEP_HOST_SECONDS.observe(max(0.0, dt - self._wait_s))
         if emitted:
             _M_TOKENS.inc(len(emitted))
             if dt > 0:
@@ -3219,10 +3247,6 @@ class LLMEngine:
                 # one decode tick per request per step (finished
                 # requests already left the live table — no-op there)
                 tracer.record(rid, "decode", tokens=n)
-        if self._last_decode_flops:
-            m = _perf.mfu(self._last_decode_flops, dt)
-            if m is not None:
-                _M_SERVING_MFU.set(m)
         _perf.update_serving_slo_gauges(_M_TTFT, _M_TPOT)
         _perf.update_hbm_gauges()
         _M_QUEUE_DEPTH.set(len(self.queue))
@@ -3235,7 +3259,6 @@ class LLMEngine:
         # contention-free — a concurrent replica already sampling means
         # this step skips instead of waiting
         _ts.step_tick()
-        return emitted
 
     def _step_inner(self):
         emitted = []
@@ -3245,22 +3268,20 @@ class LLMEngine:
         # admission: an injected squeeze shapes this step's block
         # budget, and an expired or disconnected request must not
         # occupy the slot a live one could take
-        self._apply_faults()
-        self._expire_deadlines()
-        self._apply_cancels()
-        # offload sweep AFTER cancellations (a dead request must not be
-        # staged) and BEFORE admission (blocks a landed spill just freed
-        # are allocatable THIS step; staged payloads meet their restore)
-        self._offload_tick()
-        # stale FLOPs from an earlier dispatch must not divide a
-        # no-decode step's wall time (a bogus MFU spike on idle steps)
-        self._last_decode_flops = None
+        with trace_span("serving.housekeeping"):
+            self._apply_faults()
+            self._expire_deadlines()
+            self._apply_cancels()
+            # offload sweep AFTER cancellations (a dead request must not
+            # be staged) and BEFORE admission (blocks a landed spill just
+            # freed are allocatable THIS step; staged payloads meet their
+            # restore)
+            self._offload_tick()
         # one chunk per mid-prefill slot BEFORE admission/decode: the
         # chunk program and this step's decode wave share the step, so a
         # long prefill never monopolizes it (bounded TTFT for the slots
         # already decoding)
-        self._advance_chunks()
-        self._admit()
+        self._admit_phase(chunks=True)
         if self.role == "prefill":
             # disagg (r19): no decode ever dispatches here — slots whose
             # prefill (chunked included) just completed hand their KV to
@@ -3275,24 +3296,27 @@ class LLMEngine:
                 # draft → verify → commit
                 if self._inflight is not None:
                     emitted += self._process_inflight()
-                    self._admit()
+                    self._admit_phase()
                     active = self._decode_slots()
                 if active and self._spec_eligible(active):
                     return emitted + self._spec_wave(active)
         if self._inflight is not None and not self._spec_safe():
             emitted += self._process_inflight()
-            self._admit()          # freed slots: refill before dispatching
+            self._admit_phase()    # freed slots: refill before dispatching
         active = self._decode_slots()
         if not active:
             if self._inflight is not None:
                 emitted += self._process_inflight()
             return emitted
-        emitted += self._back_or_preempt()
-        active = self._decode_slots()
-        if not active:
-            return emitted
-        self._refresh_carry(active)
-        prev = self._dispatch_decode(active)
+        # everything the host does for this decode call before it is
+        # enqueued; _dispatch_decode ends the span right at the call
+        with trace_span("serving.decode_prepare") as prep:
+            emitted += self._back_or_preempt()
+            active = self._decode_slots()
+            if not active:
+                return emitted
+            self._refresh_carry(active)
+            prev = self._dispatch_decode(active, prep)
         if prev is not None:
             emitted += self._process(prev)
         return emitted

@@ -84,6 +84,7 @@ from typing import Dict, List, Optional, Tuple
 from .. import observability as _obs
 from ..framework.flags import define_flag, get_flag
 from ..observability import flight_recorder as _flight
+from ..observability import trace_span
 from ..observability.catalog import instrument as _instrument
 from .admission import AdmissionController, ShedError
 from .resilient import ResilientEngine
@@ -108,6 +109,7 @@ _M_ACTIVE_STREAMS = _instrument("serving_http_active_streams")
 _M_DISCONNECTS = _instrument("serving_http_client_disconnects_total")
 _M_SEND_QUEUE = _instrument("serving_http_send_queue_depth")
 _M_DRAIN_SECONDS = _instrument("serving_http_drain_seconds")
+_M_EMIT_TO_WRITE = _instrument("serving_http_emit_to_write_seconds")
 
 _REASONS = {200: "OK", 400: "Bad Request", 404: "Not Found",
             405: "Method Not Allowed", 408: "Request Timeout",
@@ -338,7 +340,9 @@ class HTTPFrontDoor:
         eng = self.engine
         try:
             while not self._stopping:
-                self._run_ops()
+                if self._ops:
+                    with trace_span("serving.http.ops"):
+                        self._run_ops()
                 if self.draining and not self._drain_cut and \
                         time.monotonic() - self._drain_t0 \
                         > self._drain_budget:
@@ -358,11 +362,13 @@ class HTTPFrontDoor:
                         frame_n = self.resilient.recoveries
                         for st in self._streams.values():
                             st.post(("retry", frame_n))
-                    self._route(emitted)
-                    self._notify_terminals()
-                    self._sweep_stalls()
-                    if self.step_hook is not None:
-                        self.step_hook(eng)
+                    with trace_span("serving.http.route",
+                                    tokens=len(emitted)):
+                        self._route(emitted)
+                        self._notify_terminals()
+                        self._sweep_stalls()
+                        if self.step_hook is not None:
+                            self.step_hook(eng)
                 else:
                     if eng._inflight is not None:   # defensive, as run()
                         self._route(eng._process_inflight())
@@ -485,10 +491,13 @@ class HTTPFrontDoor:
         per: Dict[int, List[int]] = {}
         for rid, tok in emitted:
             per.setdefault(rid, []).append(int(tok))
+        # when the tokens were posted, for the writer's emit-to-write
+        # observation (one clock read a step; None while obs is off)
+        t_post = time.perf_counter() if _obs.enabled() else None
         for rid, toks in per.items():
             st = self._streams.get(rid)
             if st is not None:
-                st.post(("toks", toks))
+                st.post(("toks", toks, t_post))
 
     def _notify_terminals(self) -> None:
         """Close out every owned stream whose request reached a terminal
@@ -866,6 +875,9 @@ class HTTPFrontDoor:
                 if item[0] == "toks":
                     for tok in item[1]:
                         writer.write(sse_token_frame(tok))
+                        if item[2] is not None:
+                            _M_EMIT_TO_WRITE.observe(
+                                time.perf_counter() - item[2])
                     await self._drain_bounded(writer)
                 elif item[0] == "retry":
                     writer.write(sse_retry_frame(item[1]))
