@@ -24,7 +24,9 @@ One chip (the default, what the driver runs):
   Checks: every stream ends ``finished`` with the asked number of tokens,
   ids < vocab; ``serving_decode_kernel_total{path}`` names the path
   ``auto`` takes on a TPU and no ``*fallback*`` counter moved; the block
-  ledger balances after the drain; and the logits the engine sampled its
+  ledger balances after the drain; the ragged walk alone agrees with the
+  XLA gather oracle over a length mix that straddles its chunks' edges,
+  16 slots at these heads; and the logits the engine sampled its
   first two tokens from (prefill, then the first decode step) agree, to
   ``LOGIT_TOL``, with a float32 ``llama.forward(use_flash=False)`` of the
   same weights run on the chip under ``default_matmul_precision("highest")``.
@@ -471,6 +473,56 @@ def _drive_engine(run: Run, name: str, front, cfg, rng,
               f"no fallback counter moved ({fell_back or 'none'})")
 
 
+def _check_ragged_walk(run: Run, cfg, rng) -> None:
+    """The ragged walk alone against the XLA gather oracle, at the shapes
+    the benchmark's serving cells give it (16 slots; this model's heads,
+    head dim and KV block; bf16 pools of two layers read at layer 1): a
+    length mix that straddles every edge of the walk's chunks — empty
+    slots, one token, a block more or less, a chunk more or less, the
+    table's full width — beside lengths drawn at random."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.kernels.paged_attention import (
+        PagedKVCache, _walk_chunk_blocks, paged_attention,
+        ragged_paged_decode)
+
+    phase = "serve:walk"
+    sz = run.sizes
+    n, bs, mb = 16, sz.block, sz.max_len // sz.block
+    hkv, g, d = cfg.num_kv_heads, cfg.num_heads // cfg.num_kv_heads, \
+        cfg.head_dim
+    c = _walk_chunk_blocks(bs, hkv, d, 2, mb)
+    edges = [0, 1, bs - 1, bs, bs + 1, c * bs - 1, c * bs, c * bs + 1,
+             mb * bs - 1, mb * bs, 0]
+    lens = np.asarray(
+        [min(x, mb * bs) for x in edges]
+        + rng.integers(1, mb * bs, size=n - len(edges)).tolist(), np.int32)
+    nb = n * mb + 1
+    pool = lambda: jnp.asarray(
+        rng.standard_normal((2, nb, bs, hkv, d), np.float32), jnp.bfloat16)
+    kp, vp = pool(), pool()
+    table = jnp.asarray(rng.permutation(np.arange(1, nb)).reshape(n, mb),
+                        jnp.int32)
+    q = jnp.asarray(rng.standard_normal((n, hkv * g, d), np.float32),
+                    jnp.bfloat16)
+    got = np.asarray(ragged_paged_decode(
+        q, PagedKVCache(kp, vp, table, jnp.asarray(lens)), layer=1),
+        np.float32)
+    want = np.asarray(paged_attention(
+        q, PagedKVCache(kp[1], vp[1], table, jnp.asarray(lens))),
+        np.float32)
+    live = lens > 0
+    err = float(np.abs(got - want)[live].max())
+    run.log(phase, f"{n} slots x {hkv * g}/{hkv} heads x {d}, blocks of "
+            f"{bs}, table {mb} wide, {c} blocks a chunk; lengths "
+            f"{lens.tolist()}; max|walk - oracle| = {err:.4f}")
+    run.check(phase, bool(np.isfinite(got).all()) and err <= 5e-2
+              and bool((got[~live] == 0).all()),
+              f"the walk agrees with the XLA gather oracle to 5e-2 at "
+              f"every live slot ({err:.4f}) and emits 0 for the empty ones")
+
+
 def _probe_logits(run: Run, params, cfg, rng) -> None:
     """The engine's own logits against the float32 reference, on the chip.
 
@@ -591,6 +643,7 @@ def serve_phase(run: Run) -> None:
         run.memory(phase)
         del engines, eng, front
         gc.collect()
+        _check_ragged_walk(run, cfg, rng)
         _probe_logits(run, params, cfg, rng)
     finally:
         obs.disable()

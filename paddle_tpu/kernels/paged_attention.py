@@ -23,10 +23,12 @@ stories:
   same). This is the off-TPU / interpret fallback.
 - Ragged Pallas path (:func:`ragged_paged_decode` /
   :func:`ragged_decode_partial`): one program per slot walks the slot's
-  block table at its TRUE length — blocks past ``ceil(len/bs)`` are
-  never visited (the walk's trip count ends there: no DMA, no FLOPs),
-  the tail inside the last block is masked, and the softmax runs online
-  across the walk, so nothing is
+  block table at its TRUE length, a chunk of several blocks per loop
+  iteration — blocks past ``ceil(len/bs)`` are never fetched and chunks
+  past them never visited (the walk's trip count ends there: no DMA, no
+  FLOPs), the tail inside the last chunk is masked, all KV heads of a
+  chunk go through one dot, and the softmax runs online across the
+  chunks, so nothing is
   ever gathered to a static horizon. Lengths are a runtime operand, not
   a shape: ONE compiled variant serves any batch composition, and the
   per-step KV read scales with the actual tokens resident, not any
@@ -380,128 +382,204 @@ def paged_decode_attention(q, cache: PagedKVCache, layer=0) -> jax.Array:
 # ---------------------------------------------------------------------------
 # Ragged paged attention — the true-length block walk (arXiv 2604.15464).
 #
-# Grid: one program per slot; the program's kv-head groups are walked
-# in-register inside the block loop rather than as a grid axis, because
-# the pool layout keeps (Hkv, D) as the Mosaic-tiled pair — a per-head
-# DMA would slice the tiled Hkv dim (illegal), and a per-(slot, head)
-# grid re-DMAing whole [bs, Hkv, D] blocks would multiply the KV read
-# bytes by Hkv on a bandwidth-bound path. Each real block is DMA'd
-# exactly once (double-buffered: block b+1 streams while b computes) and
-# every kv head consumes it while it is VMEM-resident.
+# Grid: one program per slot. The pool layout keeps (Hkv, D) as the
+# Mosaic-tiled pair, so a per-head DMA would slice the tiled Hkv dim
+# (illegal) and a per-(slot, head) grid re-DMAing whole [bs, Hkv, D]
+# blocks would multiply the KV read bytes by Hkv on a bandwidth-bound
+# path. Pulling one head's [bs, D] out of a VMEM-resident block is no
+# better: bf16 packs two heads into each 32-bit sublane word, and the
+# compiler's dump of that extraction is ~90 rotate/shuffle/select
+# operations per (block, head) — it, not the copy, was the walk's time.
+#
+# So the walk never separates the heads. It moves a CHUNK of C blocks per
+# loop iteration (C copies of K and of V in flight into the other half of
+# a two-chunk buffer while this half computes), views the chunk flat as
+# [C*bs*Hkv, D] rows — the bytes as they lie, no relayout — and runs ALL
+# query heads against it in one dot. Row (token t, head h) of the chunk
+# is column t*Hkv + h of the scores; the columns of another KV head than
+# the query head's own are masked to -1e30 with the tail, before the
+# running max, so their probabilities are exactly 0.0 in the PV dot. The
+# MXU does Hkv times the useful FLOPs and is idle all the same: the bytes
+# are what this kernel costs.
 # ---------------------------------------------------------------------------
+
+# flat rows (tokens x KV heads) one loop iteration aims at, and the VMEM
+# the two-chunk K and V buffers may take (a sixth of the scoped default)
+_WALK_ROWS = 1024
+_WALK_VMEM_BYTES = 4 << 20
+
+
+def _walk_chunk_blocks(block_size, n_kv, head_dim, itemsize, max_blocks):
+    """Blocks the ragged walk moves per loop iteration, from what the
+    kernel can see at trace time: enough tokens x KV heads to fill
+    ``_WALK_ROWS`` rows (128 tokens at 8 KV heads, 256 on a tp=2 shard's
+    4), halved while two chunks of K and V exceed the VMEM budget, never
+    more than the table is wide."""
+    c = max(1, _WALK_ROWS // (block_size * n_kv))
+    while c > 1 and (4 * c * block_size * n_kv * head_dim * itemsize
+                     > _WALK_VMEM_BYTES):
+        c //= 2
+    return min(c, max_blocks)
 
 
 def _ragged_decode_kernel(layer_ref, table_ref, lens_ref, q_ref,
                           k_pool_ref, v_pool_ref, *rest, block_size,
-                          n_kv, max_blocks, kv_int8):
+                          n_kv, max_blocks, chunk, kv_int8):
     """Grid (N,): walk slot n's block table up to ``ceil(lens[n]/bs)``
-    REAL blocks with an online softmax. Blocks past the length are never
-    visited — the fori_loop trip count ends the walk there (program size
-    stays O(1) in the table width) and the ``pl.when`` prefetch guard
-    stops the DMA stream at the last real block — so a slot's cost
-    scales with its true length whatever the table width. The tail
-    inside the last block is masked to -1e30 before the running max, so
-    its exp is exactly 0.0 (bucketed-path exactness argument, applied
-    per block). int8 pools: the [bs, Hkv, D] payload
-    blocks and [bs, Hkv] per-entry scale blocks stream as stored; the
-    payload widens in-register (int8 -> q dtype is exact) and the K
-    scale multiplies the f32 scores / the V scale folds into the
-    probabilities — attn_qk / attn_pv's scale-folding math, inlined.
+    REAL blocks, ``chunk`` blocks per loop iteration, with one online-
+    softmax update per chunk. Blocks past the length are never fetched
+    and chunks past it never visited — the fori_loop trip count ends the
+    walk there, and program size stays O(chunk), not O(table width) — so
+    a slot's cost scales with its true length whatever the table width.
+    Every position a dot touches is either a row copied from a real block
+    or, in the last chunk's remainder, masked (K) and zeroed (V): the
+    tail is masked to -1e30 before the running max, so its exp is exactly
+    0.0 (bucketed-path exactness argument, applied per chunk), and 0.0
+    times a zero V row adds nothing, where stale VMEM could be a NaN.
+    int8 pools: the [bs, Hkv, D] payload blocks and [bs, Hkv] per-entry
+    scale blocks stream as stored; the payload widens in-register (int8
+    -> q dtype is exact) and the K scale multiplies the f32 scores / the
+    V scale folds into the probabilities — attn_qk / attn_pv's
+    scale-folding math, inlined.
 
-    Emits the online-softmax PARTIAL state per (slot, kv head, q-in-
-    group): unnormalized ``acc`` (f32 [N, Hkv, G, D]), running max ``m``
-    and sum ``l`` (f32 [N, Hkv, G]) — the flash-decoding combine
-    contract, so a caller can merge in-flight tokens (the engine's
-    in-call ring) before normalizing. A slot with length 0 emits
-    (acc=0, m=-1e30, l=0), the identity of the combine."""
+    Emits the online-softmax PARTIAL state per query head: unnormalized
+    ``acc`` (f32 [N, Hq, D]), running max ``m`` and sum ``l`` (f32
+    [N, Hq, 1]) — the flash-decoding combine contract, so a caller can
+    merge in-flight tokens (the engine's in-call ring) before
+    normalizing. A slot with length 0 emits (acc=0, m=-1e30, l=0), the
+    identity of the combine."""
     if kv_int8:
         (ks_pool_ref, vs_pool_ref, acc_ref, m_ref, l_ref,
-         kbuf, vbuf, ksbuf, vsbuf, accs, ms, ls, sems) = rest
+         kbuf, vbuf, ksbuf, vsbuf, tokcol, sems) = rest
     else:
-        (acc_ref, m_ref, l_ref, kbuf, vbuf, accs, ms, ls, sems) = rest
+        (acc_ref, m_ref, l_ref, kbuf, vbuf, tokcol, sems) = rest
     n = pl.program_id(0)
     lyr = layer_ref[0]
     ln = lens_ref[n]
-    sm_scale = 1.0 / math.sqrt(q_ref.shape[-1])
-    ms[:] = jnp.full(ms.shape, -1e30, jnp.float32)
-    ls[:] = jnp.zeros(ls.shape, jnp.float32)
-    accs[:] = jnp.zeros(accs.shape, jnp.float32)
+    Hq, D = q_ref.shape[1:]
+    group = Hq // n_kv
+    C, T = chunk, chunk * block_size
+    R = T * n_kv                           # rows of a chunk viewed flat
+    sm_scale = 1.0 / math.sqrt(D)
+    # the flat view of a chunk. A dtype that packs (bf16: two KV heads of
+    # a token in each 32-bit sublane word) is read as the words it lies
+    # in and bitcast back in registers: read as bf16, each load comes out
+    # in the buffer's (Hkv, 128) tiling and is shuffled into the dot's
+    # (16, 128) — the same bytes — which the copies hide at 8 KV heads and
+    # not at a tp shard's 4 (measured on the chip: 1.29 ms against 1.64
+    # for sixteen layers of sixteen slots).
+    pack = 4 // kbuf.dtype.itemsize
+    if pack > 1 and n_kv % pack == 0:
+        def flat(buf):
+            words = buf.bitcast(jnp.uint32).reshape(2, R // pack, D)
+            return lambda half: pltpu.bitcast(words[half], buf.dtype)
+    else:
+        def flat(buf):
+            rows = buf.reshape(2, R, D)
+            return lambda half: rows[half]
+    kflat, vflat = flat(kbuf), flat(vbuf)
 
-    def copies(b, slot):
-        blk = table_ref[n, b]
+    # column c of a chunk's scores is (token c // Hkv, KV head c % Hkv)
+    # and row r is a query head of KV head r // group: tokcol holds the
+    # column's token where the two heads are one, and elsewhere a number
+    # no length reaches. Scratch outlives the grid step: filled once.
+    @pl.when(n == 0)
+    def _():
+        col = jax.lax.broadcasted_iota(jnp.int32, (Hq, R), 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (Hq, R), 0)
+        tokcol[...] = jnp.where(col % n_kv == row // group, col // n_kv,
+                                jnp.int32(2 ** 30))
+
+    nblk = jnp.minimum((ln + block_size - 1) // block_size, max_blocks)
+    nchunk = (nblk + C - 1) // C
+
+    def copies(c, j, half):
+        blk = table_ref[n, c * C + j]
+        rows = pl.ds(j * block_size, block_size)
         cps = [pltpu.make_async_copy(k_pool_ref.at[lyr, blk],
-                                     kbuf.at[slot], sems.at[0, slot]),
+                                     kbuf.at[half, rows], sems.at[0, half]),
                pltpu.make_async_copy(v_pool_ref.at[lyr, blk],
-                                     vbuf.at[slot], sems.at[1, slot])]
+                                     vbuf.at[half, rows], sems.at[1, half])]
         if kv_int8:
             cps += [pltpu.make_async_copy(ks_pool_ref.at[lyr, blk],
-                                          ksbuf.at[slot], sems.at[2, slot]),
+                                          ksbuf.at[half, rows],
+                                          sems.at[2, half]),
                     pltpu.make_async_copy(vs_pool_ref.at[lyr, blk],
-                                          vsbuf.at[slot], sems.at[3, slot])]
+                                          vsbuf.at[half, rows],
+                                          sems.at[3, half])]
         return cps
 
-    # the walk's trip count IS the skip mechanism: blocks past the
-    # length are never visited, so program size stays O(1) in the table
-    # width (a python unroll over max_blocks would emit mb x Hkv copies
-    # of the DMA+MXU body — a compile cliff at long max_model_len)
-    nblk = jnp.minimum((ln + block_size - 1) // block_size, max_blocks)
+    def each_block(c, half, op):
+        """``op`` ("start" or "wait") the copies of the blocks of chunk c
+        that lie under the length: a loop and not an unroll, so the
+        kernel is traced and compiled once per call site whatever C is."""
+        def body(j, _):
+            for cp in copies(c, j, half):
+                getattr(cp, op)()
+            return 0
+        jax.lax.fori_loop(0, jnp.minimum(C, nblk - c * C), body, 0)
 
-    @pl.when(nblk > 0)
+    @pl.when(nchunk > 0)
     def _():
-        for cp in copies(0, 0):
-            cp.start()
+        each_block(0, 0, "start")
 
-    def walk(b, _):
-        sl = jax.lax.rem(b, 2)
-        # prefetch block b+1 into the other slot while b computes (the
-        # standard two-slot pipeline; pl.when ends the stream exactly at
-        # the slot's last real block)
-        @pl.when(b + 1 < nblk)
+    def walk(c, carry):
+        m_prev, l_prev, acc = carry
+        half = jax.lax.rem(c, 2)
+
+        # chunk c+1 streams into the other half while c computes (the
+        # two-slot pipeline, C copies deep; each_block's trip count ends
+        # the stream at the slot's last real block)
+        @pl.when(c + 1 < nchunk)
         def _():
-            for cp in copies(b + 1, 1 - sl):
-                cp.start()
+            each_block(c + 1, 1 - half, "start")
 
-        for cp in copies(b, sl):
-            cp.wait()
-        col = (jax.lax.broadcasted_iota(jnp.int32, (1, block_size), 1)
-               + b * block_size)
-        live = col < ln                                      # [1, bs]
-        for h in range(n_kv):                    # static kv-head groups
-            qh = q_ref[0, h]                                 # [G, D]
-            kh = kbuf[sl][:, h]                              # [bs, D]
-            if kv_int8:
-                kh = kh.astype(qh.dtype)         # int8 widen: exact
-            s = jax.lax.dot_general(
-                qh, kh, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * sm_scale
-            if kv_int8:
-                s = s * ksbuf[sl][:, h][None, :]
-            s = jnp.where(live, s, jnp.float32(-1e30))       # [G, bs]
-            m_prev = ms[h]                                   # [G]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new[:, None])
-            ls[h] = ls[h] * alpha + jnp.sum(p, axis=-1)
-            vh = vbuf[sl][:, h]
-            if kv_int8:
-                # V scale rides the probabilities (it varies along the
-                # contracted axis) and int8 V widens in-register
-                p = p * vsbuf[sl][:, h][None, :]
-                vh = vh.astype(jnp.float32)
-            else:
-                p = p.astype(vh.dtype)
-            pv = jax.lax.dot_general(
-                p, vh, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)          # [G, D]
-            accs[h] = accs[h] * alpha[:, None] + pv
-            ms[h] = m_new
-        return 0
+        each_block(c, half, "wait")
 
-    jax.lax.fori_loop(0, nblk, walk, 0)
+        def zero(j, _):
+            rows = pl.ds(j * block_size, block_size)
+            vbuf[half, rows] = jnp.zeros(
+                (block_size,) + vbuf.shape[2:], vbuf.dtype)
+            if kv_int8:
+                vsbuf[half, rows] = jnp.zeros(
+                    (block_size, n_kv), jnp.float32)
+            return 0
+        jax.lax.fori_loop(jnp.minimum(C, nblk - c * C), C, zero, 0)
 
-    acc_ref[0] = accs[:]
-    m_ref[0] = ms[:]
-    l_ref[0] = ls[:]
+        q = q_ref[0]                                         # [Hq, D]
+        k = kflat(half)                                      # [R, D]
+        if kv_int8:
+            k = k.astype(q.dtype)                # int8 widen: exact
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale   # [Hq, R]
+        if kv_int8:
+            s = s * ksbuf[half].reshape(1, R)
+        s = jnp.where(tokcol[...] < ln - c * T, s, jnp.float32(-1e30))
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        v = vflat(half)
+        if kv_int8:
+            # V scale rides the probabilities (it varies along the
+            # contracted axis) and int8 V widens in-register
+            p = p * vsbuf[half].reshape(1, R)
+            v = v.astype(jnp.float32)
+        else:
+            p = p.astype(v.dtype)
+        pv = jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)              # [Hq, D]
+        return m_new, l_new, acc * alpha + pv
+
+    m, l, acc = jax.lax.fori_loop(
+        0, nchunk, walk,
+        (jnp.full((Hq, 1), -1e30, jnp.float32),
+         jnp.zeros((Hq, 1), jnp.float32), jnp.zeros((Hq, D), jnp.float32)))
+    acc_ref[0] = acc
+    m_ref[0] = m
+    l_ref[0] = l
 
 
 def ragged_decode_partial(q, k_pool, v_pool, block_table, lengths, *,
@@ -518,8 +596,10 @@ def ragged_decode_partial(q, k_pool, v_pool, block_table, lengths, *,
 
     One compiled variant serves ANY length mix: the table width MB is
     the only shape, and slots read exactly ``ceil(lengths[n]/BS)``
-    blocks of it. VMEM use is two double-buffered blocks + the [Hkv, G,
-    D] accumulators, independent of context length — no long-context
+    blocks of it, ``_walk_chunk_blocks`` of them per loop iteration.
+    VMEM use is two chunks of K and of V (1 MiB in all at 8 KV heads of
+    128 in bf16) + the chunk's [Hq, rows] scores and mask + the [Hq, D]
+    accumulator, independent of context length — no long-context
     staging-buffer cliff like :func:`paged_decode_attention`'s.
 
     With ``mesh`` (a Mesh carrying a 'tp' axis of size > 1) the call is
@@ -566,27 +646,26 @@ def ragged_decode_partial(q, k_pool, v_pool, block_table, lengths, *,
     kv_int8 = kp.dtype == jnp.int8
     if kv_int8 and (ks_pool is None or vs_pool is None):
         raise ValueError("int8 pools require ks_pool/vs_pool scales")
-    qg = q.reshape(N, Hkv, G, D)
+    C = _walk_chunk_blocks(bs, Hkv, D, kp.dtype.itemsize, mb)
+    T = C * bs
 
     in_specs = [
-        pl.BlockSpec((1, Hkv, G, D), lambda n, l, t, ln: (n, 0, 0, 0)),
+        pl.BlockSpec((1, Hq, D), lambda n, l, t, ln: (n, 0, 0)),
         pl.BlockSpec(memory_space=pl.ANY),     # pools stay in HBM
         pl.BlockSpec(memory_space=pl.ANY),
     ]
-    inputs = [qg, kp, vp]
-    scratch = [pltpu.VMEM((2, bs, Hkv, D), kp.dtype),
-               pltpu.VMEM((2, bs, Hkv, D), vp.dtype)]
+    inputs = [q, kp, vp]
+    scratch = [pltpu.VMEM((2, T, Hkv, D), kp.dtype),
+               pltpu.VMEM((2, T, Hkv, D), vp.dtype)]
     if kv_int8:
         ksp = ks_pool if ks_pool.ndim == 4 else ks_pool[None]
         vsp = vs_pool if vs_pool.ndim == 4 else vs_pool[None]
         in_specs += [pl.BlockSpec(memory_space=pl.ANY),
                      pl.BlockSpec(memory_space=pl.ANY)]
         inputs += [ksp.astype(jnp.float32), vsp.astype(jnp.float32)]
-        scratch += [pltpu.VMEM((2, bs, Hkv), jnp.float32),
-                    pltpu.VMEM((2, bs, Hkv), jnp.float32)]
-    scratch += [pltpu.VMEM((Hkv, G, D), jnp.float32),
-                pltpu.VMEM((Hkv, G), jnp.float32),
-                pltpu.VMEM((Hkv, G), jnp.float32),
+        scratch += [pltpu.VMEM((2, T, Hkv), jnp.float32),
+                    pltpu.VMEM((2, T, Hkv), jnp.float32)]
+    scratch += [pltpu.VMEM((Hq, T * Hkv), jnp.int32),
                 pltpu.SemaphoreType.DMA((4 if kv_int8 else 2, 2))]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -594,23 +673,24 @@ def ragged_decode_partial(q, k_pool, v_pool, block_table, lengths, *,
         grid=(N,),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, Hkv, G, D), lambda n, l, t, ln: (n, 0, 0, 0)),
-            pl.BlockSpec((1, Hkv, G), lambda n, l, t, ln: (n, 0, 0)),
-            pl.BlockSpec((1, Hkv, G), lambda n, l, t, ln: (n, 0, 0)),
+            pl.BlockSpec((1, Hq, D), lambda n, l, t, ln: (n, 0, 0)),
+            pl.BlockSpec((1, Hq, 1), lambda n, l, t, ln: (n, 0, 0)),
+            pl.BlockSpec((1, Hq, 1), lambda n, l, t, ln: (n, 0, 0)),
         ],
         scratch_shapes=scratch,
     )
     acc, m, l = pl.pallas_call(
         functools.partial(_ragged_decode_kernel, block_size=bs, n_kv=Hkv,
-                          max_blocks=mb, kv_int8=kv_int8),
+                          max_blocks=mb, chunk=C, kv_int8=kv_int8),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((N, Hkv, G, D), jnp.float32),
-                   jax.ShapeDtypeStruct((N, Hkv, G), jnp.float32),
-                   jax.ShapeDtypeStruct((N, Hkv, G), jnp.float32)],
+        out_shape=[jax.ShapeDtypeStruct((N, Hq, D), jnp.float32),
+                   jax.ShapeDtypeStruct((N, Hq, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((N, Hq, 1), jnp.float32)],
         interpret=_interpret(),
     )(jnp.asarray(layer, jnp.int32)[None], block_table.astype(jnp.int32),
       lengths.astype(jnp.int32), *inputs)
-    return acc, m, l
+    return (acc.reshape(N, Hkv, G, D), m.reshape(N, Hkv, G),
+            l.reshape(N, Hkv, G))
 
 
 def ragged_paged_decode(q, cache: PagedKVCache, layer=0, ks_pool=None,
@@ -621,8 +701,8 @@ def ragged_paged_decode(q, cache: PagedKVCache, layer=0, ks_pool=None,
     contract as :func:`paged_attention` — which remains the XLA gather
     reference and the numerics oracle in tests — but lengths are a
     runtime operand: one compiled program serves any length mix, reads
-    no block past any slot's length, and holds only two blocks in VMEM
-    however long the context. Zero-length slots return 0. ``mesh``
+    no block past any slot's length, and holds only two chunks of blocks
+    in VMEM however long the context. Zero-length slots return 0. ``mesh``
     shards the walk over the 'tp' axis (see
     :func:`ragged_decode_partial`)."""
     N, Hq, D = q.shape

@@ -150,6 +150,24 @@ def test_ragged_walk_bf16(topo):
     _compile(_ragged, _one(topo), *_ragged_specs(8, BF16))
 
 
+@pytest.mark.parametrize("hkv,kv_dtype", [(8, BF16), (4, BF16),
+                                          (4, jnp.float32)],
+                         ids=["cell", "tp2-shard", "tp2-shard-f32"])
+def test_ragged_walk_cell_shapes(topo, hkv, kv_dtype):
+    """The chunked walk at the benchmark's serving cells' shapes (16 slots,
+    32 query heads on 8 KV heads, head dim 128, blocks of 16, a table 160
+    wide) and at what one shard of a tp=2 engine sees of them (4 KV heads;
+    chip_smoke.py's tp engine keeps float32 pools): the flat view of a
+    chunk and the all-heads dot have to hold at both."""
+    g = HQ // HKV
+    chunk = paged_attention._walk_chunk_blocks(
+        BS, hkv, D, jnp.dtype(kv_dtype).itemsize, 160)
+    assert chunk * BS * hkv == 1024, chunk     # 128 tokens x 8 heads' worth
+    pool = ((LAYERS, NB, BS, hkv, D), kv_dtype)
+    _compile(_ragged, _one(topo), ((16, g * hkv, D), BF16), pool, pool,
+             ((16, 160), jnp.int32), ((16,), jnp.int32))
+
+
 @refused(paged_attention.ragged_tpu_refusal(D, kv_int8=True))
 def test_ragged_walk_int8_kv(topo):
     _compile(_ragged, _one(topo), *_ragged_specs(8, jnp.int8))

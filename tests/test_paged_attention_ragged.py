@@ -4,10 +4,16 @@ Contracts under test (interpret mode — the chip lane is
 tests_tpu/test_ragged_decode_tpu.py):
 - the true-length block walk matches the dense gather reference
   (paged_attention) across mixed lengths including length-1 and exact
-  block-boundary lengths, for f32 and bf16 pools;
-- masked-tail exactness: garbage in the tail of the last block and in
-  blocks past the length changes NOTHING (bit-identical output — the
-  masked exp is exactly 0.0);
+  block-boundary lengths, for f32 and bf16 pools; and, one case a
+  length, at every length that straddles an edge of the walk's chunks
+  (0, 1, bs-1, bs, C*bs-1, C*bs, C*bs+1, the table's full width), at 8,
+  4 and 2 KV heads under 4 query heads each, over 4-D pools and over
+  5-D pools read at a layer other than 0;
+- masked-tail exactness: garbage in the tail of the last block, and NaN
+  and inf in the blocks past the length and in the trash block — which
+  lie inside the last chunk the walk fetches — change NOTHING
+  (bit-identical output — the masked exp is exactly 0.0 and a dead V
+  row is never multiplied);
 - int8 KV pools: the in-kernel scale folding (attn_qk/attn_pv math)
   matches dequantize-then-attend;
 - prefix-cache-hit shaped tables: slots sharing physical history blocks;
@@ -18,6 +24,9 @@ tests_tpu/test_ragged_decode_tpu.py):
   fallback is counted in serving_decode_kernel_total — never silent.
 """
 import dataclasses
+import functools
+import importlib
+from unittest import mock
 
 import numpy as np
 import pytest
@@ -34,17 +43,21 @@ from paddle_tpu.kernels.quant_matmul import dequantize_kv, quantize_kv
 from paddle_tpu.models import llama
 from paddle_tpu.serving import LLMEngine
 
+# kernels/__init__ re-exports a FUNCTION named paged_attention, which
+# shadows the module on attribute access
+_kernel_mod = importlib.import_module("paddle_tpu.kernels.paged_attention")
+
 BS, HKV, G, D, MB = 4, 2, 2, 16, 4
 
 
-def _mk(rng, n_slots, dtype, lens):
-    nb = n_slots * MB + 1
-    kp = jnp.asarray(rng.standard_normal((nb, BS, HKV, D)), dtype)
-    vp = jnp.asarray(rng.standard_normal((nb, BS, HKV, D)), dtype)
+def _mk(rng, n_slots, dtype, lens, bs=BS, hkv=HKV, g=G, mb=MB):
+    nb = n_slots * mb + 1
+    kp = jnp.asarray(rng.standard_normal((nb, bs, hkv, D)), dtype)
+    vp = jnp.asarray(rng.standard_normal((nb, bs, hkv, D)), dtype)
     table = jnp.asarray(rng.permutation(np.arange(1, nb)).reshape(n_slots,
-                                                                  MB),
+                                                                  mb),
                         jnp.int32)
-    q = jnp.asarray(rng.standard_normal((n_slots, G * HKV, D)), dtype)
+    q = jnp.asarray(rng.standard_normal((n_slots, g * hkv, D)), dtype)
     return q, PagedKVCache(kp, vp, table, jnp.asarray(lens, jnp.int32))
 
 
@@ -62,6 +75,83 @@ def test_ragged_kernel_matches_dense_reference(dtype, atol):
     got = ragged_paged_decode(q, cache)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), atol=atol)
+
+
+# the walk moves C blocks per loop iteration; C follows from the shapes
+# (kernels/paged_attention._walk_chunk_blocks). The cases below shrink the
+# rows a chunk aims at, so that C is 4 at every head count and a table of
+# 2C+1 blocks holds two full chunks and a partial one in interpret mode's
+# time; the last case runs the rule as it stands.
+EDGE_BS, EDGE_G, EDGE_C = 8, 4, 4
+EDGE_LENS = {"0": lambda bs, c, mb: 0, "1": lambda bs, c, mb: 1,
+             "bs-1": lambda bs, c, mb: bs - 1, "bs": lambda bs, c, mb: bs,
+             "C*bs-1": lambda bs, c, mb: c * bs - 1,
+             "C*bs": lambda bs, c, mb: c * bs,
+             "C*bs+1": lambda bs, c, mb: c * bs + 1,
+             "full": lambda bs, c, mb: mb * bs}
+
+
+# (KV heads, 5-D pools read at layer 1, block size, rows a chunk aims at)
+EDGE_CASES = {f"hkv{h}-{'5d-layer1' if layered else '4d'}":
+              (h, layered, EDGE_BS, EDGE_C * EDGE_BS * h)
+              for h in (8, 4, 2) for layered in (False, True)}
+EDGE_CASES["derived-chunk"] = (8, False, 16, _kernel_mod._WALK_ROWS)
+
+
+@functools.lru_cache(maxsize=None)
+def _edge_run(hkv, layered, bs, rows):
+    """One walk over a slot per edge length; (got, want, lens, chunk).
+    ``layered`` reads plane 1 of 5-D pools whose plane 0 is poison."""
+    with mock.patch.object(_kernel_mod, "_WALK_ROWS", rows):
+        c = _kernel_mod._walk_chunk_blocks(bs, hkv, D, 4, 10 ** 6)
+        mb = 2 * c + 1
+        lens = [f(bs, c, mb) for f in EDGE_LENS.values()]
+        q, cache = _mk(np.random.default_rng(hkv + 10 * layered), len(lens),
+                       jnp.float32, lens, bs=bs, hkv=hkv, g=EDGE_G, mb=mb)
+        want = paged_attention(q, cache)
+        if layered:
+            cache = cache._replace(
+                k_pool=jnp.stack([jnp.full_like(cache.k_pool, jnp.nan),
+                                  cache.k_pool]),
+                v_pool=jnp.stack([jnp.full_like(cache.v_pool, jnp.inf),
+                                  cache.v_pool]))
+        got = ragged_paged_decode(q, cache, layer=int(layered))
+    return np.asarray(got), np.asarray(want), lens, c
+
+
+@pytest.mark.parametrize("case", list(EDGE_CASES))
+@pytest.mark.parametrize("length", list(EDGE_LENS))
+def test_ragged_chunk_edge_lengths_match_dense_reference(length, case):
+    """One case a length at every edge of the chunked walk: shorter than
+    a block, a block, one short of a chunk, a chunk, one past it (the
+    second chunk holds one live token), the table's full width (two
+    chunks and a partial one) — and the empty slot, which emits 0. At 8,
+    4 and 2 KV heads under 4 query heads each, over 4-D pools and 5-D
+    ones read at layer 1, with C held at 4; and once with the chunk the
+    rule derives by itself (blocks of 16 under 8 KV heads: 8 blocks)."""
+    got, want, lens, c = _edge_run(*EDGE_CASES[case])
+    assert c == (8 if case == "derived-chunk" else EDGE_C)
+    i = list(EDGE_LENS).index(length)
+    if lens[i] == 0:
+        assert np.all(got[i] == 0.0)
+    else:
+        np.testing.assert_allclose(got[i], want[i], atol=1e-5)
+
+
+def test_walk_chunk_follows_the_shapes():
+    """C comes from block size, KV heads, head dim, pool dtype and a VMEM
+    budget: 1024 flat rows at the cells' shapes and at a tp=2 shard's,
+    halved where two chunks of K and V would not fit, never wider than
+    the table."""
+    chunk = _kernel_mod._walk_chunk_blocks
+    assert chunk(16, 8, 128, 2, 160) == 8
+    assert chunk(16, 4, 128, 2, 160) == 16
+    assert chunk(64, 8, 128, 2, 40) == 2
+    assert chunk(16, 8, 128, 2, 3) == 3
+    assert chunk(256, 8, 128, 2, 40) == 1
+    big = chunk(16, 8, 512, 4, 160)              # 4 MiB would be 16 MiB
+    assert big < 8
+    assert 4 * big * 16 * 8 * 512 * 4 <= _kernel_mod._WALK_VMEM_BYTES
 
 
 def test_ragged_masked_tail_bit_exact():
@@ -84,6 +174,49 @@ def test_ragged_masked_tail_bit_exact():
     poisoned = ragged_paged_decode(q, PagedKVCache(
         jnp.asarray(kp), jnp.asarray(vp), cache.block_table, cache.lengths))
     np.testing.assert_array_equal(np.asarray(clean), np.asarray(poisoned))
+
+
+@pytest.mark.parametrize("dead", ["trash-block", "stale-blocks"])
+def test_ragged_masked_tail_bit_exact_inside_the_last_chunk(dead):
+    """The chunked walk fetches whole chunks of C blocks, and a slot's
+    last chunk reaches past its length. What lies there — the trash block
+    0 every dead table entry points at in the engine, or blocks another
+    request left behind — may hold anything: NaN and inf there, and
+    finite garbage in the last live block's tail, leave every bit of the
+    output as it was. (0.0 times a NaN V row would be NaN: the dead rows
+    of a chunk are never multiplied as they were fetched.)"""
+    bs, hkv, c = 4, 2, 4
+    mb = 2 * c + 1
+    # one block of a chunk, all but one, one token into the second chunk,
+    # the second chunk's last block but one
+    lens = [3, (c - 1) * bs, c * bs + 1, (2 * c - 1) * bs - 2]
+    q, cache = _mk(np.random.default_rng(9), len(lens), jnp.float32, lens,
+                   bs=bs, hkv=hkv, g=2, mb=mb)
+    kp, vp = np.array(cache.k_pool), np.array(cache.v_pool)
+    tbl = np.array(cache.block_table)
+    if dead == "trash-block":
+        for i, ln in enumerate(lens):
+            tbl[i, -(-ln // bs):] = 0
+
+    def run(kp, vp):
+        with mock.patch.object(_kernel_mod, "_WALK_ROWS", c * bs * hkv):
+            assert _kernel_mod._walk_chunk_blocks(bs, hkv, D, 4, mb) == c
+            return np.asarray(ragged_paged_decode(q, PagedKVCache(
+                jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(tbl),
+                jnp.asarray(lens, jnp.int32))))
+
+    clean = run(kp, vp)
+    assert np.isfinite(clean).all()
+    kp, vp = kp.copy(), vp.copy()
+    kp[0], vp[0] = np.nan, np.inf                    # the trash block
+    for i, ln in enumerate(lens):
+        for b in range(mb):
+            lo = max(0, ln - b * bs)
+            if lo == 0 and tbl[i, b]:                # a whole dead block
+                kp[tbl[i, b]], vp[tbl[i, b]] = np.inf, np.nan
+            elif lo < bs:                            # the live block's tail
+                kp[tbl[i, b], lo:], vp[tbl[i, b], lo:] = 1e4, -1e4
+    np.testing.assert_array_equal(clean, run(kp, vp))
 
 
 def test_ragged_int8_matches_dequant_reference():

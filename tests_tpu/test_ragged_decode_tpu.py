@@ -2,8 +2,8 @@
 
 The CPU tier-1 lane (tests/test_paged_attention_ragged.py) only ever
 exercises the Pallas INTERPRETER; this lane proves the compiled Mosaic
-kernel — the true-length block walk, the pl.when-skipped tail blocks,
-the in-register int8 dequant — against the XLA gather oracle on the
+kernel — the true-length block walk in chunks, the pl.when-skipped tail
+blocks of a slot's last chunk, the in-register int8 dequant — against the XLA gather oracle on the
 chip, then the engine acceptance criteria: greedy stream parity vs the
 bucketed path and exactly ONE compiled decode variant per
 sampling-flag set.
@@ -52,6 +52,36 @@ def test_ragged_kernel_matches_xla_oracle_on_chip():
     want = np.asarray(paged_attention(q, cache), np.float32)
     got = np.asarray(ragged_paged_decode(q, cache), np.float32)
     np.testing.assert_allclose(got, want, atol=5e-2, rtol=5e-2)
+
+
+@pytest.mark.parametrize("hkv", [8, 4], ids=["cell", "tp2-shard"])
+def test_ragged_walk_cell_shapes_length_mix_on_chip(hkv):
+    """The chunked walk, compiled, at the benchmark's serving cells'
+    shapes (16 slots, 4 query heads a KV head, head dim 128, blocks of
+    16, a table 160 wide, 5-D bf16 pools read at layer 1) and at a tp=2
+    shard's 4 KV heads, over a ragged length mix: empty slots, one token,
+    a block and a chunk each one short, exact and one over, several
+    chunks with a partial last one, the table's full width."""
+    from paddle_tpu.kernels.paged_attention import (
+        PagedKVCache, _walk_chunk_blocks, paged_attention,
+        ragged_paged_decode)
+    rng = np.random.default_rng(2)
+    N, BS, G, D, MB = 16, 16, 4, 128, 160
+    C = _walk_chunk_blocks(BS, hkv, D, 2, MB)
+    assert C * BS * hkv == 1024
+    lens = [0, 1, BS - 1, BS, BS + 1, C * BS - 1, C * BS, C * BS + 1,
+            2 * C * BS, 5 * BS + 3, 617, 777, 900, 0, MB * BS - 1, MB * BS]
+    q, cache = _mk(rng, N, BS, hkv, G, D, MB, jnp.bfloat16, lens)
+    layered = PagedKVCache(
+        jnp.stack([jnp.full_like(cache.k_pool, jnp.nan), cache.k_pool]),
+        jnp.stack([jnp.full_like(cache.v_pool, jnp.inf), cache.v_pool]),
+        cache.block_table, cache.lengths)
+    got = np.asarray(ragged_paged_decode(q, layered, layer=1), np.float32)
+    want = np.asarray(paged_attention(q, cache), np.float32)
+    live = np.asarray(lens) > 0
+    assert np.isfinite(got).all()
+    assert (got[~live] == 0).all()
+    np.testing.assert_allclose(got[live], want[live], atol=5e-2, rtol=5e-2)
 
 
 # withdrawn from selection in PR 21: Mosaic refuses the int8 walk
