@@ -1,10 +1,10 @@
 """The comparison that decides ``correct``.
 
-What the timed path produced is compared with the plain reference
-(``benchmark/reference/``), number by number, each against a limit of its
-own kept in ``limits/<workload>.json`` with the readings it was set from.
-Every run prints each number beside its limit. A number with no limit in
-the file fails: a limit is never guessed here.
+What the timed path produced is compared with the plain reference (the
+family's: ``benchmark/reference/``), number by number, each against a limit
+of its own kept in ``limits/<workload>.json`` with the readings it was set
+from. Every run prints each number beside its limit. A number with no limit
+in the file fails: a limit is never guessed here.
 """
 from __future__ import annotations
 
@@ -15,19 +15,28 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from . import traffic, weights
+from .manifest import family_of
 
 
-def judge(numbers: Dict[str, float], limits: Dict, log) -> bool:
-    """True when every number is at or under its limit."""
-    good = True
+def judge(numbers: Dict[str, float], limits: Dict) -> Dict[str, Dict]:
+    """Each number compared beside its limit, and whether it is at or under
+    it: ``{name: {"value", "limit", "ok"}}``. A run is correct when all
+    are."""
+    out = {}
     for name, value in numbers.items():
         lim = limits.get(name, {}).get("limit")
         fine = (lim is not None and value is not None
                 and np.isfinite(value) and value <= lim)
-        log(f"correct: {name} = {value!r} (limit {lim!r}) "
-            f"{'ok' if fine else 'FAIL'}")
-        good = good and bool(fine)
-    return good
+        if value is not None and not np.isfinite(value):
+            value = repr(float(value))      # "nan" and "inf" are not JSON
+        out[name] = {"value": value, "limit": lim, "ok": bool(fine)}
+    return out
+
+
+def lines(compared: Dict[str, Dict]) -> List[str]:
+    """One line a number, as a run prints them."""
+    return [f"correct: {name} = {c['value']!r} (limit {c['limit']!r}) "
+            f"{'ok' if c['ok'] else 'FAIL'}" for name, c in compared.items()]
 
 
 def load_limits(data_dir: str, workload: str) -> Dict:
@@ -57,21 +66,23 @@ def served_gaps(model: Dict, seed: int, samples: List[Dict],
     tokens it was served: at every served position, how far the served
     token's reference logit lies below the reference's best. With
     ``control``, the same for the token that the lower precision puts
-    first. The layers are made again from the seed one at a time."""
+    first. The layers are made again from the seed one at a time; what a
+    layer is, and the reference, are the family's."""
     import jax
     import jax.numpy as jnp
 
-    from .reference import llama_f32 as ref
-
+    ref = family_of(model).reference
     key = weights.seed_key(seed)
     vocab = model["vocab_size"]
-    make = jax.jit(lambda k, l: weights.make_layer(model, k, l, jnp.bfloat16))
+    make = weights.layer_maker(model, jnp.bfloat16)
+    names = weights.top_names(model)
     top = jax.jit(lambda k: {
-        n: weights.make_top(model, k, n, jnp.bfloat16)
-        for n in ("embed", "lm_head")})(key)
-    top["final_norm"] = jnp.ones((model["hidden_size"],), jnp.float32)
-    layer = jax.jit(lambda x, p, q: ref.layer(x, p, model, q),
-                    static_argnums=2)
+        n: weights.make_top(model, k, n, jnp.bfloat16) for n in names})(key)
+    # one compiled layer a kind: a kind's layers differ in their weights
+    # only, so each runs the program of its kind's first layer
+    kinds = weights.layer_kinds(model)
+    layer = jax.jit(lambda x, p, q, l: ref.layer(x, p, model, q, l),
+                    static_argnums=(2, 3))
     seqs = []
     for s in samples:
         ids = traffic.prompt_tokens(seed, s["tag"], s["prompt_len"], vocab) \
@@ -92,14 +103,15 @@ def served_gaps(model: Dict, seed: int, samples: List[Dict],
     ends_control = jax.jit(lambda x, xq, top: below_best(
         ref.head_logits(x[0], top, model),
         ref.head_logits(xq[0], top, model, control).argmax(axis=-1)))
-    embed = jax.jit(lambda t, top: top["embed"].astype(jnp.float32)[t])
+    embed = jax.jit(ref.embed)
     with jax.default_matmul_precision("highest"):
         xs = [embed(t, top) for t in seqs]
         xc = list(xs) if control else []
-        for l in range(model["num_hidden_layers"]):
+        for l, kind in enumerate(kinds):
             p = make(key, l)
-            xs = [layer(x, p, None) for x in xs]
-            xc = [layer(x, p, control) for x in xc]
+            first = kinds.index(kind)
+            xs = [layer(x, p, None, first) for x in xs]
+            xc = [layer(x, p, control, first) for x in xc]
         gaps, cgaps = [], []
         for i, s in enumerate(samples):
             # position prompt_len - 1 predicts the first served token

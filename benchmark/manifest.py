@@ -4,8 +4,12 @@ Everything that belongs to one configuration, one traffic mix or one metric
 sits in a file of its own, found by the name in ``BENCHMARK.json``:
 ``configs/<config>.json`` (the path is the manifest's ``file``),
 ``traffic/<traffic>.json`` and ``metrics/<metric>.json`` under the
-benchmark's directory. A later PR adds a cell, a configuration or a metric
-by adding files and entries; nothing here is edited for it.
+benchmark's directory. What belongs to one family of models (its weights,
+its program config, its reference, its costs) sits in
+``families/<family>.py``, found by the configuration's ``family`` as a
+reader is found by a metric's ``reader``. A later PR adds a cell, a
+configuration, a metric or a family by adding files and entries; nothing
+here is edited for it.
 """
 from __future__ import annotations
 
@@ -21,6 +25,11 @@ ROOT = os.path.dirname(HERE)
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+# what a family module gives the harness (families/llama.py states each)
+FAMILY_MEMBERS = ("program_config", "engine_kwargs", "trainer", "layer_kind",
+                  "make_layer", "make_top", "make_params", "reference",
+                  "costs", "tiny")
+DATA_DIR = "_data_dir"      # where a loaded configuration's data files lie
 
 
 def _load(path: str) -> Dict:
@@ -44,7 +53,11 @@ class Manifest:
         return self.workloads[name]
 
     def config(self, name: str) -> Dict:
-        return _load(os.path.join(self.root, self.configs[name]["file"]))
+        """The configuration's file, and (in memory only) where its data
+        files lie, for ``family_of``."""
+        doc = _load(os.path.join(self.root, self.configs[name]["file"]))
+        doc[DATA_DIR] = self.data_dir
+        return doc
 
     def traffic(self, name: str) -> Dict:
         return _load(os.path.join(self.data_dir, "traffic", name + ".json"))
@@ -85,6 +98,10 @@ class Manifest:
             if sorted(doc.get("reduced", [])) != sorted(c["reduced"]):
                 raise ValueError(f"config {c['name']}: 'reduced' differs "
                                  "between BENCHMARK.json and its file")
+            if "family" not in doc:
+                raise ValueError(f"config {c['name']}: its file names no "
+                                 "'family'")
+            family_of(doc)
         pairs = set()
         for w in d["workloads"]:
             if set(w) != {"name", "config", "traffic", "chips", "why"}:
@@ -140,20 +157,51 @@ class Manifest:
                         "does not report")
 
 
-@functools.lru_cache(maxsize=None)
-def load_reader(name: str, data_dir: str = None):
-    """The reader module ``readers/<name>.py``: the benchmark's own, or one
-    beside the data files that a later PR added."""
-    if not NAME.match(name):
-        raise ValueError(f"bad reader name {name!r}")
-    own = os.path.join(HERE, "readers", name + ".py")
-    path = own if os.path.exists(own) or not data_dir else os.path.join(
-        data_dir, "readers", name + ".py")
+def load_file(path: str):
+    """The module in the file at ``path``, which need not lie on
+    ``sys.path``: a family beside the data files loads its reference and
+    its costs so."""
+    if not os.path.exists(path):
+        raise ValueError(f"no module at {path}")
+    sub, stem = os.path.split(os.path.splitext(os.path.abspath(path))[0])
     spec = importlib.util.spec_from_file_location(
-        f"benchmark_reader_{name}", path)
+        f"benchmark_{os.path.basename(sub)}_{stem}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def _find(sub: str, name: str, data_dir: Optional[str]):
+    """``<sub>/<name>.py``: the benchmark's own, or one beside the data
+    files that a later PR added."""
+    if not NAME.match(name):
+        raise ValueError(f"bad name {name!r} for a module of {sub}/")
+    own = os.path.join(HERE, sub, name + ".py")
+    return load_file(own if os.path.exists(own) or not data_dir
+                     else os.path.join(data_dir, sub, name + ".py"))
+
+
+@functools.lru_cache(maxsize=None)
+def load_reader(name: str, data_dir: str = None):
+    """The reader module ``readers/<name>.py``."""
+    return _find("readers", name, data_dir)
+
+
+@functools.lru_cache(maxsize=None)
+def load_family(name: str, data_dir: str = None):
+    """The family module ``families/<name>.py``, with every member of the
+    interface."""
+    mod = _find("families", name, data_dir)
+    lacks = [m for m in FAMILY_MEMBERS if not hasattr(mod, m)]
+    if lacks:
+        raise ValueError(f"family {name!r} ({mod.__file__}) lacks {lacks}")
+    return mod
+
+
+def family_of(model: Dict):
+    """The family module of a configuration, as ``Manifest.config`` gave it
+    or as a plain dict that names one of the benchmark's own families."""
+    return load_family(model["family"], model.get(DATA_DIR))
 
 
 def read_metrics(man: Manifest, cell: str, kind: str, record: Dict) -> Dict:

@@ -1,7 +1,8 @@
 """A program's or kernel's share (%) of its roofline in the traced
 stretch: the least time the chip could take for the work the algorithm
-needs (``benchmark/costs.py``, peaks from ``benchmark/peaks.py``) over the
-device time the trace shows. ``what`` picks the work:
+needs (the ``costs`` of the configuration's family, peaks from
+``benchmark/peaks.py``) over the device time the trace shows. ``what`` picks
+the work:
 
 decode        runs of the decode program: weights once a step + live KV
 prefill       runs of the prefill programs: the real prompt tokens' FLOPs
@@ -14,7 +15,8 @@ train_flash   the flash kernel's operations in the train step, fwd + bwd
 their text, inside those runs where ``program`` is given. A share over 105%
 is refused: the work would be counted too high or the time would leave
 part of it out."""
-from benchmark import costs, trace
+from benchmark import trace
+from benchmark.manifest import family_of
 from benchmark.readers_util import (traced_decode_load, traced_prefill_rows,
                                     traced_train_steps)
 
@@ -24,6 +26,7 @@ def read(rec, what, program=None, op=None, op_lacks=None):
     if not red:
         return None
     m, peak = rec["model"], rec["peak"]
+    costs = family_of(m).costs
     runs = trace.module_runs(red, **program) if program else None
     if op:
         seconds = trace.op_seconds(red, op, op_lacks, runs)
@@ -58,7 +61,7 @@ def read(rec, what, program=None, op=None, op_lacks=None):
         flops, nbytes = flops / red["chips"], nbytes / red["chips"]
     else:
         raise ValueError(f"unknown work {what!r}")
-    share, _bound = costs.roofline_share(flops, nbytes, seconds, peak)
+    share = 100.0 * max(flops / peak.flops, nbytes / peak.hbm_bw) / seconds
     if share > 105.0:
         raise ValueError(f"{what} roofline share {share:.1f}% > 105%: the "
                          "work is counted too high or the time leaves part "

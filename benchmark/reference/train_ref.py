@@ -1,7 +1,8 @@
 """The plain reference follows the first two training steps.
 
 Float32 at ``highest`` matmul precision, AdamW as published, weights made
-again from the seed by ``benchmark/weights.py``: nothing of the program is
+again from the seed by ``benchmark/weights.py``, the loss and the update the
+family's reference (``families/<family>.py``): nothing of the program is
 used. It reports what the ``correct`` check compares: each step's loss, the
 norm of the first gradient as the optimizer gets it (after the global-norm
 clip) leaf by leaf, and the norm of the parameters' change after the two
@@ -25,7 +26,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import weights
-from . import llama_f32 as ref
+from ..manifest import family_of
 
 
 def leaf_norms(tree) -> Dict[str, jnp.ndarray]:
@@ -54,6 +55,7 @@ def _spread(shape, n: int) -> P:
 def follow(m: Dict, hp: Dict, seed: int, batches: List[np.ndarray],
            devices, quant: Optional[str] = None) -> Dict:
     """Two reference steps on ``batches[0]`` and ``batches[1]``."""
+    ref = family_of(m).reference
     mesh = Mesh(np.asarray(devices), ("x",))
     n = len(devices)
     rep = NamedSharding(mesh, P())

@@ -5,8 +5,9 @@
 Fails, with no result line, where JAX finds no TPU or fewer chips than the
 cell asks for. Otherwise the last line of standard output is one JSON
 object: ``correct``, ``attempted``, ``failed``, ``metrics`` (the cell's
-end-to-end metrics, or with ``--trace 1`` its per-layer metrics) and
-``device``.
+end-to-end metrics, or with ``--trace 1`` its per-layer metrics),
+``device`` and, last, ``compared``: each number that decided ``correct``
+beside its limit, which are also the last lines of standard error.
 """
 from __future__ import annotations
 
@@ -224,9 +225,10 @@ def measure(man, args, devices) -> Dict:
     rec.update(kind=model["kind"], model=model, chips=cell["chips"])
     if devices[0].platform == "tpu":
         rec["peak"] = peaks.peak(devices[0].device_kind)
-    ok = correct.judge(rec["numbers"], limits, log)
+    compared = correct.judge(rec["numbers"], limits)
     kind = "per_layer" if args.trace else "end_to_end"
-    out = {"correct": bool(ok), "attempted": int(rec["attempted"]),
+    out = {"correct": all(c["ok"] for c in compared.values()),
+           "attempted": int(rec["attempted"]),
            "failed": int(rec["failed"]),
            "metrics": manifest.read_metrics(man, args.workload, kind, rec),
            "device": {"platform": devices[0].platform,
@@ -237,6 +239,9 @@ def measure(man, args, devices) -> Dict:
         out["device"].update(busy_s=red["busy_s"], window_s=red["window_s"])
         out["breakdown"] = {"device_ops": red["device_ops"],
                             "idle_gaps": red["idle_gaps"]}
+    out["compared"] = compared      # last in the line: what decided correct
+    for line in correct.lines(compared):
+        log(line)
     return out
 
 
@@ -254,6 +259,8 @@ def main(argv=None) -> int:
     if args.trace and out["device"].get("busy_s", 0.0) <= 0.0:
         raise SystemExit("the traced run saw no operation on the device")
     print(json.dumps(out), flush=True)
+    print("\n".join(correct.lines(out["compared"])), file=sys.stderr,
+          flush=True)       # and last on standard error
     return 0
 
 

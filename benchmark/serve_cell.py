@@ -2,7 +2,8 @@
 
 The system under test is the program's ``LLMEngine`` wired as
 ``tools/serve.py`` wires it (``HTTPFrontDoor(ResilientEngine(engine))``),
-at the shape the configuration file states. The benchmark makes the
+at the shape the configuration file states, with the program config and
+the weights that the configuration's family gives. The benchmark makes the
 weights from the seed in one jitted call, warms the programs that this
 cell's traffic uses (its prompt buckets in both batch forms, the decode
 program), starts ``benchmark/client.py`` as a child, and collects: the
@@ -22,6 +23,7 @@ import time
 from typing import Dict, List
 
 from . import clientstats, traffic, weights
+from .manifest import family_of
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 TRACE_STEPS = 240         # engine steps traced: about four seconds
@@ -29,27 +31,6 @@ TRACE_TAIL_S = 8.0        # a traced run's load goes on this long past the
 #                           window, and the capture is taken there: when it
 #                           stops, the profiler stalls the step thread for
 #                           half a minute, which the window must not pay
-
-
-def llama_config(model: Dict, **over):
-    """The program's config object from the file's published keys."""
-    import jax.numpy as jnp
-
-    from paddle_tpu.models import llama
-
-    if model.get("sliding_window") is not None:
-        raise ValueError("the engine has no sliding-window attention")
-    kw = dict(vocab_size=model["vocab_size"], hidden_size=model["hidden_size"],
-              intermediate_size=model["intermediate_size"],
-              num_layers=model["num_hidden_layers"],
-              num_heads=model["num_attention_heads"],
-              num_kv_heads=model["num_key_value_heads"],
-              head_dim=model["head_dim"], rope_theta=model["rope_theta"],
-              rms_eps=model["rms_norm_eps"],
-              tie_embeddings=model["tie_word_embeddings"],
-              dtype=jnp.bfloat16)
-    kw.update(over)
-    return llama.LlamaConfig(**kw)
 
 
 def build(model: Dict, seed: int, log):
@@ -67,7 +48,9 @@ def build(model: Dict, seed: int, log):
         raise ValueError("serving cells run bf16 weights and KV")
     obs.enable()                       # a deployment serves its counters
     set_flags({"obs_trace_capacity": 200000})
-    cfg = llama_config(model, max_seq_len=sv["max_model_len"], remat=False)
+    fam = family_of(model)
+    cfg = fam.program_config(model, max_seq_len=sv["max_model_len"],
+                             remat=False)
     t0 = time.monotonic()
     params = jax.jit(lambda k: weights.make_params(model, k, jnp.bfloat16))(
         weights.seed_key(seed))
@@ -80,7 +63,8 @@ def build(model: Dict, seed: int, log):
         prompt_buckets=list(sv["prompt_buckets"]),
         decode_steps=sv["decode_steps"], decode_kernel=sv["decode_kernel"],
         prefix_cache=sv["prefix_cache"], prefill_chunk=sv["prefill_chunk"],
-        admission=AdmissionConfig(max_queue=sv["max_queue"]), seed=0)
+        admission=AdmissionConfig(max_queue=sv["max_queue"]), seed=0,
+        **fam.engine_kwargs(model))
     front = HTTPFrontDoor(ResilientEngine(eng), host="127.0.0.1", port=0)
     return eng, front, params
 

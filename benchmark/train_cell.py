@@ -1,14 +1,15 @@
 """A training cell: the trainer's fused step on a (dp, tp) mesh.
 
-The system under test is ``paddle_tpu.models.llama.train_step`` with the
-program's own shardings and optimizer state, built as
-``examples/llama_pretrain.py`` and ``chip_smoke.py`` build it: one compiled
-step, donated state, fed its own output. The benchmark makes the initial
-weights from the seed (``benchmark/weights.py``, float32) directly onto the
-mesh, and feeds rows made on the host from the seed by a prefetching
-thread. Set-up builds ONE object (the compiled step and its state), drives
-it through its first three steps with the window's own call and feed, and
-hands that same object to the window.
+The system under test is the ``train_step`` of the program's module that
+the configuration's family names (``trainer``), with the program's own
+shardings and optimizer state, built as ``examples/llama_pretrain.py`` and
+``chip_smoke.py`` build it: one compiled step, donated state, fed its own
+output. The benchmark makes the initial weights from the seed
+(``benchmark/weights.py``, float32) directly onto the mesh, and feeds rows
+made on the host from the seed by a prefetching thread. Set-up builds ONE
+object (the compiled step and its state), drives it through its first three
+steps with the window's own call and feed, and hands that same object to
+the window.
 """
 from __future__ import annotations
 
@@ -20,8 +21,8 @@ from typing import Dict, List
 import numpy as np
 
 from . import traffic, weights
+from .manifest import family_of
 from .reference.train_ref import leaf_norms
-from .serve_cell import llama_config
 
 FOLLOWED_STEPS = 2          # steps the reference follows
 
@@ -73,7 +74,6 @@ def build(model: Dict, plan: Dict, seed: int, devices, log) -> Dict:
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from paddle_tpu.models import llama
     from paddle_tpu.optimizer.functional import init_moments, moment_shardings
 
     t = model["train"]
@@ -83,20 +83,22 @@ def build(model: Dict, plan: Dict, seed: int, devices, log) -> Dict:
                          "bf16 compute")
     dp, tp = t["mesh"]["dp"], t["mesh"]["tp"]
     mesh = Mesh(np.asarray(devices[:dp * tp]).reshape(dp, tp), ("dp", "tp"))
-    cfg = llama_config(model, max_seq_len=plan["seq"], remat=True,
-                       remat_policy=t["remat_policy"],
-                       loss_chunks=t["loss_chunks"])
-    psh = llama.make_shardings(cfg, mesh, fsdp=t["fsdp"])
+    fam = family_of(model)
+    tr = fam.trainer(model)
+    cfg = fam.program_config(model, max_seq_len=plan["seq"], remat=True,
+                             remat_policy=t["remat_policy"],
+                             loss_chunks=t["loss_chunks"])
+    psh = tr.make_shardings(cfg, mesh, fsdp=t["fsdp"])
     rep = NamedSharding(mesh, P())
 
     def init(key):
         params = weights.make_params(model, key, jnp.float32)
         mu, nu = init_moments(params, "adamw", jnp.float32)
-        return llama.TrainState(params, mu, nu, jnp.zeros((), jnp.int32))
+        return tr.TrainState(params, mu, nu, jnp.zeros((), jnp.int32))
 
     shapes = jax.eval_shape(init, weights.seed_key(seed))
     mu_sh, nu_sh = moment_shardings(psh, shapes.params, "adamw")
-    ssh = llama.TrainState(psh, mu_sh, nu_sh, rep)
+    ssh = tr.TrainState(psh, mu_sh, nu_sh, rep)
     t0 = time.monotonic()
     state = jax.jit(init, out_shardings=ssh)(weights.seed_key(seed))
     jax.block_until_ready(state)
@@ -104,9 +106,9 @@ def build(model: Dict, plan: Dict, seed: int, devices, log) -> Dict:
         f"bytes over {mesh.size} device(s) in {time.monotonic() - t0:.1f}s")
     rows = NamedSharding(mesh, P("dp", None))
     hp = hyper(model)
-    with llama.activation_mesh(mesh):
+    with tr.activation_mesh(mesh):
         step = jax.jit(
-            lambda s, tok: llama.train_step(
+            lambda s, tok: tr.train_step(
                 s, tok, cfg, lr=hp["lr"], beta1=hp["beta1"],
                 beta2=hp["beta2"], eps=hp["eps"], wd=hp["weight_decay"],
                 clip_norm=hp["clip_norm"], optimizer="adamw"),

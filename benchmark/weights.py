@@ -1,20 +1,16 @@
 """Seeded random weights, made by the benchmark and handed to the program.
 
-Every leaf is drawn from its own key, ``fold_in(fold_in(key, leaf), layer)``,
-so the plain reference can make one layer again from the seed alone and
-takes nothing that the program has made. The tree has the layout the
-program's entry points accept (``paddle_tpu/models/llama.py`` ``init_params``:
-a dict with the layers stacked on a leading axis), and the scales are that
-function's (1/sqrt(fan_in), the residual outputs divided by sqrt(2L)), which
-make the logits of unit scale.
+Every leaf is drawn from its own key, so the plain reference can make one
+layer again from the seed alone and takes nothing that the program has
+made. Which leaves, in which tree and at which scales, is the family's
+(``families/<family>.py``, found by the configuration's ``family``); this
+module makes the key and passes the calls on.
 """
 from __future__ import annotations
 
-import math
-from typing import Dict
+from typing import Callable, Dict, List
 
-LAYER_LEAVES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
-_TOP = {"embed": 100, "lm_head": 101}
+from .manifest import family_of
 
 
 def seed_key(seed: int):
@@ -26,61 +22,46 @@ def seed_key(seed: int):
                               seed >> 31)
 
 
-def layer_shapes(m: Dict) -> Dict[str, tuple]:
-    h, f, d = m["hidden_size"], m["intermediate_size"], m["head_dim"]
-    nq, nkv = m["num_attention_heads"], m["num_key_value_heads"]
-    return {"wq": (h, nq * d), "wk": (h, nkv * d), "wv": (h, nkv * d),
-            "wo": (nq * d, h), "w_gate": (h, f), "w_up": (h, f),
-            "w_down": (f, h)}
-
-
-def _scale(m: Dict, name: str) -> float:
-    h, f, L = m["hidden_size"], m["intermediate_size"], m["num_hidden_layers"]
-    if name == "wo":
-        return 1.0 / math.sqrt(h) / math.sqrt(2 * L)
-    if name == "w_down":
-        return 1.0 / math.sqrt(f) / math.sqrt(2 * L)
-    return 1.0 / math.sqrt(h)
-
-
 def make_layer(m: Dict, key, layer, dtype):
-    """The matrices of one layer (``layer`` may be traced)."""
-    import jax
-    import jax.numpy as jnp
-
-    out = {}
-    for i, name in enumerate(LAYER_LEAVES):
-        k = jax.random.fold_in(jax.random.fold_in(key, i), layer)
-        w = jax.random.normal(k, layer_shapes(m)[name], jnp.float32)
-        out[name] = (w * _scale(m, name)).astype(dtype)
-    h = m["hidden_size"]
-    out["attn_norm"] = jnp.ones((h,), dtype)
-    out["mlp_norm"] = jnp.ones((h,), dtype)
-    return out
+    """The leaves of one layer (``layer`` may be traced where the family
+    has one kind of layer)."""
+    return family_of(m).make_layer(m, key, layer, dtype)
 
 
 def make_top(m: Dict, key, name: str, dtype):
-    """``embed`` [vocab, h] or ``lm_head`` [h, vocab]."""
-    import jax
-    import jax.numpy as jnp
-
-    h, v = m["hidden_size"], m["vocab_size"]
-    shape = (v, h) if name == "embed" else (h, v)
-    w = jax.random.normal(jax.random.fold_in(key, _TOP[name]), shape,
-                          jnp.float32)
-    return (w / math.sqrt(h)).astype(dtype)
+    """The leaf ``name`` of the tree that is not a layer."""
+    return family_of(m).make_top(m, key, name, dtype)
 
 
 def make_params(m: Dict, key, dtype):
-    """The whole tree, layers stacked. Call under ``jax.jit`` with the key
-    as an argument, so that one compiled program serves every seed."""
+    """The whole tree. Call under ``jax.jit`` with the key as an argument,
+    so that one compiled program serves every seed."""
+    return family_of(m).make_params(m, key, dtype)
+
+
+def top_names(m: Dict):
+    """The names of the tree's leaves that are not layers."""
     import jax
     import jax.numpy as jnp
 
-    L = m["num_hidden_layers"]
-    layers = jax.vmap(lambda l: make_layer(m, key, l, dtype))(jnp.arange(L))
-    params = {"embed": make_top(m, key, "embed", dtype), "layers": layers,
-              "final_norm": jnp.ones((m["hidden_size"],), dtype)}
-    if not m.get("tie_word_embeddings"):
-        params["lm_head"] = make_top(m, key, "lm_head", dtype)
-    return params
+    tree = jax.eval_shape(lambda k: make_params(m, k, jnp.float32),
+                          seed_key(0))
+    return sorted(n for n in tree if n != "layers")
+
+
+def layer_kinds(m: Dict) -> List:
+    """Each layer's kind, in order."""
+    fam = family_of(m)
+    return [fam.layer_kind(m, l) for l in range(m["num_hidden_layers"])]
+
+
+def layer_maker(m: Dict, dtype) -> Callable:
+    """``make(key, l)`` for a Python ``l``: that layer's leaves as
+    ``make_params`` draws them. Where every layer is of one kind, one
+    program with ``l`` traced; where the kinds differ, the family is given
+    a static ``l``, and each layer is a (small) program of its own."""
+    import jax
+
+    one_kind = len(set(layer_kinds(m))) == 1
+    return jax.jit(lambda k, l: make_layer(m, k, l, dtype),
+                   static_argnums=() if one_kind else 1)

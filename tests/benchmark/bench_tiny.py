@@ -11,9 +11,6 @@ import types
 
 from benchmark import manifest
 
-TINY_MODEL = {"hidden_size": 64, "intermediate_size": 128,
-              "num_attention_heads": 4, "num_key_value_heads": 2,
-              "head_dim": 16, "vocab_size": 256, "num_hidden_layers": 2}
 TINY_SERVE = {"max_slots": 4, "block_size": 8, "max_model_len": 128,
               "prompt_buckets": [16, 32, 64], "max_queue": 64}
 TINY_LEN = {"prompt": {"dist": "lognormal", "median": 24, "sigma": 0.5,
@@ -23,18 +20,30 @@ TINY_LEN = {"prompt": {"dist": "lognormal", "median": 24, "sigma": 0.5,
 LOOSE = 1e9     # the rehearsals check the plumbing, not the precision
 
 
+def __getattr__(name):
+    """``TINY_MODEL``: the benchmark's first configuration at the sizes its
+    family shrinks it to, with the family's name."""
+    if name != "TINY_MODEL":
+        raise AttributeError(name)
+    man = manifest.Manifest()
+    doc = man.config(man.doc["configs"][0]["name"])
+    return {"family": doc["family"], **manifest.family_of(doc).tiny(doc)}
+
+
 def make_root(tmp: str, limits=None) -> manifest.Manifest:
     """``tmp`` becomes a checkout's worth of benchmark data: the real files
-    copied, every configuration and traffic mix shrunk in place."""
+    copied, every configuration shrunk in place as its family says, every
+    traffic mix as below. Files that ``tmp`` already holds under
+    ``benchmark/`` (a family, a configuration of it) stay."""
     shutil.copy(os.path.join(manifest.ROOT, "BENCHMARK.json"), tmp)
     data = os.path.join(tmp, "benchmark")
     for sub in ("configs", "traffic", "metrics", "limits"):
         shutil.copytree(os.path.join(manifest.HERE, sub),
-                        os.path.join(data, sub))
+                        os.path.join(data, sub), dirs_exist_ok=True)
     for name in os.listdir(os.path.join(data, "configs")):
         path = os.path.join(data, "configs", name)
         doc = json.load(open(path))
-        doc.update(TINY_MODEL)
+        doc.update(manifest.load_family(doc["family"], data).tiny(doc))
         if "serve" in doc:
             doc["serve"].update(TINY_SERVE)
         json.dump(doc, open(path, "w"))

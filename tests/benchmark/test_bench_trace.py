@@ -67,7 +67,8 @@ def test_reduce_by_hand():
 
 def test_roofline_share_from_a_trace_and_its_refusal():
     red = trace.reduce(_hand_made())
-    m = {"hidden_size": 4096, "intermediate_size": 14336, "head_dim": 128,
+    m = {"family": "llama",     # whose costs the reader looks up
+         "hidden_size": 4096, "intermediate_size": 14336, "head_dim": 128,
          "num_attention_heads": 32, "num_key_value_heads": 8,
          "vocab_size": 32768, "num_hidden_layers": 16}
     span = (10.0, 10.5)
