@@ -83,7 +83,8 @@ __all__ = [
     "dropless_moe_ffn_a2a", "dropless_moe_ffn_fused", "sort_by_expert",
     "fused_routing", "Routing", "plan_dispatch", "DispatchPlan",
     "clear_plan_cache", "pick_dispatch_form", "clear_form_cache",
-    "make_moe_operands", "time_best",
+    "make_moe_operands", "time_best", "group_limited_routing",
+    "held_expert_ffn",
 ]
 
 _M_PLAN_HITS = _instrument("moe_plan_cache_hits_total")
@@ -989,3 +990,134 @@ def dropless_moe_ffn_a2a(x, weights, idx, e_gate, e_up, e_down, mesh: Mesh,
         axis_names=set(tok_axes) | {"ep"},
         check_vma=False)
     return fn(x, weights, idx, e_gate, e_up, e_down, *shared)
+
+
+# ---------------------------------------------------------------------------
+# the served expert layer: one chip's share of an expert-parallel deployment
+# ---------------------------------------------------------------------------
+def group_limited_routing(probs, n_group: int, topk_group: int, top_k: int,
+                          scale: float):
+    """Group-limited greedy routing (DeepSeek-V2's device-limited routing):
+    ``probs`` [T, E] are the router's softmax over ALL experts; the experts
+    form ``n_group`` groups of E / n_group consecutive ones; a group scores
+    the max of its experts; the best ``topk_group`` groups stay, the rest
+    are masked to 0; the ``top_k`` best of what is left are chosen. Gates
+    are ``scale`` times the chosen probabilities, not renormalised. Ties go
+    to the lower index (``lax.top_k``), groups and experts alike. Returns
+    (gates [T, top_k] f32, idx [T, top_k] int32)."""
+    T, E = probs.shape
+    per = E // n_group
+    group = probs.reshape(T, n_group, per).max(axis=-1)
+    _, keep = jax.lax.top_k(group, topk_group)                    # [T, g]
+    kept = jnp.zeros((T, n_group), bool).at[
+        jnp.arange(T)[:, None], keep].set(True)
+    masked = jnp.where(jnp.repeat(kept, per, axis=1), probs, 0.0)
+    w, idx = jax.lax.top_k(masked, top_k)
+    return w * scale, idx.astype(jnp.int32)
+
+
+# pairs up to which a call gathers all of them at once; a wider wave walks
+# its sorted pairs in passes of about one row a token
+_HELD_PASS_ROWS = 8192
+# rows up to which the grouped matmul takes the narrow row tile
+_HELD_SMALL_ROWS = 1024
+
+
+def _mosaic() -> bool:
+    """Whether the grouped matmul lowers to the Mosaic kernel (a TPU)."""
+    return jax.default_backend() == "tpu"
+
+
+def _static_gmm(xs, w, gs):
+    """Grouped matmul with its tiling chosen by a RULE from the shapes
+    (``gmm_autotune.heuristic_tilings``), never timed: two runs of one
+    commit run the same kernel and no set-up carries a tuning. Off a TPU,
+    ``ragged_dot``. Rows past sum(gs) come back zero."""
+    from .gmm_autotune import heuristic_tilings
+
+    m, k = xs.shape
+    n = w.shape[-1]
+    if _mosaic():
+        if m <= _HELD_SMALL_ROWS and k % 512 == 0 and n % 512 == 0:
+            # a decode step: a few rows an expert. A row tile of 128 keeps
+            # the kernel at the weights' bytes (one pass of each hit
+            # expert's matrices); at 512 the MXU works through four times
+            # the padding and the step is bound by that (read on the chip,
+            # PR 28: 11.7 ms a step of grouped matmul against 4.2 of bytes)
+            tile = (128, 512, 1024 if n % 1024 == 0 else 512)
+            tilings = (tile, tile, tile)
+        else:
+            tilings = heuristic_tilings(m, k, n)
+        if tilings is None:
+            raise ValueError(f"no static gmm tiling for rows={m} k={k} n={n}")
+        return _gmm_tuned(xs, w, gs, tilings, False)
+    return jax.lax.ragged_dot(xs, w, gs)
+
+
+def held_expert_ffn(x, gates, idx, valid, e_gu, e_down, first: int):
+    """The routed part of an expert layer for the experts THIS chip holds:
+    ``sum_{e in top-k(x), e held} gate_e * Expert_e(x)`` for every row of
+    ``x`` [T, h]. ``idx``/``gates`` [T, k] come from a router over all
+    experts; the held ones are ``first .. first + E_held - 1`` (``e_gu``
+    [E_held, h, 2f] is gate and up side by side, ``e_down`` [E_held, f,
+    h]); a pair routed elsewhere, or of a row that is not ``valid``
+    (padding of a wave, an idle slot), is dropped here and loads no
+    expert: other chips compute it, and nothing stands in for them.
+
+    The pairs are sorted by held expert (foreign ones last), the rows of
+    the held pairs gathered, one grouped matmul form run over them and the
+    results scatter-added. A decode step (k*T rows at most
+    ``_HELD_PASS_ROWS``) gathers all pairs at once; a prefill wave walks
+    the sorted pairs in passes of about T rows, each under a ``cond`` on
+    whether any held pair is left, so that memory is bounded by the wave
+    and not by k times it, and no pair is dropped however skewed the
+    routing (with even routing one pass in k runs).
+
+    Returns (y [T, h] in x's dtype, counts): ``counts`` f32 [4] =
+    [pairs routed by valid rows, pairs held here, held experts with a row,
+    rows of the fullest held expert x held experts] (the last over the
+    second is the fullest expert's load over the mean, and stays so when
+    layers' counts are summed)."""
+    T, h = x.shape
+    k = idx.shape[1]
+    E = e_gu.shape[0]
+    f = e_down.shape[1]
+    dt = x.dtype
+    local = idx - first
+    held = (local >= 0) & (local < E) & valid[:, None]
+    local = jnp.where(held, local, E).reshape(T * k)              # E: foreign
+    order = jnp.argsort(local)                    # stable: by expert, by row
+    gs = jnp.sum(local[:, None] == jnp.arange(E, dtype=local.dtype)[None, :],
+                 axis=0).astype(jnp.int32)                        # [E]
+    ends = jnp.cumsum(gs)
+    total = ends[-1]
+    tok = (order // k).astype(jnp.int32)
+    gate = jnp.where(held, gates, 0.0).reshape(T * k)[order]
+    align = (128 if T * k <= _HELD_SMALL_ROWS else 512) if _mosaic() else 8
+    M = -(-(T * k if T * k <= _HELD_PASS_ROWS else T) // align) * align
+    n_pass = -(-T * k // M)
+    pad = n_pass * M - T * k
+    tok = jnp.pad(tok, (0, pad))
+    gate = jnp.pad(gate, (0, pad))
+
+    def one_pass(y, p):
+        lo = p * M
+        rows = jax.lax.dynamic_slice(tok, (lo,), (M,))
+        g = jax.lax.dynamic_slice(gate, (lo,), (M,))
+        # this pass's slice of each group: [lo, lo + M) cut out of the
+        # sorted pairs' group boundaries
+        cut = jnp.clip(ends, lo, lo + M)
+        gs_p = cut - jnp.concatenate([jnp.clip(lo, 0, total)[None],
+                                      cut[:-1]])
+        gu = _static_gmm(x[rows], e_gu.astype(dt), gs_p)
+        out = _static_gmm(jax.nn.silu(gu[:, :f]) * gu[:, f:],
+                          e_down.astype(dt), gs_p)
+        return y.at[rows].add(out * g[:, None].astype(dt))
+
+    y = jnp.zeros((T, h), dt)
+    for p in range(n_pass):
+        y = one_pass(y, p) if n_pass == 1 else jax.lax.cond(
+            p * M < total, functools.partial(one_pass, p=p), lambda y: y, y)
+    counts = jnp.stack([jnp.sum(valid) * k, total, jnp.sum(gs > 0),
+                        jnp.max(gs) * E]).astype(jnp.float32)
+    return y, counts

@@ -51,7 +51,7 @@ __all__ = ["PagedKVCache", "paged_cache_init", "paged_append",
            "RAGGED_INT8_KV_TPU_REFUSAL", "ragged_tpu_refusal",
            "paged_attention", "paged_append_token", "paged_append_blocks",
            "paged_decode_attention", "ragged_decode_partial",
-           "ragged_paged_decode"]
+           "ragged_paged_decode", "latent_decode_partial"]
 
 
 def _interpret() -> bool:
@@ -691,6 +691,131 @@ def ragged_decode_partial(q, k_pool, v_pool, block_table, lengths, *,
       lengths.astype(jnp.int32), *inputs)
     return (acc.reshape(N, Hkv, G, D), m.reshape(N, Hkv, G),
             l.reshape(N, Hkv, G))
+
+
+# ---------------------------------------------------------------------------
+# The latent walk: the same true-length chunked walk over a pool of LATENT
+# rows (multi-head latent attention in its absorbed form). A cached token is
+# one row per layer, shared by every query head: its first ``v_cols``
+# columns are the normed latent, which is key AND value, the next ones the
+# roped key part, the rest padding up to a multiple of the 128 lanes (zero
+# in the pool and in the query, so it adds exactly 0.0 to a score). The
+# query arrives absorbed — q_nope . W_UK beside q_rope — so scores are ONE
+# dot of all query heads against the chunk as it lies, and the values are a
+# lane-aligned slice of the same buffer: one copy per block, no second
+# pool, Hkv = 1 and no head mask. Per cached token-layer the heads do
+# 2 * Hq * (row + v_cols) FLOPs against one row's bytes: at 128 heads on
+# 512 + 64 columns that is the v5e's ridge, so the walk is bound by the MXU
+# and by HBM at once (the GQA walk above is bytes only).
+# ---------------------------------------------------------------------------
+def _latent_decode_kernel(layer_ref, table_ref, lens_ref, q_ref, pool_ref,
+                          acc_ref, m_ref, l_ref, buf, sems, *, block_size,
+                          max_blocks, chunk, v_cols, sm_scale):
+    """Grid (N,): slot n's walk, ``chunk`` blocks per loop iteration, as
+    ``_ragged_decode_kernel`` does it (two-chunk buffer, copies of chunk
+    c+1 in flight while c computes, the trip count ends at the slot's last
+    real block, the last chunk's remainder zeroed because a value row of
+    stale VMEM could be a NaN). Emits the online-softmax partials (acc
+    [Hq, v_cols], m, l) for the flash-decoding combine."""
+    n = pl.program_id(0)
+    lyr = layer_ref[0]
+    ln = lens_ref[n]
+    Hq = q_ref.shape[1]
+    C, T = chunk, chunk * block_size
+    nblk = jnp.minimum((ln + block_size - 1) // block_size, max_blocks)
+    nchunk = (nblk + C - 1) // C
+
+    def each_block(c, half, op):
+        def body(j, _):
+            cp = pltpu.make_async_copy(
+                pool_ref.at[lyr, table_ref[n, c * C + j]],
+                buf.at[half, pl.ds(j * block_size, block_size)],
+                sems.at[half])
+            getattr(cp, op)()
+            return 0
+        jax.lax.fori_loop(0, jnp.minimum(C, nblk - c * C), body, 0)
+
+    @pl.when(nchunk > 0)
+    def _():
+        each_block(0, 0, "start")
+
+    def walk(c, carry):
+        m_prev, l_prev, acc = carry
+        half = jax.lax.rem(c, 2)
+
+        @pl.when(c + 1 < nchunk)
+        def _():
+            each_block(c + 1, 1 - half, "start")
+
+        each_block(c, half, "wait")
+
+        def zero(j, _):
+            buf[half, pl.ds(j * block_size, block_size)] = jnp.zeros(
+                (block_size, buf.shape[2]), buf.dtype)
+            return 0
+        jax.lax.fori_loop(jnp.minimum(C, nblk - c * C), C, zero, 0)
+
+        q = q_ref[0]                                         # [Hq, W]
+        k = buf[half]                                        # [T, W]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale   # [Hq, T]
+        tok = jax.lax.broadcasted_iota(jnp.int32, (Hq, T), 1)
+        s = jnp.where(tok < ln - c * T, s, jnp.float32(-1e30))
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p.astype(k.dtype), k[:, :v_cols], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)              # [Hq, v_cols]
+        return m_new, l_new, acc * alpha + pv
+
+    m, l, acc = jax.lax.fori_loop(
+        0, nchunk, walk,
+        (jnp.full((Hq, 1), -1e30, jnp.float32),
+         jnp.zeros((Hq, 1), jnp.float32),
+         jnp.zeros((Hq, v_cols), jnp.float32)))
+    acc_ref[0] = acc
+    m_ref[0] = m
+    l_ref[0] = l
+
+
+def latent_decode_partial(q, pool, block_table, lengths, *, layer=0,
+                          v_cols: int, sm_scale: float):
+    """The latent walk, partial (flash-decoding) form. q: [N, Hq, W]
+    absorbed queries; pool: [L, NB, BS, W] latent rows (W a multiple of
+    128, ``v_cols`` too); block_table: [N, MB]; lengths: [N], a runtime
+    operand. Returns ``(acc [N, Hq, v_cols] f32, m [N, Hq] f32, l [N, Hq]
+    f32)``; a slot of length 0 gives the combine's identity. The chunk is
+    ``_walk_chunk_blocks`` with one KV head: 1024 tokens a loop iteration
+    at blocks of 16, 2.5 MiB of VMEM for the two-chunk buffer at W = 640."""
+    N, Hq, W = q.shape
+    bs, mb = pool.shape[2], block_table.shape[1]
+    assert pool.shape[3] == W and W % 128 == 0 and v_cols % 128 == 0, (
+        pool.shape, W, v_cols)
+    C = _walk_chunk_blocks(bs, 1, W, pool.dtype.itemsize, mb)
+    row = lambda n, l, t, ln: (n, 0, 0)
+    acc, m, l = pl.pallas_call(
+        functools.partial(_latent_decode_kernel, block_size=bs,
+                          max_blocks=mb, chunk=C, v_cols=v_cols,
+                          sm_scale=sm_scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(N,),
+            in_specs=[pl.BlockSpec((1, Hq, W), row),
+                      pl.BlockSpec(memory_space=pl.ANY)],  # pool stays in HBM
+            out_specs=[pl.BlockSpec((1, Hq, v_cols), row),
+                       pl.BlockSpec((1, Hq, 1), row),
+                       pl.BlockSpec((1, Hq, 1), row)],
+            scratch_shapes=[pltpu.VMEM((2, C * bs, W), pool.dtype),
+                            pltpu.SemaphoreType.DMA((2,))]),
+        out_shape=[jax.ShapeDtypeStruct((N, Hq, v_cols), jnp.float32),
+                   jax.ShapeDtypeStruct((N, Hq, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((N, Hq, 1), jnp.float32)],
+        interpret=_interpret(), name="mla_latent_walk",
+    )(jnp.asarray(layer, jnp.int32)[None], block_table.astype(jnp.int32),
+      lengths.astype(jnp.int32), q, pool)
+    return acc, m[..., 0], l[..., 0]
 
 
 def ragged_paged_decode(q, cache: PagedKVCache, layer=0, ks_pool=None,

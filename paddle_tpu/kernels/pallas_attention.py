@@ -322,3 +322,133 @@ def flash_attention_fwd(q, k, v, causal: bool = False,
     out = _flash(to_bh(q), to_bh(k), to_bh(v), causal, block_q, block_kv,
                  scale, groups)
     return jnp.swapaxes(out.reshape(B, H, S, D), 1, 2)
+
+
+# ---------------------------------------------------------------------------
+# forward only, partial form: unequal key and value widths, a key length per
+# group, and the log-sum-exp returned so that two calls combine
+# ---------------------------------------------------------------------------
+
+def _partial_kernel(len_ref, q_ref, k_ref, *rest, scale, causal, block_q,
+                    block_kv, groups, v_cols):
+    if v_cols is None:
+        v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
+    else:
+        o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
+    i, j = pl.program_id(1), pl.program_id(2)
+    nj = pl.num_programs(2)
+    kv_len = len_ref[pl.program_id(0) // groups]
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    run = j * block_kv < kv_len
+    if causal:
+        # tile fully above the diagonal contributes nothing
+        run &= (j * block_kv) <= (i * block_q + block_q - 1)
+
+    @pl.when(run)
+    def _():
+        q = q_ref[0]
+        k = k_ref[0]
+        v = k[:, :v_cols] if v_cols is not None else v_ref[0]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + j * block_kv
+        keep = col < kv_len
+        if causal:
+            row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) \
+                + i * block_q
+            keep &= row >= col
+        s = jnp.where(keep, s, jnp.float32(NEG_INF))
+        m_prev = m_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        # a row with no key yet keeps m = NEG_INF: exp(s - m) would be 1
+        p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
+        l_new = l_ref[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * alpha + pv
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+    @pl.when(j == nj - 1)
+    def _():
+        l = l_ref[:, :1]
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        lse_ref[0] = jnp.where(l > 0, m_ref[:, :1] + jnp.log(
+            jnp.maximum(l, 1e-30)), NEG_INF)
+
+
+def flash_partial(q, k, v=None, *, scale: float, causal: bool = False,
+                  kv_len=None, v_cols=None, block_q: int = 512,
+                  block_kv: int = 512, name: str = "flash_partial"):
+    """Blockwise softmax attention in partial form, forward only.
+
+    q: [G, S, Dk]; k: [Gk, T, Dk] with G a multiple of Gk (group g reads
+    keys g // (G // Gk)); v: [Gk, T, Dv] with any Dv, or None with
+    ``v_cols``: the values are then the first ``v_cols`` columns of the
+    keys, sliced in VMEM (a latent row is key and value at once).
+    ``kv_len`` [Gk] int32 masks keys at or past it, a runtime operand: key
+    tiles past it are neither fetched again nor computed. ``causal``
+    compares row and column indices as they are (S and T start together).
+    Returns ``(o [G, S, Dv] in q's dtype, lse [G, S] f32)``: normalised
+    output and log-sum-exp, -1e30 where a row saw no key, so that
+    ``combine_partials`` merges calls over disjoint key sets. Nothing of
+    size S x T is materialised. Dk, Dv and v_cols are multiples of 128 on
+    a TPU (Mosaic's lanes): pad with zero columns, which add 0.0."""
+    G, S, Dk = q.shape
+    Gk, T, _ = k.shape
+    assert G % Gk == 0 and (v is None) != (v_cols is None), (G, Gk, v_cols)
+    groups = G // Gk
+    Dv = v_cols if v is None else v.shape[-1]
+    bq, bkv = _pick_block(S, block_q), _pick_block(T, block_kv)
+    if kv_len is None:
+        kv_len = jnp.full((Gk,), T, jnp.int32)
+
+    def kv_map(g, i, j, lens):
+        # past the length the same tile is named again: no new copy
+        last = jnp.maximum((lens[g // groups] + bkv - 1) // bkv - 1, 0)
+        return (g // groups, jnp.minimum(j, last), 0)
+
+    in_specs = [pl.BlockSpec((1, bq, Dk), lambda g, i, j, lens: (g, i, 0)),
+                pl.BlockSpec((1, bkv, Dk), kv_map)]
+    operands = [q, k]
+    if v is not None:
+        in_specs.append(pl.BlockSpec((1, bkv, Dv), kv_map))
+        operands.append(v)
+    out, lse = pl.pallas_call(
+        functools.partial(_partial_kernel, scale=scale, causal=causal,
+                          block_q=bq, block_kv=bkv, groups=groups,
+                          v_cols=v_cols),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(G, S // bq, T // bkv),
+            in_specs=in_specs,
+            out_specs=[
+                pl.BlockSpec((1, bq, Dv), lambda g, i, j, lens: (g, i, 0)),
+                pl.BlockSpec((1, bq, 1), lambda g, i, j, lens: (g, i, 0))],
+            scratch_shapes=[pltpu.VMEM((bq, Dv), jnp.float32),
+                            pltpu.VMEM((bq, STATS), jnp.float32),
+                            pltpu.VMEM((bq, STATS), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((G, S, Dv), q.dtype),
+                   jax.ShapeDtypeStruct((G, S, 1), jnp.float32)],
+        interpret=_interpret(), name=name,
+    )(kv_len.astype(jnp.int32), *operands)
+    return out, lse[..., 0]
+
+
+def combine_partials(o1, lse1, o2, lse2):
+    """One softmax over the union of two disjoint key sets, from each
+    set's normalised output and log-sum-exp (``flash_partial``)."""
+    m = jnp.maximum(lse1, lse2)
+    w1, w2 = jnp.exp(lse1 - m), jnp.exp(lse2 - m)
+    out = (o1.astype(jnp.float32) * w1[..., None]
+           + o2.astype(jnp.float32) * w2[..., None]) \
+        / jnp.maximum(w1 + w2, 1e-30)[..., None]
+    return out.astype(o1.dtype)

@@ -96,6 +96,12 @@ class LlamaConfig:
     # parallel_cross_entropy serve the same memory goal on GPU.
     loss_chunks: int = 1
 
+    def served_model(self):
+        """This family behind the serving engine's model interface."""
+        from .llama_served import LlamaServed
+
+        return LlamaServed(self)
+
 
 def llama3_8b() -> LlamaConfig:
     return LlamaConfig()

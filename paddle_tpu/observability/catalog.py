@@ -249,6 +249,25 @@ CATALOG = {
                          "fetch + relay publish, per handed-off "
                          "stream (the d2h leg of the disagg "
                          "transfer)"),
+    # -- the served model's own counts (model interface, PR 28) -------------
+    "serving_kv_bytes_per_token": (
+        "gauge", (), "bytes a cached token occupies in the engine's pools "
+                     "over all layers, whatever the model's cache entries "
+                     "are (K and V rows; one padded latent row)"),
+    "serving_moe_routed_total": (
+        "counter", (), "token-expert pairs the served expert layers' "
+                       "routers chose (real tokens x top-k, summed over "
+                       "the expert layers), read back with the step's "
+                       "tokens"),
+    "serving_moe_assigned_total": (
+        "counter", (), "of serving_moe_routed_total, the pairs that fell "
+                       "on experts this engine holds (its share of an "
+                       "expert-parallel deployment) and were computed "
+                       "here"),
+    "serving_moe_load_max_over_mean": (
+        "gauge", (), "rows of the most loaded held expert over the mean "
+                     "rows of a held expert (each layer's ratio weighed "
+                     "by its rows), in the program read back last"),
     # -- fleet observability (observability.fleet, r17) --------------------
     "serving_fleet_slo_attainment": (
         "gauge", ("replica", "slo"),

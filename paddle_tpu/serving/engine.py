@@ -1,4 +1,7 @@
-"""Continuous-batching paged-KV serving engine for llama-family models.
+"""Continuous-batching paged-cache serving engine for any model that gives
+the served-model interface (models/llama_served.py states it; llama-family
+dense decoders and the latent-attention sparse-expert family
+models/deepseek_v2.py provide it).
 
 Parity surface: the reference wires its paged decode kernel into serving via
 incubate/nn/functional/block_multihead_attention (block tables + per-seq
@@ -91,7 +94,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import itertools
-import math
 import threading
 import time
 from collections import deque
@@ -103,14 +105,7 @@ import numpy as np
 
 from .. import observability as _obs
 from ..distributed.resilience.faults import SimulatedCrash
-from ..kernels.mega_decode import (MEGA_TPU_REFUSAL, mega_decode_loop,
-                                   mega_decode_step, mega_supported)
-from ..kernels.paged_attention import (ragged_decode_partial,
-                                       ragged_tpu_refusal)
-from ..kernels.quant_matmul import (attn_pv, attn_qk, quantize_kv,
-                                    weight_only_matmul as _wo_mm)
-from ..models.llama import (LlamaConfig, _apply_rope, _apply_rope_at,
-                            _attention, _rms_norm, _wmat)  # noqa: F401
+from ..models.llama_served import ServeOpts
 from ..observability import flight_recorder as _flight
 from ..observability import numerics as _nm
 from ..observability import perf as _perf
@@ -157,6 +152,10 @@ _M_CANCEL_NOOP = _instrument("serving_cancel_noop_total")
 _M_MEGA_FALLBACK = _instrument("serving_mega_fallback_total")
 _M_DISAGG_HANDOFFS = _instrument("serving_disagg_handoffs_total")
 _M_DISAGG_SECONDS = _instrument("serving_disagg_handoff_seconds")
+_M_KV_TOKEN_BYTES = _instrument("serving_kv_bytes_per_token")
+_M_MOE_ROUTED = _instrument("serving_moe_routed_total")
+_M_MOE_ASSIGNED = _instrument("serving_moe_assigned_total")
+_M_MOE_LOAD = _instrument("serving_moe_load_max_over_mean")
 
 
 @dataclasses.dataclass
@@ -259,21 +258,27 @@ def _apply_admissions(c_last, c_len, c_done, c_rem, wave_toks, slot_of_row,
 
 def _paged_prefill(params, tokens, blk_ids, true_len, pools,
                    temps, top_ks, top_ps, key, hist_len=None,
-                   ctx_tbl=None, *, config: LlamaConfig,
-                   sample_flags=(True, True, True), kv_int8: bool = False,
-                   numerics: bool = False, prefix_nbk: int = 0,
-                   kv_prefix: str = "", mesh=None):
+                   ctx_tbl=None, *, model, opts: ServeOpts = ServeOpts(),
+                   sample_flags=(True, True, True), prefix_nbk: int = 0):
     """Prefill a WAVE of admissions in one compiled program: causal
-    forward over the padded prompt batch, every layer's K/V written into
-    the slots' pool blocks by ONE batched scatter, and each request's
-    FIRST generated token sampled in-program.
+    forward over the padded prompt batch, every layer's new cache entries
+    written into the slots' pool blocks by ONE batched scatter per entry,
+    and each request's FIRST generated token sampled in-program.
+
+    The layer is the MODEL's (``model.prefill_layer``, the interface in
+    models/llama_served.py); this program owns what every served model
+    shares: the wave, the loop over the layers at static indices (a
+    model's layers may differ in kind), the write-back, the head on the
+    last real position, sampling.
 
     tokens: [B, S_bucket]; blk_ids: [B, S_bucket // bs] physical block
     ids (0 = trash block for pad rows / the pad tail); true_len: [B];
     temps/top_ks/top_ps: [B] sampling knobs; pools: the donated pool dict
-    ({"k", "v"} [L, NB, bs, Hkv, D] — plus per-entry f32 scale pools
-    {"ks", "vs"} [L, NB, bs, Hkv] when ``kv_int8``). Returns
-    (first_tokens [B] int32, pools).
+    (the model's cache entries, ``{name: [L, NB, bs, ...]}``, ``L`` the
+    layers an entry covers). Returns
+    (first_tokens [B] int32, pools, stats) — ``stats`` is the model's
+    small vector of counts for this wave (an expert layer's routed and
+    assigned pairs), or None.
 
     The engine pads every multi-admission wave to ``max_slots`` rows
     (single admissions use a dedicated B=1 variant — steady-state churn
@@ -291,174 +296,78 @@ def _paged_prefill(params, tokens, blk_ids, true_len, pools,
 
     Suffix/chunked prefill (``prefix_nbk > 0``, r10): the wave prefills
     only a PIECE of each row's context — tokens ``[hist_len[b],
-    hist_len[b] + true_len[b])`` — against KV already resident in the
-    pools (a matched prefix-cache path and/or this slot's earlier
+    hist_len[b] + true_len[b])`` — against cache entries already resident
+    in the pools (a matched prefix-cache path and/or this slot's earlier
     chunks). ``ctx_tbl`` [B, prefix_nbk] names the history's physical
-    blocks (power-of-two bucketed like the decode table; pad rows point
-    at the trash block and mask via ``hist_len``); the history K/V is
-    gathered ONCE up front, each piece token attends to
-    (masked history) + (causal within the piece), and RoPE offsets by
-    ``hist_len`` per row. With ``prefix_nbk == 0`` the program is the
-    original full-prompt prefill, bit for bit — cold traffic never pays
+    blocks (``model.history_blocks`` gives the width: llama's is
+    power-of-two bucketed like the decode table; pad rows point at the
+    trash block and mask via ``hist_len``). With ``prefix_nbk == 0`` the
+    program is the original full-prompt prefill — cold traffic never pays
     for the feature. The compiled family stays bounded: (prompt bucket)
-    x (2 batch forms) x (<= 8 flag tuples) x (log2 history buckets).
+    x (2 batch forms) x (<= 8 flag tuples) x (history widths).
 
-    ``kv_prefix`` (r13 speculative decoding) selects which pool entries
-    this program reads/writes: ``""`` = the target model's ``k``/``v``
-    (plus ``ks``/``vs`` under int8), ``"d"`` = the draft model's
-    ``dk``/``dv``. The draft prefill is the SAME program over the draft
-    params/config, dispatched right after the target's so both models'
-    KV cover every prefilled position (the draft's sampled token is
-    discarded — the target samples the stream).
-
-    ``mesh``: the engine's tp mesh, handed to the flash kernel, which
-    shard_maps itself over the heads (GSPMD partitions everything else
-    here, but not a Mosaic kernel).
+    ``opts.prefix`` (r13 speculative decoding) selects which pool entries
+    this program reads/writes: ``""`` = the target model's, ``"d"`` = the
+    draft model's. The draft prefill is the SAME program over the draft
+    params/model, dispatched right after the target's so both models'
+    entries cover every prefilled position (the draft's sampled token is
+    discarded — the target samples the stream). ``opts.mesh``: the
+    engine's tp mesh, handed to the model's kernels, which shard_map
+    themselves (GSPMD partitions everything else, but not a Mosaic
+    kernel).
     """
-    c = config
-    dt = c.dtype
-    pk, pv = kv_prefix + "k", kv_prefix + "v"
-    pks, pvs = kv_prefix + "ks", kv_prefix + "vs"
     B, S = tokens.shape
-    bs = pools[pk].shape[2]
-    nb = S // bs
-    x = params["embed"].astype(dt)[tokens]
-    freq = c.rope_theta ** (-jnp.arange(0, c.head_dim, 2, jnp.float32)
-                            / c.head_dim)
-    if prefix_nbk:
-        Lc, Hkv, D = c.num_layers, c.num_kv_heads, c.head_dim
-        G = c.num_heads // c.num_kv_heads
-        Pp = prefix_nbk * bs
-        scale = 1.0 / math.sqrt(D)
-        # per-row absolute positions: row b's piece starts hist_len[b]
-        # tokens into its sequence
-        pos = (hist_len.astype(jnp.float32)[:, None]
-               + jnp.arange(S, dtype=jnp.float32)[None, :])
-        ang = pos[:, :, None] * freq[None, None, :]        # [B, S, D/2]
-        cos, sin = jnp.cos(ang), jnp.sin(ang)
-        # one dense gather of every row's history (the decode hoist,
-        # applied to prefill); int8 pools dequantize here — prefill is
-        # compute-bound, the simple form wins over fused-scale dots
-        kpre = pools[pk][:, ctx_tbl].reshape(Lc, B, Pp, Hkv, D)
-        vpre = pools[pv][:, ctx_tbl].reshape(Lc, B, Pp, Hkv, D)
-        if kv_int8:
-            ksc = pools[pks][:, ctx_tbl].reshape(Lc, B, Pp, Hkv)
-            vsc = pools[pvs][:, ctx_tbl].reshape(Lc, B, Pp, Hkv)
-            kpre = kpre.astype(dt) * ksc[..., None].astype(dt)
-            vpre = vpre.astype(dt) * vsc[..., None].astype(dt)
-        # [B,1,1,1,Pp] over scores [B,Hkv,G,S,Pp]
-        pre_mask = (jnp.arange(Pp)[None, :]
-                    < hist_len[:, None])[:, None, None, None, :]
-        in_mask = jnp.tril(jnp.ones((S, S), bool))[None, None, None]
-    else:
-        pos = jnp.arange(S, dtype=jnp.float32)
-        ang = pos[:, None] * freq[None, :]
-        cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x = model.embed(params, tokens)
+    aux = model.prefill_begin(params, pools, tokens, true_len, hist_len,
+                              ctx_tbl, prefix_nbk, opts)
+    new = []
+    for l in range(model.num_layers):
+        x, ent = model.prefill_layer(params, l, x, aux, pools, opts)
+        new.append(ent)
 
-    k_all, v_all = [], []
-    for l in range(c.num_layers):
-        p = jax.tree_util.tree_map(lambda a: a[l], params["layers"])
-        hn = _rms_norm(x, p["attn_norm"], c.rms_eps)
-        q = _wo_mm(hn, p["wq"], dt).reshape(B, S, c.num_heads, c.head_dim)
-        k = _wo_mm(hn, p["wk"], dt).reshape(B, S, c.num_kv_heads,
-                                            c.head_dim)
-        v = _wo_mm(hn, p["wv"], dt).reshape(B, S, c.num_kv_heads,
-                                            c.head_dim)
-        if prefix_nbk:
-            q = _apply_rope_at(q, cos, sin)
-            k = _apply_rope_at(k, cos, sin)
-        else:
-            q = _apply_rope(q, cos, sin)
-            k = _apply_rope(k, cos, sin)
-        k_all.append(k)
-        v_all.append(v)
-        if prefix_nbk:
-            # piece attention: softmax over [history ; causal in-piece],
-            # the decode program's concat structure at prefill width —
-            # masked history positions contribute an exact 0.0
-            qg = q.reshape(B, S, Hkv, G, D)
-            s_pre = jnp.einsum("bshgd,bphd->bhgsp", qg, kpre[l],
-                               preferred_element_type=jnp.float32) * scale
-            s_in = jnp.einsum("bshgd,bthd->bhgst", qg, k,
-                              preferred_element_type=jnp.float32) * scale
-            s_pre = jnp.where(pre_mask, s_pre, -1e30)
-            s_in = jnp.where(in_mask, s_in, -1e30)
-            probs = jax.nn.softmax(
-                jnp.concatenate([s_pre, s_in], axis=-1), axis=-1)
-            att = (jnp.einsum("bhgsp,bphd->bshgd",
-                              probs[..., :Pp].astype(dt), vpre[l])
-                   + jnp.einsum("bhgst,bthd->bshgd",
-                                probs[..., Pp:].astype(dt), v))
-            att = att.reshape(B, S, c.num_heads * c.head_dim).astype(dt)
-        else:
-            # plain causal GQA attention — the model's own core
-            # (llama._attention)
-            att = _attention(q, k, v, c, mesh).reshape(
-                B, S, c.num_heads * c.head_dim)
-        x = x + _wo_mm(att, p["wo"], dt)
-        hn = _rms_norm(x, p["mlp_norm"], c.rms_eps)
-        gate = jax.nn.silu(_wo_mm(hn, p["w_gate"], dt))
-        x = x + _wo_mm(gate * _wo_mm(hn, p["w_up"], dt), p["w_down"], dt)
-
-    # hoisted writeback: all layers' K/V in ONE scatter per pool (the
+    # hoisted writeback: all layers' entries in ONE scatter per pool (the
     # per-layer Pallas/XLA block appends cost ~0.6 ms of launch overhead
     # each — 2L calls/prefill dwarfed the prefill math itself)
-    L = c.num_layers
-    flat = blk_ids.reshape(B * nb)
-    k_stack = jnp.stack(k_all).reshape(L, B * nb, bs, c.num_kv_heads,
-                                       c.head_dim)
-    v_stack = jnp.stack(v_all).reshape(L, B * nb, bs, c.num_kv_heads,
-                                       c.head_dim)
+    flat = blk_ids.reshape(-1)
+    stacked = {n: jnp.stack([e[n] for e in new]) for n in new[0]
+               if not n.startswith("_")}
     pools = dict(pools)
-    if kv_int8:
-        qk, sk = quantize_kv(k_stack)
-        qv, sv = quantize_kv(v_stack)
-        if numerics:
-            # paired pre/post-quant probe for the int8-KV site: one tiny
-            # fused reduction over this wave's K/V, shipped async — the
-            # numerics_quant_error{site="kv_int8"} error budget
-            _nm.record_quant_error("kv_int8", [(k_stack, qk, sk, -1),
-                                               (v_stack, qv, sv, -1)])
-        pools[pk] = pools[pk].at[:, flat].set(qk)
-        pools[pv] = pools[pv].at[:, flat].set(qv)
-        pools[pks] = pools[pks].at[:, flat].set(sk)
-        pools[pvs] = pools[pvs].at[:, flat].set(sv)
-    else:
-        pools[pk] = pools[pk].at[:, flat].set(k_stack)
-        pools[pv] = pools[pv].at[:, flat].set(v_stack)
+    for name, val in model.pack_entries(stacked, opts).items():
+        bs = pools[name].shape[2]
+        pools[name] = pools[name].at[:, flat].set(
+            val.reshape((val.shape[0], B * (S // bs), bs) + val.shape[3:]))
 
-    x = _rms_norm(x, params["final_norm"], c.rms_eps)
+    x = model.final_norm(params, x)
     last_h = x[jnp.arange(B), jnp.maximum(true_len - 1, 0)]
-    if c.tie_embeddings:
-        logits = (last_h @ params["embed"].astype(dt).T).astype(jnp.float32)
-    else:
-        logits = _wo_mm(last_h, params["lm_head"], dt).astype(jnp.float32)
+    logits = model.head(params, last_h)
     toks = _sample_rows(logits, key, temps, top_ks, top_ps, *sample_flags)
-    return toks, pools
+    stats = (sum(e["_stats"] for e in new) if "_stats" in new[-1] else None)
+    return toks, pools, stats
 
 
 def _paged_decode(params, last_tokens, lengths, done0, budgets, key, active,
                   block_table, pools, temps, top_ks, top_ps,
-                  eos_ids, *, config: LlamaConfig, n_steps: int,
-                  sample_flags=(True, True, True), kv_int8: bool = False,
-                  numerics: bool = False, ragged: bool = False,
-                  mega: bool = False, mega_multistep: bool = False,
-                  kv_prefix: str = "", mesh=None):
+                  eos_ids, *, model, n_steps: int,
+                  opts: ServeOpts = ServeOpts(),
+                  sample_flags=(True, True, True)):
     """``n_steps`` decode iterations in ONE compiled program (multi-step
     scheduling): the host loop syncs once per call instead of once per
     token. Slots that hit their eos or budget
     mid-scan flip to done (their ring entries are masked and never written
     back; their emitted entries read -1).
 
+    The layer is the MODEL's (``model.decode_layer``); this program owns
+    the scan, the in-call ring of new cache entries, the sampling epilogue
+    and the ring's write-back, shared by every served model.
+
     Hoisted-dense structure (r4; the per-step Pallas paged-append +
     paged-attention variant measured ~0.6 ms of launch overhead per call
     × 24 calls/step — 4-5× the decode math): the slot prefixes are frozen
-    for the whole call, so the pools are GATHERED ONCE into dense
-    [L, N, P, Hkv, D] arrays up front, the scan body runs pure fused XLA
-    (dense GQA attention over prefix + an in-call ring buffer written at
+    for the whole call, so a model may gather them ONCE up front
+    (``model.decode_begin``), the scan body runs pure fused XLA
+    (attention over prefix + an in-call ring buffer written at
     the uniform step index — no scatter), and the ring is written back to
-    the pools in ONE batched scatter at call end. Zero kernel launches
-    inside the scan; per-step cost matches the fixed-batch fused loop.
+    the pools in ONE batched scatter per entry at call end.
 
     Ragged prefix bucketing (r6): ``block_table`` arrives SLICED to the
     engine-chosen bucket [N, MB_bucket], so P = MB_bucket * bs covers only
@@ -468,32 +377,29 @@ def _paged_decode(params, last_tokens, lengths, done0, budgets, key, active,
     position >= a slot's length was masked to -1e30 before the softmax,
     so dropping it changes nothing (exp underflows to exactly 0.0).
 
-    int8 KV pools (``kv_int8``): the gathered prefix stays int8 through
-    the QK/PV contractions with per-entry scales applied to the f32
-    scores resp. folded into the probabilities (kernels/quant_matmul) —
-    half the gather/attention KV bytes. The in-call ring stays model
-    dtype and is quantized once at writeback.
+    int8 KV pools (``opts.kv_int8``, llama): the gathered prefix stays
+    int8 through the QK/PV contractions with per-entry scales applied to
+    the f32 scores resp. folded into the probabilities
+    (kernels/quant_matmul) — half the gather/attention KV bytes. The
+    in-call ring stays model dtype and is quantized once at writeback.
 
-    Ragged Pallas path (``ragged``, r12 — the default on TPU): no dense
-    hoist at all. ``block_table`` arrives at FULL width [N, mb] (one
+    Ragged Pallas path (``opts.ragged``, r12 — the default on TPU): no
+    dense hoist at all. ``block_table`` arrives at FULL width [N, mb] (one
     static shape forever) and ``lengths`` is a runtime operand: each
-    step, each layer calls kernels/paged_attention.ragged_decode_partial,
-    whose per-slot program walks the slot's block table at its TRUE
-    length (blocks past ``ceil(len/bs)`` are never visited — the walk's
-    trip count ends there: no DMA, no FLOPs) with an online softmax,
-    streaming int8 blocks unconverted
-    and dequantizing in-register. The kernel's partial state (acc, m, l)
-    merges with the in-call ring's scores via the flash-decoding combine
-    — mathematically the same softmax over [prefix ; ring], computed
-    blockwise. Consequences: the compile cache loses its prefix-bucket
-    axis entirely (ONE variant per sampling-flag set), per-step KV reads
-    scale with the tokens actually resident, and inactive / mid-chunk
-    slots walk zero blocks (their lengths are zeroed going in). The
-    writeback scatter and kv_int8 numerics probes are shared with the
-    bucketed path verbatim. Under a tp ``mesh`` the kernel call is
-    shard_mapped over the KV heads (r19): every shard walks the same
-    tables against its head slice of the pools — bit-identical partials,
-    no cross-shard collective inside the walk.
+    step, each layer calls the model's walk kernel
+    (kernels/paged_attention: ``ragged_decode_partial`` over K/V pools,
+    ``latent_decode_partial`` over a latent pool), whose per-slot program
+    walks the slot's block table at its TRUE length (blocks past
+    ``ceil(len/bs)`` are never visited — the walk's trip count ends
+    there: no DMA, no FLOPs) with an online softmax. The kernel's partial
+    state (acc, m, l) merges with the in-call ring's scores via the
+    flash-decoding combine — mathematically the same softmax over
+    [prefix ; ring], computed blockwise. Consequences: the compile cache
+    loses its prefix-bucket axis entirely (ONE variant per sampling-flag
+    set), per-step cache reads scale with the tokens actually resident,
+    and inactive / mid-chunk slots walk zero blocks (their lengths are
+    zeroed going in). Under a tp ``mesh`` llama's kernel call is
+    shard_mapped over the KV heads (r19).
 
     The (last, lengths, done, budgets, key) quintet is a device-resident
     carry: the engine feeds each call the previous call's outputs
@@ -507,158 +413,51 @@ def _paged_decode(params, last_tokens, lengths, done0, budgets, key, active,
 
     eos_ids: [N] (-1 = no eos); budgets: [N] tokens each slot may still
     emit. Returns (emitted [n_steps, N] int32 with -1 padding, last,
-    lengths, done, budgets, key, pools).
+    lengths, done, budgets, key, pools, stats) — ``stats`` as in
+    ``_paged_prefill``.
 
-    ``kv_prefix`` (r13): ``"d"`` runs this program as the speculative
-    DRAFT proposal loop — draft params/config, greedy flags, the draft's
-    ``dk``/``dv`` pool entries — reusing the identical ragged/bucketed
-    machinery at draft scale. Target pool entries pass through the
-    donated dict untouched.
+    ``opts.prefix`` (r13): ``"d"`` runs this program as the speculative
+    DRAFT proposal loop — draft params/model, greedy flags, the draft's
+    pool entries — reusing the identical ragged/bucketed machinery at
+    draft scale. Target pool entries pass through the donated dict
+    untouched.
 
-    Mega path (``mega``, r18): the whole layer stack of each step runs
-    as ONE persistent Pallas launch (kernels/mega_decode) — the r12
-    block walk, the per-layer ring write and the FFN fused, weights
+    Mega path (``opts.mega``, r18, llama): the whole layer stack of each
+    step runs as ONE persistent Pallas launch (kernels/mega_decode) — the
+    r12 block walk, the per-layer ring write and the FFN fused, weights
     streamed in tiles — so a decode step costs one kernel launch instead
     of L, and the hidden state never round-trips HBM between layers. The
     scan, the sampling epilogue and the end-of-call ring->pool scatter
     below are SHARED with the ragged path verbatim: that is the greedy
     stream-parity contract, and it keeps the variant cache at ONE entry
-    per sampling-flag set. ``mega_multistep`` (greedy draft waves only)
-    additionally hoists the scan itself into the kernel: the draft's k
-    sequential steps — lm_head argmax, embed gather, done/budget
+    per sampling-flag set. ``opts.mega_multistep`` (greedy draft waves
+    only) additionally hoists the scan itself into the kernel: the draft's
+    k sequential steps — lm_head argmax, embed gather, done/budget
     bookkeeping included — become one persistent launch instead of k.
     """
-    c = config
-    dt = c.dtype
-    pk, pv = kv_prefix + "k", kv_prefix + "v"
-    pks, pvs = kv_prefix + "ks", kv_prefix + "vs"
-    Lc = c.num_layers
     N, MB = block_table.shape
-    k_pool, v_pool = pools[pk], pools[pv]
-    bs = k_pool.shape[2]
-    Hkv, D = k_pool.shape[3], k_pool.shape[4]
-    G = c.num_heads // c.num_kv_heads
-    P = MB * bs
     S = n_steps
     lens0 = lengths                       # frozen prefix lengths
-    scale = 1.0 / math.sqrt(D)
-
-    if ragged or mega:
-        # true-length walk: no gather, no mask — the kernel reads only
-        # real blocks. Slots outside the decode set (inactive or
-        # mid-chunked-prefill) walk zero blocks.
-        walk_lens = jnp.where(active, lens0.astype(jnp.int32), 0)
-    else:
-        # ---- hoist: one dense gather of every slot's (frozen) prefix ----
-        # (int8 pools: the dense arrays stay int8 — half the bytes moved)
-        kd = k_pool[:, block_table].reshape(Lc, N, P, Hkv, D)
-        vd = v_pool[:, block_table].reshape(Lc, N, P, Hkv, D)
-        if kv_int8:
-            ksc = pools[pks][:, block_table].reshape(Lc, N, P, Hkv)
-            vsc = pools[pvs][:, block_table].reshape(Lc, N, P, Hkv)
-        pre_mask = (jnp.arange(P)[None, :]
-                    < lens0[:, None])[:, None, None, :]   # [N,1,1,P]
-
-    freq = c.rope_theta ** (-jnp.arange(0, c.head_dim, 2, jnp.float32)
-                            / c.head_dim)
-
-    def rope1(t, ang):                    # t: [N, H, D]; ang: [N, D/2]
-        d2 = t.shape[-1] // 2
-        t1, t2 = t[..., :d2], t[..., d2:]
-        cc = jnp.cos(ang)[:, None, :].astype(t.dtype)
-        ss = jnp.sin(ang)[:, None, :].astype(t.dtype)
-        return jnp.concatenate([t1 * cc - t2 * ss, t2 * cc + t1 * ss], -1)
-
-    # hoist the dense head operand (incl. its dtype convert) out of the
-    # scan — XLA does not lift the loop-invariant [hidden, vocab] astype
-    # out of the body on its own. An int8 weight-only lm_head has nothing
-    # to hoist: it contracts unconverted in-body (weight_only_matmul).
-    if c.tie_embeddings:
-        head_w = params["embed"].astype(dt).T
-    elif not isinstance(params["lm_head"], dict):
-        head_w = params["lm_head"].astype(dt)
-    else:
-        head_w = None
+    aux = model.decode_begin(params, pools, block_table, lens0, active,
+                             n_steps, opts)
+    head_w = model.decode_head(params)
 
     def body(carry, t):
-        last, lens, done, rem, rk, rv, k = carry
+        last, lens, done, rem, ring, k = carry
         k, sub = jax.random.split(k)
         act = active & ~done
-        if mega:
-            # one persistent launch replaces the whole per-layer loop;
-            # the sampling epilogue below stays shared with ragged
-            xh, rk, rv = mega_decode_step(
-                params, c, x0=params["embed"].astype(dt)[last], t=t,
-                block_table=block_table, walk_lens=walk_lens, lens=lens,
-                ring_k=rk, ring_v=rv, k_pool=pools[pk], v_pool=pools[pv],
-                ks_pool=pools.get(pks), vs_pool=pools.get(pvs))
-            x = xh[:, None]
+        if opts.mega:
+            x, ring = model.mega_step(params, last, aux, lens, ring, t,
+                                      pools, opts)
         else:
-            x = params["embed"].astype(dt)[last][:, None]   # [N, 1, h]
-            ang = lens.astype(jnp.float32)[:, None] * freq[None, :]
-            ring_mask = (jnp.arange(S) <= t)[None, None, None, :]
-            for l in range(Lc):
-                p = jax.tree_util.tree_map(lambda a: a[l],
-                                           params["layers"])
-                hn = _rms_norm(x, p["attn_norm"], c.rms_eps)
-                q = _wo_mm(hn[:, 0], p["wq"], dt).reshape(N, Hkv * G, D)
-                kk = _wo_mm(hn[:, 0], p["wk"], dt).reshape(N, Hkv, D)
-                vv = _wo_mm(hn[:, 0], p["wv"], dt).reshape(N, Hkv, D)
-                q, kk = rope1(q, ang), rope1(kk, ang)
-                # uniform step index: dynamic_update_slice, no scatter
-                rk = jax.lax.dynamic_update_slice(
-                    rk, kk[None, :, None], (l, 0, t, 0, 0))
-                rv = jax.lax.dynamic_update_slice(
-                    rv, vv[None, :, None], (l, 0, t, 0, 0))
-                qg = q.reshape(N, Hkv, G, D)
-                s_rng = jnp.einsum(
-                    "nhgd,nshd->nhgs", qg, rk[l],
-                    preferred_element_type=jnp.float32) * scale
-                s_rng = jnp.where(ring_mask, s_rng, -1e30)
-                if ragged:
-                    # flash-decoding combine: the kernel's online-softmax
-                    # partials over the pool prefix merge with the
-                    # in-call ring's scores — one softmax over
-                    # [prefix ; ring], computed blockwise (exact up to
-                    # f32 rounding). The ring always holds >= 1 live
-                    # position, so l_tot >= 1.
-                    acc_p, m_p, l_p = ragged_decode_partial(
-                        q, pools[pk], pools[pv], block_table, walk_lens,
-                        layer=l, ks_pool=pools.get(pks),
-                        vs_pool=pools.get(pvs), mesh=mesh)
-                    m_tot = jnp.maximum(m_p, jnp.max(s_rng, axis=-1))
-                    corr = jnp.exp(m_p - m_tot)
-                    p_rng = jnp.exp(s_rng - m_tot[..., None])
-                    l_tot = l_p * corr + jnp.sum(p_rng, axis=-1)
-                    acc_tot = (acc_p * corr[..., None]
-                               + jnp.einsum(
-                                   "nhgs,nshd->nhgd", p_rng, rv[l],
-                                   preferred_element_type=jnp.float32))
-                    att = acc_tot / l_tot[..., None]
-                else:
-                    s_pre = attn_qk(qg, kd[l],
-                                    ksc[l] if kv_int8 else None) * scale
-                    s_pre = jnp.where(pre_mask, s_pre, -1e30)
-                    probs = jax.nn.softmax(
-                        jnp.concatenate([s_pre, s_rng], axis=-1), axis=-1)
-                    p_rng = probs[..., P:].astype(dt)
-                    att = (attn_pv(probs[..., :P], vd[l],
-                                   vsc[l] if kv_int8 else None,
-                                   out_dtype=dt)
-                           + jnp.einsum("nhgs,nshd->nhgd", p_rng, rv[l]))
-                att = att.reshape(N, 1, Hkv * G * D).astype(dt)
-                x = x + _wo_mm(att, p["wo"], dt)
-                hn = _rms_norm(x, p["mlp_norm"], c.rms_eps)
-                gate = jax.nn.silu(_wo_mm(hn, p["w_gate"], dt))
-                x = x + _wo_mm(gate * _wo_mm(hn, p["w_up"], dt),
-                               p["w_down"], dt)
+            x = model.embed(params, last)[:, None]          # [N, 1, h]
+            step = model.decode_step_begin(aux, lens, t, S)
+            for l in range(model.num_layers):
+                x, ring = model.decode_layer(params, l, x, aux, step, ring,
+                                             t, pools, act, opts)
 
-        xf = _rms_norm(x, params["final_norm"], c.rms_eps)
-        if head_w is not None:
-            logits = (xf[:, 0] @ head_w).astype(jnp.float32)
-        else:
-            logits = _wo_mm(xf[:, 0], params["lm_head"],
-                            dt).astype(jnp.float32)
+        xf = model.final_norm(params, x)
+        logits = model.decode_logits(params, head_w, xf[:, 0])
         nxt = _sample_rows(logits, sub, temps, top_ks, top_ps,
                            *sample_flags)
         emitted = jnp.where(act, nxt, -1)
@@ -667,31 +466,27 @@ def _paged_decode(params, last_tokens, lengths, done0, budgets, key, active,
         done = done | (act & (eos_ids >= 0) & (nxt == eos_ids)) \
             | (act & (rem <= 0))
         last = jnp.where(act, nxt, last)
-        return (last, lens, done, rem, rk, rv, k), emitted
+        return (last, lens, done, rem, ring, k), emitted
 
-    ring_k = jnp.zeros((Lc, N, S, Hkv, D), dt)
-    ring_v = jnp.zeros((Lc, N, S, Hkv, D), dt)
-    if mega and mega_multistep:
-        # draft fusion: the scan itself lives in the kernel — S greedy
-        # steps, argmax + embed gather + bookkeeping included, in ONE
-        # persistent launch. ``done0`` must be all-false (the spec
-        # wave's contract) and the PRNG key rides through untouched.
+    ring = model.ring_init(N, S, opts)
+    if opts.mega and opts.mega_multistep:
+        # ``done0`` must be all-false (the spec wave's contract) and the
+        # PRNG key rides through untouched.
         assert sample_flags == (False, False, False), \
             "mega_multistep is greedy-only"
-        (emitted, last_tokens, lens_end, done0, budgets, ring_k,
-         ring_v) = mega_decode_loop(
-            params, c, x0=params["embed"].astype(dt)[last_tokens],
-            n_steps=S, block_table=block_table, walk_lens=walk_lens,
-            lens=lengths, active=active, last0=last_tokens,
-            budgets=budgets, eos_ids=eos_ids, ring_k=ring_k,
-            ring_v=ring_v, k_pool=pools[pk], v_pool=pools[pv])
+        (emitted, last_tokens, lens_end, done0, budgets,
+         ring) = model.mega_loop(params, last_tokens, aux, lengths, active,
+                                 budgets, eos_ids, ring, S, pools, opts)
     else:
-        init = (last_tokens, lengths, done0, budgets, ring_k, ring_v,
-                key)
-        (last_tokens, lens_end, done0, budgets, ring_k, ring_v, key), \
+        init = (last_tokens, lengths, done0, budgets, ring, key)
+        (last_tokens, lens_end, done0, budgets, ring, key), \
             emitted = jax.lax.scan(body, init, jnp.arange(S))
 
     # ---- writeback: the ring's valid entries → pools, one scatter -------
+    stats = ring.pop("_stats", None)
+    packed = model.pack_entries(ring, opts)
+    bs = pools[next(iter(packed))].shape[2]
+    P = MB * bs
     cnt = lens_end - lens0                                # [N]
     j = jnp.arange(S)[None, :]
     valid = (j < cnt[:, None]) & active[:, None]          # [N, S]
@@ -701,182 +496,10 @@ def _paged_decode(params, last_tokens, lengths, done0, budgets, key, active,
     phys = jnp.where(valid, phys, 0)                      # trash block 0
     off = pos % bs
     pools = dict(pools)
-    if kv_int8:
-        rq_k, rs_k = quantize_kv(ring_k)
-        rq_v, rs_v = quantize_kv(ring_v)
-        if numerics:
-            # decode-writeback rung of the kv_int8 error budget (the
-            # ring is small — the reduction is noise next to the scan)
-            _nm.record_quant_error("kv_int8", [(ring_k, rq_k, rs_k, -1),
-                                               (ring_v, rq_v, rs_v, -1)])
-        pools[pk] = pools[pk].at[:, phys, off].set(rq_k)
-        pools[pv] = pools[pv].at[:, phys, off].set(rq_v)
-        pools[pks] = pools[pks].at[:, phys, off].set(rs_k)
-        pools[pvs] = pools[pvs].at[:, phys, off].set(rs_v)
-    else:
-        pools[pk] = pools[pk].at[:, phys, off].set(ring_k)
-        pools[pv] = pools[pv].at[:, phys, off].set(ring_v)
-    return (emitted, last_tokens, lens_end, done0, budgets, key, pools)
-
-
-def _spec_verify(params, block_table, last, draft_toks, lengths, active,
-                 pools, *, config: LlamaConfig, n_spec: int,
-                 kv_int8: bool = False, numerics: bool = False,
-                 max_model_len: int = 0):
-    """Score a speculative wave in ONE target forward: for every slot the
-    piece ``[last, d_1 .. d_k]`` (k = ``n_spec``) runs a prefill-shaped
-    pass against the slot's resident KV — the chunked-prefill program's
-    structure (dense history gather over the power-of-two ``block_table``
-    bucket, per-row RoPE offsets at ``lengths``, softmax over
-    [masked history ; causal in-piece]) at the fixed piece width k+1 —
-    and returns the target's GREEDY token at ALL k+1 positions:
-    ``out[b, j]`` is what the target would emit after consuming piece
-    token j. The host accepts the longest prefix where the draft agreed
-    (MPK's collapse-many-small-launches argument: k draft steps verify
-    in one launch whose arithmetic intensity is prefill's, not
-    decode's).
-
-    Writeback is decode-shaped, not prefill-shaped: pieces start at
-    ``lengths[b]``, which is NOT block-aligned mid-decode, so each
-    position scatters individually via its (physical block, offset)
-    pair. ALL k+1 positions write — a later host commit of c <= k
-    tokens simply leaves positions >= lengths+c stale, which the length
-    invariant makes unreadable and the next wave overwrites (that IS
-    the rejected-suffix rollback). Inactive rows and positions past
-    ``max_model_len`` divert to trash block 0.
-
-    draft_toks: [k, N] (the draft call's emitted grid, fed back without
-    a host round-trip); returns (greedy [N, k+1] int32, pools).
-    """
-    c = config
-    dt = c.dtype
-    N, nbk = block_table.shape
-    S = n_spec + 1
-    bs = pools["k"].shape[2]
-    Lc, Hkv, D = c.num_layers, c.num_kv_heads, c.head_dim
-    G = c.num_heads // c.num_kv_heads
-    Pp = nbk * bs
-    scale = 1.0 / math.sqrt(D)
-
-    tokens = jnp.concatenate(
-        [last[:, None], draft_toks.T.astype(jnp.int32)], axis=1)  # [N, S]
-    tokens = jnp.clip(tokens, 0, c.vocab_size - 1)   # -1 pads embed-safe
-    hist = jnp.where(active, lengths.astype(jnp.int32), 0)
-
-    x = params["embed"].astype(dt)[tokens]
-    freq = c.rope_theta ** (-jnp.arange(0, c.head_dim, 2, jnp.float32)
-                            / c.head_dim)
-    pos = (hist.astype(jnp.float32)[:, None]
-           + jnp.arange(S, dtype=jnp.float32)[None, :])
-    ang = pos[:, :, None] * freq[None, None, :]       # [N, S, D/2]
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    pre_mask = (jnp.arange(Pp)[None, :]
-                < hist[:, None])[:, None, None, None, :]
-    in_mask = jnp.tril(jnp.ones((S, S), bool))[None, None, None]
-
-    k_all, v_all = [], []
-    for l in range(Lc):
-        p = jax.tree_util.tree_map(lambda a: a[l], params["layers"])
-        hn = _rms_norm(x, p["attn_norm"], c.rms_eps)
-        q = _wo_mm(hn, p["wq"], dt).reshape(N, S, c.num_heads, D)
-        k = _wo_mm(hn, p["wk"], dt).reshape(N, S, Hkv, D)
-        v = _wo_mm(hn, p["wv"], dt).reshape(N, S, Hkv, D)
-        q = _apply_rope_at(q, cos, sin)
-        k = _apply_rope_at(k, cos, sin)
-        k_all.append(k)
-        v_all.append(v)
-        # the prefill piece attention verbatim: int8 history dequantizes
-        # up front (verify is prefill-shaped — compute-bound, the simple
-        # form wins over fused-scale dots)
-        kpre = pools["k"][l][block_table].reshape(N, Pp, Hkv, D)
-        vpre = pools["v"][l][block_table].reshape(N, Pp, Hkv, D)
-        if kv_int8:
-            ksc = pools["ks"][l][block_table].reshape(N, Pp, Hkv)
-            vsc = pools["vs"][l][block_table].reshape(N, Pp, Hkv)
-            kpre = kpre.astype(dt) * ksc[..., None].astype(dt)
-            vpre = vpre.astype(dt) * vsc[..., None].astype(dt)
-        qg = q.reshape(N, S, Hkv, G, D)
-        s_pre = jnp.einsum("bshgd,bphd->bhgsp", qg, kpre,
-                           preferred_element_type=jnp.float32) * scale
-        if kv_int8:
-            # in-piece K/V BELOW the diagonal must read as the
-            # step-wise decode path would read them: from the pool,
-            # int8-quantized. Round-trip the piece through quantize_kv
-            # (the exact writeback transform) for t < s; the diagonal
-            # (each position's own K/V — the decode ring) stays raw.
-            # Without this, verify attends unquantized neighbors and
-            # the ~1% quant delta can flip near-tie argmaxes vs the
-            # non-speculative stream.
-            qk_p, sk_p = quantize_kv(k)
-            qv_p, sv_p = quantize_kv(v)
-            k_rt = qk_p.astype(dt) * sk_p[..., None].astype(dt)
-            v_rt = qv_p.astype(dt) * sv_p[..., None].astype(dt)
-        else:
-            k_rt, v_rt = k, v
-        s_in = jnp.einsum("bshgd,bthd->bhgst", qg, k_rt,
-                          preferred_element_type=jnp.float32) * scale
-        if kv_int8:
-            eye = jnp.eye(S, dtype=bool)[None, None, None]
-            s_diag = jnp.einsum("bshgd,bshd->bhgs", qg, k,
-                                preferred_element_type=jnp.float32) \
-                * scale
-            s_in = jnp.where(eye, s_diag[..., None], s_in)
-        s_pre = jnp.where(pre_mask, s_pre, -1e30)
-        s_in = jnp.where(in_mask, s_in, -1e30)
-        probs = jax.nn.softmax(
-            jnp.concatenate([s_pre, s_in], axis=-1), axis=-1)
-        p_in = probs[..., Pp:].astype(dt)
-        if kv_int8:
-            eye_f = jnp.eye(S, dtype=p_in.dtype)[None, None, None]
-            att_in = (jnp.einsum("bhgst,bthd->bshgd",
-                                 p_in * (1 - eye_f), v_rt)
-                      + jnp.einsum("bhgs,bshd->bshgd",
-                                   jnp.sum(p_in * eye_f, -1), v))
-        else:
-            att_in = jnp.einsum("bhgst,bthd->bshgd", p_in, v)
-        att = jnp.einsum("bhgsp,bphd->bshgd",
-                         probs[..., :Pp].astype(dt), vpre) + att_in
-        att = att.reshape(N, S, c.num_heads * D).astype(dt)
-        x = x + _wo_mm(att, p["wo"], dt)
-        hn = _rms_norm(x, p["mlp_norm"], c.rms_eps)
-        gate = jax.nn.silu(_wo_mm(hn, p["w_gate"], dt))
-        x = x + _wo_mm(gate * _wo_mm(hn, p["w_up"], dt), p["w_down"], dt)
-
-    # positional writeback (the decode ring's scatter at piece width):
-    # invalid lanes — inactive rows, positions past max_model_len —
-    # divert to the trash block
-    j = jnp.arange(S)[None, :]
-    wpos = hist[:, None] + j                              # [N, S]
-    valid = active[:, None] & (wpos < max_model_len)
-    wposc = jnp.minimum(wpos, max_model_len - 1)
-    log_blk = jnp.minimum(wposc // bs, nbk - 1)
-    phys = jnp.take_along_axis(block_table, log_blk, axis=1)
-    phys = jnp.where(valid, phys, 0)
-    off = wposc % bs
-    k_stack = jnp.stack(k_all)                            # [L, N, S, Hkv, D]
-    v_stack = jnp.stack(v_all)
-    pools = dict(pools)
-    if kv_int8:
-        qk, sk = quantize_kv(k_stack)
-        qv, sv = quantize_kv(v_stack)
-        if numerics:
-            # verify-writeback rung of the kv_int8 error budget
-            _nm.record_quant_error("kv_int8", [(k_stack, qk, sk, -1),
-                                               (v_stack, qv, sv, -1)])
-        pools["k"] = pools["k"].at[:, phys, off].set(qk)
-        pools["v"] = pools["v"].at[:, phys, off].set(qv)
-        pools["ks"] = pools["ks"].at[:, phys, off].set(sk)
-        pools["vs"] = pools["vs"].at[:, phys, off].set(sv)
-    else:
-        pools["k"] = pools["k"].at[:, phys, off].set(k_stack)
-        pools["v"] = pools["v"].at[:, phys, off].set(v_stack)
-
-    x = _rms_norm(x, params["final_norm"], c.rms_eps)
-    if c.tie_embeddings:
-        logits = (x @ params["embed"].astype(dt).T).astype(jnp.float32)
-    else:
-        logits = _wo_mm(x, params["lm_head"], dt).astype(jnp.float32)
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32), pools
+    for name, val in packed.items():
+        pools[name] = pools[name].at[:, phys, off].set(val)
+    return (emitted, last_tokens, lens_end, done0, budgets, key, pools,
+            stats)
 
 
 # ---------------------------------------------------------------------------
@@ -893,7 +516,7 @@ class LLMEngine:
     and returns the (req_id, token) pairs emitted — the streaming hook.
     """
 
-    def __init__(self, params, config: LlamaConfig, max_slots: int = 4,
+    def __init__(self, params, config, max_slots: int = 4,
                  block_size: int = 16, max_model_len: int = 512,
                  num_blocks: Optional[int] = None,
                  prompt_buckets: Optional[List[int]] = None, seed: int = 0,
@@ -902,8 +525,8 @@ class LLMEngine:
                  prefix_cache: bool = False, prefill_chunk: int = 0,
                  prefix_cache_host_bytes: int = 0,
                  decode_kernel: str = "auto",
-                 draft_params=None, draft_config: Optional[LlamaConfig]
-                 = None, spec_tokens: int = 4, spec: bool = True,
+                 draft_params=None, draft_config=None,
+                 spec_tokens: int = 4, spec: bool = True,
                  kv_offload: str = "auto", role: str = "both",
                  relay: Optional[HostKVPool] = None):
         """``params`` may be dense (bf16/f32) or int8 weight-only
@@ -1060,6 +683,27 @@ class LLMEngine:
         requires a ``relay``."""
         c = config
         assert max_model_len % block_size == 0
+        if not hasattr(config, "served_model"):
+            raise TypeError(
+                f"{type(config).__name__} names no served model: the "
+                "engine runs a model through the interface that "
+                "models/llama_served.py states (config.served_model())")
+        self.model = model = config.served_model()
+        # what the model cannot do yet is refused here, with the reason:
+        # no option falls back to another path unasked
+        asked = {"spec": spec and draft_params is not None,
+                 "prefix_cache": bool(prefix_cache),
+                 "kv_swap": bool(kv_swap_bytes),
+                 "mesh": mesh is not None,
+                 "kv_int8": kv_dtype is not None,
+                 "mega": decode_kernel == "mega",
+                 "disagg": role != "both" or relay is not None,
+                 "decode_steps": int(decode_steps) > 1}
+        for feature, on in asked.items():
+            if on and feature in model.unsupported:
+                raise NotImplementedError(
+                    f"{type(model).__name__} does not support {feature}: "
+                    + model.unsupported[feature])
         self.params = params
         self.config = config
         self.N = max_slots
@@ -1085,19 +729,10 @@ class LLMEngine:
             raise ValueError(
                 f"kv_dtype must be None or 'int8', got {kv_dtype!r}")
         self.kv_int8 = kv_dtype is not None
-        pool_shape = (c.num_layers, self.nb, block_size, c.num_kv_heads,
-                      c.head_dim)
-        if self.kv_int8:
-            # int8 payload + f32 per-entry scales (~3% overhead at D=128)
-            self.pools = {
-                "k": jnp.zeros(pool_shape, jnp.int8),
-                "v": jnp.zeros(pool_shape, jnp.int8),
-                "ks": jnp.zeros(pool_shape[:-1], jnp.float32),
-                "vs": jnp.zeros(pool_shape[:-1], jnp.float32),
-            }
-        else:
-            self.pools = {"k": jnp.zeros(pool_shape, c.dtype),
-                          "v": jnp.zeros(pool_shape, c.dtype)}
+        # the model's cache entries, one pool each: per-head K and V rows
+        # for llama, one latent row for a latent-attention model
+        self.pools = model.make_pools(self.nb, block_size, self.kv_int8)
+        self._target_pools = tuple(self.pools)
         # -- speculative decoding (r13): the optional draft model --------
         self._spec_on = spec and draft_params is not None
         self.spec_k = int(spec_tokens)
@@ -1115,7 +750,7 @@ class LLMEngine:
             if self.spec_k < 1:
                 raise ValueError(
                     f"spec_tokens must be >= 1, got {spec_tokens}")
-            dc = draft_config
+            self.draft_model = draft_config.served_model()
             # draft KV pools share the target's physical block grid
             # (same nb/bs, same block ids): one block backs BOTH
             # models' KV for its token range, so block accounting,
@@ -1123,10 +758,8 @@ class LLMEngine:
             # recovery cover the draft with zero new bookkeeping. Draft
             # pools stay in the draft dtype (the draft is small — int8
             # draft WEIGHTS are the bandwidth lever, not its KV).
-            dshape = (dc.num_layers, self.nb, block_size,
-                      dc.num_kv_heads, dc.head_dim)
-            self.pools["dk"] = jnp.zeros(dshape, dc.dtype)
-            self.pools["dv"] = jnp.zeros(dshape, dc.dtype)
+            self.pools.update(self.draft_model.make_pools(
+                self.nb, block_size, prefix="d"))
         self.mesh = mesh
         if mesh is not None:
             # tp serving (r19): target params shard Megatron-style, the
@@ -1137,30 +770,9 @@ class LLMEngine:
             # DRAFT stays replicated: its params and dk/dv pools carry
             # P() shardings (draft kv heads need not divide tp), while
             # _spec_verify reuses the sharded prefill program via GSPMD.
-            from jax.sharding import NamedSharding
-            from jax.sharding import PartitionSpec as P
-
-            from ..models import llama as _llama
-
-            tp = dict(mesh.shape).get("tp", 1)
-            if c.num_kv_heads % max(tp, 1):
-                raise ValueError(
-                    f"tp={tp} must divide num_kv_heads={c.num_kv_heads}")
-            self.params = params = jax.device_put(
-                params, _llama.make_serving_shardings(params, c, mesh,
-                                                      fsdp=False))
-            if self._spec_on:
-                self.draft_params = jax.device_put(
-                    self.draft_params,
-                    _llama.make_replicated_shardings(self.draft_params,
-                                                     mesh))
-            pool_sh = NamedSharding(mesh, P(None, None, None, "tp", None))
-            scale_sh = NamedSharding(mesh, P(None, None, None, "tp"))
-            rep_sh = NamedSharding(mesh, P())
-            self.pools = {
-                k: jax.device_put(v, rep_sh if k.startswith("d")
-                                  else pool_sh if v.ndim == 5 else scale_sh)
-                for k, v in self.pools.items()}
+            self.params, self.pools, self.draft_params = model.shard(
+                params, self.pools, mesh, self.draft_params)
+            params = self.params
         self.free_blocks = deque(range(1, self.nb))
         self.table = np.zeros((self.N, self.mb), np.int32)
         self.n_alloc = np.zeros(self.N, np.int64)  # backed logical blocks
@@ -1187,13 +799,12 @@ class LLMEngine:
             if decode_kernel == "mega":
                 raise NotImplementedError(
                     "decode_kernel='mega' does not compile for TPU: "
-                    + MEGA_TPU_REFUSAL)
-            refusal = ragged_tpu_refusal(c.head_dim, self.kv_int8) or (
-                self._spec_on
-                and ragged_tpu_refusal(draft_config.head_dim, False))
+                    + model.mega_tpu_refusal)
+            refusal = model.ragged_refusal(self.kv_int8) or (
+                self._spec_on and self.draft_model.ragged_refusal(False))
             if decode_kernel == "ragged" and refusal:
                 raise NotImplementedError(
-                    f"decode_kernel='ragged' at head_dim={c.head_dim}, "
+                    f"decode_kernel='ragged' for {type(c).__name__}, "
                     f"kv_dtype={kv_dtype!r} does not compile for TPU: "
                     + refusal)
         self.decode_kernel = decode_kernel
@@ -1232,6 +843,10 @@ class LLMEngine:
         # admissions whose in-program-sampled first token has not yet been
         # read back; attached to the next dispatch record
         self._pending_adm: List = []
+        # (stats array, span attrs) of dispatched programs whose model
+        # counts (an expert layer's routed/assigned pairs) are not read
+        # yet; attached to the next dispatch record like _pending_adm
+        self._pending_stats: List = []
         # observability: add_request wall time per req awaiting its first
         # host-visible token (TTFT); entries die with the request
         self._obs_t_add: Dict[int, float] = {}
@@ -1464,15 +1079,16 @@ class LLMEngine:
             # flip the flag before the engine serves to instrument
             fn = jax.jit(_named("paged_prefill", functools.partial(
                              _paged_prefill,
-                             config=(self.draft_config if draft
-                                     else self.config),
+                             model=(self.draft_model if draft
+                                    else self.model),
                              sample_flags=flags,
-                             kv_int8=self.kv_int8 and not draft,
-                             numerics=(self.kv_int8 and not draft
-                                       and _nm.active()),
-                             prefix_nbk=prefix_nbk,
-                             kv_prefix="d" if draft else "",
-                             mesh=self.mesh)),
+                             opts=ServeOpts(
+                                 kv_int8=self.kv_int8 and not draft,
+                                 numerics=(self.kv_int8 and not draft
+                                           and _nm.active()),
+                                 prefix="d" if draft else "",
+                                 mesh=self.mesh),
+                             prefix_nbk=prefix_nbk)),
                          donate_argnums=(4,))
             self._prefill[key] = fn
         return fn
@@ -2325,16 +1941,32 @@ class LLMEngine:
         ``_chunks``. The variant key (bucket, batch form, flags, history
         bucket) keeps the compiled family bounded — chunking and the
         cache extend the EXISTING (bucket, flags) cache with one
-        log-bounded axis, not a new family."""
+        log-bounded axis, not a new family.
+
+        A model whose prefill gains nothing from a wide wave says so
+        (``model.wave_rows``, rows a wave may hold): the rows then go one
+        program after another instead of padded to ``max_slots`` rows."""
+        cap = self.model.wave_rows
+        if cap and len(rows) > cap:
+            for i in range(0, len(rows), cap):
+                self._dispatch_prefill(rows[i:i + cap])
+            return
         with trace_span("serving.prefill_build", wave=len(rows)) as sp:
             bucket, B, flags, pnbk, args = self._prefill_operands(rows)
             sp.attrs.update(bucket=bucket, batch=B)
         wave_rids = [r.req_id for _s, r, _c, _h, _p, _f in rows]
+        # tokens: each row's real tokens in THIS wave; start: what of the
+        # row is already cached (a chunked wave is not a whole prompt)
         with trace_span("serving.prefill", bucket=bucket, batch=B,
                         wave=len(rows), prefix_bucket=pnbk * self.bs,
-                        request_ids=wave_rids):
-            tok_dev, self.pools = self._prefill_fn(
+                        request_ids=wave_rids,
+                        tokens=[p for _s, _r, _c, _h, p, _f in rows],
+                        start=[h for _s, _r, _c, h, _p, _f in rows]) as sp:
+            tok_dev, self.pools, stats = self._prefill_fn(
                 bucket, B, flags, pnbk)(*args)
+        if stats is not None:
+            # read back with the next decode record's tokens
+            self._pending_stats.append((stats, sp.attrs))
         if self._spec_on:
             # the SAME wave through the draft model, right behind the
             # target's call (pools chain through donation): both models'
@@ -2347,7 +1979,7 @@ class LLMEngine:
             with trace_span("serving.prefill", bucket=bucket, batch=B,
                             wave=len(rows), model="draft",
                             request_ids=wave_rids):
-                _junk, self.pools = self._prefill_fn(
+                _junk, self.pools, _st = self._prefill_fn(
                     bucket, B, flags, pnbk, draft=True)(*dargs)
         self._prefill_dispatched(rows, bucket, B, tok_dev)
 
@@ -2363,8 +1995,7 @@ class LLMEngine:
         nbp = bucket // self.bs
         hist_blocks = max(hist // self.bs for _s, _r, _c, hist, _p, _f
                           in rows)
-        pnbk = ((1 << (hist_blocks - 1).bit_length()) if hist_blocks
-                else 0)
+        pnbk = self.model.history_blocks(hist_blocks, self.mb)
         toks = np.zeros((B, bucket), np.int32)
         blk_ids = np.zeros((B, nbp), np.int32)  # pad rows: all trash
         true_lens = np.ones(B, np.int32)
@@ -2696,7 +2327,7 @@ class LLMEngine:
         return self.decode_kernel == "ragged" or (
             self.decode_kernel == "auto"
             and jax.default_backend() == "tpu"
-            and not ragged_tpu_refusal(self.config.head_dim, self.kv_int8))
+            and not self.model.ragged_refusal(self.kv_int8))
 
     def _decode_path(self) -> str:
         """Kernel path for the next decode dispatch: ``"mega"`` (the
@@ -2710,8 +2341,8 @@ class LLMEngine:
         takes the bucketed path and is COUNTED in
         serving_mega_fallback_total{reason} — never silent."""
         if self.decode_kernel == "mega":
-            ok, reason = mega_supported(
-                self.params, self.config, n_slots=self.N,
+            ok, reason = self.model.mega_supported(
+                self.params, n_slots=self.N,
                 n_steps=self.decode_steps, block_size=self.bs,
                 kv_int8=self.kv_int8, mesh=self.mesh)
             if ok:
@@ -2721,13 +2352,13 @@ class LLMEngine:
 
     def _pool_block_bytes(self, draft: bool = False) -> int:
         """Bytes one physical block occupies across one MODEL's pool
-        entries and layers (int8 pools: payload + scales). The decode
-        KV-traffic estimates count the target's entries only — the
-        draft's ``dk``/``dv`` share the block ids but are read by the
-        draft's own (cheaper) walks."""
-        want = ("dk", "dv") if draft else ("k", "v", "ks", "vs")
+        entries and layers, whatever the entries are (K and V rows, int8
+        payload + scales, latent rows). The decode cache-traffic estimates
+        count the target's entries only — a draft's entries share the
+        block ids but are read by the draft's own (cheaper) walks."""
         return sum(a.shape[0] * int(np.prod(a.shape[2:])) * a.dtype.itemsize
-                   for n, a in self.pools.items() if n in want)
+                   for n, a in self.pools.items()
+                   if (n in self._target_pools) != draft)
 
     def _dispatch_decode(self, active_slots, prep=None):
         """Enqueue one multi-step decode call and record it as in-flight.
@@ -2782,12 +2413,13 @@ class LLMEngine:
             # instruments new variants only — docs/observability.md)
             decode = self._decode_cache[vk] = jax.jit(
                 _named("paged_decode", functools.partial(
-                    _paged_decode, config=self.config,
+                    _paged_decode, model=self.model,
                     n_steps=self.decode_steps, sample_flags=flags,
-                    kv_int8=self.kv_int8,
-                    numerics=self.kv_int8 and _nm.active(),
-                    ragged=(path == "ragged"), mega=(path == "mega"),
-                    mesh=self.mesh)),
+                    opts=ServeOpts(
+                        kv_int8=self.kv_int8,
+                        numerics=self.kv_int8 and _nm.active(),
+                        ragged=(path == "ragged"), mega=(path == "mega"),
+                        mesh=self.mesh))),
                 donate_argnums=(8,))
             _M_DECODE_RECOMPILES.inc()
         # path + traffic accounting (host ints — kept whether or not the
@@ -2828,28 +2460,36 @@ class LLMEngine:
         if prep is not None:
             prep.attrs["slots"] = len(active_slots)
             prep.end()
+        latent = self.model.cache_kind == "latent"
         with trace_span("serving.decode", slots=len(active_slots),
                         steps=self.decode_steps,
                         walk_blocks=walk, kv_bytes=step_bytes,
+                        latent_bytes=step_bytes if latent else 0,
                         # the true dispatched horizon (ragged: max real
                         # length; bucketed: the ceiling) — matches the
                         # serving_decode_prefix_bucket gauge, never the
                         # full-width table shape
                         prefix_bucket=bucket_tokens,
-                        request_ids=[r.req_id for r in reqs]):
+                        request_ids=[r.req_id for r in reqs]) as sp:
             (toks, c_last, c_len, c_done, c_rem, c_key,
-             self.pools) = decode(
+             self.pools, stats) = decode(
                 self.params, c_last, c_len, c_done, c_rem, c_key, v_act,
                 tbl, self.pools, v_t, v_k, v_p, v_eos)
         self._carry = (c_last, c_len, c_done, c_rem, c_key)
+        if stats is not None:
+            self._pending_stats.append((stats, sp.attrs))
         self._inflight = {
             "toks": toks,
+            # the model's counts of this call and of the prefill waves
+            # since the last one: they ride this record's readback
+            "stats": self._pending_stats,
             "snapshot": [(i, self.slot_req[i].req_id)
                          for i in active_slots],
             "adm": self._pending_adm,
             "rem_start": rem_start,
         }
         self._pending_adm = []
+        self._pending_stats = []
         self._fresh_swapins = set()
         return prev
 
@@ -2900,13 +2540,12 @@ class LLMEngine:
         if fn is None:
             fn = self._spec_draft_cache[key] = jax.jit(
                 _named("spec_draft", functools.partial(
-                    _paged_decode, config=self.draft_config,
+                    _paged_decode, model=self.draft_model,
                     n_steps=self.spec_k,
                     sample_flags=(False, False, False),
-                    kv_int8=False, numerics=False,
-                    ragged=(key == "ragged"), mega=(key == "mega"),
-                    mega_multistep=(key == "mega"),
-                    kv_prefix="d")),
+                    opts=ServeOpts(
+                        ragged=(key == "ragged"), mega=(key == "mega"),
+                        mega_multistep=(key == "mega"), prefix="d"))),
                 donate_argnums=(8,))
         return fn
 
@@ -2918,7 +2557,7 @@ class LLMEngine:
         if fn is None:
             fn = self._spec_verify_cache[nbk] = jax.jit(
                 _named("spec_verify", functools.partial(
-                    _spec_verify, config=self.config,
+                    self.model.spec_verify,
                     n_spec=self.spec_k, kv_int8=self.kv_int8,
                     numerics=self.kv_int8 and _nm.active(),
                     max_model_len=self.max_model_len)),
@@ -2969,15 +2608,15 @@ class LLMEngine:
             # the draft's eligibility envelope is its own (draft-sized
             # weights, multi-step epilogue buffers) — screen it
             # separately and count the fallback
-            ok, reason = mega_supported(
-                self.draft_params, self.draft_config, n_slots=N,
+            ok, reason = self.draft_model.mega_supported(
+                self.draft_params, n_slots=N,
                 n_steps=k, block_size=self.bs, kv_int8=False,
                 multi_step=True)
             if not ok:
                 _M_MEGA_FALLBACK.inc(reason="draft_" + reason)
                 path = "bucketed"
         if path == "ragged" and jax.default_backend() == "tpu" \
-                and ragged_tpu_refusal(self.draft_config.head_dim, False):
+                and self.draft_model.ragged_refusal(False):
             # the walk runs over the DRAFT's pools here, and the draft
             # has its own head dim: selection by its shape (by name the
             # request was refused at construction)
@@ -3019,7 +2658,8 @@ class LLMEngine:
         draft_fn = self._spec_draft_fn(path)
         with trace_span("serving.spec_draft", slots=len(active), k=k,
                         request_ids=rids):
-            (demitted, _dl, _dn, _dd, _db, _dk, self.pools) = draft_fn(
+            (demitted, _dl, _dn, _dd, _db, _dk, self.pools,
+             _st) = draft_fn(
                 self.draft_params, last_j, lens_j, jnp.zeros(N, bool),
                 jnp.asarray(budgets), jax.random.PRNGKey(0), act_j,
                 tbl_d, self.pools, jnp.zeros(N, jnp.float32),
@@ -3165,7 +2805,14 @@ class LLMEngine:
         emitted = []
         if rec["adm"]:
             emitted += self._flush_adm(rec["adm"])
-        toks_host = self._device_get(rec["toks"])            # [K, N]
+        stats = rec.get("stats") or []
+        if stats:
+            # one blocking sync for the tokens and the counts together
+            toks_host, stats_host = self._device_get(
+                (rec["toks"], [a for a, _ in stats]))
+            self._note_stats(stats_host, [at for _, at in stats])
+        else:
+            toks_host = self._device_get(rec["toks"])        # [K, N]
         for slot, rid in rec["snapshot"]:
             req = self.slot_req[slot]
             if req is None or req.req_id != rid:
@@ -3186,6 +2833,21 @@ class LLMEngine:
                 if self._emit(slot, tok):
                     break          # freed: later entries are -1 anyway
         return emitted
+
+    def _note_stats(self, stats_host, span_attrs) -> None:
+        """A model's counts, read back with a record's tokens (one step
+        after their program ran): the expert layers' ``[routed, assigned,
+        experts_hit, fullest x held]`` (``kernels/moe_dispatch.
+        held_expert_ffn``, summed over the layers) go to the counters and,
+        as ``expert_rows`` / ``experts_hit``, onto the span of the program
+        that produced them."""
+        for st, attrs in zip(stats_host, span_attrs):
+            routed, assigned, hit, fullest = (float(v) for v in st)
+            attrs.update(expert_rows=int(assigned), experts_hit=int(hit))
+            _M_MOE_ROUTED.inc(routed)
+            _M_MOE_ASSIGNED.inc(assigned)
+            if assigned:
+                _M_MOE_LOAD.set(fullest / assigned)
 
     def _process_inflight(self):
         rec, self._inflight = self._inflight, None
@@ -3252,6 +2914,7 @@ class LLMEngine:
         _M_QUEUE_DEPTH.set(len(self.queue))
         _M_ACTIVE_SLOTS.set(sum(r is not None for r in self.slot_req))
         _M_KV_BLOCKS.set(self.nb - 1)
+        _M_KV_TOKEN_BYTES.set(self._pool_block_bytes() / self.bs)
         _M_KV_USED.set(self.nb - 1 - len(self.free_blocks))
         if self.prefix_cache is not None:
             self.prefix_cache.update_gauges()

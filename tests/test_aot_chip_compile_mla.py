@@ -1,0 +1,99 @@
+"""Compile the latent-attention and expert-share kernels for a DESCRIBED
+TPU v5e, in ``tests/test_aot_chip_compile.py``'s manner: nothing executes,
+a pass says the chip's compiler accepts the kernel at the widths the
+``longdoc-offline`` cell runs (DeepSeek-V2: 128 heads, latent 512 + rope 64
+padded to 640, q/k 192 padded to 256, v 128, experts 5120 x 1536, blocks of
+16, 24 slots, a table 1152 wide)."""
+import importlib
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+_mod = lambda name: importlib.import_module("paddle_tpu.kernels." + name)
+paged_attention, pallas_attention = _mod("paged_attention"), \
+    _mod("pallas_attention")
+moe_dispatch = _mod("moe_dispatch")
+BF16, I32 = jnp.bfloat16, jnp.int32
+SCALE = 0.11472
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:       # no TPU compiler in this installation
+        pytest.skip(f"cannot describe v5e:2x2: {e}")
+
+
+@pytest.fixture(autouse=True)
+def _chip_lowering(monkeypatch):
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    for mod in (pallas_attention, paged_attention):
+        monkeypatch.setattr(mod, "_interpret", lambda: False)
+    monkeypatch.setattr(moe_dispatch, "_mosaic", lambda: True)
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _compile(fn, topo, *specs):
+    sh = SingleDeviceSharding(topo.devices[0])
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sh) for s, d in specs]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+def test_latent_walk(topo):
+    text = _compile(
+        lambda q, pool, tbl, lens: paged_attention.latent_decode_partial(
+            q, pool, tbl, lens, layer=0, v_cols=512, sm_scale=SCALE),
+        topo, ((24, 128, 640), BF16), ((1, 16385, 16, 640), BF16),
+        ((24, 1152), I32), ((24,), I32))
+    assert "%mla_latent_walk" in text      # the name a device trace shows
+
+
+def test_prefill_chunk_attention_at_unequal_widths(topo):
+    text = _compile(
+        lambda q, k, v: pallas_attention.flash_partial(
+            q, k, v, scale=SCALE, causal=True, name="mla_prefill_chunk"),
+        topo, ((128, 1024, 256), BF16), ((128, 1024, 256), BF16),
+        ((128, 1024, 128), BF16))
+    assert "%mla_prefill_chunk" in text
+
+
+def test_prefill_history_attention_over_latent_rows(topo):
+    text = _compile(
+        lambda q, k, n: pallas_attention.flash_partial(
+            q, k, None, scale=SCALE, kv_len=n, v_cols=512,
+            name="mla_prefill_history"),
+        topo, ((1, 1024 * 128, 640), BF16), ((1, 18432, 640), BF16),
+        ((1,), I32))
+    assert "%mla_prefill_history" in text
+
+
+@pytest.mark.parametrize("tokens", [24, 1024, 24 * 1024],
+                         ids=["decode", "one-row-chunk", "padded-wave"])
+def test_held_expert_ffn_with_its_static_tiling(topo, tokens):
+    """The chip's share of an expert layer: the grouped matmul's tiling
+    comes from the shapes (no timing), for a decode step, a one-row chunk
+    and a padded wave (which walks its pairs in passes)."""
+    text = _compile(
+        lambda x, g, i, v, gu, dn: moe_dispatch.held_expert_ffn(
+            x, g, i, v, gu, dn, 0)[0],
+        topo, ((tokens, 5120), BF16), ((tokens, 6), jnp.float32),
+        ((tokens, 6), I32), ((tokens,), jnp.bool_),
+        ((20, 5120, 3072), BF16), ((20, 1536, 5120), BF16))
+    assert "%gmm" in text

@@ -185,7 +185,9 @@ def test_engine_mega_fallback_counted_never_silent(model, monkeypatch):
     prompt = rng.integers(1, 64, size=6).tolist()
     ref, _ = _streams(params, cfg, "bucketed", [prompt], [4])
 
-    monkeypatch.setattr(eng_mod, "mega_supported",
+    # the screen is the served model's (models/llama_served.py)
+    import paddle_tpu.models.llama_served as served_mod
+    monkeypatch.setattr(served_mod, "mega_supported",
                         lambda *a, **k: (False, "vmem"))
     obs.get_registry().reset()
     obs.enable()

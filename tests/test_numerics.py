@@ -119,6 +119,7 @@ def test_disabled_path_jaxpr_identical_moe_and_grad():
 
 
 def test_engine_prefill_decode_bake_zero_ops_when_off():
+    from paddle_tpu.models.llama_served import ServeOpts
     from paddle_tpu.serving.engine import _paged_decode, _paged_prefill
 
     cfg = dataclasses.replace(_tiny_cfg(), dtype=jnp.float32)
@@ -132,8 +133,8 @@ def test_engine_prefill_decode_bake_zero_ops_when_off():
         return str(jax.make_jaxpr(
             lambda p, t, b, tl, po, k: _paged_prefill(
                 p, t, b, tl, po, jnp.zeros(1), jnp.zeros(1, jnp.int32),
-                jnp.ones(1), k, config=cfg, kv_int8=True,
-                numerics=numerics_flag))(
+                jnp.ones(1), k, model=cfg.served_model(),
+                opts=ServeOpts(kv_int8=True, numerics=numerics_flag)))(
             params, jnp.zeros((1, 8), jnp.int32),
             jnp.zeros((1, 1), jnp.int32), jnp.ones(1, jnp.int32), pools,
             jax.random.PRNGKey(0)))

@@ -79,6 +79,7 @@ def _time_raw(params, cfg, prompts):
         eng._refresh_carry(active)
         import functools
 
+        from paddle_tpu.models.llama_served import ServeOpts
         from paddle_tpu.serving.engine import _paged_decode
         flags = (False, False, False)          # all-greedy workload
         # one bucket for the WHOLE chained run: _prefix_blocks covers a
@@ -93,17 +94,17 @@ def _time_raw(params, cfg, prompts):
         decode = eng._decode_cache.get(key)
         if decode is None:
             decode = eng._decode_cache[key] = jax.jit(
-                functools.partial(_paged_decode, config=eng.config,
+                functools.partial(_paged_decode, model=eng.model,
                                   n_steps=eng.decode_steps,
                                   sample_flags=flags,
-                                  kv_int8=eng.kv_int8),
+                                  opts=ServeOpts(kv_int8=eng.kv_int8)),
                 donate_argnums=(8,))
         grids = []
         for _ in range(CALLS):
             c_last, c_len, c_done, c_rem, c_key = eng._carry
             v_act, v_t, v_k, v_p, v_eos = eng._slot_vecs
             (toks, c_last, c_len, c_done, c_rem, c_key,
-             eng.pools) = decode(
+             eng.pools, _stats) = decode(
                 eng.params, c_last, c_len, c_done, c_rem, c_key, v_act,
                 tbl, eng.pools, v_t, v_k, v_p, v_eos)
             eng._carry = (c_last, c_len, c_done, c_rem, c_key)
