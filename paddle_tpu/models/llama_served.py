@@ -39,8 +39,8 @@ The interface (what the engine calls; ``opts`` is the engine's
                                       them lists the feature as
                                       unsupported
 
-The bodies below are the engine's former llama layer, moved and not
-changed: the Mistral cells' compiled programs do the same work.
+The bodies below are the engine's former llama layer, moved (PR 28); the
+q/k/v projections are one expression for every program (``_qkv``, PR 29).
 """
 from __future__ import annotations
 
@@ -173,6 +173,22 @@ class LlamaServed:
         return c.rope_theta ** (-jnp.arange(0, c.head_dim, 2, jnp.float32)
                                 / c.head_dim)
 
+    def _qkv(self, hn, p):
+        """The attention projections of ``hn`` [..., h], in heads:
+        q [..., H, D], k and v [..., Hkv, D]. The barrier holds the three
+        products [..., out] in the compiled program: without it XLA folds
+        the reshape into heads (and rope's split of a head) back into the
+        dot, wants the dot's output heads-major, and pays for that by
+        transposing the WEIGHT, in every call (3.3 ms of a 12.8 ms decode
+        step at Mistral-7B widths, PR 29).
+        tests/test_aot_chip_compile_decode.py reads the compiled text."""
+        c = self.config
+        q, k, v = jax.lax.optimization_barrier(
+            tuple(_wo_mm(hn, p[w], self.dtype) for w in ("wq", "wk", "wv")))
+        kv_heads = hn.shape[:-1] + (c.num_kv_heads, c.head_dim)
+        return (q.reshape(hn.shape[:-1] + (c.num_heads, c.head_dim)),
+                k.reshape(kv_heads), v.reshape(kv_heads))
+
     def _mlp(self, x, p):
         c, dt = self.config, self.dtype
         hn = _rms_norm(x, p["mlp_norm"], c.rms_eps)
@@ -225,11 +241,7 @@ class LlamaServed:
         cos, sin = aux["cos"], aux["sin"]
         p = jax.tree_util.tree_map(lambda a: a[l], params["layers"])
         hn = _rms_norm(x, p["attn_norm"], c.rms_eps)
-        q = _wo_mm(hn, p["wq"], dt).reshape(B, S, c.num_heads, c.head_dim)
-        k = _wo_mm(hn, p["wk"], dt).reshape(B, S, c.num_kv_heads,
-                                            c.head_dim)
-        v = _wo_mm(hn, p["wv"], dt).reshape(B, S, c.num_kv_heads,
-                                            c.head_dim)
+        q, k, v = self._qkv(hn, p)
         if aux["prefix_nbk"]:
             Hkv, D = c.num_kv_heads, c.head_dim
             G = c.num_heads // c.num_kv_heads
@@ -343,9 +355,7 @@ class LlamaServed:
 
         p = jax.tree_util.tree_map(lambda a: a[l], params["layers"])
         hn = _rms_norm(x, p["attn_norm"], c.rms_eps)
-        q = _wo_mm(hn[:, 0], p["wq"], dt).reshape(N, Hkv * G, D)
-        kk = _wo_mm(hn[:, 0], p["wk"], dt).reshape(N, Hkv, D)
-        vv = _wo_mm(hn[:, 0], p["wv"], dt).reshape(N, Hkv, D)
+        q, kk, vv = self._qkv(hn[:, 0], p)
         q, kk = rope1(q, ang), rope1(kk, ang)
         # uniform step index: dynamic_update_slice, no scatter
         rk = jax.lax.dynamic_update_slice(
@@ -481,9 +491,7 @@ class LlamaServed:
         for l in range(Lc):
             p = jax.tree_util.tree_map(lambda a: a[l], params["layers"])
             hn = _rms_norm(x, p["attn_norm"], c.rms_eps)
-            q = _wo_mm(hn, p["wq"], dt).reshape(N, S, c.num_heads, D)
-            k = _wo_mm(hn, p["wk"], dt).reshape(N, S, Hkv, D)
-            v = _wo_mm(hn, p["wv"], dt).reshape(N, S, Hkv, D)
+            q, k, v = self._qkv(hn, p)
             q = _apply_rope_at(q, cos, sin)
             k = _apply_rope_at(k, cos, sin)
             k_all.append(k)
