@@ -765,77 +765,6 @@ def bench_serving(dev, results):
             "decode_variants_bucketed": int(var_b),
         }))
 
-    def attempt_megadecode(make_params):
-        """Persistent fused decode megakernel (r18): decode_kernel
-        ="mega" vs "ragged" on the SAME greedy workload at batch 1 and
-        batch 4 — the launch-bound regime the fusion targets. Per
-        decode step the ragged path launches one attention kernel per
-        layer (24 at 2.6B) with the hidden state round-tripping HBM at
-        every XLA boundary; the mega path is ONE persistent launch for
-        the whole step (the launch-count evidence is structural:
-        launches/step = 1 vs num_layers). Reports decode tok/s both
-        ways per batch, the step wall-clock ratio (vs_baseline =
-        mega/ragged tok/s at batch 4; acceptance: > 1 at batch <= 4)
-        and the engines' cumulative kv_read_bytes estimates."""
-        if jax.default_backend() != "tpu":
-            # forcing "mega" off-TPU would time the Pallas INTERPRETER
-            # at 2.6B scale — same screen as the mixedlen row
-            return
-        params = make_params()
-        new_tok = 64
-        rng0 = np.random.default_rng(0)
-        out = {}
-
-        def run(kernel, slots):
-            reqs = [rng0.integers(1, 32768, size=160).tolist()
-                    for _ in range(slots)]
-            eng = LLMEngine(params, cfg, max_slots=slots, block_size=64,
-                            max_model_len=1024,
-                            prompt_buckets=[256],
-                            decode_steps=16, kv_dtype="int8",
-                            decode_kernel=kernel)
-            # untimed pass compiles the prefill bucket + decode variant
-            for p in reqs:
-                eng.add_request(p, max_new_tokens=new_tok,
-                                temperature=0.0)
-            eng.run()
-            eng.kv_read_bytes_total = 0
-            t0 = time.perf_counter()
-            rids = [eng.add_request(p, max_new_tokens=new_tok,
-                                    temperature=0.0) for p in reqs]
-            res = eng.run()
-            dt = time.perf_counter() - t0
-            gen = sum(len(res[r]) for r in rids)
-            return gen / dt, eng.kv_read_bytes_total
-
-        for slots in (1, 4):
-            tps_m, kvb_m = run("mega", slots)
-            _release()
-            tps_r, kvb_r = run("ragged", slots)
-            _release()
-            out[slots] = (tps_m, tps_r, kvb_m, kvb_r)
-        tps_m1, tps_r1, kvb_m1, kvb_r1 = out[1]
-        tps_m4, tps_r4, kvb_m4, kvb_r4 = out[4]
-        results.append(_efficiency({
-            "metric": "llama-2.6b_serving_megadecode_tokens_per_sec",
-            "value": round(tps_m4, 1),
-            "unit": "tokens/s",
-            # acceptance (ISSUE 18): one persistent launch per decode
-            # step beats launch-per-layer at small batch
-            "vs_baseline": round(tps_m4 / max(tps_r4, 1e-9), 4),
-            "step_speedup_batch1": round(tps_m1 / max(tps_r1, 1e-9), 4),
-            "step_speedup_batch4": round(tps_m4 / max(tps_r4, 1e-9), 4),
-            "mega_tokens_per_sec_batch1": round(tps_m1, 1),
-            "ragged_tokens_per_sec_batch1": round(tps_r1, 1),
-            "mega_tokens_per_sec_batch4": round(tps_m4, 1),
-            "ragged_tokens_per_sec_batch4": round(tps_r4, 1),
-            # structural launch-count evidence: kernels per decode step
-            "launches_per_step_mega": 1,
-            "launches_per_step_ragged": cfg.num_layers,
-            "kv_read_bytes_mega_batch4": int(kvb_m4),
-            "kv_read_bytes_ragged_batch4": int(kvb_r4),
-        }))
-
     def attempt_spec(make_params):
         """Speculative-decoding row (r13): draft-then-verify vs the
         plain engine on the SAME greedy workload. The draft is the
@@ -1293,11 +1222,6 @@ def bench_serving(dev, results):
         # mixed short/long decode lengths: the r12 ragged Pallas kernel
         # vs the bucketed path on the same workload (ISSUE 12 row)
         attempt_mixedlen(
-            lambda: jax.jit(llama.quantize_params)(_init_bf16_params(cfg)))
-        _release()
-        # persistent fused decode megakernel vs the ragged path at
-        # batch 1 and 4 (ISSUE 18 row, ROADMAP 3: megakernel decode)
-        attempt_megadecode(
             lambda: jax.jit(llama.quantize_params)(_init_bf16_params(cfg)))
         _release()
         # speculative decoding: int8 draft / bf16 target, spec on vs

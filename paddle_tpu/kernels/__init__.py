@@ -1,6 +1,6 @@
-"""TPU kernel library: attention (flash/ring/ulysses/paged), the
-persistent fused decode megakernel, MoE dispatch + fused FFN,
-grouped-matmul autotuning, and int8 weight-only / KV quantized matmuls.
+"""TPU kernel library: attention (flash/ring/ulysses/paged), MoE dispatch
++ fused FFN, grouped-matmul autotuning, and int8 weight-only / KV
+quantized matmuls.
 
 This is the package's public surface — serving, bench and the chip
 lanes import kernel entry points from here; module paths stay available
@@ -10,8 +10,6 @@ reach into directly.
 from .flash_attention import flash_attention  # noqa: F401
 from .gmm_autotune import (candidate_tilings, get_tilings,  # noqa: F401
                            heuristic_tilings)
-from .mega_decode import (mega_decode_loop, mega_decode_step,  # noqa: F401
-                          mega_supported)
 from .moe_fused import fused_moe_ffn, gather_gmm  # noqa: F401
 from .paged_attention import (PagedKVCache, paged_append,  # noqa: F401
                               paged_append_blocks, paged_append_token,
@@ -22,8 +20,6 @@ from .quant_matmul import (attn_pv, attn_qk, dequantize_kv,  # noqa: F401
                            quantize_kv, weight_only_matmul)
 
 __all__ = [
-    # fused decode megakernel (r18)
-    "mega_decode_step", "mega_decode_loop", "mega_supported",
     # paged / ragged decode attention (r4/r12)
     "PagedKVCache", "paged_cache_init", "paged_append",
     "paged_attention", "paged_append_token", "paged_append_blocks",

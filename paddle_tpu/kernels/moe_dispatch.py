@@ -447,7 +447,7 @@ _gmm_tuned.defvjp(_gmm_tuned_fwd, _gmm_tuned_bwd)
 
 def grouped_matmul(xs, w, gs, full_rows: bool = False):
     """[m, k] @ per-group [E, k, n] over expert-sorted rows. On TPU this is
-    the Mosaic block-sparse grouped matmul (MegaBlocks-style: only row
+    the Mosaic block-sparse grouped matmul (megablox-style: only row
     blocks that exist are computed — the analogue of the reference's
     cutlass moe_gemm), with per-pass tilings from the measured autotuner
     (:func:`gmm_autotune.get_tilings`: first encounter of each

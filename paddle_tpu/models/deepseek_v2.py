@@ -191,9 +191,6 @@ def _swiglu(x, w_gate, w_up, w_down, dt):
     return (g * (x @ w_up.astype(dt))) @ w_down.astype(dt)
 
 
-_NO_MEGA = "the fused decode kernel is written for llama's layer"
-
-
 class DeepseekV2Served:
     cache_kind = "latent"
     # attention runs one wave row at a time (the expanded operands of a
@@ -215,12 +212,10 @@ class DeepseekV2Served:
                 "share (the share IS the deployment's expert parallelism)",
         "kv_int8": "the latent walk reads bf16/f32 rows; an int8 latent "
                    "needs its own scale entry and kernel path",
-        "mega": _NO_MEGA,
         "disagg": "the relay's spill/restore is tested on K/V pools only",
         "decode_steps": "the latent ring combine is written for one token "
                         "a call",
     }
-    mega_tpu_refusal = _NO_MEGA
 
     def __init__(self, config: DeepseekV2Config):
         c = config

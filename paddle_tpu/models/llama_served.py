@@ -10,9 +10,8 @@ the final norm and the head. A config object names its served model
 (``config.served_model()``); this module is ``LlamaConfig``'s.
 
 The interface (what the engine calls; ``opts`` is the engine's
-``ServeOpts``: ``kv_int8``, ``numerics``, ``ragged``, ``mega``,
-``mega_multistep``, ``prefix`` (the pool-name prefix of a draft model) and
-``mesh``):
+``ServeOpts``: ``kv_int8``, ``numerics``, ``ragged``, ``prefix`` (the
+pool-name prefix of a draft model) and ``mesh``):
 
 ``num_layers``, ``vocab_size``, ``dtype``
 ``cache_kind``                        "kv" or "latent": what a pool row is
@@ -35,9 +34,8 @@ The interface (what the engine calls; ``opts`` is the engine's
 ``decode_begin(...)`` -> aux          what is frozen for the whole call
 ``decode_layer(params, l, x, aux, step, ring, t, pools, opts)``
 ``shard(params, pools, mesh, ...)``   a tp mesh's placements
-``spec_verify`` / ``mega_*``          llama's own extras: a model without
-                                      them lists the feature as
-                                      unsupported
+``spec_verify``                       llama's own extra: a model without
+                                      it lists ``spec`` as unsupported
 
 The bodies below are the engine's former llama layer, moved (PR 28); the
 q/k/v projections are one expression for every program (``_qkv``, PR 29).
@@ -50,8 +48,6 @@ from typing import Dict, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from ..kernels.mega_decode import (MEGA_TPU_REFUSAL, mega_decode_loop,
-                                   mega_decode_step, mega_supported)
 from ..kernels.paged_attention import (ragged_decode_partial,
                                        ragged_tpu_refusal)
 from ..kernels.quant_matmul import (attn_pv, attn_qk, quantize_kv,
@@ -69,8 +65,6 @@ class ServeOpts(NamedTuple):
     kv_int8: bool = False
     numerics: bool = False
     ragged: bool = False
-    mega: bool = False
-    mega_multistep: bool = False
     prefix: str = ""
     mesh: object = None
 
@@ -79,7 +73,6 @@ class LlamaServed:
     cache_kind = "kv"
     wave_rows = None        # the engine's two batch forms: 1 and max_slots
     unsupported: Dict[str, str] = {}
-    mega_tpu_refusal = MEGA_TPU_REFUSAL
 
     def __init__(self, config: LlamaConfig):
         self.config = config
@@ -133,9 +126,6 @@ class LlamaServed:
                                    else pool_sh if v.ndim == 5 else scale_sh)
                  for k, v in pools.items()}
         return params, pools, draft_params
-
-    def mega_supported(self, params, **kw):
-        return mega_supported(params, self.config, **kw)
 
     # -- top of the model ----------------------------------------------------
     def embed(self, params, tokens):
@@ -310,7 +300,7 @@ class LlamaServed:
         Hkv, D = k_pool.shape[3], k_pool.shape[4]
         P = MB * bs
         aux = {"freq": self._freq(), "block_table": block_table}
-        if opts.ragged or opts.mega:
+        if opts.ragged:
             # true-length walk: no gather, no mask — the kernel reads only
             # real blocks. Slots outside the decode set (inactive or
             # mid-chunked-prefill) walk zero blocks.
@@ -403,37 +393,7 @@ class LlamaServed:
         x = x + _wo_mm(att, p["wo"], dt)
         return self._mlp(x, p), {"k": rk, "v": rv}
 
-    # -- llama's own extras --------------------------------------------------
-    def mega_step(self, params, last, aux, lens, ring, t, pools,
-                  opts: ServeOpts):
-        """One persistent launch replaces the whole per-layer loop; the
-        sampling epilogue stays shared with the ragged path."""
-        pk, pv = opts.prefix + "k", opts.prefix + "v"
-        xh, rk, rv = mega_decode_step(
-            params, self.config, x0=self.embed(params, last), t=t,
-            block_table=aux["block_table"], walk_lens=aux["walk_lens"],
-            lens=lens, ring_k=ring["k"], ring_v=ring["v"],
-            k_pool=pools[pk], v_pool=pools[pv],
-            ks_pool=pools.get(pk + "s"), vs_pool=pools.get(pv + "s"))
-        return xh[:, None], {"k": rk, "v": rv}
-
-    def mega_loop(self, params, last_tokens, aux, lengths, active, budgets,
-                  eos_ids, ring, n_steps: int, pools, opts: ServeOpts):
-        """Draft fusion: the scan itself lives in the kernel — S greedy
-        steps, argmax + embed gather + bookkeeping included, in ONE
-        persistent launch."""
-        pk, pv = opts.prefix + "k", opts.prefix + "v"
-        (emitted, last_tokens, lens_end, done0, budgets, rk,
-         rv) = mega_decode_loop(
-            params, self.config, x0=self.embed(params, last_tokens),
-            n_steps=n_steps, block_table=aux["block_table"],
-            walk_lens=aux["walk_lens"], lens=lengths, active=active,
-            last0=last_tokens, budgets=budgets, eos_ids=eos_ids,
-            ring_k=ring["k"], ring_v=ring["v"], k_pool=pools[pk],
-            v_pool=pools[pv])
-        return emitted, last_tokens, lens_end, done0, budgets, \
-            {"k": rk, "v": rv}
-
+    # -- llama's own extra ---------------------------------------------------
     def spec_verify(self, params, block_table, last, draft_toks, lengths,
                     active, pools, *, n_spec: int, kv_int8: bool = False,
                     numerics: bool = False, max_model_len: int = 0):

@@ -63,24 +63,15 @@ CATALOG = {
                      "(int8 pools halve either)"),
     "serving_decode_kernel_total": (
         "counter", ("path",),
-        "decode dispatches by attention path (mega = persistent fused "
-        "megakernel, one launch per decode step; ragged = true-length "
+        "decode dispatches by attention path (ragged = true-length "
         "Pallas block-walk kernel, one launch per layer; bucketed = "
         "power-of-two dense gather, dense = gather at the full "
         "allocation horizon) — the off-TPU fallback is counted here, "
         "never silent"),
     "serving_decode_variants": (
         "gauge", (), "compiled decode program variants currently cached "
-                     "(mega/ragged paths: exactly one per (batch, "
+                     "(ragged path: exactly one per (batch, "
                      "sampling-flags) set — test-enforced)"),
-    "serving_mega_fallback_total": (
-        "counter", ("reason",),
-        "decode dispatches that asked for the mega megakernel but took "
-        "the bucketed path (vmem = the kernel's scratch envelope "
-        "exceeds the ~12 MiB budget, mixed_weights = partially "
-        "quantized layer stack, mesh = one fused launch cannot be "
-        "tp-partitioned; draft_* = the speculative draft's own screen) "
-        "— the fallback is counted, never silent"),
     # -- serving speculative decoding (r13, draft-then-verify waves) -------
     "serving_spec_proposed_total": (
         "counter", (), "draft tokens proposed to the target's batched "

@@ -149,7 +149,6 @@ _M_SPEC_ACCEPTED = _instrument("serving_spec_accepted_total")
 _M_SPEC_ACCEPT_RATE = _instrument("serving_spec_acceptance_rate")
 _M_SPEC_TOKENS_PER_WAVE = _instrument("serving_spec_tokens_per_wave")
 _M_CANCEL_NOOP = _instrument("serving_cancel_noop_total")
-_M_MEGA_FALLBACK = _instrument("serving_mega_fallback_total")
 _M_DISAGG_HANDOFFS = _instrument("serving_disagg_handoffs_total")
 _M_DISAGG_SECONDS = _instrument("serving_disagg_handoff_seconds")
 _M_KV_TOKEN_BYTES = _instrument("serving_kv_bytes_per_token")
@@ -421,19 +420,6 @@ def _paged_decode(params, last_tokens, lengths, done0, budgets, key, active,
     pool entries — reusing the identical ragged/bucketed machinery at
     draft scale. Target pool entries pass through the donated dict
     untouched.
-
-    Mega path (``opts.mega``, r18, llama): the whole layer stack of each
-    step runs as ONE persistent Pallas launch (kernels/mega_decode) — the
-    r12 block walk, the per-layer ring write and the FFN fused, weights
-    streamed in tiles — so a decode step costs one kernel launch instead
-    of L, and the hidden state never round-trips HBM between layers. The
-    scan, the sampling epilogue and the end-of-call ring->pool scatter
-    below are SHARED with the ragged path verbatim: that is the greedy
-    stream-parity contract, and it keeps the variant cache at ONE entry
-    per sampling-flag set. ``opts.mega_multistep`` (greedy draft waves
-    only) additionally hoists the scan itself into the kernel: the draft's
-    k sequential steps — lm_head argmax, embed gather, done/budget
-    bookkeeping included — become one persistent launch instead of k.
     """
     N, MB = block_table.shape
     S = n_steps
@@ -446,15 +432,11 @@ def _paged_decode(params, last_tokens, lengths, done0, budgets, key, active,
         last, lens, done, rem, ring, k = carry
         k, sub = jax.random.split(k)
         act = active & ~done
-        if opts.mega:
-            x, ring = model.mega_step(params, last, aux, lens, ring, t,
-                                      pools, opts)
-        else:
-            x = model.embed(params, last)[:, None]          # [N, 1, h]
-            step = model.decode_step_begin(aux, lens, t, S)
-            for l in range(model.num_layers):
-                x, ring = model.decode_layer(params, l, x, aux, step, ring,
-                                             t, pools, act, opts)
+        x = model.embed(params, last)[:, None]              # [N, 1, h]
+        step = model.decode_step_begin(aux, lens, t, S)
+        for l in range(model.num_layers):
+            x, ring = model.decode_layer(params, l, x, aux, step, ring, t,
+                                         pools, act, opts)
 
         xf = model.final_norm(params, x)
         logits = model.decode_logits(params, head_w, xf[:, 0])
@@ -469,18 +451,9 @@ def _paged_decode(params, last_tokens, lengths, done0, budgets, key, active,
         return (last, lens, done, rem, ring, k), emitted
 
     ring = model.ring_init(N, S, opts)
-    if opts.mega and opts.mega_multistep:
-        # ``done0`` must be all-false (the spec wave's contract) and the
-        # PRNG key rides through untouched.
-        assert sample_flags == (False, False, False), \
-            "mega_multistep is greedy-only"
-        (emitted, last_tokens, lens_end, done0, budgets,
-         ring) = model.mega_loop(params, last_tokens, aux, lengths, active,
-                                 budgets, eos_ids, ring, S, pools, opts)
-    else:
-        init = (last_tokens, lengths, done0, budgets, ring, key)
-        (last_tokens, lens_end, done0, budgets, ring, key), \
-            emitted = jax.lax.scan(body, init, jnp.arange(S))
+    init = (last_tokens, lengths, done0, budgets, ring, key)
+    (last_tokens, lens_end, done0, budgets, ring, key), \
+        emitted = jax.lax.scan(body, init, jnp.arange(S))
 
     # ---- writeback: the ring's valid entries → pools, one scatter -------
     stats = ring.pop("_stats", None)
@@ -505,6 +478,36 @@ def _paged_decode(params, last_tokens, lengths, done0, budgets, key, active,
 # ---------------------------------------------------------------------------
 # host engine
 # ---------------------------------------------------------------------------
+def decode_path(decode_kernel: str, backend: str, model,
+                kv_int8: bool = False) -> str:
+    """Which decode path a model's pools are read by: ``"ragged"`` (the
+    model's true-length Pallas walk) or ``"bucketed"`` (the dense gather
+    over a power-of-two prefix). The ONE place the rule is written; the
+    engine asks it for the target and, with a draft, for the draft model,
+    whose pools have their own head dim and are never int8.
+
+    ``"auto"`` walks where the walk is compiled and is the faster of the
+    two: on a TPU ``backend``, at a shape the chip's compiler takes
+    (``model.ragged_refusal(kv_int8)`` is None). Elsewhere it gathers:
+    off a TPU the walk would run in the Pallas interpreter. A path asked
+    for by name is taken, except that ``"ragged"`` at a shape the TPU's
+    compiler refuses raises with the compiler's message: no request falls
+    to another path unasked."""
+    if decode_kernel not in ("auto", "ragged", "bucketed"):
+        raise ValueError(
+            "decode_kernel must be 'auto', 'ragged' or 'bucketed', got "
+            f"{decode_kernel!r}")
+    on_tpu = backend == "tpu"
+    refusal = on_tpu and model.ragged_refusal(kv_int8)
+    if decode_kernel == "auto":
+        return "ragged" if on_tpu and not refusal else "bucketed"
+    if decode_kernel == "ragged" and refusal:
+        raise NotImplementedError(
+            f"decode_kernel='ragged' for {type(model.config).__name__}, "
+            f"kv_int8={kv_int8} does not compile for TPU: {refusal}")
+    return decode_kernel
+
+
 class LLMEngine:
     """Continuous-batching serving loop.
 
@@ -604,18 +607,14 @@ class LLMEngine:
         ragged_tpu_refusal``) and bucketed elsewhere (other shapes; and
         off-TPU, where the kernel would run in the Pallas interpreter —
         correct but slow); the choice is counted per dispatch in
-        ``serving_decode_kernel_total{path}``, never silent. ``"mega"``
-        (r18) runs only when asked for by name and only off-TPU: the
-        TPU compiler refuses the kernel, so on a TPU backend the
-        request raises here with the compiler's message — as does
-        ``"ragged"`` at a shape its walk is refused for. The
+        ``serving_decode_kernel_total{path}``, never silent.
+        ``"ragged"`` at a shape its walk is refused for raises here
+        with the compiler's message; :func:`decode_path` is the rule,
+        asked for the target and for a draft model separately. The
         supported mesh matrix (r19): ragged and bucketed both compose
         with a 'tp' mesh (ragged shard_maps the block walk over the KV
-        heads; bucketed shards through its plain gathers/dots), spec
-        decode runs its draft replicated under the mesh, and ``"mega"``
-        alone bows out — a tp mesh falls back counted
-        (``serving_mega_fallback_total{reason="mesh"}``) to bucketed,
-        never raising.
+        heads; bucketed shards through its plain gathers/dots), and
+        spec decode runs its draft replicated under the mesh.
         Both paths share admission, writeback, preemption, the prefix
         cache, chunked prefill, swap and the numerics probes; greedy
         token streams are parity-tested identical.
@@ -696,7 +695,6 @@ class LLMEngine:
                  "kv_swap": bool(kv_swap_bytes),
                  "mesh": mesh is not None,
                  "kv_int8": kv_dtype is not None,
-                 "mega": decode_kernel == "mega",
                  "disagg": role != "both" or relay is not None,
                  "decode_steps": int(decode_steps) > 1}
         for feature, on in asked.items():
@@ -787,27 +785,13 @@ class LLMEngine:
         self._key = jax.random.PRNGKey(seed)
         self._prefill = {}
         self.decode_steps = max(1, int(decode_steps))
-        if decode_kernel not in ("auto", "ragged", "bucketed", "mega"):
-            raise ValueError(
-                f"decode_kernel must be 'auto', 'ragged', 'bucketed' or "
-                f"'mega', got {decode_kernel!r}")
-        if jax.default_backend() == "tpu":
-            # kernels Mosaic refuses are withdrawn from selection on a
-            # TPU (tests/test_aot_chip_compile.py keeps their compiles as
-            # strict xfails): asking for one by name is an error here,
-            # with the compiler's message, not a quiet other path
-            if decode_kernel == "mega":
-                raise NotImplementedError(
-                    "decode_kernel='mega' does not compile for TPU: "
-                    + model.mega_tpu_refusal)
-            refusal = model.ragged_refusal(self.kv_int8) or (
-                self._spec_on and self.draft_model.ragged_refusal(False))
-            if decode_kernel == "ragged" and refusal:
-                raise NotImplementedError(
-                    f"decode_kernel='ragged' for {type(c).__name__}, "
-                    f"kv_dtype={kv_dtype!r} does not compile for TPU: "
-                    + refusal)
         self.decode_kernel = decode_kernel
+        # what decode_path refuses (an unknown name; a walk asked for by
+        # name that the TPU's compiler refuses, for either model) is an
+        # error here, not at the first dispatch
+        self._decode_path()
+        if self._spec_on:
+            self._decode_path(draft=True)
         # decode compile cache. Ragged path (r12): keyed ("ragged",
         # flags) — ONE variant per sampling-flag tuple (≤8 total; an
         # all-greedy slot mix must not pay top-k/top-p's full-vocab
@@ -966,7 +950,7 @@ class LLMEngine:
         # throughput concern only: proposals from bad draft KV still
         # verify against the target, they just stop being accepted.
         self._draft_len = np.zeros(self.N, np.int64)
-        self._spec_draft_cache: Dict = {}    # ("ragged"|nbk) → draft fn
+        self._spec_draft_cache: Dict = {}    # "ragged"|"bucketed" → draft fn
         self._spec_verify_cache: Dict = {}   # nbk → verify fn
         # host-side spec evidence (kept whether or not the metrics
         # registry is enabled — bench rows read these)
@@ -2315,40 +2299,16 @@ class LLMEngine:
         nbk = 1 << (need - 1).bit_length()
         return min(nbk, self.mb)        # mb >= need, so the clamp is safe
 
-    def _use_ragged(self) -> bool:
-        """True when decode dispatches the ragged Pallas block-walk
-        kernel: forced by ``decode_kernel="ragged"``, or picked by
-        ``"auto"`` on a TPU backend — sharded or not (under a 'tp' mesh
-        the walk shard_maps over the KV heads, r19) — for the shapes
-        Mosaic compiles (``ragged_tpu_refusal``: not int8 pools, head
-        dim a multiple of 128). Off-TPU ``auto`` keeps the bucketed
-        dense-gather path (the kernel would run interpreted); the choice
-        is counted per dispatch in serving_decode_kernel_total{path}."""
-        return self.decode_kernel == "ragged" or (
-            self.decode_kernel == "auto"
-            and jax.default_backend() == "tpu"
-            and not self.model.ragged_refusal(self.kv_int8))
-
-    def _decode_path(self) -> str:
-        """Kernel path for the next decode dispatch: ``"mega"`` (the
-        r18 persistent fused megakernel — only when asked for by name,
-        which construction allows off-TPU only: Mosaic refuses the
-        kernel, so ``"auto"`` never picks it), ``"ragged"`` (the r12
-        block-walk kernel) or ``"bucketed"`` (the dense-gather path;
-        the per-dispatch label refines to ``dense`` at the full-width
-        bucket). An ineligible mega request — a 'tp' mesh included
-        (reason="mesh": GSPMD cannot partition the fused launch) —
-        takes the bucketed path and is COUNTED in
-        serving_mega_fallback_total{reason} — never silent."""
-        if self.decode_kernel == "mega":
-            ok, reason = self.model.mega_supported(
-                self.params, n_slots=self.N,
-                n_steps=self.decode_steps, block_size=self.bs,
-                kv_int8=self.kv_int8, mesh=self.mesh)
-            if ok:
-                return "mega"
-            _M_MEGA_FALLBACK.inc(reason=reason)
-        return "ragged" if self._use_ragged() else "bucketed"
+    def _decode_path(self, draft: bool = False) -> str:
+        """``"ragged"`` or ``"bucketed"``: the path the next decode
+        dispatch takes over the target's pools, or a speculation wave's
+        draft program over the draft's (:func:`decode_path`). The
+        per-dispatch label in serving_decode_kernel_total{path} refines
+        bucketed to ``dense`` at the full-width bucket."""
+        model, kv_int8 = ((self.draft_model, False) if draft
+                          else (self.model, self.kv_int8))
+        return decode_path(self.decode_kernel, jax.default_backend(),
+                           model, kv_int8)
 
     def _pool_block_bytes(self, draft: bool = False) -> int:
         """Bytes one physical block occupies across one MODEL's pool
@@ -2384,11 +2344,11 @@ class LLMEngine:
                 rem_start[i] = req.max_new_tokens - len(req.generated) \
                     - len(self.slot_out[i])
         path = self._decode_path()
-        ragged_like = path in ("mega", "ragged")
-        # ragged/mega: the table ships at FULL width — one static shape
+        ragged = path == "ragged"
+        # ragged: the table ships at FULL width — one static shape
         # forever, lengths ride as a runtime operand (no bucket axis in
         # the compile key). Bucketed: host-side power-of-two slice.
-        nbk = self.mb if ragged_like else self._prefix_blocks(active_slots)
+        nbk = self.mb if ragged else self._prefix_blocks(active_slots)
         if self._table_dirty:
             self._table_dev = {}
             self._table_dirty = False
@@ -2405,11 +2365,11 @@ class LLMEngine:
                                  if r.temperature > 0),
                  sampled and any(r.top_p < 1.0 for r in reqs
                                  if r.temperature > 0))
-        vk = (path, flags) if ragged_like else (nbk, flags)
+        vk = (path, flags) if ragged else (nbk, flags)
         decode = self._decode_cache.get(vk)
         if decode is None:
             # numerics gate baked per variant, like _prefill_fn (the key
-            # stays ("mega"|"ragged"|bucket, flags): a mid-run flag flip
+            # stays ("ragged"|bucket, flags): a mid-run flag flip
             # instruments new variants only — docs/observability.md)
             decode = self._decode_cache[vk] = jax.jit(
                 _named("paged_decode", functools.partial(
@@ -2418,19 +2378,18 @@ class LLMEngine:
                     opts=ServeOpts(
                         kv_int8=self.kv_int8,
                         numerics=self.kv_int8 and _nm.active(),
-                        ragged=(path == "ragged"), mega=(path == "mega"),
-                        mesh=self.mesh))),
+                        ragged=ragged, mesh=self.mesh))),
                 donate_argnums=(8,))
             _M_DECODE_RECOMPILES.inc()
         # path + traffic accounting (host ints — kept whether or not the
         # registry is on, so bench rows can report evidence without
         # perturbing the measured workload with full telemetry)
-        if not ragged_like:
+        if not ragged:
             path = "dense" if nbk >= self.mb else "bucketed"
         _M_DECODE_KERNEL.inc(path=path)
         _M_DECODE_VARIANTS.set(len(self._decode_cache))
         pb = self._pool_block_bytes()
-        if ragged_like:
+        if ragged:
             # every scan step re-walks each slot's true-length blocks.
             # The kernel walks the DEVICE carry lengths, which lag the
             # host's view by up to decode_steps for slots chained
@@ -2529,23 +2488,17 @@ class LLMEngine:
     def _spec_draft_fn(self, path: str):
         """The draft proposal program: ``_paged_decode`` at draft scale
         — draft config, ``spec_k`` fused steps, greedy flags, the
-        ``dk``/``dv`` pool entries. One cached jit per kernel path (the
-        bucketed table width re-specializes inside jax's own cache).
-        On the mega path the draft is the second fusion target: the k
-        sequential tiny steps run as ONE persistent multi-step launch
-        (argmax, embed gather and bookkeeping in-kernel) instead of k
-        scan iterations of L launches each."""
-        key = path if path in ("mega", "ragged") else "bucketed"
-        fn = self._spec_draft_cache.get(key)
+        ``dk``/``dv`` pool entries. One cached jit per decode path (the
+        bucketed table width re-specializes inside jax's own cache)."""
+        fn = self._spec_draft_cache.get(path)
         if fn is None:
-            fn = self._spec_draft_cache[key] = jax.jit(
+            fn = self._spec_draft_cache[path] = jax.jit(
                 _named("spec_draft", functools.partial(
                     _paged_decode, model=self.draft_model,
                     n_steps=self.spec_k,
                     sample_flags=(False, False, False),
-                    opts=ServeOpts(
-                        ragged=(key == "ragged"), mega=(key == "mega"),
-                        mega_multistep=(key == "mega"), prefix="d"))),
+                    opts=ServeOpts(ragged=(path == "ragged"),
+                                   prefix="d"))),
                 donate_argnums=(8,))
         return fn
 
@@ -2603,25 +2556,11 @@ class LLMEngine:
             return emitted
         k = self.spec_k
         N = self.N
-        path = self._decode_path()
-        if path == "mega":
-            # the draft's eligibility envelope is its own (draft-sized
-            # weights, multi-step epilogue buffers) — screen it
-            # separately and count the fallback
-            ok, reason = self.draft_model.mega_supported(
-                self.draft_params, n_slots=N,
-                n_steps=k, block_size=self.bs, kv_int8=False,
-                multi_step=True)
-            if not ok:
-                _M_MEGA_FALLBACK.inc(reason="draft_" + reason)
-                path = "bucketed"
-        if path == "ragged" and jax.default_backend() == "tpu" \
-                and self.draft_model.ragged_refusal(False):
-            # the walk runs over the DRAFT's pools here, and the draft
-            # has its own head dim: selection by its shape (by name the
-            # request was refused at construction)
-            path = "bucketed"
-        ragged_like = path in ("mega", "ragged")
+        # the draft program reads the DRAFT's pools, at its own head dim:
+        # its path is asked for the draft model; the verify program is
+        # prefill-shaped and takes neither
+        path = self._decode_path(draft=True)
+        ragged = path == "ragged"
         nbk = self._spec_bucket(active)
         if self._table_dirty:
             self._table_dev = {}
@@ -2635,7 +2574,7 @@ class LLMEngine:
             return t
 
         tbl_v = tdev(nbk)
-        tbl_d = tdev(self.mb) if ragged_like else tbl_v
+        tbl_d = tdev(self.mb) if ragged else tbl_v
         last = np.zeros(N, np.int32)
         budgets = np.zeros(N, np.int32)
         act = np.zeros(N, bool)
@@ -2727,7 +2666,7 @@ class LLMEngine:
         # dense history gather at target-pool bytes
         pb_t, pb_d = self._pool_block_bytes(), \
             self._pool_block_bytes(draft=True)
-        if ragged_like:
+        if ragged:
             self.kv_read_bytes_total += walk * pb_d * k
         else:
             self.kv_read_bytes_total += pb_d * N * nbk * (2 + k)

@@ -30,17 +30,15 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from paddle_tpu.models import llama
-
 # import_module: kernels/__init__ re-exports a FUNCTION named
 # paged_attention, which shadows the module on attribute access
 _mod = lambda name: importlib.import_module("paddle_tpu.kernels." + name)
-mega_decode, moe_fused = _mod("mega_decode"), _mod("moe_fused")
+moe_fused = _mod("moe_fused")
 paged_attention = _mod("paged_attention")
 pallas_attention = _mod("pallas_attention")
 
 # Llama-3-8B widths (models/llama.py LlamaConfig defaults), depth cut
-HQ, HKV, D, HID, FFN = 32, 8, 128, 4096, 14336
+HQ, HKV, D = 32, 8, 128
 BS, NB, LAYERS = 16, 1024, 2
 BF16 = jnp.bfloat16
 
@@ -63,7 +61,7 @@ def _chip_lowering(monkeypatch):
     cache but cannot be read back without a chip."""
     from jax.experimental.compilation_cache import compilation_cache as cc
 
-    for mod in (pallas_attention, paged_attention, mega_decode):
+    for mod in (pallas_attention, paged_attention):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -192,34 +190,6 @@ def test_ragged_walk_tp2(topo):
             for (s, d), p in zip(specs, shard)]
     compiled = jax.jit(
         lambda *a: _ragged(*a, mesh=mesh)).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-def _mega_step(params, x0, tbl, wl, lens, rk, rv, kp, vp):
-    cfg = llama.LlamaConfig(num_layers=LAYERS)
-    return mega_decode.mega_decode_step(
-        params, cfg, x0=x0, t=0, block_table=tbl, walk_lens=wl, lens=lens,
-        ring_k=rk, ring_v=rv, k_pool=kp, v_pool=vp)
-
-
-@refused(mega_decode.MEGA_TPU_REFUSAL)
-def test_mega_decode_step(topo):
-    n = 4
-    cfg = llama.LlamaConfig(num_layers=LAYERS)
-    one = _one(topo)
-    params = jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, BF16, sharding=one),
-        llama._abstract_params(cfg))
-    ok, why = mega_decode.mega_supported(
-        params, cfg, n_slots=n, n_steps=1, block_size=BS, kv_int8=False)
-    assert ok, why
-    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one)
-    ring = sds((LAYERS, n, 1, HKV, D), BF16)
-    pool = sds((LAYERS, NB, BS, HKV, D), BF16)
-    compiled = jax.jit(_mega_step).lower(
-        params, sds((n, HID), BF16), sds((n, 128), jnp.int32),
-        sds((n,), jnp.int32), sds((n,), jnp.int32), ring, ring, pool,
-        pool).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 

@@ -270,7 +270,6 @@ def test_the_latent_walk_against_jax_numpy(lens):
     ("kv_swap", dict(kv_swap_bytes=1 << 20)),
     ("mesh", dict(mesh="a mesh")),
     ("kv_int8", dict(kv_dtype="int8")),
-    ("mega", dict(decode_kernel="mega")),
     ("disagg", dict(role="decode")),
     ("decode_steps", dict(decode_steps=4))])
 def test_what_the_model_cannot_do_is_refused_at_construction(feature, kw):

@@ -124,8 +124,12 @@ def test_routing_reuse_gmm_path_values_and_grads():
 def test_routing_reuse_gmm_path_bf16():
     """Production dtype: the fused prologue feeds the bf16 dispatch with
     no drift — values and expert-weight grads stay bit-identical to the
-    re-deriving path (same ops either way), and within bf16 tolerance of
-    the f32 computation."""
+    re-deriving path (same ops either way), and within bf16 tolerance
+    (rtol 5e-2, atol 5e-3) of the f32 computation UNDER THE SAME ROUTING:
+    a router run of its own over the unrounded activations may resolve a
+    knife-edge top-k the other way for a token, and that token's whole row
+    then differs by another expert's output, which is no drift of the
+    dispatch."""
     x32, r32, eg32, eu32, ed32 = _ffn_operands(64, 32, 8, 16, 2, seed=21)
     x, eg, eu, ed = (a.astype(jnp.bfloat16) for a in (x32, eg32, eu32,
                                                       ed32))
@@ -135,9 +139,9 @@ def test_routing_reuse_gmm_path_bf16():
     y1 = md.dropless_moe_ffn(x, r.weights, r.idx, eg, eu, ed, routing=r)
     assert y1.dtype == jnp.bfloat16
     assert (np.asarray(y0, np.float32) == np.asarray(y1, np.float32)).all()
-    r_f32 = md.fused_routing(x32, rw, 2)
-    y_f32 = md.dropless_moe_ffn(x32, r_f32.weights, r_f32.idx, eg32, eu32,
-                                ed32, routing=r_f32)
+    y_f32 = md.dropless_moe_ffn(x32, r.weights, r.idx, eg32, eu32, ed32,
+                                routing=r)
+    assert y_f32.dtype == jnp.float32
     np.testing.assert_allclose(np.asarray(y1, np.float32),
                                np.asarray(y_f32), rtol=5e-2, atol=5e-3)
 

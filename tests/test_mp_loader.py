@@ -174,7 +174,7 @@ def test_iterable_dataset_with_workers():
 
 class _BusyDataset(Dataset):
     """CPU-heavy pure-Python transform — the GIL case multiprocess workers
-    exist for."""
+    exist for. Each item says which process made it."""
 
     def __init__(self, n=24, iters=120_000):
         self.n = n
@@ -187,27 +187,41 @@ class _BusyDataset(Dataset):
         acc = 0
         for k in range(self.iters):       # holds the GIL
             acc += k & 7
-        return np.full((64,), float(acc % 97 + i), np.float32)
+        return (np.full((64,), float(acc % 97 + i), np.float32),
+                np.int64(os.getpid()))
+
+
+def test_process_workers_run_cpu_bound_transforms_in_order_elsewhere():
+    """What the loader guarantees whatever the host's load: process
+    workers give the batches of the in-process loader, in its order, and
+    every transform ran in a process that is not this one."""
+    ds = _BusyDataset()
+    ref_x, ref_pid = _collect(DataLoader(ds, batch_size=6, num_workers=0))
+    got_x, got_pid = _collect(DataLoader(ds, batch_size=6, num_workers=3))
+    assert len(got_x) == len(ref_x) == 4
+    for a, b in zip(ref_x, got_x):
+        np.testing.assert_array_equal(a, b)
+    assert set(np.concatenate(ref_pid).tolist()) == {os.getpid()}
+    made_by = set(np.concatenate(got_pid).tolist())
+    assert os.getpid() not in made_by
+    assert len(made_by) > 1          # the work was spread
 
 
 def test_process_workers_beat_threads_on_cpu_bound_transforms():
+    """The wall-clock claim, kept off the fast lane (tests/conftest.py
+    ``_SLOW``): beside five other xdist workers it is a race against
+    whatever else the host runs."""
     ds = _BusyDataset()
     kw = dict(batch_size=6, num_workers=3)
 
-    def collect(loader):
-        return [np.asarray(b.numpy()) for b in loader]
+    def seconds(loader):
+        t0 = time.monotonic()
+        for _ in loader:
+            pass
+        return time.monotonic() - t0
 
-    t0 = time.monotonic()
-    thread_out = collect(DataLoader(ds, worker_mode="thread", **kw))
-    t_thread = time.monotonic() - t0
-
-    t0 = time.monotonic()
-    proc_out = collect(DataLoader(ds, **kw))
-    t_proc = time.monotonic() - t0
-
-    # thread pool yields in completion order → compare as multisets
-    assert sorted(a.tobytes() for a in thread_out) == \
-        sorted(b.tobytes() for b in proc_out)
+    t_thread = seconds(DataLoader(ds, worker_mode="thread", **kw))
+    t_proc = seconds(DataLoader(ds, **kw))
     # GIL serializes the thread pool; processes should win clearly — but
     # only where there is real parallelism to be had
     if (os.cpu_count() or 1) >= 2:

@@ -18,10 +18,13 @@ tests_tpu/test_ragged_decode_tpu.py):
   matches dequantize-then-attend;
 - prefix-cache-hit shaped tables: slots sharing physical history blocks;
 - through the engine: greedy token streams ragged ≡ bucketed, bf16 and
-  int8 KV, including a prefix-cache-hit admission and a swap-in restore;
+  int8 KV, under a speculative draft and with int8 weight-only params,
+  including a prefix-cache-hit admission and a swap-in restore;
 - the decode compile cache holds exactly ONE variant per sampling-flag
   set on the ragged path (the acceptance bound), while the off-TPU
-  fallback is counted in serving_decode_kernel_total — never silent.
+  fallback is counted in serving_decode_kernel_total — never silent;
+- which path serves (``serving.engine.decode_path``), with the backend
+  given: the one tier-1 hold on what ``auto`` picks on a TPU.
 """
 import dataclasses
 import functools
@@ -40,8 +43,9 @@ from paddle_tpu.kernels.paged_attention import (PagedKVCache,
                                                 ragged_decode_partial,
                                                 ragged_paged_decode)
 from paddle_tpu.kernels.quant_matmul import dequantize_kv, quantize_kv
-from paddle_tpu.models import llama
+from paddle_tpu.models import deepseek_v2, llama
 from paddle_tpu.serving import LLMEngine
+from paddle_tpu.serving.engine import decode_path
 
 # kernels/__init__ re-exports a FUNCTION named paged_attention, which
 # shadows the module on attribute access
@@ -314,6 +318,40 @@ def test_engine_greedy_streams_ragged_equals_bucketed(model, kv):
     assert all(k[0] == "ragged" for k in eng._decode_cache)
 
 
+def test_engine_ragged_spec_draft_parity(model):
+    """Speculation on the ragged path: the draft program walks the DRAFT's
+    pools at their true lengths (``ServeOpts(ragged=True, prefix="d")``),
+    and the committed streams equal the bucketed engine's waves'."""
+    cfg, params = model
+    rng = np.random.default_rng(9)
+    prompts = [rng.integers(1, 64, size=n).tolist() for n in (4, 11)]
+
+    def run(kernel):
+        out, eng = _streams(params, cfg, kernel, prompts, (6, 6),
+                            draft_params=params, draft_config=cfg,
+                            spec_tokens=3)
+        assert eng.spec_waves >= 1
+        return out, eng
+
+    a, _ = run("bucketed")
+    b, eng = run("ragged")
+    assert a == b
+    assert "ragged" in eng._spec_draft_cache     # the draft walked too
+
+
+def test_engine_ragged_int8_weights_parity(model):
+    """int8 weight-only params feed the projections around the walk
+    unconverted; the streams equal the bucketed path's."""
+    cfg, params = model
+    qp = llama.quantize_params(params)
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(1, 64, size=n).tolist() for n in (5, 13)]
+    a, _ = _streams(qp, cfg, "bucketed", prompts, (6, 6))
+    b, eng = _streams(qp, cfg, "ragged", prompts, (6, 6))
+    assert a == b
+    assert all(k[0] == "ragged" for k in eng._decode_cache)
+
+
 def test_engine_ragged_prefix_cache_hit_parity(model):
     """A finished prompt re-sent through the prefix cache (pinned
     history blocks, suffix-only prefill) must stream the same tokens on
@@ -433,7 +471,7 @@ def test_engine_fallback_counted_never_silent(model):
     try:
         eng = LLMEngine(params, cfg, max_slots=2, block_size=8,
                         max_model_len=128, prompt_buckets=[8])
-        assert not eng._use_ragged()       # CPU backend under tier-1
+        assert eng._decode_path() == "bucketed"    # the CPU, under tier-1
         eng.add_request(list(range(1, 6)), max_new_tokens=4)
         eng.run()
         reg = obs.get_registry()
@@ -447,3 +485,88 @@ def test_engine_fallback_counted_never_silent(model):
     finally:
         obs.disable()
         obs.get_registry().reset()
+
+
+# ---------------------------------------------------------------------------
+# which path: the rule the cells depend on, with the backend GIVEN (every
+# tier-1 run detects "cpu", so the TPU's side is held here and nowhere else)
+# ---------------------------------------------------------------------------
+def _dense(head_dim):
+    return llama.LlamaConfig(hidden_size=4 * head_dim, num_heads=4,
+                             num_kv_heads=2, head_dim=head_dim,
+                             num_layers=1).served_model()
+
+
+SERVED = {"dense128": _dense(128), "dense64": _dense(64),
+          "latent": deepseek_v2.DeepseekV2Config(num_layers=1).served_model()}
+INT8_MESSAGE = "Slice shape along dimension 3 must be aligned to tiling"
+NAMES_THE_THREE = "'auto', 'ragged' or 'bucketed'"
+
+
+@pytest.mark.parametrize("asked,backend,model,kv,want", [
+    ("auto", "tpu", "dense128", "bf16", "ragged"),
+    ("auto", "tpu", "dense64", "bf16", "bucketed"),
+    ("auto", "tpu", "dense128", "int8", "bucketed"),
+    ("ragged", "tpu", "dense128", "int8", INT8_MESSAGE),
+    ("ragged", "tpu", "dense64", "bf16", "dimension 4 must be aligned"),
+    ("ragged", "tpu", "dense128", "bf16", "ragged"),
+    ("bucketed", "tpu", "dense128", "bf16", "bucketed"),
+    ("auto", "cpu", "dense128", "bf16", "bucketed"),
+    ("ragged", "cpu", "dense128", "int8", "ragged"),
+    ("ragged", "cpu", "dense64", "bf16", "ragged"),
+    ("auto", "tpu", "latent", "bf16", "ragged"),
+    ("auto", "cpu", "latent", "bf16", "bucketed"),
+    ("fused", "tpu", "dense128", "bf16", NAMES_THE_THREE),
+    ("fused", "cpu", "dense128", "bf16", NAMES_THE_THREE),
+    ("fused", "cpu", "latent", "bf16", NAMES_THE_THREE),
+])
+def test_decode_path_is_one_rule(asked, backend, model, kv, want):
+    """``auto`` on a TPU walks where Mosaic compiles the walk and gathers
+    where it refuses; off a TPU it gathers; a path asked for by name is
+    taken or, at a shape the TPU's compiler refuses, an error with the
+    compiler's message; any other name (the withdrawn fused kernel's
+    among them) is a ValueError naming the three."""
+    ask = functools.partial(decode_path, asked, backend, SERVED[model],
+                            kv == "int8")
+    if want in ("ragged", "bucketed"):
+        assert ask() == want
+    else:
+        error = ValueError if want == NAMES_THE_THREE else NotImplementedError
+        with pytest.raises(error, match=want):
+            ask()
+
+
+@pytest.fixture
+def on_a_tpu(monkeypatch):
+    """The engine detects a TPU; nothing is compiled or run."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _engine(cfg, **kw):
+    return LLMEngine({}, cfg, max_slots=2, block_size=8, max_model_len=64,
+                     **kw)
+
+
+def test_engine_asks_the_rule_for_target_and_draft(on_a_tpu):
+    """A target at head dim 128 with a draft off 128, ``auto`` on a TPU:
+    the target walks and the draft gathers, each by its own shape. By
+    name the draft's refusal is the constructor's error."""
+    target, draft = SERVED["dense128"].config, SERVED["dense64"].config
+    eng = _engine(target, draft_params={}, draft_config=draft)
+    assert eng._decode_path() == "ragged"
+    assert eng._decode_path(draft=True) == "bucketed"
+    assert _engine(target, kv_dtype="int8")._decode_path() == "bucketed"
+    with pytest.raises(NotImplementedError, match="dimension 4"):
+        _engine(target, draft_params={}, draft_config=draft,
+                decode_kernel="ragged")
+    with pytest.raises(NotImplementedError, match=INT8_MESSAGE):
+        _engine(target, kv_dtype="int8", decode_kernel="ragged")
+
+
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+@pytest.mark.parametrize("family", ["dense128", "latent"])
+def test_engine_refuses_an_unknown_decode_kernel(family, backend,
+                                                 monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    with pytest.raises(ValueError, match=NAMES_THE_THREE):
+        _engine(SERVED[family].config, decode_kernel="fused")

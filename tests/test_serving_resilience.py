@@ -465,5 +465,5 @@ def test_chaos_run_serving():
     assert proc.returncode == 0, out[-2000:]
     assert "SERVING_CHAOS: OK" in out
     assert "swap_out=" in out and "recoveries=" in out
-    # r18 phase: the forced-megakernel leg recovered its mid-wave crash
-    assert "mega chaos:" in out
+    # r13 phase: the speculative leg recovered its mid-verify crash
+    assert "spec chaos:" in out

@@ -77,6 +77,28 @@ def _prompts(seed, sizes):
 # ---------------------------------------------------------------------------
 # exact greedy parity
 # ---------------------------------------------------------------------------
+def _assert_parts_only_at_a_tie(params, cfg, prompt, base, spec):
+    """bf16: the two runs' streams are equal up to the first position
+    where they part, and there both picks are the best logit up to bf16
+    rounding. The logits are a float32 forward of the same bf16 weights
+    over the common context; the tolerance is 2^-6 of the best logit, two
+    roundings of bf16's 8 significant bits (the partings seen read 1.4
+    roundings or less). Past that position the contexts differ and
+    nothing is compared."""
+    if base == spec:
+        return
+    assert len(base) == len(spec)
+    j = next(i for i, (x, y) in enumerate(zip(base, spec)) if x != y)
+    f32 = dataclasses.replace(cfg, dtype=jnp.float32)
+    logits = np.asarray(llama.forward(
+        jax.tree_util.tree_map(lambda p: p.astype(jnp.float32), params),
+        jnp.asarray([prompt + base[:j]]), f32)[0, -1])
+    best = logits.max()
+    for tok in (base[j], spec[j]):
+        assert best - logits[tok] <= 2.0 ** -6 * abs(best), (
+            j, tok, float(best), float(logits[tok]))
+
+
 @pytest.mark.parametrize("variant", ["f32", "bf16", "int8kv"])
 def test_spec_greedy_parity(model, variant):
     """Speculative greedy output == non-speculative greedy output,
@@ -87,26 +109,27 @@ def test_spec_greedy_parity(model, variant):
     can differ across those shapes, so a knife-edge argmax tie (top-2
     logit gap inside bf16 rounding) may resolve differently — the same
     cross-program caveat docs/serving.md states for r10's warm-path
-    logits. The bf16 workload below is pinned to one where every argmax
-    is decisive (verified: seeds 4-5 of the probe sweep are flip-free
-    over the full 52-token run); f32 and int8-KV-over-f32 are robustly
-    exact (noise ~1e-7 vs argmax gaps)."""
+    logits. So in bf16 a stream may part from the plain one, but only
+    at such a tie (``_assert_parts_only_at_a_tie``); f32 and
+    int8-KV-over-f32 are exact (noise ~1e-7 vs argmax gaps)."""
     cfg, params = model
     kv = None
-    seed = 0
     if variant == "bf16":
         cfg = dataclasses.replace(cfg, dtype=jnp.bfloat16)
         params = jax.tree_util.tree_map(
             lambda p: p.astype(jnp.bfloat16), params)
-        seed = 4
     elif variant == "int8kv":
         kv = "int8"
-    prompts = _prompts(seed, (1, 5, 11, 20, 3))
+    prompts = _prompts(0, (1, 5, 11, 20, 3))
     n_new = (9, 12, 6, 11, 14)
     base, _ = _run(params, cfg, prompts, n_new, kv_dtype=kv)
     spec, eng = _run(params, cfg, prompts, n_new, kv_dtype=kv,
                      draft_params=params, draft_config=cfg, spec_tokens=4)
-    assert base == spec
+    if variant == "bf16":
+        for prompt, b, s in zip(prompts, base, spec):
+            _assert_parts_only_at_a_tie(params, cfg, prompt, b, s)
+    else:
+        assert base == spec
     assert eng.spec_waves > 0          # the spec path actually ran
 
 

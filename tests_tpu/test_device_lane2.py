@@ -216,7 +216,7 @@ def test_op_numeric_bf16_slice_on_chip():
 
 
 def test_grouped_matmul_matches_ragged_dot_on_chip():
-    """The Mosaic grouped matmul (MegaBlocks-style gmm, the dropless-MoE
+    """The Mosaic grouped matmul (megablox-style gmm, the dropless-MoE
     GEMM backend on TPU) must match jax.lax.ragged_dot exactly — values
     and both gradients — including uneven and empty groups."""
     from paddle_tpu.kernels.moe_dispatch import grouped_matmul

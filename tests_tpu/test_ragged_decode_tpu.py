@@ -148,7 +148,7 @@ def test_engine_ragged_one_variant_and_stream_parity_on_chip(model):
                         decode_steps=16, kv_dtype="int8",
                         decode_kernel=kernel)
         if kernel == "auto":
-            assert eng._use_ragged()       # TPU backend picks ragged
+            assert eng._decode_path() == "ragged"  # a TPU backend walks
         t0 = time.perf_counter()
         rids = [eng.add_request(p, max_new_tokens=32, temperature=0.0)
                 for p in reqs]

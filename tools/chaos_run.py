@@ -40,13 +40,7 @@ phase runs the r13 speculative engine (draft-then-verify waves) under
 its readback must roll back to the last committed token — the recovered
 streams must equal a clean non-speculative greedy run token-for-token,
 with the ledger balancing throughout (draft KV shares the target's
-blocks, so the 4-term invariant is unchanged with spec on). A third
-phase (r18) forces ``decode_kernel="mega"`` — the persistent fused
-decode megakernel, running interpreted off-TPU — with the draft's
-fused multi-step launch in play: a seeded readback crash lands
-mid-wave, the 5-term ledger must balance at every step, and the
-recovered streams must equal a clean forced-ragged run's
-token-for-token.
+blocks, so the 4-term invariant is unchanged with spec on).
 
     JAX_PLATFORMS=cpu python tools/chaos_run.py --serving --steps 24 --seed 7
 
@@ -355,70 +349,6 @@ def serving_main(args):
             ok = False
         if streamed2[rid] != spec.results.get(rid):
             print(f"spec request {rid}: streamed/result mismatch")
-            ok = False
-
-    # -- phase 3 (r18): megakernel chaos ----------------------------------
-    # the fused decode path under fire: decode_kernel="mega" forced on
-    # (the Pallas megakernel runs interpreted off-TPU), the draft's
-    # fused multi-step launch in play, and seeded readback crashes
-    # timed to land mid-wave (spec_verify_fail raises at the wave's
-    # blocking readback sync). Recovery must roll back to the last
-    # committed token, the 5-term block ledger must balance at every
-    # step, and the recovered streams must equal a clean forced-ragged
-    # run's token-for-token (the acceptance parity, under faults).
-    mega_inj = FaultInjector([("spec_verify_fail", 2),
-                              ("spec_verify_fail", 4),
-                              ("pool_squeeze", 6)])
-    prompts = [rng.integers(1, 64, size=int(rng.integers(3, 14))).tolist()
-               for _ in range(4)]
-    news = [int(rng.integers(6, 16)) for _ in range(4)]
-    rag = LLMEngine(params, cfg, max_slots=2, block_size=8,
-                    max_model_len=64, prompt_buckets=[8, 32],
-                    decode_kernel="ragged", draft_params=params,
-                    draft_config=cfg, spec_tokens=3)
-    rag_ids = [rag.add_request(p, max_new_tokens=n)
-               for p, n in zip(prompts, news)]
-    rag_out = rag.run()
-    mega = LLMEngine(params, cfg, max_slots=2, block_size=8,
-                     max_model_len=64, num_blocks=9,
-                     prompt_buckets=[8, 32], kv_swap_bytes=1 << 20,
-                     injector=mega_inj, decode_kernel="mega",
-                     draft_params=params, draft_config=cfg,
-                     spec_tokens=3)
-    rmega = ResilientEngine(mega)
-    mids = [mega.add_request(p, max_new_tokens=n)
-            for p, n in zip(prompts, news)]
-    streamed3 = {rid: [] for rid in mids}
-    while mega.has_work():
-        for rid, tok in rmega.step():
-            streamed3[rid].append(tok)
-        acct = mega.block_accounting()
-        if acct["free"] + acct["backed"] + acct["cached"] \
-                + acct["squeezed"] + acct["in_flight"] != acct["total"]:
-            print(f"mega ledger out of balance at step "
-                  f"{mega._step_idx}: {acct}")
-            ok = False
-            break
-    print(f"mega chaos: recoveries={rmega.recoveries} "
-          f"waves={mega.spec_waves} committed={mega.spec_committed} "
-          f"faults fired={mega_inj.fired}")
-    if rmega.recoveries < 1:
-        print("no mid-wave crash was recovered — the fault never fired")
-        ok = False
-    if not all(k[0] == "mega" for k in mega._decode_cache):
-        print(f"forced mega engine compiled non-mega variants: "
-              f"{sorted(mega._decode_cache)}")
-        ok = False
-    if "mega" not in mega._spec_draft_cache:
-        print("the fused multi-step draft launch never compiled")
-        ok = False
-    for rid, refid in zip(mids, rag_ids):
-        if mega.results.get(rid) != rag_out[refid]:
-            print(f"mega request {rid} diverged from the clean ragged "
-                  f"stream: {mega.results.get(rid)} != {rag_out[refid]}")
-            ok = False
-        if streamed3[rid] != mega.results.get(rid):
-            print(f"mega request {rid}: streamed/result mismatch")
             ok = False
 
     if not ok:

@@ -473,13 +473,11 @@ def demo_serving():
     def _c(name, **lbl):
         return int(reg.counter(name).labels(**lbl).value)
 
-    # r12/r18: which decode path served the dispatches (the fused mega
-    # megakernel and the ragged Pallas walk are TPU-only picks under
-    # auto; this CPU demo counts their bucketed fallback — the choice is
-    # never silent, so mega stays 0 here) and how many compiled decode
-    # variants the cache holds
+    # r12: which decode path served the dispatches (the ragged Pallas
+    # walk is auto's pick on a TPU only; this CPU demo counts the
+    # bucketed path it takes instead — the choice is never silent) and
+    # how many compiled decode variants the cache holds
     print("decode kernel paths: "
-          f"mega={_c('serving_decode_kernel_total', path='mega')} "
           f"ragged={_c('serving_decode_kernel_total', path='ragged')} "
           f"bucketed={_c('serving_decode_kernel_total', path='bucketed')} "
           f"dense={_c('serving_decode_kernel_total', path='dense')}; "
