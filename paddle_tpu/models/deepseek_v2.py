@@ -33,9 +33,9 @@ What the engine sees (the interface of ``models/llama_served.py``):
   the tokens of earlier chunks in the absorbed form straight against the
   gathered latent rows — expanding a 16k history's keys and values for a
   padded wave would take tens of GB, the absorbed form takes the rows as
-  they lie, at 3.6 times the FLOPs per pair. Both run one wave row at a
-  time (``lax.map``): the expanded operands of a whole padded wave would
-  not fit either.
+  they lie, at 3.6 times the FLOPs per pair. Both run one row at a time
+  (``lax.map``; the engine's programs take one row each): the expanded
+  operands of several rows at once would not fit either.
 - **the chip's share of an expert layer**: the router scores all
   ``n_routed_experts``, the routing rule runs over all groups, and this
   chip computes the pairs that fell on the experts it holds
@@ -193,13 +193,6 @@ def _swiglu(x, w_gate, w_up, w_down, dt):
 
 class DeepseekV2Served:
     cache_kind = "latent"
-    # attention runs one wave row at a time (the expanded operands of a
-    # wide wave do not fit), so a wave padded to max_slots rows buys only
-    # the weights' reuse, 11 ms of a 1024-token row's ~120, and costs
-    # max_slots rows of work whenever two prompts chunk together: read on
-    # the chip (PR 28) as 2.5 s a padded wave and a tenth of the cell's
-    # tokens per second from run to run. One row a wave.
-    wave_rows = 1
     unsupported = {
         "spec": "there is no draft of this family and spec_verify is "
                 "llama's program",

@@ -2,12 +2,14 @@
 
 ``serving/engine.py`` writes no model's layer out. Its programs
 (``_paged_prefill``, ``_paged_decode``) own what every served model shares
-— the wave's shapes, the loop over the layers, the in-call ring, the
-write-back into the paged pools, sampling — and call a model object for
-everything that is the model's: which entries a token leaves in the cache
-per layer, a layer's prefill step, a layer's decode step, the embedding,
-the final norm and the head. A config object names its served model
-(``config.served_model()``); this module is ``LlamaConfig``'s.
+— the operands' shapes (a prefill program takes ONE row: a prompt, a
+cache-hit suffix or a chunk, in its own bucket), the loop over the layers,
+the in-call ring, the write-back into the paged pools, sampling — and call
+a model object for everything that is the model's: which entries a token
+leaves in the cache per layer, a layer's prefill step, a layer's decode
+step, the embedding, the final norm and the head. A config object names
+its served model (``config.served_model()``); this module is
+``LlamaConfig``'s.
 
 The interface (what the engine calls; ``opts`` is the engine's
 ``ServeOpts``: ``kv_int8``, ``numerics``, ``ragged``, ``prefix`` (the
@@ -21,10 +23,7 @@ pool-name prefix of a draft model) and ``mesh``):
                                       ``{name: [L, nb, bs, ...]}``
 ``ragged_refusal(kv_int8)``           why the chip's compiler refuses the
                                       decode walk at this shape, or None
-``history_blocks(hist_blocks, mb)``   how wide a chunk's history table is
-``wave_rows``                         rows a prefill wave may hold, or None
-                                      for the engine's two forms (1 and
-                                      ``max_slots``, padded)
+``history_blocks(hist_blocks, mb)``   how wide a row's history table is
 ``embed(params, tokens)``
 ``final_norm(params, x)``, ``head(params, x)``, ``decode_head(params)``
 ``prefill_begin(...)`` -> aux         positions, masks, gathered history
@@ -71,7 +70,6 @@ class ServeOpts(NamedTuple):
 
 class LlamaServed:
     cache_kind = "kv"
-    wave_rows = None        # the engine's two batch forms: 1 and max_slots
     unsupported: Dict[str, str] = {}
 
     def __init__(self, config: LlamaConfig):
@@ -99,8 +97,8 @@ class LlamaServed:
 
     @staticmethod
     def history_blocks(hist_blocks: int, mb: int) -> int:
-        """The power of two over the longest history in the wave: the
-        history is gathered dense, so its width is a program shape."""
+        """The power of two over the row's history: the history is
+        gathered dense, so its width is a program shape."""
         return (1 << (hist_blocks - 1).bit_length()) if hist_blocks else 0
 
     def shard(self, params, pools, mesh, draft_params=None):

@@ -239,12 +239,13 @@ def _sample_rows(logits, key, temps, top_ks, top_ps, any_sampled=True,
 @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3))
 def _apply_admissions(c_last, c_len, c_done, c_rem, wave_toks, slot_of_row,
                       lens_new, rems_new, upd_mask):
-    """Scatter one admission wave into the decode carry — a SINGLE
-    compiled program with shapes fixed at [max_slots], whatever the
-    admission count (pad rows carry slot_of_row == N, dropped by the
-    out-of-bounds scatter mode). The eager .at[].set chain this replaces
-    re-specialized per wave size: each new size was a compile inside the
-    serving hot path."""
+    """Scatter admitted rows' first tokens into the decode carry — a
+    SINGLE compiled program: ``wave_toks`` and ``slot_of_row`` are as
+    wide as a prefill program's token output (the engine's are one row),
+    everything else is fixed at [max_slots] (a slot_of_row == N is
+    dropped by the out-of-bounds scatter mode). The eager .at[].set
+    chain this replaces re-specialized per wave size: each new size was
+    a compile inside the serving hot path."""
     N = c_last.shape[0]
     scattered = jnp.zeros((N,), c_last.dtype).at[slot_of_row].set(
         wave_toks.astype(c_last.dtype), mode="drop")
@@ -279,13 +280,13 @@ def _paged_prefill(params, tokens, blk_ids, true_len, pools,
     small vector of counts for this wave (an expert layer's routed and
     assigned pairs), or None.
 
-    The engine pads every multi-admission wave to ``max_slots`` rows
-    (single admissions use a dedicated B=1 variant — steady-state churn
-    must not pay max_slots× the prefill FLOPs) and to the largest bucket
-    the wave needs, so TWO compiled variants per (bucket, flags) serve
-    any admission mix — batch-size-shaped recompiles can never land
-    inside a serving burst. Pad rows point all their blocks at the trash
-    block and sample a discarded token.
+    The program is written over B rows, and the engine calls it with
+    ONE: every admitted row, cache-hit suffix or chunk is its own call
+    in its own bucket (``LLMEngine._dispatch_prefill``), so ONE compiled
+    variant per (bucket, flags, history width) serves any admission mix
+    and no row pays for another's padding — batch-size-shaped recompiles
+    cannot exist. (A caller that does pass pad rows points all their
+    blocks at the trash block and discards their token.)
 
     Sampling lives inside the compiled program: an eager ~15-op sampling
     pipeline plus a blocking int() per admission is host work and a sync
@@ -303,7 +304,7 @@ def _paged_prefill(params, tokens, blk_ids, true_len, pools,
     trash block and mask via ``hist_len``). With ``prefix_nbk == 0`` the
     program is the original full-prompt prefill — cold traffic never pays
     for the feature. The compiled family stays bounded: (prompt bucket)
-    x (2 batch forms) x (<= 8 flag tuples) x (history widths).
+    x (<= 8 flag tuples) x (history widths).
 
     ``opts.prefix`` (r13 speculative decoding) selects which pool entries
     this program reads/writes: ``""`` = the target model's, ``"d"`` = the
@@ -1045,16 +1046,17 @@ class LLMEngine:
         raise ValueError(f"prompt length {n} exceeds largest bucket "
                          f"{self.buckets[-1]}")
 
-    def _prefill_fn(self, bucket: int, B: int, flags, prefix_nbk: int = 0,
+    def _prefill_fn(self, bucket: int, flags, prefix_nbk: int = 0,
                     draft: bool = False):
         if draft:
             # draft prefill: greedy flags always (its sampled token is
             # discarded) so the draft never multiplies the flag axis
             flags = (False, False, False)
-        # target keys stay the documented 4-tuple; the draft adds a
-        # parallel family, one tag deeper
-        key = ((bucket, B, flags, prefix_nbk) if not draft
-               else (bucket, B, flags, prefix_nbk, "draft"))
+        # target keys are (bucket, flags, history width): every program
+        # takes ONE row, so no batch form is part of the key; the draft
+        # adds a parallel family, one tag deeper
+        key = ((bucket, flags, prefix_nbk) if not draft
+               else (bucket, flags, prefix_nbk, "draft"))
         fn = self._prefill.get(key)
         if fn is None:
             # the numerics gate is baked at variant-compile time (the
@@ -1744,13 +1746,14 @@ class LLMEngine:
 
     def _admit(self):
         """Admit every queued request a free slot and free blocks can
-        take, then dispatch ONE batched prefill program for the whole
-        wave (padded to max_slots rows and the wave's largest bucket, so
-        the compiled-variant set is one per bucket — a serving burst can
-        never hit a batch-size-shaped recompile). NO host sync: each
-        first generated token is sampled inside the prefill program and
-        rides to the host one decode call later (``_pending_adm`` → the
-        next dispatch record).
+        take, then dispatch the wave row by row: one one-row prefill
+        program a row, each in the row's own bucket
+        (:meth:`_dispatch_prefill`), so the compiled-variant set is one
+        per bucket — a serving burst can never hit a batch-size-shaped
+        recompile, and no row is padded to another's length. NO host
+        sync: each first generated token is sampled inside the row's
+        program and rides to the host one decode call later
+        (``_pending_adm`` → the next dispatch record).
 
         With the prefix cache on, each admission first matches the
         longest cached prefix at block granularity (capped at
@@ -1917,142 +1920,129 @@ class LLMEngine:
             self._dispatch_prefill(rows)
 
     def _dispatch_prefill(self, rows):
-        """Dispatch one compiled prefill program for a wave of context
-        PIECES — full prompts, cache-hit suffixes, and chunk
-        continuations mix freely in one call. Rows whose piece completes
-        the context (``final``) keep their in-program-sampled first
-        token (``_pending_adm``); chunk rows discard it and stay in
-        ``_chunks``. The variant key (bucket, batch form, flags, history
-        bucket) keeps the compiled family bounded — chunking and the
-        cache extend the EXISTING (bucket, flags) cache with one
-        log-bounded axis, not a new family.
+        """Dispatch a wave of context PIECES — full prompts, cache-hit
+        suffixes and chunk continuations mix freely — as one compiled
+        ONE-ROW program a row, each in its own bucket and against its
+        own history width, one behind the other in this step. Dispatch
+        is asynchronous: row i+1's operands are built while row i's
+        program runs, and nothing drains between rows.
 
-        A model whose prefill gains nothing from a wide wave says so
-        (``model.wave_rows``, rows a wave may hold): the rows then go one
-        program after another instead of padded to ``max_slots`` rows."""
-        cap = self.model.wave_rows
-        if cap and len(rows) > cap:
-            for i in range(0, len(rows), cap):
-                self._dispatch_prefill(rows[i:i + cap])
-            return
-        with trace_span("serving.prefill_build", wave=len(rows)) as sp:
-            bucket, B, flags, pnbk, args = self._prefill_operands(rows)
-            sp.attrs.update(bucket=bucket, batch=B)
-        wave_rids = [r.req_id for _s, r, _c, _h, _p, _f in rows]
-        # tokens: each row's real tokens in THIS wave; start: what of the
-        # row is already cached (a chunked wave is not a whole prompt)
-        with trace_span("serving.prefill", bucket=bucket, batch=B,
-                        wave=len(rows), prefix_bucket=pnbk * self.bs,
-                        request_ids=wave_rids,
-                        tokens=[p for _s, _r, _c, _h, p, _f in rows],
-                        start=[h for _s, _r, _c, h, _p, _f in rows]) as sp:
+        Rows whose piece completes the context (``final``) keep their
+        in-program-sampled first token (``_pending_adm``, one ``[1]``
+        array a row, all fetched by the next record's one readback);
+        chunk rows discard it and stay in ``_chunks``. The variant key
+        (bucket, flags, history width) keeps the compiled family
+        bounded — chunking and the cache extend the (bucket, flags)
+        cache with one log-bounded axis, not a new family — and holds
+        no batch form: a row never pays for a wider wave's padding
+        (prompts of 300 and 900 tokens cost 729 ms as 16 rows x 1024
+        and 71 ms as their own two programs, PERF.md §6 PR 31)."""
+        for row in rows:
+            self._dispatch_row(row, len(rows))
+
+    def _dispatch_row(self, row, wave: int):
+        """One row's program: operands, the call, the draft's call
+        behind it, the host's bookkeeping. ``wave``: rows dispatched
+        with it in this step."""
+        slot, req, ctx, hist, piece, final = row
+        with trace_span("serving.prefill_build", wave=wave) as sp:
+            bucket, flags, pnbk, args = self._prefill_operands(row)
+            sp.attrs.update(bucket=bucket, batch=1)
+        # tokens: the row's real tokens in THIS program; start: what of
+        # the row is already cached (a chunk is not a whole prompt)
+        with trace_span("serving.prefill", bucket=bucket, batch=1,
+                        wave=wave, prefix_bucket=pnbk * self.bs,
+                        request_ids=[req.req_id], tokens=[piece],
+                        start=[hist]) as sp:
             tok_dev, self.pools, stats = self._prefill_fn(
-                bucket, B, flags, pnbk)(*args)
+                bucket, flags, pnbk)(*args)
         if stats is not None:
             # read back with the next decode record's tokens
             self._pending_stats.append((stats, sp.attrs))
         if self._spec_on:
-            # the SAME wave through the draft model, right behind the
+            # the SAME row through the draft model, right behind the
             # target's call (pools chain through donation): both models'
-            # KV now cover every prefilled position, so these slots
-            # enter spec waves in sync. The draft's sampled token is
+            # KV now cover every prefilled position, so the slot enters
+            # spec waves in sync. The draft's sampled token is
             # discarded — the target owns the stream.
             self._key, dsub = jax.random.split(self._key)
             dargs = [self.draft_params] + args[1:8] + [dsub] + args[9:]
             dargs[4] = self.pools
-            with trace_span("serving.prefill", bucket=bucket, batch=B,
-                            wave=len(rows), model="draft",
-                            request_ids=wave_rids):
+            with trace_span("serving.prefill", bucket=bucket, batch=1,
+                            wave=wave, model="draft",
+                            request_ids=[req.req_id]):
                 _junk, self.pools, _st = self._prefill_fn(
-                    bucket, B, flags, pnbk, draft=True)(*dargs)
-        self._prefill_dispatched(rows, bucket, B, tok_dev)
+                    bucket, flags, pnbk, draft=True)(*dargs)
+        self._prefill_dispatched(row, bucket, tok_dev)
 
-    def _prefill_operands(self, rows):
-        """The wave's program variant and its operands, from the bucket
-        choice to the last host-to-device copy: ``(bucket, B, flags,
-        pnbk, args)``."""
-        bucket = self._bucket_for(max(piece for *_x, piece, _f in rows))
-        # two batch variants only: 1 (steady-state churn admits one slot
-        # at a time — full-width padding would pay max_slots× the prefill
-        # FLOPs) and max_slots (bursts). Bounded compiles, bounded waste.
-        B = 1 if len(rows) == 1 else self.N
-        nbp = bucket // self.bs
-        hist_blocks = max(hist // self.bs for _s, _r, _c, hist, _p, _f
-                          in rows)
-        pnbk = self.model.history_blocks(hist_blocks, self.mb)
-        toks = np.zeros((B, bucket), np.int32)
-        blk_ids = np.zeros((B, nbp), np.int32)  # pad rows: all trash
-        true_lens = np.ones(B, np.int32)
-        hist_lens = np.zeros(B, np.int32)
-        ctx_tbl = np.zeros((B, pnbk), np.int32) if pnbk else None
-        temps = np.zeros(B, np.float32)
-        top_ks = np.zeros(B, np.int32)
-        top_ps = np.ones(B, np.float32)
-        for i, (slot, req, ctx, hist, piece, final) in enumerate(rows):
-            b0 = hist // self.bs
-            nblk = -(-(hist + piece) // self.bs) - b0
-            toks[i, :piece] = ctx[hist:hist + piece]
-            blk_ids[i, :nblk] = self.table[slot, b0:b0 + nblk]
-            true_lens[i] = piece
-            hist_lens[i] = hist
-            if pnbk and b0:
-                ctx_tbl[i, :b0] = self.table[slot, :b0]
-            if final:        # non-final rows sample a discarded argmax
-                temps[i] = req.temperature
-                top_ks[i] = req.top_k
-                top_ps[i] = req.top_p
-        finals = [r for _s, r, _c, _h, _p, final in rows if final]
-        sampled = any(r.temperature > 0 for r in finals)
-        flags = (sampled,
-                 sampled and any(r.top_k > 0 for r in finals
-                                 if r.temperature > 0),
-                 sampled and any(r.top_p < 1.0 for r in finals
-                                 if r.temperature > 0))
+    def _prefill_operands(self, row):
+        """A row's program variant and its operands, from the bucket
+        choice to the last host-to-device copy: ``(bucket, flags, pnbk,
+        args)``. Every operand is one row wide."""
+        slot, req, ctx, hist, piece, final = row
+        bucket = self._bucket_for(piece)
+        b0 = hist // self.bs
+        pnbk = self.model.history_blocks(b0, self.mb)
+        # only the blocks the piece occupies; the bucket's pad tail
+        # scatters into the trash block (never read: causality)
+        nblk = -(-(hist + piece) // self.bs) - b0
+        toks = np.zeros((1, bucket), np.int32)
+        toks[0, :piece] = ctx[hist:hist + piece]
+        blk_ids = np.zeros((1, bucket // self.bs), np.int32)
+        blk_ids[0, :nblk] = self.table[slot, b0:b0 + nblk]
+        # a non-final row samples a discarded argmax
+        sampled = final and req.temperature > 0
+        flags = (sampled, sampled and req.top_k > 0,
+                 sampled and req.top_p < 1.0)
         self._key, sub = jax.random.split(self._key)
         args = [self.params, jnp.asarray(toks), jnp.asarray(blk_ids),
-                jnp.asarray(true_lens), self.pools,
-                jnp.asarray(temps), jnp.asarray(top_ks),
-                jnp.asarray(top_ps), sub]
+                jnp.asarray([piece], jnp.int32), self.pools,
+                jnp.asarray([req.temperature if final else 0.0],
+                            jnp.float32),
+                jnp.asarray([req.top_k if final else 0], jnp.int32),
+                jnp.asarray([req.top_p if final else 1.0], jnp.float32),
+                sub]
         if pnbk:
-            args += [jnp.asarray(hist_lens), jnp.asarray(ctx_tbl)]
-        return bucket, B, flags, pnbk, args
+            ctx_tbl = np.zeros((1, pnbk), np.int32)
+            ctx_tbl[0, :b0] = self.table[slot, :b0]
+            args += [jnp.asarray([hist], jnp.int32), jnp.asarray(ctx_tbl)]
+        return bucket, flags, pnbk, args
 
-    def _prefill_dispatched(self, rows, bucket, B, tok_dev):
-        """Host bookkeeping of a dispatched wave: lengths, pending first
-        tokens, chunk state, timelines, prefix-cache adoption."""
-        tracer = _rt.get_request_tracer() if _obs.enabled() else None
-        for i, (slot, req, ctx, hist, piece, final) in enumerate(rows):
-            self.lengths[slot] = hist + piece
-            if self._spec_on:
-                self._draft_len[slot] = hist + piece
-            if final:
-                if self._chunks.pop(slot, None) is not None:
-                    self._slots_dirty = True   # rejoins the decode mask
-                # reference the WHOLE [B] first-token array + row index:
-                # the readback then fetches one array per wave, not one
-                # tiny transfer per admission
-                self._pending_adm.append((slot, req.req_id, tok_dev, i))
-            else:
-                if slot not in self._chunks:
-                    self._slots_dirty = True   # leaves the decode mask
-                self._chunks[slot] = {"ctx": ctx, "pos": hist + piece,
-                                      "rid": req.req_id}
-            if tracer is not None:
-                tracer.record(req.req_id, "prefill", bucket=bucket,
-                              batch=B, chunk_start=hist, chunk=piece)
-            if self.prefix_cache is not None \
-                    and len(self._pinned[slot]) == hist // self.bs:
-                # adopt this piece's FULL blocks into the trie (pinned:
-                # the slot itself holds them); adoption stays contiguous
-                # with the pinned head — a gap (another request cached
-                # the same block first) ends adoption for this slot
-                b0 = hist // self.bs
-                full = (hist + piece) // self.bs
-                if full > b0:
-                    self._pinned[slot].extend(self.prefix_cache.extend(
-                        ctx, b0,
-                        [int(self.table[slot, j]) for j in range(b0, full)],
-                        pin=True))
+    def _prefill_dispatched(self, row, bucket, tok_dev):
+        """Host bookkeeping of a dispatched row: lengths, the pending
+        first token, chunk state, timelines, prefix-cache adoption."""
+        slot, req, ctx, hist, piece, final = row
+        self.lengths[slot] = hist + piece
+        if self._spec_on:
+            self._draft_len[slot] = hist + piece
+        if final:
+            if self._chunks.pop(slot, None) is not None:
+                self._slots_dirty = True   # rejoins the decode mask
+            # the row's [1] first-token array: the readback fetches all
+            # of a record's arrays in one call
+            self._pending_adm.append((slot, req.req_id, tok_dev))
+        else:
+            if slot not in self._chunks:
+                self._slots_dirty = True   # leaves the decode mask
+            self._chunks[slot] = {"ctx": ctx, "pos": hist + piece,
+                                  "rid": req.req_id}
+        if _obs.enabled():
+            _rt.get_request_tracer().record(
+                req.req_id, "prefill", bucket=bucket, batch=1,
+                chunk_start=hist, chunk=piece)
+        if self.prefix_cache is not None \
+                and len(self._pinned[slot]) == hist // self.bs:
+            # adopt this piece's FULL blocks into the trie (pinned:
+            # the slot itself holds them); adoption stays contiguous
+            # with the pinned head — a gap (another request cached
+            # the same block first) ends adoption for this slot
+            b0 = hist // self.bs
+            full = (hist + piece) // self.bs
+            if full > b0:
+                self._pinned[slot].extend(self.prefix_cache.extend(
+                    ctx, b0,
+                    [int(self.table[slot, j]) for j in range(b0, full)],
+                    pin=True))
 
     def _emit(self, slot: int, tok: int) -> bool:
         """Record a generated token; free the slot when the request is done.
@@ -2187,7 +2177,7 @@ class LLMEngine:
                 "carry rebuild requires a drained pipeline"
             last = np.zeros(self.N, np.int32)
             budgets = np.zeros(self.N, np.int32)
-            pend = {s for s, _, _, _ in self._pending_adm}
+            pend = {s for s, _, _ in self._pending_adm}
             for i in active_slots:
                 req = self.slot_req[i]
                 # swap-in slots continue from the context tail (their KV
@@ -2204,29 +2194,22 @@ class LLMEngine:
                            jnp.zeros(self.N, bool),
                            jnp.asarray(budgets), sub)
         if self._pending_adm:
-            # one _apply_admissions call per wave array (usually one):
-            # every operand shape is pinned to [max_slots], so nothing
-            # here can ever compile inside the serving loop
-            groups: Dict = {}
-            for s, rid, arr, i in self._pending_adm:
-                groups.setdefault(id(arr), (arr, []))[1].append((s, i))
+            # one _apply_admissions call per admitted row (usually one):
+            # the row's [1] token array, everything else pinned to
+            # [max_slots], so nothing here can ever compile inside the
+            # serving loop
             c_last, c_len, c_done, c_rem, c_key = self._carry
-            for arr, items in groups.values():
-                B = arr.shape[0]
-                slot_of_row = np.full(B, self.N, np.int32)  # N → dropped
+            for s, _rid, arr in self._pending_adm:
                 upd = np.zeros(self.N, bool)
                 lens_new = np.zeros(self.N, np.int32)
                 rems_new = np.zeros(self.N, np.int32)
-                for s, i in items:
-                    slot_of_row[i] = s
-                    upd[s] = True
-                    lens_new[s] = int(self.lengths[s])
-                    req = self.slot_req[s]
-                    rems_new[s] = (req.max_new_tokens
-                                   - len(req.generated) - 1)
+                upd[s] = True
+                lens_new[s] = int(self.lengths[s])
+                req = self.slot_req[s]
+                rems_new[s] = req.max_new_tokens - len(req.generated) - 1
                 c_last, c_len, c_done, c_rem = _apply_admissions(
                     c_last, c_len, c_done, c_rem, arr,
-                    jnp.asarray(slot_of_row), jnp.asarray(lens_new),
+                    jnp.asarray([s], jnp.int32), jnp.asarray(lens_new),
                     jnp.asarray(rems_new), jnp.asarray(upd))
             self._carry = (c_last, c_len, c_done, c_rem, c_key)
         if self._pending_swapin:
@@ -2327,7 +2310,7 @@ class LLMEngine:
         record when pipelined). ``prep``: the caller's open
         ``serving.decode_prepare`` span, ended here right at the call."""
         prev = self._inflight
-        pend = {s for s, _, _, _ in self._pending_adm}
+        pend = {s for s, _, _ in self._pending_adm}
         rem_start = {}
         for i in active_slots:
             req = self.slot_req[i]
@@ -2717,21 +2700,15 @@ class LLMEngine:
 
     def _flush_adm(self, adm):
         """Read back a list of pending-admission first tokens
-        ((slot, rid, wave_array, row) tuples) and commit them host-side
-        — one readback per distinct wave array, not per admission."""
+        ((slot, rid, [1] token array) tuples) and commit them host-side
+        — ONE readback for all of them, not one per admission."""
         emitted = []
-        uniq = {}
-        for slot, rid, arr, i in adm:
-            uniq.setdefault(id(arr), (arr, []))[1].append(
-                (slot, rid, i))
-        host = self._device_get({aid: arr for aid, (arr, _)
-                                 in uniq.items()})
-        first = [int(host[id(arr)][i]) for _, _, arr, i in adm]
-        for (slot, rid, _, _), tok in zip(adm, first):
+        host = self._device_get([arr for _, _, arr in adm])
+        for (slot, rid, _), h in zip(adm, host):
             req = self.slot_req[slot]
             if req is None or req.req_id != rid:
                 continue              # preempted before its call ran
-            tok = int(tok)
+            tok = int(h[0])
             emitted.append((rid, tok))
             # commit point: host-visible from here on — mirrored into
             # the step's salvage buffer so a crash later in this SAME

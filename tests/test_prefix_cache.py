@@ -290,8 +290,9 @@ def test_chunk_size_rounds_and_validates(model):
 
 def test_prefill_variant_family_stays_bounded(model):
     """The history axis adds only power-of-two buckets to the existing
-    (bucket, batch, flags) prefill key — mixed cold/warm/chunked traffic
-    keeps the compiled set log-bounded, and cold keys keep pnbk=0."""
+    (bucket, flags) prefill key — mixed cold/warm/chunked traffic keeps
+    the compiled set log-bounded, cold keys keep pnbk=0, and no key
+    holds a batch form: every program takes one row."""
     cfg, params = model
     rng = np.random.default_rng(8)
     eng = _engine(params, cfg, prefix_cache=True, prefill_chunk=8)
@@ -304,12 +305,12 @@ def test_prefill_variant_family_stays_bounded(model):
             eng.run()
     eng.run()
     keys = list(eng._prefill)
-    assert all(len(k) == 4 for k in keys)
-    pnbks = {k[3] for k in keys}
+    assert all(len(k) == 3 for k in keys)       # (bucket, flags, pnbk)
+    assert {k[0] for k in keys} <= set(eng.buckets)
+    pnbks = {k[2] for k in keys}
     assert all(p == 0 or (p & (p - 1)) == 0 for p in pnbks), pnbks
-    n_buckets, n_batch = len(eng.buckets), 2
     n_pnbk = eng.mb.bit_length() + 1
-    assert len(keys) <= n_buckets * n_batch * 8 * n_pnbk
+    assert len(keys) <= len(eng.buckets) * 8 * n_pnbk
 
 
 # ---------------------------------------------------------------------------

@@ -400,6 +400,9 @@ def test_spec_off_is_byte_identical_same_variant_count(model):
     assert len(oeng._decode_cache) == len(beng._decode_cache)
     assert sorted(oeng._decode_cache) == sorted(beng._decode_cache)
     assert sorted(oeng._prefill) == sorted(beng._prefill)
+    # one-row programs only: (bucket, flags, history width), no batch
+    # form and no draft family
+    assert all(len(k) == 3 for k in beng._prefill)
     assert set(oeng.pools) == set(beng.pools)      # no dk/dv
     assert oeng.spec_waves == oeng.spec_verify_calls == 0
     # and with spec ON, the normal decode family is untouched: spec
@@ -411,6 +414,12 @@ def test_spec_off_is_byte_identical_same_variant_count(model):
     assert spec == base
     assert len(seng._decode_cache) == 0
     assert set(seng._spec_draft_cache) <= {"ragged", "bucketed"}
+    # the draft's prefill programs mirror the target's, one tag deeper
+    target = {k for k in seng._prefill if len(k) == 3}
+    assert target == set(beng._prefill)
+    assert {k[:3] for k in seng._prefill if len(k) == 4} == \
+        {(b, (False, False, False), h) for b, _f, h in target}
+    assert all(k[3] == "draft" for k in seng._prefill if len(k) == 4)
 
 
 def test_spec_validation_errors(model):

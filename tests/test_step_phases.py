@@ -119,10 +119,11 @@ def test_every_phase_lies_inside_its_parent_and_says_so(model, obs_on):
         # a sibling right after its step, before the next one
         assert st.t1 <= te.t0
         assert i + 1 == len(steps) or te.t1 <= steps[i + 1].t0
-    # the counts the benchmark's readers lean on are unchanged: one
-    # prefill per wave, one decode per dispatch, one readback per record
-    assert sum(s.name == "serving.prefill" for s in spans) == 2
-    assert sum(s.name == "serving.prefill_build" for s in spans) == 2
+    # the counts the benchmark's readers lean on: one prefill and one
+    # build per ROW (a wave of two is two one-row programs), one decode
+    # per dispatch, one readback per record
+    assert sum(s.name == "serving.prefill" for s in spans) == 3
+    assert sum(s.name == "serving.prefill_build" for s in spans) == 3
 
 
 def test_phase_attributes(model, obs_on):
@@ -130,9 +131,16 @@ def test_phase_attributes(model, obs_on):
     by = {}
     for s in obs.get_tracer().spans():
         by.setdefault(s.name, []).append(s)
-    build, wave = by["serving.prefill_build"][0], by["serving.prefill"][0]
-    for k in ("bucket", "batch", "wave"):
-        assert build.attrs[k] == wave.attrs[k]
+    builds, rows = by["serving.prefill_build"], by["serving.prefill"]
+    for build, row in zip(builds, rows):
+        for k in ("bucket", "batch", "wave"):
+            assert build.attrs[k] == row.attrs[k]
+    # the two-row admission is two one-row programs, each in its own
+    # bucket (3 and 7 tokens: 8; 20 tokens: 32), then the third alone
+    assert [(r.attrs["bucket"], r.attrs["batch"], r.attrs["wave"],
+             r.attrs["tokens"], r.attrs["start"]) for r in rows] == \
+        [(8, 1, 2, [3], [0]), (8, 1, 2, [7], [0]), (32, 1, 1, [20], [0])]
+    assert all(len(r.attrs["request_ids"]) == 1 for r in rows)
     admits = by["serving.admit"]
     assert admits[0].attrs == {"queue": 3, "wave": 2}
     assert all(a.attrs["queue"] >= 1 for a in admits)   # none when idle
