@@ -51,7 +51,8 @@ __all__ = ["PagedKVCache", "paged_cache_init", "paged_append",
            "RAGGED_INT8_KV_TPU_REFUSAL", "ragged_tpu_refusal",
            "paged_attention", "paged_append_token", "paged_append_blocks",
            "paged_decode_attention", "ragged_decode_partial",
-           "ragged_paged_decode", "latent_decode_partial"]
+           "ragged_paged_decode", "latent_decode_partial",
+           "flat_decode_partial", "pack_queries", "unpack_outputs"]
 
 
 def _interpret() -> bool:
@@ -71,10 +72,14 @@ RAGGED_INT8_KV_TPU_REFUSAL = (
 
 def ragged_tpu_refusal(head_dim: int, kv_int8: bool):
     """The TPU compiler's message for a ragged walk it refuses, ``None``
-    for one it compiles — the engine's selection by shape. Besides int8
-    pools, the block DMA slices a pool whose minor dim is the head dim:
-    anything but a multiple of the 128-lane tile is refused (found on the
-    chip at head dims 8 and 64)."""
+    for one it compiles — the engine's selection by shape. ``head_dim`` is
+    the minor dim of the pool's rows, which the block DMA slices: a
+    multiple of the 128-lane tile runs on the chip, anything else is
+    refused (found on the chip at rows of 8 and of 64). A model of head
+    dim 64 walks on the chip all the same where its pools keep a token's
+    KV heads side by side in ONE row (values then keys, 2 x 8 x 64 = 1024
+    lanes, ``flat_decode_partial``; models/lfm2_moe.py does, and asks with
+    its row's width); int8 pools are refused at any head dim."""
     if kv_int8:
         return RAGGED_INT8_KV_TPU_REFUSAL
     if head_dim % 128:
@@ -782,7 +787,8 @@ def _latent_decode_kernel(layer_ref, table_ref, lens_ref, q_ref, pool_ref,
 
 
 def latent_decode_partial(q, pool, block_table, lengths, *, layer=0,
-                          v_cols: int, sm_scale: float):
+                          v_cols: int, sm_scale: float,
+                          name: str = "mla_latent_walk"):
     """The latent walk, partial (flash-decoding) form. q: [N, Hq, W]
     absorbed queries; pool: [L, NB, BS, W] latent rows (W a multiple of
     128, ``v_cols`` too); block_table: [N, MB]; lengths: [N], a runtime
@@ -812,10 +818,74 @@ def latent_decode_partial(q, pool, block_table, lengths, *, layer=0,
         out_shape=[jax.ShapeDtypeStruct((N, Hq, v_cols), jnp.float32),
                    jax.ShapeDtypeStruct((N, Hq, 1), jnp.float32),
                    jax.ShapeDtypeStruct((N, Hq, 1), jnp.float32)],
-        interpret=_interpret(), name="mla_latent_walk",
+        interpret=_interpret(), name=name,
     )(jnp.asarray(layer, jnp.int32)[None], block_table.astype(jnp.int32),
       lengths.astype(jnp.int32), q, pool)
     return acc, m[..., 0], l[..., 0]
+
+
+# ---------------------------------------------------------------------------
+# The flat walk: grouped-query attention at a head dim that does not fill
+# the 128 lanes (64). A pool whose rows are [Hkv, 64] cannot be sliced by
+# the block DMA (``ragged_tpu_refusal``), and one whose rows pair the heads
+# up ([Hkv / 2, 128]) is re-laid out whole, there and back, around the
+# prefill's scatter of a piece's blocks (4 x 0.8 GB a piece at LFM2's cell,
+# read in the compiled program). So a token's KV heads lie side by side in
+# ONE row of a layer's ONE pool, values first: [V (Hkv x 64) | K (Hkv x
+# 64)] = 1024 lanes, the bytes of the unpadded K and V rows, which scatters
+# in place like a latent row does. That row IS a latent row — its first
+# ``v_cols`` columns the values, all of it the key — once the query is zero
+# over the value columns, so the walk is the latent walk above, unchanged:
+# a query sits in the columns of its own KV head's key with zeros in the
+# others (``pack_queries``), one dot of all query heads against a chunk as
+# it lies gives exactly q . k of each head's own key, and the columns of
+# its head are taken out of the weighted sum of whole value rows
+# (``unpack_outputs``). The MXU contracts 1024 columns for 64 (an eighth of
+# the v5e's ridge): the bytes are what this walk costs, and they are the
+# mathematics'. Measured against a K and a V pool walked by a second copy
+# of the kernel with two DMAs a block (PR 32, 91k live tokens at 64 slots):
+# 0.427 ms a layer against 0.441.
+# ---------------------------------------------------------------------------
+def pack_queries(q, n_kv: int):
+    """Queries [..., Hq, D] for keys whose ``n_kv`` heads lie side by side
+    in a row: [..., Hq, n_kv * D], each query in the columns of its own KV
+    head (head ``h // (Hq / n_kv)``), zeros in the others."""
+    Hq, D = q.shape[-2:]
+    own = jnp.arange(Hq) // (Hq // n_kv)                            # [Hq]
+    sel = (own[:, None] == jnp.arange(n_kv)[None, :]).astype(q.dtype)
+    return (q[..., None, :] * sel[:, :, None]).reshape(
+        q.shape[:-1] + (n_kv * D,))
+
+
+def unpack_outputs(o, n_kv: int):
+    """Each query head's own KV head's columns out of its output over
+    whole rows: [..., Hq, n_kv * D] -> [..., Hq, D]."""
+    Hq, W = o.shape[-2:]
+    own = jnp.arange(Hq) // (Hq // n_kv)
+    o = o.reshape(o.shape[:-1] + (n_kv, W // n_kv))
+    return jnp.take_along_axis(
+        o, own.reshape((1,) * (o.ndim - 3) + (Hq, 1, 1)), axis=-2)[..., 0, :]
+
+
+def flat_decode_partial(q, pool, block_table, lengths, *, n_kv: int,
+                        layer=0, name: str = "flat_walk"):
+    """The flat walk, partial (flash-decoding) form. q: [N, Hq, D]; pool:
+    [L, NB, BS, 2 * n_kv * D], a token's values then its keys, heads side
+    by side (``n_kv * D`` a multiple of 128); block_table: [N, MB];
+    lengths: [N], a runtime operand. Returns ``(acc [N, Hkv, G, D] f32, m
+    [N, Hkv, G] f32, l [N, Hkv, G] f32)`` as ``ragged_decode_partial``
+    does; a slot of length 0 gives the combine's identity."""
+    N, Hq, D = q.shape
+    W = n_kv * D
+    assert pool.shape[3] == 2 * W and Hq % n_kv == 0, (pool.shape, W)
+    qk = pack_queries(q, n_kv)
+    acc, m, l = latent_decode_partial(
+        jnp.concatenate([jnp.zeros_like(qk), qk], -1), pool, block_table,
+        lengths, layer=layer, v_cols=W, sm_scale=1.0 / math.sqrt(D),
+        name=name)
+    G = Hq // n_kv
+    return (unpack_outputs(acc, n_kv).reshape(N, n_kv, G, D),
+            m.reshape(N, n_kv, G), l.reshape(N, n_kv, G))
 
 
 def ragged_paged_decode(q, cache: PagedKVCache, layer=0, ks_pool=None,

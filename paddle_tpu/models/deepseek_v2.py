@@ -193,6 +193,7 @@ def _swiglu(x, w_gate, w_up, w_down, dt):
 
 class DeepseekV2Served:
     cache_kind = "latent"
+    state_entries = ()       # nothing is kept per slot beside the cache
     unsupported = {
         "spec": "there is no draft of this family and spec_verify is "
                 "llama's program",

@@ -21,6 +21,11 @@ pool-name prefix of a draft model) and ``mesh``):
                                       each with its reason
 ``make_pools(nb, bs, kv_int8)``       the zeroed pool entries
                                       ``{name: [L, nb, bs, ...]}``
+``state_entries``, ``make_state(N)``  names of per-SLOT entries ``{name:
+                                      [L', N + 1, ...]}`` kept beside the
+                                      cache (``()`` here: nothing; a short
+                                      convolution's last inputs in
+                                      models/lfm2_moe.py) and their zeros
 ``ragged_refusal(kv_int8)``           why the chip's compiler refuses the
                                       decode walk at this shape, or None
 ``history_blocks(hist_blocks, mb)``   how wide a row's history table is
@@ -71,6 +76,7 @@ class ServeOpts(NamedTuple):
 class LlamaServed:
     cache_kind = "kv"
     unsupported: Dict[str, str] = {}
+    state_entries = ()       # nothing is kept per slot beside the cache
 
     def __init__(self, config: LlamaConfig):
         self.config = config

@@ -259,6 +259,17 @@ CATALOG = {
         "gauge", (), "rows of the most loaded held expert over the mean "
                      "rows of a held expert (each layer's ratio weighed "
                      "by its rows), in the program read back last"),
+    "serving_state_bytes_per_slot": (
+        "gauge", (), "bytes of per-slot state a served model keeps beside "
+                     "the paged cache (a short convolution's last inputs, "
+                     "over its layers); 0 for a model whose layers all "
+                     "cache per token"),
+    "serving_state_resets_total": (
+        "counter", ("reason",),
+        "rows that began their context from zero per-slot state: "
+        "reason=admit (a new request) or preempt (a re-admission after "
+        "preemption-by-recompute, which recomputes the state with the "
+        "tokens)"),
     # -- fleet observability (observability.fleet, r17) --------------------
     "serving_fleet_slo_attainment": (
         "gauge", ("replica", "slo"),

@@ -7,7 +7,8 @@ GET /requests.json      -> per-request summaries + TTFT/TPOT exemplars
                            (?sort=ttft|tpot|queue|tokens, ?limit=N)
 GET /request/<id>.json  -> one request's full structured timeline
 GET /control/profile    -> arm an on-demand device capture
-                           (?steps=N; windowed to N step boundaries)
+                           (?steps=N; windowed to N step boundaries,
+                           ?seconds=S; or the whole steps within S)
 GET /fleet/metrics      -> fleet-merged Prometheus text (counters
                            summed, histogram buckets merged, gauges
                            per-replica-labeled)
@@ -136,14 +137,16 @@ class _Handler(BaseHTTPRequestHandler):
             self._send_json({"ok": True,
                              "status": profiling.get_controller().stop()})
             return
-        steps = None
         try:
             steps = int(qs["steps"]) if "steps" in qs else None
+            seconds = float(qs["seconds"]) if "seconds" in qs else None
         except ValueError:
             self._send_json({"ok": False,
-                             "error": f"bad steps={qs['steps']!r}"}, 400)
+                             "error": f"bad steps={qs.get('steps')!r} or "
+                                      f"seconds={qs.get('seconds')!r}"},
+                            400)
             return
-        out = profiling.request_capture(steps=steps)
+        out = profiling.request_capture(steps=steps, seconds=seconds)
         # invalid input is the caller's fault (400); a capture already
         # in flight is a state conflict (409)
         code = 200 if out.get("ok") \

@@ -64,6 +64,12 @@ __all__ = ["ProfileController", "get_controller",
 # package __init__ (this module is lazily loaded; the flags must
 # register up front so set_flags sees them).
 
+# A capture this old ends at the next step boundary unless its request
+# names another bound. The serving captures the benchmark reads end in
+# under 5.1 s (240 engine steps; PERF.md); stopping a capture takes about
+# as long as it ran.
+MAX_SECONDS = 6.0
+
 _M_CAPTURES = _instrument("obs_profile_captures_total")
 
 class ProfileController:
@@ -92,6 +98,7 @@ class ProfileController:
         self._sig_armed = False
         self._steps_left = 0
         self._armed_n = 0
+        self._max_s = 0.0           # the capture's bound in seconds
         self._active = False
         self._dir: Optional[str] = None
         self._seq = 0
@@ -99,16 +106,22 @@ class ProfileController:
 
     # -- control ----------------------------------------------------------
     def request(self, steps: Optional[int] = None,
-                out_dir: Optional[str] = None) -> Dict:
-        """Arm a capture spanning ``steps`` step boundaries. Returns a
-        status dict (also the ``/control/profile`` response body). A
-        second request while one is armed/active is rejected — two
-        overlapping jax traces would abort the first."""
+                out_dir: Optional[str] = None,
+                seconds: Optional[float] = None) -> Dict:
+        """Arm a capture spanning ``steps`` step boundaries, or as many
+        whole steps as end within ``seconds``. Returns a status dict
+        (also the ``/control/profile`` response body). A second request
+        while one is armed/active is rejected — two overlapping jax
+        traces would abort the first."""
         n = int(steps) if steps is not None else int(
             get_flag("obs_profile_default_steps"))
         if n <= 0:
             return {"ok": False, "bad_request": True,
                     "error": f"steps must be > 0, got {n}"}
+        max_s = float(seconds) if seconds is not None else MAX_SECONDS
+        if not max_s > 0:
+            return {"ok": False, "bad_request": True,
+                    "error": f"seconds must be > 0, got {max_s}"}
         if self._stopping:
             # the helper thread holds the lock while it writes the file
             return {"ok": False, "error": "capture already in flight",
@@ -119,10 +132,12 @@ class ProfileController:
                         "status": self._status_locked()}
             self._steps_left = n
             self._armed_n = n
+            self._max_s = max_s
             self._seq += 1
             self._dir = self._derive_dir(out_dir)
             self._pending = True
-            return {"ok": True, "armed_steps": n, "dir": self._dir,
+            return {"ok": True, "armed_steps": n, "max_seconds": max_s,
+                    "dir": self._dir,
                     "status": self._status_locked()}
 
     def _derive_dir(self, out_dir: Optional[str]) -> str:
@@ -137,7 +152,8 @@ class ProfileController:
 
     def step_tick(self) -> None:
         """One engine/train step boundary. Starts the armed capture,
-        counts down, stops at zero. Called with ``_pending`` true only."""
+        counts down, stops at zero or where the capture is overdue.
+        Called with ``_pending`` true only."""
         if self._stopping:
             return      # a SIGUSR2 during a stop waits for the next step
         if self._sig_armed:
@@ -159,7 +175,8 @@ class ProfileController:
                 self._start_locked()
                 return
             self._steps_left -= 1
-            if self._steps_left <= 0:
+            if self._steps_left <= 0 or \
+                    time.perf_counter() - self._t_started >= self._max_s:
                 self._pending = False
                 self._stop_locked(wait=False)
                 handed = True
@@ -231,7 +248,8 @@ class ProfileController:
             tracing.get_tracer().record(
                 "serving.profile_capture", self._t_started,
                 time.perf_counter(),
-                {"dir": self._dir, "steps": self._armed_n}, depth=0)
+                {"dir": self._dir, "steps": self._traced_steps()},
+                depth=0)
         if wait:
             self._write_trace()
             return
@@ -245,8 +263,14 @@ class ProfileController:
         finally:
             self._lock.release()
 
+    def _traced_steps(self) -> int:
+        """Whole steps of the capture so far: the armed count unless it
+        was cut short (overdue, or a forced stop)."""
+        return self._armed_n - max(0, self._steps_left)
+
     def _write_trace(self) -> None:
-        steps = self._armed_n
+        steps = self._traced_steps()
+        self._steps_left = 0
         try:
             import jax
 
@@ -286,9 +310,11 @@ get_profile_controller = get_controller
 
 
 def request_capture(steps: Optional[int] = None,
-                    out_dir: Optional[str] = None) -> Dict:
+                    out_dir: Optional[str] = None,
+                    seconds: Optional[float] = None) -> Dict:
     """Arm a windowed device capture on the default controller."""
-    return _default_controller.request(steps=steps, out_dir=out_dir)
+    return _default_controller.request(steps=steps, out_dir=out_dir,
+                                       seconds=seconds)
 
 
 def step_tick() -> None:

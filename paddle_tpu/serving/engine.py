@@ -1,7 +1,8 @@
 """Continuous-batching paged-cache serving engine for any model that gives
 the served-model interface (models/llama_served.py states it; llama-family
-dense decoders and the latent-attention sparse-expert family
-models/deepseek_v2.py provide it).
+dense decoders, the latent-attention sparse-expert family
+models/deepseek_v2.py and the short-convolution family models/lfm2_moe.py,
+which keeps per-slot state beside the paged cache, provide it).
 
 Parity surface: the reference wires its paged decode kernel into serving via
 incubate/nn/functional/block_multihead_attention (block tables + per-seq
@@ -155,6 +156,8 @@ _M_KV_TOKEN_BYTES = _instrument("serving_kv_bytes_per_token")
 _M_MOE_ROUTED = _instrument("serving_moe_routed_total")
 _M_MOE_ASSIGNED = _instrument("serving_moe_assigned_total")
 _M_MOE_LOAD = _instrument("serving_moe_load_max_over_mean")
+_M_STATE_SLOT_BYTES = _instrument("serving_state_bytes_per_slot")
+_M_STATE_RESETS = _instrument("serving_state_resets_total")
 
 
 @dataclasses.dataclass
@@ -258,7 +261,8 @@ def _apply_admissions(c_last, c_len, c_done, c_rem, wave_toks, slot_of_row,
 
 def _paged_prefill(params, tokens, blk_ids, true_len, pools,
                    temps, top_ks, top_ps, key, hist_len=None,
-                   ctx_tbl=None, *, model, opts: ServeOpts = ServeOpts(),
+                   ctx_tbl=None, slot=None, *, model,
+                   opts: ServeOpts = ServeOpts(),
                    sample_flags=(True, True, True), prefix_nbk: int = 0):
     """Prefill a WAVE of admissions in one compiled program: causal
     forward over the padded prompt batch, every layer's new cache entries
@@ -315,11 +319,30 @@ def _paged_prefill(params, tokens, blk_ids, true_len, pools,
     engine's tp mesh, handed to the model's kernels, which shard_map
     themselves (GSPMD partitions everything else, but not a Mosaic
     kernel).
+
+    Per-slot state (``model.state_entries``, e.g. what a short
+    convolution remembers; ``{name: [layers that have one, slots + 1,
+    ...]}`` in the same donated dict): ``slot`` [B] names each row's slot
+    (pad rows the trash row). A row that starts its context (``hist_len``
+    0: an admission, or a re-admission after a preemption, which
+    recomputes the state with the tokens) begins from ZERO state, a
+    continuing piece from what its predecessor left in the row's slot;
+    the model returns the state after the piece's last real token under
+    the entry's name and it is scattered back to the slot with the
+    piece's cache entries. A layer returns either kind of entry, never
+    both, and a layer with no per-token entry takes no pool.
     """
     B, S = tokens.shape
     x = model.embed(params, tokens)
     aux = model.prefill_begin(params, pools, tokens, true_len, hist_len,
                               ctx_tbl, prefix_nbk, opts)
+    if model.state_entries:
+        carried = (jnp.zeros((B,), bool) if hist_len is None
+                   else hist_len > 0)
+        aux["state"] = {
+            n: jnp.where(carried.reshape((1, B) + (1,) * (pools[n].ndim - 2)),
+                         pools[n][:, slot], 0)
+            for n in model.state_entries}
     new = []
     for l in range(model.num_layers):
         x, ent = model.prefill_layer(params, l, x, aux, pools, opts)
@@ -329,9 +352,12 @@ def _paged_prefill(params, tokens, blk_ids, true_len, pools,
     # per-layer Pallas/XLA block appends cost ~0.6 ms of launch overhead
     # each — 2L calls/prefill dwarfed the prefill math itself)
     flat = blk_ids.reshape(-1)
-    stacked = {n: jnp.stack([e[n] for e in new]) for n in new[0]
-               if not n.startswith("_")}
+    names = dict.fromkeys(n for e in new for n in e if not n.startswith("_"))
+    stacked = {n: jnp.stack([e[n] for e in new if n in e]) for n in names}
     pools = dict(pools)
+    for name in model.state_entries:
+        pools[name] = pools[name].at[:, slot].set(
+            stacked.pop(name).astype(pools[name].dtype))
     for name, val in model.pack_entries(stacked, opts).items():
         bs = pools[name].shape[2]
         pools[name] = pools[name].at[:, flat].set(
@@ -452,12 +478,18 @@ def _paged_decode(params, last_tokens, lengths, done0, budgets, key, active,
         return (last, lens, done, rem, ring, k), emitted
 
     ring = model.ring_init(N, S, opts)
+    # per-slot state rides in the carry beside the ring: the model advances
+    # it only where ``act`` (an idle, mid-chunk or finished slot's must not
+    # move), and it is written back once, below
+    for name in model.state_entries:
+        ring[name] = pools[name][:, :N]
     init = (last_tokens, lengths, done0, budgets, ring, key)
     (last_tokens, lens_end, done0, budgets, ring, key), \
         emitted = jax.lax.scan(body, init, jnp.arange(S))
 
     # ---- writeback: the ring's valid entries → pools, one scatter -------
     stats = ring.pop("_stats", None)
+    state = {name: ring.pop(name) for name in model.state_entries}
     packed = model.pack_entries(ring, opts)
     bs = pools[next(iter(packed))].shape[2]
     P = MB * bs
@@ -472,6 +504,8 @@ def _paged_decode(params, last_tokens, lengths, done0, budgets, key, active,
     pools = dict(pools)
     for name, val in packed.items():
         pools[name] = pools[name].at[:, phys, off].set(val)
+    for name, val in state.items():
+        pools[name] = pools[name].at[:, :N].set(val)
     return (emitted, last_tokens, lens_end, done0, budgets, key, pools,
             stats)
 
@@ -489,7 +523,11 @@ def decode_path(decode_kernel: str, backend: str, model,
 
     ``"auto"`` walks where the walk is compiled and is the faster of the
     two: on a TPU ``backend``, at a shape the chip's compiler takes
-    (``model.ragged_refusal(kv_int8)`` is None). Elsewhere it gathers:
+    (``model.ragged_refusal(kv_int8)`` is None: bf16/f32 pool rows of a
+    multiple of 128 lanes — the dense family at head dim 128, a latent
+    row, head dim 64 with a token's KV heads side by side in one row; the
+    dense family's ``[Hkv, D]`` rows at another head dim, and int8 pools,
+    are refused). Elsewhere it gathers:
     off a TPU the walk would run in the Pallas interpreter. A path asked
     for by name is taken, except that ``"ragged"`` at a shape the TPU's
     compiler refuses raises with the compiler's message: no request falls
@@ -603,8 +641,10 @@ class LLMEngine:
         sampling-flags) set. ``"bucketed"`` — the r6 host-side
         power-of-two prefix buckets over the hoisted dense gather.
         ``"auto"`` (default) picks ragged on a TPU backend — sharded or
-        not — for the shapes Mosaic compiles (bf16/f32 pools, head dim
-        a multiple of 128: ``kernels.paged_attention.
+        not — for the shapes Mosaic compiles (bf16/f32 pools whose rows
+        are a multiple of 128 lanes: head dim 128 for the dense family,
+        head dim 64 where a row holds all of a token's KV heads as
+        models/lfm2_moe.py keeps them: ``kernels.paged_attention.
         ragged_tpu_refusal``) and bucketed elsewhere (other shapes; and
         off-TPU, where the kernel would run in the Pallas interpreter —
         correct but slow); the choice is counted per dispatch in
@@ -732,6 +772,18 @@ class LLMEngine:
         # for llama, one latent row for a latent-attention model
         self.pools = model.make_pools(self.nb, block_size, self.kv_int8)
         self._target_pools = tuple(self.pools)
+        # the model's per-slot entries ride in the same donated dict: one
+        # row a slot and a trash row, indexed by slot and never by block
+        # (the block ledger, block bytes and the cache-traffic estimates
+        # count the per-token entries only)
+        self._state_bytes_per_slot = 0
+        if model.state_entries:
+            state = model.make_state(self.N)
+            if tuple(state) != tuple(model.state_entries):
+                raise ValueError("make_state must give state_entries")
+            self.pools.update(state)
+            self._state_bytes_per_slot = sum(
+                a.nbytes // a.shape[1] for a in state.values())
         # -- speculative decoding (r13): the optional draft model --------
         self._spec_on = spec and draft_params is not None
         self.spec_k = int(spec_tokens)
@@ -1950,12 +2002,22 @@ class LLMEngine:
             sp.attrs.update(bucket=bucket, batch=1)
         # tokens: the row's real tokens in THIS program; start: what of
         # the row is already cached (a chunk is not a whole prompt)
-        with trace_span("serving.prefill", bucket=bucket, batch=1,
-                        wave=wave, prefix_bucket=pnbk * self.bs,
-                        request_ids=[req.req_id], tokens=[piece],
-                        start=[hist]) as sp:
+        attrs = dict(bucket=bucket, batch=1, wave=wave,
+                     prefix_bucket=pnbk * self.bs, request_ids=[req.req_id],
+                     tokens=[piece], start=[hist])
+        kw = {}
+        if self.model.state_entries:
+            # the row's slot for its per-slot state: carried from the
+            # piece before, or begun from zero (the program decides by
+            # hist_len; counted here by why the row starts over)
+            kw["slot"] = jnp.asarray([slot], jnp.int32)
+            attrs["state_in"] = hist > 0
+            if not hist:
+                _M_STATE_RESETS.inc(
+                    reason="preempt" if req.generated else "admit")
+        with trace_span("serving.prefill", **attrs) as sp:
             tok_dev, self.pools, stats = self._prefill_fn(
-                bucket, flags, pnbk)(*args)
+                bucket, flags, pnbk)(*args, **kw)
         if stats is not None:
             # read back with the next decode record's tokens
             self._pending_stats.append((stats, sp.attrs))
@@ -2301,7 +2363,8 @@ class LLMEngine:
         block ids but are read by the draft's own (cheaper) walks."""
         return sum(a.shape[0] * int(np.prod(a.shape[2:])) * a.dtype.itemsize
                    for n, a in self.pools.items()
-                   if (n in self._target_pools) != draft)
+                   if n not in self.model.state_entries
+                   and (n in self._target_pools) != draft)
 
     def _dispatch_decode(self, active_slots, prep=None):
         """Enqueue one multi-step decode call and record it as in-flight.
@@ -2407,6 +2470,10 @@ class LLMEngine:
                         steps=self.decode_steps,
                         walk_blocks=walk, kv_bytes=step_bytes,
                         latent_bytes=step_bytes if latent else 0,
+                        # per-slot state a step reads and writes, of the
+                        # slots that move
+                        state_bytes=(self._state_bytes_per_slot
+                                     * len(active_slots)),
                         # the true dispatched horizon (ragged: max real
                         # length; bucketed: the ceiling) — matches the
                         # serving_decode_prefix_bucket gauge, never the
@@ -2831,6 +2898,7 @@ class LLMEngine:
         _M_ACTIVE_SLOTS.set(sum(r is not None for r in self.slot_req))
         _M_KV_BLOCKS.set(self.nb - 1)
         _M_KV_TOKEN_BYTES.set(self._pool_block_bytes() / self.bs)
+        _M_STATE_SLOT_BYTES.set(self._state_bytes_per_slot)
         _M_KV_USED.set(self.nb - 1 - len(self.free_blocks))
         if self.prefix_cache is not None:
             self.prefix_cache.update_gauges()

@@ -179,6 +179,58 @@ def test_ragged_walk_head_dim_64(topo):
     _compile(_ragged, _one(topo), *_ragged_specs(8, BF16, d=64))
 
 
+def test_flat_walk_head_dim_64(topo):
+    """Head dim 64 runs on the chip where a pool row holds ALL of a token's
+    heads, values then keys (2 x 8 x 64 = 1024 lanes, one pool a layer;
+    models/lfm2_moe.py): the latent walk as it is, the query in its own
+    head's key columns and zero over the values. At the
+    ``rag-offline`` cell's shapes: 64 slots, 32 query heads on 8 KV heads
+    of 64, a pool of 12,289 blocks of 16, a table 576 wide."""
+    c = _compile(
+        lambda q, pool, tbl, lens: paged_attention.flat_decode_partial(
+            q, pool, tbl, lens, n_kv=8, name="lfm2_ragged_walk"),
+        _one(topo), ((64, 32, 64), BF16), ((1, 12289, BS, 1024), BF16),
+        ((64, 576), jnp.int32), ((64,), jnp.int32))
+    assert "%lfm2_ragged_walk" in c.as_text()   # the name a trace shows
+
+
+def test_flash_partial_head_dim_64(topo):
+    """The prefill's blockwise attention at heads of 64 as they are (a
+    block's minor dim may be the array's own): a piece of 1024, causal,
+    and a history of 9,216 rows with a runtime length."""
+    c = _compile(
+        lambda q, k, v: pallas_attention.flash_partial(
+            q, k, v, scale=0.125, causal=True, name="lfm2_prefill_chunk"),
+        _one(topo), ((32, 1024, 64), BF16), ((8, 1024, 64), BF16),
+        ((8, 1024, 64), BF16))
+    assert "%lfm2_prefill_chunk" in c.as_text()
+    c = _compile(
+        lambda q, k, v, n: pallas_attention.flash_partial(
+            q, k, v, scale=0.125, kv_len=n, name="lfm2_prefill_history"),
+        _one(topo), ((32, 1024, 64), BF16), ((8, 9216, 64), BF16),
+        ((8, 9216, 64), BF16), ((8,), jnp.int32))
+    assert "%lfm2_prefill_history" in c.as_text()
+
+
+@pytest.mark.parametrize("tokens", [64, 1024], ids=["decode", "piece"])
+def test_held_expert_ffn_narrow_experts(topo, monkeypatch, tokens):
+    """All 32 of LFM2's experts held (2048 x 1792, top-4): the grouped
+    matmul's whole-contraction tiles for a decode step of 64 slots (256
+    pairs, row tile 128) and a one-row piece of 1024 tokens (4096 pairs,
+    row tile 256), chosen from the shapes, never timed."""
+    import importlib
+
+    moe_dispatch = importlib.import_module("paddle_tpu.kernels.moe_dispatch")
+    monkeypatch.setattr(moe_dispatch, "_mosaic", lambda: True)
+    c = _compile(
+        lambda x, g, i, v, gu, dn: moe_dispatch.held_expert_ffn(
+            x, g, i, v, gu, dn, 0)[0],
+        _one(topo), ((tokens, 2048), BF16), ((tokens, 4), jnp.float32),
+        ((tokens, 4), jnp.int32), ((tokens,), jnp.bool_),
+        ((32, 2048, 3584), BF16), ((32, 1792, 2048), BF16))
+    assert "%gmm" in c.as_text()
+
+
 def test_ragged_walk_tp2(topo):
     """The shard_mapped walk on two described devices: pools sharded on
     the KV-head axis, tables and lengths replicated."""

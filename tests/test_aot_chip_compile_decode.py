@@ -217,3 +217,104 @@ def test_decode_reads_the_attention_weights_where_they_lie(topo, slots):
 
 def test_one_row_prefill_reads_the_attention_weights_where_they_lie(topo):
     _check(_prefill_text(topo, 1, 128), 128)
+
+
+# -- the third family: convolutions with a per-slot state, experts -----------
+def _lfm2_shapes(topo, slots):
+    """LFM2-8B-A1B's first period (conv conv attention conv: both dense
+    layers, two expert layers) at the published widths, as shapes."""
+    from paddle_tpu.models import lfm2_moe
+
+    moe_dispatch = importlib.import_module("paddle_tpu.kernels.moe_dispatch")
+    sh = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sh)
+    cfg = lfm2_moe.Lfm2MoeConfig(
+        layer_types=lfm2_moe.PUBLISHED_LAYER_TYPES[:4], dtype=BF16)
+    model = cfg.served_model()
+    h, f, fe, E = (cfg.hidden_size, cfg.intermediate_size,
+                   cfg.moe_intermediate_size, cfg.num_experts)
+    norms = {"op_norm": (h,), "ffn_norm": (h,)}
+    conv = {"w_in": (h, 3 * h), "conv_w": (h, 3), "w_out": (h, h)}
+    attn = {"wq": (h, h), "wk": (h, 512), "wv": (h, 512), "wo": (h, h),
+            "q_norm": (64,), "k_norm": (64,)}
+    dense = {"w_gate": (h, f), "w_up": (h, f), "w_down": (f, h)}
+    moe = {"router": (h, E), "e_gu": (E, h, 2 * fe), "e_down": (E, fe, h)}
+    tree = lambda *parts: {n: sds(s, BF16) for p in parts
+                           for n, s in p.items()}
+    layers = [tree(norms, conv, dense), tree(norms, conv, dense),
+              tree(norms, attn, moe), tree(norms, conv, moe)]
+    for l in layers[2:]:
+        l["expert_bias"] = sds((E,), F32)
+    params = {"embed": sds((cfg.vocab_size, h), BF16), "layers": layers,
+              "final_norm": sds((h,), BF16)}
+    pools = jax.eval_shape(lambda: {**model.make_pools(LFM2_NB, BS),
+                                    **model.make_state(slots)})
+    pools = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), pools)
+    return model, params, pools, sds, moe_dispatch
+
+
+LFM2_NB = 12289     # the cell's pool: too large for the chip's fast memory
+
+
+def _lfm2_big_writes(text):
+    """Writes of 4 MiB or more that are neither a pool's write-back in
+    place (the pool's own dims and layout: a pool re-laid out around a
+    scatter has another) nor a prefetch of a weight into the chip's fast
+    memory in slices."""
+    entry = _entry(text)
+    lies = re.compile(r"bf16\[(1,)?12289,16,1024\]\{(3,2,1,0|2,1,0):"
+                      r"T\(8,128\)\(2,1\)\}")
+    relaid = [f"{n} = {ty[:70]} {op}" for n, (ty, op, _) in entry.items()
+              if "12289,16,1024]" in ty and not lies.search(ty)]
+    return relaid + [
+        b for b in _weight_sized_writes(entry, (LFM2_NB, BS, 1024))
+        if not b.endswith(_PREFETCH)]
+
+
+def test_lfm2_decode_writes_no_weight_sized_array(topo, monkeypatch):
+    """32 slots (the walk's f32 partials over whole rows stay under the
+    reader's 4 MiB) through convolutions, the flat walk and two expert
+    layers: w_in's product stays [rows, 3h] behind its dot, the tied head
+    contracts the embedding where it lies (no transposed copy of 268 MB),
+    a layer's state is rewritten alone (0.5 MB) and never the stack, and a
+    layer's pool of [V | K] rows takes the step's rows in place (over a
+    pool with a leading layer axis, or with rows of two heads, XLA re-lays
+    the whole pool out and back around a scatter: 0.8 GB each way)."""
+    N = 32
+    model, params, pools, sds, moe_dispatch = _lfm2_shapes(topo, N)
+    monkeypatch.setattr(moe_dispatch, "_mosaic", lambda: True)
+    text = jax.jit(functools.partial(
+        engine._paged_decode, model=model, n_steps=1,
+        opts=ServeOpts(ragged=True), sample_flags=GREEDY),
+        donate_argnums=(8,)).lower(
+        params, sds((N,), I32), sds((N,), I32), sds((N,), jnp.bool_),
+        sds((N,), I32), sds((2,), jnp.uint32), sds((N,), jnp.bool_),
+        sds((N, TABLE), I32), pools, sds((N,), F32), sds((N,), I32),
+        sds((N,), F32), sds((N,), I32)).compile().as_text()
+    assert "%lfm2_ragged_walk" in text and "%gmm" in text
+    assert _lfm2_big_writes(text) == []
+
+
+@pytest.mark.parametrize("history", [0, TABLE // 2],
+                         ids=["first", "continuing"])
+def test_lfm2_one_row_prefill_writes_no_weight_sized_array(topo, monkeypatch,
+                                                           history):
+    """A piece of 128 tokens, from zero state and from a carried one with
+    a history: the same reading. The history is TABLE / 2 blocks wide, so
+    that its own gathered rows (one array of [V | K] rows, 2.6 MB) stay
+    under the reader's 4 MiB: they are the history, not a weight or a
+    pool."""
+    model, params, pools, sds, moe_dispatch = _lfm2_shapes(topo, 64)
+    monkeypatch.setattr(moe_dispatch, "_mosaic", lambda: True)
+    args = [params, sds((1, 128), I32), sds((1, 128 // BS), I32),
+            sds((1,), I32), pools, sds((1,), F32), sds((1,), I32),
+            sds((1,), F32), sds((2,), jnp.uint32)]
+    if history:
+        args += [sds((1,), I32), sds((1, history), I32)]
+    text = jax.jit(functools.partial(
+        engine._paged_prefill, model=model, opts=ServeOpts(ragged=True),
+        sample_flags=GREEDY, prefix_nbk=history), donate_argnums=(4,)).lower(
+        *args, slot=sds((1,), I32)).compile().as_text()
+    assert "%lfm2_prefill_chunk" in text
+    assert ("%lfm2_prefill_history" in text) == bool(history)
+    assert _lfm2_big_writes(text) == []

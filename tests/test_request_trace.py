@@ -364,6 +364,27 @@ def test_http_profile_control_arm_and_conflict(obs_http_server):
     assert out["ok"] and out["status"]["steps_left"] == 0
 
 
+@pytest.mark.parametrize("query, code, max_s", [
+    ("steps=3&seconds=2.5", 200, 2.5),     # the caller's bound
+    ("steps=3", 200, 6.0),                 # the module's
+    ("steps=3&seconds=0", 400, None),      # no bound at all: refused
+    ("steps=3&seconds=soon", 400, None),
+])
+def test_http_profile_control_takes_a_bound_in_seconds(
+        obs_http_server, query, code, max_s):
+    try:
+        if code == 200:
+            out = _get_json(obs_http_server, f"/control/profile?{query}")
+            assert out["ok"] and out["max_seconds"] == max_s
+        else:
+            with pytest.raises(urllib.error.HTTPError) as ei:
+                _get_json(obs_http_server, f"/control/profile?{query}")
+            assert ei.value.code == code
+            assert profiling.get_controller().status()["steps_left"] == 0
+    finally:
+        profiling.get_controller().stop()
+
+
 def test_http_request_id_junk_is_404_not_500(obs_http_server):
     for junk in ("--5", "abc", "-"):
         with pytest.raises(urllib.error.HTTPError) as ei:

@@ -285,6 +285,44 @@ def test_capture_has_no_python_tracer_and_stops_off_the_step_thread(
     ctl.stop()
 
 
+def test_an_overdue_capture_ends_with_the_whole_steps_it_has(
+        obs_on, tmp_path, monkeypatch):
+    """A capture is bounded in seconds as well as in steps: the boundary
+    that finds it overdue stops it, the ring span says how many whole
+    steps it holds, and one in time runs to its count as before."""
+    stopped = threading.Event()
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda *a, **kw: None)
+    monkeypatch.setattr(jax.profiler, "stop_trace", stopped.set)
+    ctl = profiling.ProfileController()
+    d = str(tmp_path / "cap")
+    out = ctl.request(steps=50, out_dir=d, seconds=0.2)
+    assert out["ok"] and out["max_seconds"] == 0.2
+    ctl.step_tick()                                    # starts
+    ctl.step_tick()                                    # in time: goes on
+    ctl.step_tick()
+    assert ctl.status()["active"] and ctl.status()["steps_left"] == 48
+    time.sleep(0.25)
+    ctl.step_tick()                                    # overdue: stops
+    assert stopped.wait(5)
+    st = ctl.status()
+    assert not st["active"] and st["steps_left"] == 0
+    assert st["last_capture"]["ok"]
+    cap = [s for s in obs.get_tracer().spans()
+           if s.name == "serving.profile_capture"]
+    assert len(cap) == 1 and cap[0].attrs == {"dir": d, "steps": 3}
+    assert not ctl._pending                            # ticks cost nothing
+    # the default bound is the module's, and a bound of nothing is refused
+    assert ctl.request(steps=2, out_dir=d)["max_seconds"] == \
+        profiling.MAX_SECONDS == 6.0
+    stopped.clear()
+    ctl.step_tick(), ctl.step_tick(), ctl.step_tick()
+    assert stopped.wait(5) and not ctl.status()["active"]
+    assert [s.attrs["steps"] for s in obs.get_tracer().spans()
+            if s.name == "serving.profile_capture"] == [3, 2]
+    bad = ctl.request(steps=2, seconds=0)
+    assert not bad["ok"] and bad["bad_request"]
+
+
 def test_capture_anchors_the_ring_clock_in_the_trace(obs_on, tmp_path):
     """A real capture (the CPU backend has a host plane): the anchor's
     argument is a perf_counter reading inside the capture's ring span."""
