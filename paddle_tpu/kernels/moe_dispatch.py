@@ -1036,7 +1036,9 @@ def sigmoid_bias_routing(scores, bias, top_k: int, scale: float = 1.0,
 # pairs up to which a call gathers all of them at once; a wider wave walks
 # its sorted pairs in passes of about one row a token
 _HELD_PASS_ROWS = 8192
-# rows up to which the grouped matmul takes the narrow row tile
+# pairs of a pass up to which a call is a decode step: its pairs stay packed
+# one after another; over it (a prefill piece) every held expert's rows start
+# on a row-tile boundary of the grouped matmul
 _HELD_SMALL_ROWS = 1024
 # the longest side of an expert's matrices up to which the whole contraction
 # is one tile (DeepSeek-V2's 5120 x 1536 lies above it and keeps its tiles)
@@ -1048,44 +1050,67 @@ def _mosaic() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _static_gmm(xs, w, gs):
-    """Grouped matmul with its tiling chosen by a RULE from the shapes
-    (``gmm_autotune.heuristic_tilings``), never timed: two runs of one
-    commit run the same kernel and no set-up carries a tuning. Off a TPU,
-    ``ragged_dot``. Rows past sum(gs) come back zero."""
-    from .gmm_autotune import heuristic_tilings
+def _row_tile() -> int:
+    """Rows of the grouped matmul's row tile under ``held_expert_ffn``:
+    the Mosaic kernel's 128, ``ragged_dot``'s nominal 8 off a TPU."""
+    return 128 if _mosaic() else 8
 
+
+def _static_gmm(xs, w, gs):
+    """Grouped matmul with its tiling chosen by a RULE from the shapes,
+    never timed: two runs of one commit run the same kernel and no set-up
+    carries a tuning. Off a TPU, ``ragged_dot``. Rows past sum(gs) come
+    back zero.
+
+    The row tile is 128 whatever the rows: a decode step has a few rows an
+    expert, and a piece's experts each start on a tile boundary
+    (``held_expert_ffn``), so a taller tile only adds rows of padding for
+    the MXU to work through. What the tile's other sides are follows from
+    the expert's width. Read on the chip (PRs 28, 32, 33; scratch
+    ``output/chip_micro*.py``), the kernel alone:
+
+    - a narrow expert (no side of its matrices over 4096: LFM2's 2048 x
+      1792): the whole contraction in one tile, so an expert's weights
+      stream once and no accumulator is revisited. ``[rows, 2048] x [32,
+      2048, 3584]`` + ``[rows, 1792] x [32, 1792, 2048]``: a decode step's
+      256 packed rows 0.68 + 0.37 ms (0.92 + 0.46 at (128, 512, 1024)),
+      84% of the weights' bytes; a piece's 2,948 real pairs 0.95 + 0.50 ms
+      on 8,192 aligned rows, against 1.02 + 0.54 packed in 4,096 rows at a
+      256-row tile and 1.04 + 0.56 packed at 128: the kernel is bound by
+      the bytes it moves (the weights once, x's tiles once a column tile),
+      not by the MXU's passes over masked rows, and the layout wins a
+      tenth of it. (128, 512, 3584) and (128, 1792, 2048) read 0.83 + 0.44
+      alone and nothing in the whole function (1.44 against 1.45 ms), so
+      the one rule stays.
+    - any other (DeepSeek-V2's 5120 x 1536): (128, 512, 1024), or what of
+      it divides the expert's sides. A decode step's 144 pairs: 4.2 ms of
+      bytes a step against 11.7 at a 512-row tile (PR 28). A piece's ~840 real pairs over 20 experts:
+      1.06 + 0.65 ms aligned against 1.99 + 1.10 packed under
+      ``heuristic_tilings``' (512, 512, 1024), where every one of ~21
+      visits works through 512 rows; (128, 512, 512) 1.26 + 0.76."""
     m, k = xs.shape
     n = w.shape[-1]
     if _mosaic():
-        if max(k, n) <= _HELD_NARROW and k % 128 == 0 and n % 512 == 0:
-            # a narrow expert (no side of its matrices over 4096: LFM2's
-            # 2048 x 1792): the whole contraction in one tile, so an
-            # expert's weights stream once and no accumulator is revisited,
-            # and a row tile no taller than the rows an expert has — a
-            # decode step's few (128), a one-row piece's ~128 of 4096
-            # pairs over 32 experts (256; at 512 every tile straddles four
-            # experts and the MXU works through each of them). Read on the
-            # chip, PR 32, [rows, 2048] x [32, 2048, 3584] and [rows, 1792]
-            # x [32, 1792, 2048]: 256 rows 0.68 + 0.37 ms against 0.92 +
-            # 0.46 under the rule below; 4096 rows 1.10 + 0.59 ms against
-            # 2.01 + 0.57 under ``heuristic_tilings``.
-            tile = (128 if m <= _HELD_SMALL_ROWS else 256, k, 512)
-            tilings = (tile, tile, tile)
-        elif m <= _HELD_SMALL_ROWS and k % 512 == 0 and n % 512 == 0:
-            # a decode step: a few rows an expert. A row tile of 128 keeps
-            # the kernel at the weights' bytes (one pass of each hit
-            # expert's matrices); at 512 the MXU works through four times
-            # the padding and the step is bound by that (read on the chip,
-            # PR 28: 11.7 ms a step of grouped matmul against 4.2 of bytes)
-            tile = (128, 512, 1024 if n % 1024 == 0 else 512)
-            tilings = (tile, tile, tile)
-        else:
-            tilings = heuristic_tilings(m, k, n)
-        if tilings is None:
+        if k % 128 or n % 128:
             raise ValueError(f"no static gmm tiling for rows={m} k={k} n={n}")
-        return _gmm_tuned(xs, w, gs, tilings, False)
+        if max(k, n) <= _HELD_NARROW and n % 512 == 0:
+            tile = (_row_tile(), k, 512)
+        else:
+            tile = (_row_tile(),
+                    next(t for t in (512, 256, 128) if k % t == 0),
+                    next(t for t in (1024, 512, 256, 128) if n % t == 0))
+        return _gmm_tuned(xs, w, gs, (tile, tile, tile), False)
     return jax.lax.ragged_dot(xs, w, gs)
+
+
+def _tile_visits(gs, tile: int):
+    """Row tiles the grouped matmul works through for groups of ``gs``
+    rows laid one after another (``megablox.gmm.make_group_metadata``): a
+    group with a row visits every tile it touches, so a tile that holds
+    rows of two groups is worked through twice."""
+    ends = jnp.cumsum(gs)
+    starts = ends - gs
+    return jnp.sum(jnp.where(gs > 0, -(-ends // tile) - starts // tile, 0))
 
 
 def held_expert_ffn(x, gates, idx, valid, e_gu, e_down, first: int):
@@ -1100,18 +1125,34 @@ def held_expert_ffn(x, gates, idx, valid, e_gu, e_down, first: int):
 
     The pairs are sorted by held expert (foreign ones last), the rows of
     the held pairs gathered, one grouped matmul form run over them and the
-    results scatter-added. A decode step (k*T rows at most
-    ``_HELD_PASS_ROWS``) gathers all pairs at once; a prefill wave walks
-    the sorted pairs in passes of about T rows, each under a ``cond`` on
-    whether any held pair is left, so that memory is bounded by the wave
-    and not by k times it, and no pair is dropped however skewed the
-    routing (with even routing one pass in k runs).
+    results combined. A call of up to ``_HELD_PASS_ROWS`` pairs gathers all
+    of them at once; a wider prefill wave walks the sorted pairs in passes
+    of about T rows, each under a ``cond`` on whether any held pair is
+    left, so that memory is bounded by the wave and not by k times it, and
+    no pair is dropped however skewed the routing (with even routing one
+    pass in k runs).
 
-    Returns (y [T, h] in x's dtype, counts): ``counts`` f32 [4] =
+    The row layout of a pass follows from its static pair count M. A
+    decode step (M up to ``_HELD_SMALL_ROWS``: a few rows an expert) keeps
+    the sorted pairs packed, M rows, and scatter-adds the results: every
+    expert's tile is visited once whatever the layout, and nothing around
+    the kernel grows. A prefill piece (M over it: ~100 rows an expert)
+    gives each held expert ``ceil(rows / tile) * tile`` rows, M + E x tile
+    rows in all, so that every expert STARTS on a tile boundary and no tile
+    is worked through for two experts; each token then gathers its own k
+    rows of the result and sums them in float32, so nothing is
+    scatter-added and the rows between the experts are never read (on the
+    chip, a layer of LFM2's piece: 1.92 ms packed with the scatter-add,
+    2.13 aligned with it, 1.73 packed with the gather, 1.45 aligned with
+    it; PR 33).
+
+    Returns (y [T, h] in x's dtype, counts): ``counts`` f32 [5] =
     [pairs routed by valid rows, pairs held here, held experts with a row,
-    rows of the fullest held expert x held experts] (the last over the
-    second is the fullest expert's load over the mean, and stays so when
-    layers' counts are summed)."""
+    rows of the fullest held expert x held experts, row tiles the grouped
+    matmul visits] (the fourth over the second is the fullest expert's
+    load over the mean, and stays so when layers' counts are summed; the
+    second over the fifth x the tile is the share of the MXU's rows that
+    are real)."""
     T, h = x.shape
     k = idx.shape[1]
     E = e_gu.shape[0]
@@ -1120,38 +1161,69 @@ def held_expert_ffn(x, gates, idx, valid, e_gu, e_down, first: int):
     local = idx - first
     held = (local >= 0) & (local < E) & valid[:, None]
     local = jnp.where(held, local, E).reshape(T * k)              # E: foreign
-    order = jnp.argsort(local)                    # stable: by expert, by row
-    gs = jnp.sum(local[:, None] == jnp.arange(E, dtype=local.dtype)[None, :],
-                 axis=0).astype(jnp.int32)                        # [E]
+    hot = local[:, None] == jnp.arange(E, dtype=local.dtype)[None, :]
+    gs = jnp.sum(hot, axis=0).astype(jnp.int32)                   # [E]
     ends = jnp.cumsum(gs)
     total = ends[-1]
-    tok = (order // k).astype(jnp.int32)
-    gate = jnp.where(held, gates, 0.0).reshape(T * k)[order]
-    align = (128 if T * k <= _HELD_SMALL_ROWS else 512) if _mosaic() else 8
-    M = -(-(T * k if T * k <= _HELD_PASS_ROWS else T) // align) * align
+    gate = jnp.where(held, gates, 0.0)
+    tile = _row_tile()
+    M = -(-(T * k if T * k <= _HELD_PASS_ROWS else T) // tile) * tile
+    aligned = M > _HELD_SMALL_ROWS
     n_pass = -(-T * k // M)
-    pad = n_pass * M - T * k
-    tok = jnp.pad(tok, (0, pad))
-    gate = jnp.pad(gate, (0, pad))
+    if aligned:
+        # where each pair stands among the pairs sorted by expert and then
+        # by row: its expert's first place + the earlier pairs of that expert
+        mine = jnp.minimum(local, E - 1)
+        place = (ends - gs)[mine] - 1 + jnp.sum(
+            jnp.where(hot, jnp.cumsum(hot, axis=0, dtype=jnp.int32), 0),
+            axis=1)
+        tok = jnp.arange(T * k, dtype=jnp.int32) // k
+    else:
+        order = jnp.argsort(local)                # stable: by expert, by row
+        pad = (0, n_pass * M - T * k)
+        tok = jnp.pad((order // k).astype(jnp.int32), pad)
+        gate = jnp.pad(gate.reshape(T * k)[order], pad)
 
-    def one_pass(y, p):
+    def one_pass(carry, p):
+        y, visits = carry
         lo = p * M
-        rows = jax.lax.dynamic_slice(tok, (lo,), (M,))
-        g = jax.lax.dynamic_slice(gate, (lo,), (M,))
         # this pass's slice of each group: [lo, lo + M) cut out of the
         # sorted pairs' group boundaries
         cut = jnp.clip(ends, lo, lo + M)
-        gs_p = cut - jnp.concatenate([jnp.clip(lo, 0, total)[None],
-                                      cut[:-1]])
+        starts = jnp.concatenate([jnp.clip(lo, 0, total)[None], cut[:-1]])
+        gs_p = cut - starts
+        if aligned:
+            # a pair of this pass lies at its group's padded offset + its
+            # rank in the cut; the rows between the groups gather row 0
+            gs_p = -(-gs_p // tile) * tile
+            here = held.reshape(T * k) & (place >= lo) & (place < lo + M)
+            at = (jnp.cumsum(gs_p) - gs_p - starts)[mine] + place
+            rows = jnp.zeros((M + E * tile,), jnp.int32).at[
+                jnp.where(here, at, M + E * tile)].set(tok, mode="drop")
+        else:
+            rows = jax.lax.dynamic_slice(tok, (lo,), (M,))
         gu = _static_gmm(x[rows], e_gu.astype(dt), gs_p)
         out = _static_gmm(jax.nn.silu(gu[:, :f]) * gu[:, f:],
                           e_down.astype(dt), gs_p)
-        return y.at[rows].add(out * g[:, None].astype(dt))
+        visits = visits + _tile_visits(gs_p, tile)
+        if aligned:
+            # each token takes its own pairs' rows of ``out`` (a gather: no
+            # row is added to another, and the padding is never read)
+            here = here.reshape(T, k)
+            part = jnp.einsum(
+                "tkh,tk->th",
+                out[jnp.where(here, at.reshape(T, k), 0)].astype(jnp.float32),
+                jnp.where(here, gate, 0.0))
+            return y + part.astype(dt), visits
+        g = jax.lax.dynamic_slice(gate, (lo,), (M,))
+        return y.at[rows].add(out * g[:, None].astype(dt)), visits
 
-    y = jnp.zeros((T, h), dt)
+    carry = (jnp.zeros((T, h), dt), jnp.zeros((), jnp.int32))
     for p in range(n_pass):
-        y = one_pass(y, p) if n_pass == 1 else jax.lax.cond(
-            p * M < total, functools.partial(one_pass, p=p), lambda y: y, y)
+        carry = one_pass(carry, p) if n_pass == 1 else jax.lax.cond(
+            p * M < total, functools.partial(one_pass, p=p), lambda c: c,
+            carry)
+    y, visits = carry
     counts = jnp.stack([jnp.sum(valid) * k, total, jnp.sum(gs > 0),
-                        jnp.max(gs) * E]).astype(jnp.float32)
+                        jnp.max(gs) * E, visits]).astype(jnp.float32)
     return y, counts

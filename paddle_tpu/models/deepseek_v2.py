@@ -370,7 +370,7 @@ class DeepseekV2Served:
         ent = {"c": row}
         if self._has_experts:
             ent["_stats"] = (counts if counts is not None
-                             else jnp.zeros((4,), jnp.float32))
+                             else jnp.zeros((5,), jnp.float32))
         return x + y.reshape(B, S, h), ent
 
     def pack_entries(self, new: Dict, opts: ServeOpts) -> Dict:
@@ -384,7 +384,7 @@ class DeepseekV2Served:
         ring = {"c": jnp.zeros((c.num_layers, N, S, c.latent_width),
                                c.dtype)}
         if self._has_experts:
-            ring["_stats"] = jnp.zeros((4,), jnp.float32)
+            ring["_stats"] = jnp.zeros((5,), jnp.float32)
         return ring
 
     def decode_begin(self, params, pools, block_table, lens0, active,

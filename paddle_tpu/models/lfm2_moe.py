@@ -342,7 +342,7 @@ class Lfm2MoeServed:
         y, counts = self._ffn(p, l, hn.reshape(B * S, h), aux["valid"])
         if self._has_experts:
             ent["_stats"] = (counts if counts is not None
-                             else jnp.zeros((4,), jnp.float32))
+                             else jnp.zeros((5,), jnp.float32))
         return x + y.reshape(B, S, h), ent
 
     def pack_entries(self, new: Dict, opts: ServeOpts) -> Dict:
@@ -357,7 +357,7 @@ class Lfm2MoeServed:
         ring = {"kv": jnp.zeros((max(len(self._attn), 1), N, S,
                                   2 * c.num_kv_heads * c.head_dim), c.dtype)}
         if self._has_experts:
-            ring["_stats"] = jnp.zeros((4,), jnp.float32)
+            ring["_stats"] = jnp.zeros((5,), jnp.float32)
         return ring
 
     def decode_begin(self, params, pools, block_table, lens0, active,

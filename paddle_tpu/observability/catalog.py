@@ -259,6 +259,13 @@ CATALOG = {
         "gauge", (), "rows of the most loaded held expert over the mean "
                      "rows of a held expert (each layer's ratio weighed "
                      "by its rows), in the program read back last"),
+    "serving_moe_row_tiles_total": (
+        "counter", (), "row tiles the served expert layers' grouped "
+                       "matmuls visited (a tile that holds rows of two "
+                       "experts counts twice: the kernel works through it "
+                       "for each); serving_moe_assigned_total over this "
+                       "times the tile's rows is the share of the MXU's "
+                       "rows that are real"),
     "serving_state_bytes_per_slot": (
         "gauge", (), "bytes of per-slot state a served model keeps beside "
                      "the paged cache (a short convolution's last inputs, "

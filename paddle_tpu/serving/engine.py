@@ -156,6 +156,7 @@ _M_KV_TOKEN_BYTES = _instrument("serving_kv_bytes_per_token")
 _M_MOE_ROUTED = _instrument("serving_moe_routed_total")
 _M_MOE_ASSIGNED = _instrument("serving_moe_assigned_total")
 _M_MOE_LOAD = _instrument("serving_moe_load_max_over_mean")
+_M_MOE_TILES = _instrument("serving_moe_row_tiles_total")
 _M_STATE_SLOT_BYTES = _instrument("serving_state_bytes_per_slot")
 _M_STATE_RESETS = _instrument("serving_state_resets_total")
 
@@ -2820,15 +2821,17 @@ class LLMEngine:
     def _note_stats(self, stats_host, span_attrs) -> None:
         """A model's counts, read back with a record's tokens (one step
         after their program ran): the expert layers' ``[routed, assigned,
-        experts_hit, fullest x held]`` (``kernels/moe_dispatch.
+        experts_hit, fullest x held, row tiles]`` (``kernels/moe_dispatch.
         held_expert_ffn``, summed over the layers) go to the counters and,
-        as ``expert_rows`` / ``experts_hit``, onto the span of the program
-        that produced them."""
+        as ``expert_rows`` / ``experts_hit`` / ``expert_tiles``, onto the
+        span of the program that produced them."""
         for st, attrs in zip(stats_host, span_attrs):
-            routed, assigned, hit, fullest = (float(v) for v in st)
-            attrs.update(expert_rows=int(assigned), experts_hit=int(hit))
+            routed, assigned, hit, fullest, tiles = (float(v) for v in st)
+            attrs.update(expert_rows=int(assigned), experts_hit=int(hit),
+                         expert_tiles=int(tiles))
             _M_MOE_ROUTED.inc(routed)
             _M_MOE_ASSIGNED.inc(assigned)
+            _M_MOE_TILES.inc(tiles)
             if assigned:
                 _M_MOE_LOAD.set(fullest / assigned)
 

@@ -212,23 +212,34 @@ def test_flash_partial_head_dim_64(topo):
     assert "%lfm2_prefill_history" in c.as_text()
 
 
-@pytest.mark.parametrize("tokens", [64, 1024], ids=["decode", "piece"])
-def test_held_expert_ffn_narrow_experts(topo, monkeypatch, tokens):
+@pytest.mark.parametrize("tokens,rows", [(64, 256), (1024, 8192)],
+                         ids=["decode", "piece"])
+def test_held_expert_ffn_narrow_experts(topo, monkeypatch, tokens, rows):
     """All 32 of LFM2's experts held (2048 x 1792, top-4): the grouped
-    matmul's whole-contraction tiles for a decode step of 64 slots (256
-    pairs, row tile 128) and a one-row piece of 1024 tokens (4096 pairs,
-    row tile 256), chosen from the shapes, never timed."""
+    matmul's whole-contraction tile of 128 rows in both regimes, chosen
+    from the shapes, never timed. A decode step of 64 slots keeps its 256
+    pairs packed (the program the parent had, but for the fifth count); a
+    one-row piece of 1024 tokens lays its 4,096 pairs out on tile
+    boundaries, 4,096 + 32 x 128 static rows."""
     import importlib
 
     moe_dispatch = importlib.import_module("paddle_tpu.kernels.moe_dispatch")
     monkeypatch.setattr(moe_dispatch, "_mosaic", lambda: True)
-    c = _compile(
+    text = _compile(
         lambda x, g, i, v, gu, dn: moe_dispatch.held_expert_ffn(
-            x, g, i, v, gu, dn, 0)[0],
+            x, g, i, v, gu, dn, 0),
         _one(topo), ((tokens, 2048), BF16), ((tokens, 4), jnp.float32),
         ((tokens, 4), jnp.int32), ((tokens,), jnp.bool_),
-        ((32, 2048, 3584), BF16), ((32, 1792, 2048), BF16))
-    assert "%gmm" in c.as_text()
+        ((32, 2048, 3584), BF16), ((32, 1792, 2048), BF16)).as_text()
+    calls = [ln for ln in text.splitlines() if "custom-call(" in ln
+             and "%gmm" in ln]
+    # gate|up and down, each over the regime's static rows, and no other
+    assert sorted(ln.split(" = ")[1].split("{")[0] for ln in calls) == [
+        f"bf16[{rows},2048]", f"bf16[{rows},3584]"], calls
+    # the scalar-prefetched tile ids: rows / 128 tiles + 31 groups' seams
+    assert all(f"s32[{rows // 128 + 31}]" in ln for ln in calls)
+    if rows == 256:
+        assert "[8192," not in text and "[4352," not in text
 
 
 def test_ragged_walk_tp2(topo):

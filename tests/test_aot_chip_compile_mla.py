@@ -84,16 +84,24 @@ def test_prefill_history_attention_over_latent_rows(topo):
     assert "%mla_prefill_history" in text
 
 
-@pytest.mark.parametrize("tokens", [24, 1024, 24 * 1024],
-                         ids=["decode", "one-row-chunk", "padded-wave"])
-def test_held_expert_ffn_with_its_static_tiling(topo, tokens):
+@pytest.mark.parametrize("tokens,rows", [
+    (24, 256), (1024, 6144 + 20 * 128), (24 * 1024, 24 * 1024 + 20 * 128)],
+    ids=["decode", "one-row-chunk", "padded-wave"])
+def test_held_expert_ffn_with_its_static_tiling(topo, tokens, rows):
     """The chip's share of an expert layer: the grouped matmul's tiling
-    comes from the shapes (no timing), for a decode step, a one-row chunk
-    and a padded wave (which walks its pairs in passes)."""
+    comes from the shapes (no timing), a 128-row tile in every regime. A
+    decode step keeps its 144 pairs packed in 256 rows (the program the
+    parent had, but for the fifth count); a one-row chunk and a padded wave
+    (which walks its pairs in passes) lay each held expert's rows out on
+    tile boundaries, 20 x 128 static rows more."""
     text = _compile(
         lambda x, g, i, v, gu, dn: moe_dispatch.held_expert_ffn(
-            x, g, i, v, gu, dn, 0)[0],
+            x, g, i, v, gu, dn, 0),
         topo, ((tokens, 5120), BF16), ((tokens, 6), jnp.float32),
         ((tokens, 6), I32), ((tokens,), jnp.bool_),
         ((20, 5120, 3072), BF16), ((20, 1536, 5120), BF16))
-    assert "%gmm" in text
+    calls = [ln for ln in text.splitlines() if "custom-call(" in ln
+             and "%gmm" in ln]
+    assert {ln.split(" = ")[1].split("{")[0] for ln in calls} == {
+        f"bf16[{rows},5120]", f"bf16[{rows},3072]"}, calls
+    assert all(f"s32[{rows // 128 + 19}]" in ln for ln in calls)

@@ -254,7 +254,7 @@ def test_held_expert_ffn_with_all_held_equals_the_reference_s_sum():
     np.testing.assert_allclose(np.asarray(y[:33]), np.asarray(want[:33]),
                                atol=2e-5)
     assert float(jnp.abs(y[33:]).max()) == 0.0          # pad rows: unrouted
-    routed, assigned, hit, _full = (float(c) for c in counts)
+    routed, assigned, hit, _full, _tiles = (float(c) for c in counts)
     assert routed == assigned == 33 * cfg.num_experts_per_tok  # all held
     assert hit <= cfg.num_experts
 
