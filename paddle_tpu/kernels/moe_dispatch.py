@@ -1070,8 +1070,10 @@ def _static_gmm(xs, w, gs):
     ``output/chip_micro*.py``), the kernel alone:
 
     - a narrow expert (no side of its matrices over 4096: LFM2's 2048 x
-      1792): the whole contraction in one tile, so an expert's weights
-      stream once and no accumulator is revisited. ``[rows, 2048] x [32,
+      1792, Mellum2's 2304 x 896): the whole contraction in one tile, so
+      an expert's weights stream once and no accumulator is revisited,
+      and 512 columns, or the widest of (512, 256, 128) that divides the
+      output side (Mellum2's 1792 and 2304: 256). ``[rows, 2048] x [32,
       2048, 3584]`` + ``[rows, 1792] x [32, 1792, 2048]``: a decode step's
       256 packed rows 0.68 + 0.37 ms (0.92 + 0.46 at (128, 512, 1024)),
       84% of the weights' bytes; a piece's 2,948 real pairs 0.95 + 0.50 ms
@@ -1093,8 +1095,11 @@ def _static_gmm(xs, w, gs):
     if _mosaic():
         if k % 128 or n % 128:
             raise ValueError(f"no static gmm tiling for rows={m} k={k} n={n}")
-        if max(k, n) <= _HELD_NARROW and n % 512 == 0:
-            tile = (_row_tile(), k, 512)
+        if max(k, n) <= _HELD_NARROW:
+            # the widest column tile that divides the output side, so that
+            # no side is padded in HBM (Mellum2's 1792 and 2304 take 256)
+            tile = (_row_tile(), k,
+                    next(t for t in (512, 256, 128) if n % t == 0))
         else:
             tile = (_row_tile(),
                     next(t for t in (512, 256, 128) if k % t == 0),

@@ -713,27 +713,48 @@ def ragged_decode_partial(q, k_pool, v_pool, block_table, lengths, *,
 # 512 + 64 columns that is the v5e's ridge, so the walk is bound by the MXU
 # and by HBM at once (the GQA walk above is bytes only).
 # ---------------------------------------------------------------------------
-def _latent_decode_kernel(layer_ref, table_ref, lens_ref, q_ref, pool_ref,
-                          acc_ref, m_ref, l_ref, buf, sems, *, block_size,
-                          max_blocks, chunk, v_cols, sm_scale):
+def _latent_decode_kernel(layer_ref, table_ref, lens_ref, *rest, block_size,
+                          max_blocks, chunk, v_cols, sm_scale,
+                          windowed=False):
     """Grid (N,): slot n's walk, ``chunk`` blocks per loop iteration, as
     ``_ragged_decode_kernel`` does it (two-chunk buffer, copies of chunk
     c+1 in flight while c computes, the trip count ends at the slot's last
     real block, the last chunk's remainder zeroed because a value row of
     stale VMEM could be a NaN). Emits the online-softmax partials (acc
-    [Hq, v_cols], m, l) for the flash-decoding combine."""
+    [Hq, v_cols], m, l) for the flash-decoding combine.
+
+    ``windowed`` (static): a fourth scalar operand gives each slot a START
+    beside its length, and the table is a RING of ``max_blocks`` columns:
+    logical block b lies in column ``b % max_blocks``. The walk then
+    begins at block ``start // bs`` (the blocks wholly before it are never
+    fetched, whatever the context) and the head of its first block is
+    masked as the tail of its last is. Softmax does not care in which
+    order blocks arrive, so nothing is sorted."""
+    if windowed:
+        start_ref, *rest = rest
+    q_ref, pool_ref, acc_ref, m_ref, l_ref, buf, sems = rest
     n = pl.program_id(0)
     lyr = layer_ref[0]
     ln = lens_ref[n]
     Hq = q_ref.shape[1]
     C, T = chunk, chunk * block_size
-    nblk = jnp.minimum((ln + block_size - 1) // block_size, max_blocks)
+    if windowed:
+        st = jnp.minimum(start_ref[n], ln)
+        sb = st // block_size                  # the walk's first block
+        # (a start at or past the length leaves nothing to walk: a block
+        # of masked columns alone would count each as exp(0))
+        nblk = jnp.where(st < ln, jnp.minimum(
+            (ln + block_size - 1) // block_size - sb, max_blocks), 0)
+    else:
+        nblk = jnp.minimum((ln + block_size - 1) // block_size, max_blocks)
     nchunk = (nblk + C - 1) // C
 
     def each_block(c, half, op):
         def body(j, _):
+            col = (jax.lax.rem(sb + c * C + j, max_blocks) if windowed
+                   else c * C + j)
             cp = pltpu.make_async_copy(
-                pool_ref.at[lyr, table_ref[n, c * C + j]],
+                pool_ref.at[lyr, table_ref[n, col]],
                 buf.at[half, pl.ds(j * block_size, block_size)],
                 sems.at[half])
             getattr(cp, op)()
@@ -766,7 +787,13 @@ def _latent_decode_kernel(layer_ref, table_ref, lens_ref, q_ref, pool_ref,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale   # [Hq, T]
         tok = jax.lax.broadcasted_iota(jnp.int32, (Hq, T), 1)
-        s = jnp.where(tok < ln - c * T, s, jnp.float32(-1e30))
+        if windowed:
+            # the chunk's first token is position base: keep [start, len)
+            base = sb * block_size + c * T
+            s = jnp.where((tok < ln - base) & (tok >= st - base), s,
+                          jnp.float32(-1e30))
+        else:
+            s = jnp.where(tok < ln - c * T, s, jnp.float32(-1e30))
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
@@ -788,26 +815,42 @@ def _latent_decode_kernel(layer_ref, table_ref, lens_ref, q_ref, pool_ref,
 
 def latent_decode_partial(q, pool, block_table, lengths, *, layer=0,
                           v_cols: int, sm_scale: float,
-                          name: str = "mla_latent_walk"):
+                          name: str = "mla_latent_walk", starts=None):
     """The latent walk, partial (flash-decoding) form. q: [N, Hq, W]
     absorbed queries; pool: [L, NB, BS, W] latent rows (W a multiple of
     128, ``v_cols`` too); block_table: [N, MB]; lengths: [N], a runtime
     operand. Returns ``(acc [N, Hq, v_cols] f32, m [N, Hq] f32, l [N, Hq]
     f32)``; a slot of length 0 gives the combine's identity. The chunk is
     ``_walk_chunk_blocks`` with one KV head: 1024 tokens a loop iteration
-    at blocks of 16, 2.5 MiB of VMEM for the two-chunk buffer at W = 640."""
+    at blocks of 16, 2.5 MiB of VMEM for the two-chunk buffer at W = 640.
+
+    ``starts`` [N] (a window layer's; absent, the kernel is the one a
+    full layer compiles): slot n attends to positions ``[starts[n],
+    lengths[n])`` only, and ``block_table`` is then a RING of MB columns,
+    logical block b in column ``b % MB``: a slot whose window is W tokens
+    keeps ``ceil(W / BS) + 1`` blocks whatever its context and writes a new
+    block over the one that fell behind the window, and the walk DMAs
+    blocks ``[starts[n] // BS, ceil(lengths[n] / BS))`` and masks the head
+    of the first as it masks the tail of the last."""
     N, Hq, W = q.shape
     bs, mb = pool.shape[2], block_table.shape[1]
     assert pool.shape[3] == W and W % 128 == 0 and v_cols % 128 == 0, (
         pool.shape, W, v_cols)
     C = _walk_chunk_blocks(bs, 1, W, pool.dtype.itemsize, mb)
     row = lambda n, l, t, ln: (n, 0, 0)
+    kernel = functools.partial(_latent_decode_kernel, block_size=bs,
+                               max_blocks=mb, chunk=C, v_cols=v_cols,
+                               sm_scale=sm_scale)
+    scalars = [jnp.asarray(layer, jnp.int32)[None],
+               block_table.astype(jnp.int32), lengths.astype(jnp.int32)]
+    if starts is not None:
+        row = lambda n, l, t, ln, st: (n, 0, 0)
+        kernel = functools.partial(kernel, windowed=True)
+        scalars.append(starts.astype(jnp.int32))
     acc, m, l = pl.pallas_call(
-        functools.partial(_latent_decode_kernel, block_size=bs,
-                          max_blocks=mb, chunk=C, v_cols=v_cols,
-                          sm_scale=sm_scale),
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(N,),
+            num_scalar_prefetch=len(scalars), grid=(N,),
             in_specs=[pl.BlockSpec((1, Hq, W), row),
                       pl.BlockSpec(memory_space=pl.ANY)],  # pool stays in HBM
             out_specs=[pl.BlockSpec((1, Hq, v_cols), row),
@@ -819,8 +862,7 @@ def latent_decode_partial(q, pool, block_table, lengths, *, layer=0,
                    jax.ShapeDtypeStruct((N, Hq, 1), jnp.float32),
                    jax.ShapeDtypeStruct((N, Hq, 1), jnp.float32)],
         interpret=_interpret(), name=name,
-    )(jnp.asarray(layer, jnp.int32)[None], block_table.astype(jnp.int32),
-      lengths.astype(jnp.int32), q, pool)
+    )(*scalars, q, pool)
     return acc, m[..., 0], l[..., 0]
 
 
@@ -868,13 +910,14 @@ def unpack_outputs(o, n_kv: int):
 
 
 def flat_decode_partial(q, pool, block_table, lengths, *, n_kv: int,
-                        layer=0, name: str = "flat_walk"):
+                        layer=0, name: str = "flat_walk", starts=None):
     """The flat walk, partial (flash-decoding) form. q: [N, Hq, D]; pool:
     [L, NB, BS, 2 * n_kv * D], a token's values then its keys, heads side
     by side (``n_kv * D`` a multiple of 128); block_table: [N, MB];
     lengths: [N], a runtime operand. Returns ``(acc [N, Hkv, G, D] f32, m
     [N, Hkv, G] f32, l [N, Hkv, G] f32)`` as ``ragged_decode_partial``
-    does; a slot of length 0 gives the combine's identity."""
+    does; a slot of length 0 gives the combine's identity. ``starts``: a
+    window layer's walk over a ring (``latent_decode_partial``)."""
     N, Hq, D = q.shape
     W = n_kv * D
     assert pool.shape[3] == 2 * W and Hq % n_kv == 0, (pool.shape, W)
@@ -882,7 +925,7 @@ def flat_decode_partial(q, pool, block_table, lengths, *, n_kv: int,
     acc, m, l = latent_decode_partial(
         jnp.concatenate([jnp.zeros_like(qk), qk], -1), pool, block_table,
         lengths, layer=layer, v_cols=W, sm_scale=1.0 / math.sqrt(D),
-        name=name)
+        name=name, starts=starts)
     G = Hq // n_kv
     return (unpack_outputs(acc, n_kv).reshape(N, n_kv, G, D),
             m.reshape(N, n_kv, G), l.reshape(N, n_kv, G))

@@ -329,8 +329,11 @@ def flash_attention_fwd(q, k, v, causal: bool = False,
 # group, and the log-sum-exp returned so that two calls combine
 # ---------------------------------------------------------------------------
 
-def _partial_kernel(len_ref, q_ref, k_ref, *rest, scale, causal, block_q,
-                    block_kv, groups, v_cols):
+def _partial_kernel(len_ref, *rest, scale, causal, block_q, block_kv,
+                    groups, v_cols, banded=False):
+    if banded:
+        lo_ref, *rest = rest
+    q_ref, k_ref, *rest = rest
     if v_cols is None:
         v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
     else:
@@ -349,6 +352,11 @@ def _partial_kernel(len_ref, q_ref, k_ref, *rest, scale, causal, block_q,
     if causal:
         # tile fully above the diagonal contributes nothing
         run &= (j * block_kv) <= (i * block_q + block_q - 1)
+    if banded:
+        # row r sees no key before column r + lo: a tile whose last
+        # column lies before the first row's bound is outside the band
+        lo = lo_ref[pl.program_id(0) // groups]
+        run &= (j * block_kv + block_kv - 1) >= (i * block_q + lo)
 
     @pl.when(run)
     def _():
@@ -364,6 +372,10 @@ def _partial_kernel(len_ref, q_ref, k_ref, *rest, scale, causal, block_q,
             row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) \
                 + i * block_q
             keep &= row >= col
+        if banded:
+            row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) \
+                + i * block_q
+            keep &= col >= row + lo
         s = jnp.where(keep, s, jnp.float32(NEG_INF))
         m_prev = m_ref[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -388,7 +400,8 @@ def _partial_kernel(len_ref, q_ref, k_ref, *rest, scale, causal, block_q,
 
 def flash_partial(q, k, v=None, *, scale: float, causal: bool = False,
                   kv_len=None, v_cols=None, block_q: int = 512,
-                  block_kv: int = 512, name: str = "flash_partial"):
+                  block_kv: int = 512, name: str = "flash_partial",
+                  band_lo=None):
     """Blockwise softmax attention in partial form, forward only.
 
     q: [G, S, Dk]; k: [Gk, T, Dk] with G a multiple of Gk (group g reads
@@ -398,6 +411,13 @@ def flash_partial(q, k, v=None, *, scale: float, causal: bool = False,
     ``kv_len`` [Gk] int32 masks keys at or past it, a runtime operand: key
     tiles past it are neither fetched again nor computed. ``causal``
     compares row and column indices as they are (S and T start together).
+    ``band_lo`` [Gk] int32 (a window layer's; absent, the kernel is the
+    one a full layer compiles) is a LOWER bound on the key a query row may
+    see, a runtime operand beside the key length: row r sees column c only
+    where ``c >= r + band_lo[g]`` (a window of W tokens over keys that
+    start ``off`` positions before the queries: ``off - W + 1``), and key
+    tiles wholly before a query tile's band are neither fetched nor
+    computed.
     Returns ``(o [G, S, Dv] in q's dtype, lse [G, S] f32)``: normalised
     output and log-sum-exp, -1e30 where a row saw no key, so that
     ``combine_partials`` merges calls over disjoint key sets. Nothing of
@@ -412,34 +432,51 @@ def flash_partial(q, k, v=None, *, scale: float, causal: bool = False,
     if kv_len is None:
         kv_len = jnp.full((Gk,), T, jnp.int32)
 
-    def kv_map(g, i, j, lens):
-        # past the length the same tile is named again: no new copy
-        last = jnp.maximum((lens[g // groups] + bkv - 1) // bkv - 1, 0)
-        return (g // groups, jnp.minimum(j, last), 0)
+    banded = band_lo is not None
+    if banded:
+        def kv_map(g, i, j, lens, lo):
+            # before the band and past the length the nearest tile inside
+            # is named again: no new copy
+            last = jnp.maximum((lens[g // groups] + bkv - 1) // bkv - 1, 0)
+            first = jnp.clip((i * bq + lo[g // groups]) // bkv, 0, last)
+            return (g // groups, jnp.clip(j, first, last), 0)
 
-    in_specs = [pl.BlockSpec((1, bq, Dk), lambda g, i, j, lens: (g, i, 0)),
+        q_map = lambda g, i, j, lens, lo: (g, i, 0)
+        scalars = [kv_len.astype(jnp.int32), band_lo.astype(jnp.int32)]
+    else:
+        def kv_map(g, i, j, lens):
+            # past the length the same tile is named again: no new copy
+            last = jnp.maximum((lens[g // groups] + bkv - 1) // bkv - 1, 0)
+            return (g // groups, jnp.minimum(j, last), 0)
+
+        q_map = lambda g, i, j, lens: (g, i, 0)
+        scalars = [kv_len.astype(jnp.int32)]
+
+    in_specs = [pl.BlockSpec((1, bq, Dk), q_map),
                 pl.BlockSpec((1, bkv, Dk), kv_map)]
     operands = [q, k]
     if v is not None:
         in_specs.append(pl.BlockSpec((1, bkv, Dv), kv_map))
         operands.append(v)
+    kernel = functools.partial(_partial_kernel, scale=scale, causal=causal,
+                               block_q=bq, block_kv=bkv, groups=groups,
+                               v_cols=v_cols)
+    if banded:
+        kernel = functools.partial(kernel, banded=True)
     out, lse = pl.pallas_call(
-        functools.partial(_partial_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_kv=bkv, groups=groups,
-                          v_cols=v_cols),
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(G, S // bq, T // bkv),
+            num_scalar_prefetch=len(scalars), grid=(G, S // bq, T // bkv),
             in_specs=in_specs,
-            out_specs=[
-                pl.BlockSpec((1, bq, Dv), lambda g, i, j, lens: (g, i, 0)),
-                pl.BlockSpec((1, bq, 1), lambda g, i, j, lens: (g, i, 0))],
+            out_specs=[pl.BlockSpec((1, bq, Dv), q_map),
+                       pl.BlockSpec((1, bq, 1), q_map)],
             scratch_shapes=[pltpu.VMEM((bq, Dv), jnp.float32),
                             pltpu.VMEM((bq, STATS), jnp.float32),
                             pltpu.VMEM((bq, STATS), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct((G, S, Dv), q.dtype),
                    jax.ShapeDtypeStruct((G, S, 1), jnp.float32)],
         interpret=_interpret(), name=name,
-    )(kv_len.astype(jnp.int32), *operands)
+    )(*scalars, *operands)
     return out, lse[..., 0]
 
 

@@ -65,6 +65,8 @@ from ..kernels.paged_attention import latent_decode_partial
 from ..kernels.pallas_attention import combine_partials, flash_partial
 from .llama import _rms_norm
 from .llama_served import ServeOpts
+from .rope import rope_half as _rope
+from .rope import yarn_frequencies
 
 __all__ = ["DeepseekV2Config", "DeepseekV2Served", "from_published",
            "yarn_inv_freq", "LATENT_PAD"]
@@ -131,20 +133,12 @@ def _yarn_mscale(scale: float, mscale: float) -> float:
 
 
 def yarn_inv_freq(c: DeepseekV2Config):
-    """YaRN's inverse frequencies over the rope dims and the factor on
-    cos/sin (1.0 where ``mscale == mscale_all_dim``)."""
-    d = c.qk_rope_head_dim
-    f = c.rope_theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-
-    def corr(beta):
-        return (d * math.log(c.rope_original_max / (beta * 2 * math.pi))
-                / (2 * math.log(c.rope_theta)))
-
-    low = max(math.floor(corr(c.rope_beta_fast)), 0)
-    high = min(math.ceil(corr(c.rope_beta_slow)), d - 1)
-    ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - low)
-                    / max(high - low, 0.001), 0.0, 1.0)
-    inv = f / c.rope_factor * ramp + f * (1.0 - ramp)
+    """YaRN's inverse frequencies over the rope dims (``rope.
+    yarn_frequencies``) and the factor on cos/sin (1.0 where ``mscale ==
+    mscale_all_dim``)."""
+    inv = yarn_frequencies(c.qk_rope_head_dim, c.rope_theta, c.rope_factor,
+                           c.rope_original_max, c.rope_beta_fast,
+                           c.rope_beta_slow)
     mscale = (_yarn_mscale(c.rope_factor, c.rope_mscale)
               / _yarn_mscale(c.rope_factor, c.rope_mscale_all_dim))
     return inv, mscale
@@ -174,16 +168,6 @@ def from_published(layer: Dict, c: DeepseekV2Config) -> Dict:
     if "e_gate" in layer:
         out["e_gu"] = jnp.concatenate([layer["e_gate"], layer["e_up"]], -1)
     return out
-
-
-def _rope(x, ang, mscale):
-    """Half-split rotation of x [..., d] by angles [..., d/2] (broadcast
-    over the head axis by the caller)."""
-    d2 = x.shape[-1] // 2
-    x1, x2 = x[..., :d2], x[..., d2:]
-    c = (jnp.cos(ang) * mscale).astype(x.dtype)
-    s = (jnp.sin(ang) * mscale).astype(x.dtype)
-    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], -1)
 
 
 def _swiglu(x, w_gate, w_up, w_down, dt):

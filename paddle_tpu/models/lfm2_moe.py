@@ -62,15 +62,14 @@ half-split there too); gate and up of the experts are stored side by side
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ..kernels.moe_dispatch import held_expert_ffn, sigmoid_bias_routing
-from ..kernels.paged_attention import flat_decode_partial, ragged_tpu_refusal
-from ..kernels.pallas_attention import combine_partials, flash_partial
+from ..kernels.paged_attention import ragged_tpu_refusal
+from .flat_kv_attention import decode_attention, pack_rows, prefill_attention
 from .deepseek_v2 import _rope, _swiglu
 from .llama import _rms_norm
 from .llama_served import ServeOpts
@@ -287,28 +286,13 @@ class Lfm2MoeServed:
     def _prefill_attention(self, p, a: int, hn, aux, pools, opts):
         """Causal attention of a piece over [history ; piece]: both parts
         blockwise at heads of 64 as they are, one softmax."""
-        c = self.config
-        B, S, _ = hn.shape
-        H, Hkv, D = c.num_heads, c.num_kv_heads, c.head_dim
-        scale = 1.0 / math.sqrt(D)
         q, k, v = self._qkv(hn, p, aux["ang"])
-        heads = lambda x: jnp.swapaxes(x, 1, 2).reshape(
-            -1, x.shape[1], D)                       # [B,T,h,D] -> [B*h,T,D]
-        qf = heads(q)
-        o, lse = flash_partial(qf, heads(k), heads(v), scale=scale,
-                               causal=True, name="lfm2_prefill_chunk")
-        if aux["prefix_nbk"]:
-            rows = pools[f"{opts.prefix}kv{a}"][0][aux["ctx_tbl"]].reshape(
-                B, -1, 2, Hkv, D)                    # a row is [V | K]
-            n_hist = jnp.repeat(aux["hist_len"].astype(jnp.int32), Hkv)
-            o_h, lse_h = flash_partial(
-                qf, heads(rows[:, :, 1]), heads(rows[:, :, 0]), scale=scale,
-                kv_len=n_hist, name="lfm2_prefill_history")
-            o = combine_partials(o, lse, o_h, lse_h)
-        o = jnp.swapaxes(o.reshape(B, H, S, D), 1, 2).reshape(B, S, H * D)
-        return (o @ p["wo"].astype(self.dtype),
-                {"kv": jnp.concatenate([v.reshape(B, S, Hkv * D),
-                                        k.reshape(B, S, Hkv * D)], -1)})
+        o = prefill_attention(
+            q, k, v, chunk_name="lfm2_prefill_chunk",
+            history=(pools[f"{opts.prefix}kv{a}"], aux["ctx_tbl"],
+                     aux["hist_len"], None, "lfm2_prefill_history")
+            if aux["prefix_nbk"] else None)
+        return o @ p["wo"].astype(self.dtype), {"kv": pack_rows(k, v)}
 
     def _prefill_conv(self, p, ci: int, hn, aux):
         """The gated short convolution of a piece from the state its
@@ -386,49 +370,16 @@ class Lfm2MoeServed:
 
     def _decode_attention(self, p, a: int, hn, aux, step, ring, t, pools,
                           opts):
-        c, dt = self.config, self.dtype
-        N = hn.shape[0]
-        Hkv, D = c.num_kv_heads, c.head_dim
-        G = c.num_heads // Hkv
-        scale = 1.0 / math.sqrt(D)
+        dt = self.dtype
         q, kk, vv = self._qkv(hn, p, step["ang"])
-        rkv = jax.lax.dynamic_update_slice(
-            ring["kv"], jnp.concatenate(
-                [vv.reshape(1, N, 1, Hkv * D), kk.reshape(1, N, 1, Hkv * D)],
-                -1), (a, 0, t, 0))
-        qg = q.reshape(N, Hkv, G, D)
-        rows = rkv[a].reshape(N, -1, 2, Hkv, D)           # a row is [V | K]
-        rka, rva = rows[:, :, 1], rows[:, :, 0]
-        s_rng = jnp.einsum("nhgd,nshd->nhgs", qg, rka,
-                           preferred_element_type=jnp.float32) * scale
-        s_rng = jnp.where(step["ring_mask"], s_rng, -1e30)
-        if opts.ragged:
-            # the walk's partials over the pool, combined with the in-call
-            # ring (which always holds the step's own token: l_tot >= 1)
-            acc_p, m_p, l_p = flat_decode_partial(
-                q, pools[f"{opts.prefix}kv{a}"], aux["block_table"],
-                aux["walk_lens"], n_kv=Hkv, name="lfm2_ragged_walk")
-            m_tot = jnp.maximum(m_p, jnp.max(s_rng, axis=-1))
-            corr = jnp.exp(m_p - m_tot)
-            p_rng = jnp.exp(s_rng - m_tot[..., None])
-            l_tot = l_p * corr + jnp.sum(p_rng, axis=-1)
-            att = (acc_p * corr[..., None] + jnp.einsum(
-                "nhgs,nshd->nhgd", p_rng, rva,
-                preferred_element_type=jnp.float32)) / l_tot[..., None]
-        else:
-            kd, vd = aux["kd"][a], aux["vd"][a]
-            P = kd.shape[1]
-            s_pre = jnp.einsum("nhgd,nphd->nhgp", qg, kd,
-                               preferred_element_type=jnp.float32) * scale
-            s_pre = jnp.where(aux["pre_mask"], s_pre, -1e30)
-            probs = jax.nn.softmax(
-                jnp.concatenate([s_pre, s_rng], axis=-1), axis=-1)
-            att = (jnp.einsum("nhgp,nphd->nhgd", probs[..., :P].astype(dt),
-                              vd)
-                   + jnp.einsum("nhgs,nshd->nhgd", probs[..., P:].astype(dt),
-                                rva))
-        y = att.reshape(N, Hkv * G * D).astype(dt) @ p["wo"].astype(dt)
-        return y, dict(ring, kv=rkv)
+        att, rkv = decode_attention(
+            q, kk, vv, ring["kv"], a, t, step["ring_mask"], dt,
+            walk=(pools[f"{opts.prefix}kv{a}"], aux["block_table"],
+                  aux["walk_lens"], None, "lfm2_ragged_walk")
+            if opts.ragged else None,
+            dense=None if opts.ragged else
+            (aux["kd"][a], aux["vd"][a], aux["pre_mask"]))
+        return att @ p["wo"].astype(dt), dict(ring, kv=rkv)
 
     def _decode_conv(self, p, ci: int, hn, ring, act):
         """One token a slot: the state moves only where the slot is active

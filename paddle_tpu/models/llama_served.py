@@ -26,6 +26,16 @@ pool-name prefix of a draft model) and ``mesh``):
                                       cache (``()`` here: nothing; a short
                                       convolution's last inputs in
                                       models/lfm2_moe.py) and their zeros
+``window_entries``, ``window``        names of the pool entries of the
+                                      WINDOW kind and the window's tokens
+                                      (absent here: every entry is of the
+                                      full kind; models/mellum.py names its
+                                      window layers' pools, the engine keeps
+                                      a second block ledger for them and
+                                      passes ``make_pools`` an
+                                      ``nb_window``, ``prefill_begin`` a
+                                      ``win`` and ``decode_begin`` a
+                                      ``win_table``)
 ``ragged_refusal(kv_int8)``           why the chip's compiler refuses the
                                       decode walk at this shape, or None
 ``history_blocks(hist_blocks, mb)``   how wide a row's history table is

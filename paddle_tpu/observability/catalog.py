@@ -277,6 +277,16 @@ CATALOG = {
         "reason=admit (a new request) or preempt (a re-admission after "
         "preemption-by-recompute, which recomputes the state with the "
         "tokens)"),
+    "serving_window_bytes_per_slot": (
+        "gauge", (),
+        "bytes of window-kind cache a slot holds at most, over the window "
+        "layers: ring width (ceil(window / block) + 1 blocks) x a block's "
+        "bytes; it does not grow with the context "
+        "(serving_kv_bytes_per_token counts the entries that do)"),
+    "serving_window_blocks_recycled_total": (
+        "counter", (),
+        "window-kind blocks written again in place behind the window (what "
+        "a free list would have been given back and asked for again)"),
     # -- fleet observability (observability.fleet, r17) --------------------
     "serving_fleet_slo_attainment": (
         "gauge", ("replica", "slo"),

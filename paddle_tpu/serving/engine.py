@@ -1,8 +1,10 @@
 """Continuous-batching paged-cache serving engine for any model that gives
 the served-model interface (models/llama_served.py states it; llama-family
 dense decoders, the latent-attention sparse-expert family
-models/deepseek_v2.py and the short-convolution family models/lfm2_moe.py,
-which keeps per-slot state beside the paged cache, provide it).
+models/deepseek_v2.py, the short-convolution family models/lfm2_moe.py,
+which keeps per-slot state beside the paged cache, and models/mellum.py,
+whose window layers' pools are a second kind of cache entry with a block
+ledger of their own (serving/window_ledger.py), provide it).
 
 Parity surface: the reference wires its paged decode kernel into serving via
 incubate/nn/functional/block_multihead_attention (block tables + per-seq
@@ -120,6 +122,7 @@ from .admission import AdmissionConfig, AdmissionController, ShedError
 from .kv_swap import HostKVPool
 from .offload import OffloadEngine
 from .prefix_cache import PrefixCache
+from .window_ledger import WindowLedger
 
 __all__ = ["LLMEngine", "Request"]
 
@@ -159,6 +162,8 @@ _M_MOE_LOAD = _instrument("serving_moe_load_max_over_mean")
 _M_MOE_TILES = _instrument("serving_moe_row_tiles_total")
 _M_STATE_SLOT_BYTES = _instrument("serving_state_bytes_per_slot")
 _M_STATE_RESETS = _instrument("serving_state_resets_total")
+_M_WINDOW_SLOT_BYTES = _instrument("serving_window_bytes_per_slot")
+_M_WINDOW_RECYCLED = _instrument("serving_window_blocks_recycled_total")
 
 
 @dataclasses.dataclass
@@ -262,7 +267,7 @@ def _apply_admissions(c_last, c_len, c_done, c_rem, wave_toks, slot_of_row,
 
 def _paged_prefill(params, tokens, blk_ids, true_len, pools,
                    temps, top_ks, top_ps, key, hist_len=None,
-                   ctx_tbl=None, slot=None, *, model,
+                   ctx_tbl=None, slot=None, win=None, *, model,
                    opts: ServeOpts = ServeOpts(),
                    sample_flags=(True, True, True), prefix_nbk: int = 0):
     """Prefill a WAVE of admissions in one compiled program: causal
@@ -332,11 +337,21 @@ def _paged_prefill(params, tokens, blk_ids, true_len, pools,
     the entry's name and it is scattered back to the slot with the
     piece's cache entries. A layer returns either kind of entry, never
     both, and a layer with no per-token entry takes no pool.
+
+    Window entries (``model.window_entries``: pools of the WINDOW kind,
+    with a block-id space of their own, ``serving/window_ledger.py``):
+    ``win`` carries their operands, ``blk_ids`` [B, S_bucket // bs] (the
+    ring's columns this piece writes, as ``blk_ids`` is for the full
+    kind) and, with a history, ``ctx_tbl`` [B, ring width] and
+    ``ctx_start`` [B]: the blocks that hold the last ``W - 1`` tokens
+    before the piece, in order, and the position of the first of them. A
+    model with one kind is given no ``win`` and its program is unchanged.
     """
     B, S = tokens.shape
     x = model.embed(params, tokens)
     aux = model.prefill_begin(params, pools, tokens, true_len, hist_len,
-                              ctx_tbl, prefix_nbk, opts)
+                              ctx_tbl, prefix_nbk, opts,
+                              **({} if win is None else {"win": win}))
     if model.state_entries:
         carried = (jnp.zeros((B,), bool) if hist_len is None
                    else hist_len > 0)
@@ -359,9 +374,12 @@ def _paged_prefill(params, tokens, blk_ids, true_len, pools,
     for name in model.state_entries:
         pools[name] = pools[name].at[:, slot].set(
             stacked.pop(name).astype(pools[name].dtype))
+    wflat = None if win is None else win["blk_ids"].reshape(-1)
+    window = getattr(model, "window_entries", ())
     for name, val in model.pack_entries(stacked, opts).items():
         bs = pools[name].shape[2]
-        pools[name] = pools[name].at[:, flat].set(
+        pools[name] = pools[name].at[
+            :, wflat if name in window else flat].set(
             val.reshape((val.shape[0], B * (S // bs), bs) + val.shape[3:]))
 
     x = model.final_norm(params, x)
@@ -374,7 +392,7 @@ def _paged_prefill(params, tokens, blk_ids, true_len, pools,
 
 def _paged_decode(params, last_tokens, lengths, done0, budgets, key, active,
                   block_table, pools, temps, top_ks, top_ps,
-                  eos_ids, *, model, n_steps: int,
+                  eos_ids, win_table=None, *, model, n_steps: int,
                   opts: ServeOpts = ServeOpts(),
                   sample_flags=(True, True, True)):
     """``n_steps`` decode iterations in ONE compiled program (multi-step
@@ -448,12 +466,19 @@ def _paged_decode(params, last_tokens, lengths, done0, budgets, key, active,
     pool entries — reusing the identical ragged/bucketed machinery at
     draft scale. Target pool entries pass through the donated dict
     untouched.
+
+    ``win_table`` [N, ring width] (a model with ``window_entries``): the
+    window kind's table, a ring — logical block b in column ``b % width``
+    (``serving/window_ledger.py``). The model's window layers walk it from
+    the window's edge; the ring's write-back names its columns for the
+    window entries as ``block_table`` does for the others.
     """
     N, MB = block_table.shape
     S = n_steps
     lens0 = lengths                       # frozen prefix lengths
-    aux = model.decode_begin(params, pools, block_table, lens0, active,
-                             n_steps, opts)
+    aux = model.decode_begin(
+        params, pools, block_table, lens0, active, n_steps, opts,
+        **({} if win_table is None else {"win_table": win_table}))
     head_w = model.decode_head(params)
 
     def body(carry, t):
@@ -503,8 +528,13 @@ def _paged_decode(params, last_tokens, lengths, done0, budgets, key, active,
     phys = jnp.where(valid, phys, 0)                      # trash block 0
     off = pos % bs
     pools = dict(pools)
+    window = getattr(model, "window_entries", ())
+    if win_table is not None:
+        phys_w = jnp.where(valid, jnp.take_along_axis(
+            win_table, log_blk % win_table.shape[1], axis=1), 0)
     for name, val in packed.items():
-        pools[name] = pools[name].at[:, phys, off].set(val)
+        pools[name] = pools[name].at[
+            :, phys_w if name in window else phys, off].set(val)
     for name, val in state.items():
         pools[name] = pools[name].at[:, :N].set(val)
     return (emitted, last_tokens, lens_end, done0, budgets, key, pools,
@@ -771,7 +801,22 @@ class LLMEngine:
         self.kv_int8 = kv_dtype is not None
         # the model's cache entries, one pool each: per-head K and V rows
         # for llama, one latent row for a latent-attention model
-        self.pools = model.make_pools(self.nb, block_size, self.kv_int8)
+        # a model whose layers differ in what they keep of a context names
+        # its entries of the WINDOW kind (a layer that sees ``window``
+        # tokens back keeps a ring of ceil(window / bs) + 1 blocks a slot,
+        # whatever the context): those get an id space of their own in
+        # which every slot owns its ring for good (``window_ledger.py``:
+        # nothing to allocate, nothing that runs dry, a table that never
+        # changes). A model with one kind has one ledger, as before
+        self.win: Optional[WindowLedger] = None
+        self._wtable_dev = None
+        if getattr(model, "window_entries", ()):
+            self.win = WindowLedger(self.N, model.window, block_size)
+            self._wtable_dev = jnp.asarray(self.win.table)
+            self.pools = model.make_pools(self.nb, block_size, self.kv_int8,
+                                          nb_window=self.win.nb)
+        else:
+            self.pools = model.make_pools(self.nb, block_size, self.kv_int8)
         self._target_pools = tuple(self.pools)
         # the model's per-slot entries ride in the same donated dict: one
         # row a slot and a trash row, indexed by slot and never by block
@@ -875,6 +920,7 @@ class LLMEngine:
         self._slots_dirty = True
         self._table_dirty = True
         self._table_dev = {}         # prefix-bucket (blocks) → device table
+        self._win_recycled = 0       # of win.recycled, what the counter has
         # the dispatched-but-unread decode call (pipeline depth 1): its
         # tokens are fetched while the NEXT call occupies the chip
         self._inflight = None
@@ -1251,6 +1297,8 @@ class LLMEngine:
             self.prefix_cache.unpin(self._pinned[slot])
             self._pinned[slot] = []
         self._chunks.pop(slot, None)
+        if self.win is not None:
+            self.win.release(slot)
         self.table[slot, :] = 0
         self.n_alloc[slot] = 0
         self.lengths[slot] = 0
@@ -1795,6 +1843,10 @@ class LLMEngine:
                                     else 0),
             "swapped_host_blocks": (self.swap_pool.swapped_blocks
                                     if self.swap_pool is not None else 0),
+            # the window kind's own ledger (free + backed == total), where
+            # the model has one
+            **({"window": self.win.accounting()}
+               if self.win is not None else {}),
         }
 
     def _admit(self):
@@ -2016,6 +2068,10 @@ class LLMEngine:
             if not hist:
                 _M_STATE_RESETS.inc(
                     reason="preempt" if req.generated else "admit")
+        if self.win is not None:
+            kw["win"] = self._window_operands(row, bucket, pnbk)
+            # the history tokens a window layer gathers for this piece
+            attrs["hist_window"] = min(hist, self.win.W - 1)
         with trace_span("serving.prefill", **attrs) as sp:
             tok_dev, self.pools, stats = self._prefill_fn(
                 bucket, flags, pnbk)(*args, **kw)
@@ -2071,11 +2127,28 @@ class LLMEngine:
             args += [jnp.asarray([hist], jnp.int32), jnp.asarray(ctx_tbl)]
         return bucket, flags, pnbk, args
 
+    def _window_operands(self, row, bucket: int, pnbk: int) -> Dict:
+        """The window kind's operands of a row's program: the ring's
+        columns its piece writes and, with a history, the blocks that
+        hold the window's reach before the piece."""
+        slot, _req, _ctx, hist, piece, _final = row
+        b0 = hist // self.bs
+        nblk = -(-(hist + piece) // self.bs) - b0
+        win = {"blk_ids": jnp.asarray(self.win.write_ids(
+            slot, b0, nblk, bucket // self.bs)[None])}
+        if pnbk:
+            tbl, start = self.win.history(slot, hist)
+            win["ctx_tbl"] = jnp.asarray(tbl[None])
+            win["ctx_start"] = jnp.asarray([start], jnp.int32)
+        return win
+
     def _prefill_dispatched(self, row, bucket, tok_dev):
         """Host bookkeeping of a dispatched row: lengths, the pending
         first token, chunk state, timelines, prefix-cache adoption."""
         slot, req, ctx, hist, piece, final = row
         self.lengths[slot] = hist + piece
+        if self.win is not None:
+            self.win.note_written(slot, -(-(hist + piece) // self.bs))
         if self._spec_on:
             self._draft_len[slot] = hist + piece
         if final:
@@ -2137,6 +2210,10 @@ class LLMEngine:
         steps = max(1, min(base + lag, remaining + lag))
         horizon = int(self.lengths[slot]) + steps - 1
         last_blk = min(horizon, self.max_model_len - 1) // self.bs
+        if self.win is not None:
+            # the window kind is backed by the slot's own ring: past its
+            # width the block is written again in place
+            self.win.note_written(slot, last_blk + 1)
         need = last_blk + 1 - int(self.n_alloc[slot])
         if need <= 0:
             return True
@@ -2356,16 +2433,21 @@ class LLMEngine:
         return decode_path(self.decode_kernel, jax.default_backend(),
                            model, kv_int8)
 
-    def _pool_block_bytes(self, draft: bool = False) -> int:
+    def _pool_block_bytes(self, draft: bool = False,
+                          window: bool = False) -> int:
         """Bytes one physical block occupies across one MODEL's pool
         entries and layers, whatever the entries are (K and V rows, int8
         payload + scales, latent rows). The decode cache-traffic estimates
         count the target's entries only — a draft's entries share the
-        block ids but are read by the draft's own (cheaper) walks."""
+        block ids but are read by the draft's own (cheaper) walks.
+        ``window``: the entries of the window kind instead (a block of the
+        second ledger), which the others' count leaves out."""
+        wnames = getattr(self.model, "window_entries", ())
         return sum(a.shape[0] * int(np.prod(a.shape[2:])) * a.dtype.itemsize
                    for n, a in self.pools.items()
                    if n not in self.model.state_entries
-                   and (n in self._target_pools) != draft)
+                   and (n in self._target_pools) != draft
+                   and (n in wnames) == window)
 
     def _dispatch_decode(self, active_slots, prep=None):
         """Enqueue one multi-step decode call and record it as in-flight.
@@ -2450,6 +2532,9 @@ class LLMEngine:
             walk = sum(-(-ln // self.bs) for ln in lens.values())
             kv_call_bytes = walk * pb * self.decode_steps
             step_bytes = walk * pb
+            if self.win is not None:
+                # the window layers' walks start at the window's edge
+                wwalk = sum(self.win.walk_blocks(ln) for ln in lens.values())
             horizon = max(lens.values(), default=0)
             bucket_tokens = -(-horizon // self.bs) * self.bs
         else:
@@ -2459,6 +2544,18 @@ class LLMEngine:
             step_bytes = pb * walk
             kv_call_bytes = step_bytes * (2 + self.decode_steps)
             bucket_tokens = nbk * self.bs
+            if self.win is not None:
+                wwalk = self.N * self.win.width      # the whole ring, dense
+        win_attrs, win_args = {}, ()
+        if self.win is not None:
+            # kv_bytes is what BOTH kinds' walks read; window_bytes the
+            # window layers' part of it
+            wbytes = wwalk * self._pool_block_bytes(window=True)
+            step_bytes += wbytes
+            kv_call_bytes += wbytes * (self.decode_steps if ragged
+                                       else 2 + self.decode_steps)
+            win_attrs = dict(window_bytes=wbytes, window_walk_blocks=wwalk)
+            win_args = (self._wtable_dev,)
         self.kv_read_bytes_total += kv_call_bytes
         if _obs.enabled():
             _M_PREFIX_BUCKET.set(bucket_tokens)
@@ -2479,12 +2576,12 @@ class LLMEngine:
                         # length; bucketed: the ceiling) — matches the
                         # serving_decode_prefix_bucket gauge, never the
                         # full-width table shape
-                        prefix_bucket=bucket_tokens,
+                        prefix_bucket=bucket_tokens, **win_attrs,
                         request_ids=[r.req_id for r in reqs]) as sp:
             (toks, c_last, c_len, c_done, c_rem, c_key,
              self.pools, stats) = decode(
                 self.params, c_last, c_len, c_done, c_rem, c_key, v_act,
-                tbl, self.pools, v_t, v_k, v_p, v_eos)
+                tbl, self.pools, v_t, v_k, v_p, v_eos, *win_args)
         self._carry = (c_last, c_len, c_done, c_rem, c_key)
         if stats is not None:
             self._pending_stats.append((stats, sp.attrs))
@@ -2903,6 +3000,11 @@ class LLMEngine:
         _M_KV_TOKEN_BYTES.set(self._pool_block_bytes() / self.bs)
         _M_STATE_SLOT_BYTES.set(self._state_bytes_per_slot)
         _M_KV_USED.set(self.nb - 1 - len(self.free_blocks))
+        if self.win is not None:
+            _M_WINDOW_SLOT_BYTES.set(self._pool_block_bytes(window=True)
+                                     * self.win.width)
+            _M_WINDOW_RECYCLED.inc(self.win.recycled - self._win_recycled)
+            self._win_recycled = self.win.recycled
         if self.prefix_cache is not None:
             self.prefix_cache.update_gauges()
         # time-series sampler (r20): throttled by FLAGS_obs_ts_interval_s,

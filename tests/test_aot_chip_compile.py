@@ -242,6 +242,70 @@ def test_held_expert_ffn_narrow_experts(topo, monkeypatch, tokens, rows):
         assert "[8192," not in text and "[4352," not in text
 
 
+@pytest.mark.parametrize("starts", [False, True], ids=["full", "window"])
+def test_flat_walk_with_a_start(topo, starts):
+    """Mellum2's two walks at the ``repo-offline`` cell's shapes: 32 slots,
+    32 query heads on 4 KV heads of 128 in one row of 1024 lanes; a full
+    layer over a pool of 24,577 blocks and a table 2,112 wide, a window
+    layer over its own pool of 2,113 blocks and a RING of 65 columns, with
+    a fourth scalar operand (the start) that Mosaic must take."""
+    nb, width = (2113, 65) if starts else (24577, 2112)
+    specs = [((32, 32, 128), BF16), ((1, nb, BS, 1024), BF16),
+             ((32, width), jnp.int32), ((32,), jnp.int32)]
+    name = "mellum_walk_window" if starts else "mellum_walk_full"
+    if starts:
+        fn = lambda q, pool, tbl, lens, st: \
+            paged_attention.flat_decode_partial(
+                q, pool, tbl, lens, n_kv=4, starts=st, name=name)
+        specs.append(((32,), jnp.int32))
+    else:
+        fn = lambda q, pool, tbl, lens: paged_attention.flat_decode_partial(
+            q, pool, tbl, lens, n_kv=4, name=name)
+    assert "%" + name in _compile(fn, _one(topo), *specs).as_text()
+
+
+@pytest.mark.parametrize("keys,causal", [(1536, False), (1024, True)],
+                         ids=["history", "chunk"])
+def test_flash_partial_with_a_band(topo, keys, causal):
+    """The banded blockwise attention at Mellum2's shapes: a piece of 1024
+    queries of 32 heads against the window's gathered history (1,536 rows:
+    the ring's 1,040 padded to the key tile) under a key length and a
+    lower bound, both runtime operands; and a piece banded inside itself
+    (what a bucket longer than the window runs)."""
+    c = _compile(
+        lambda q, k, v, n, lo: pallas_attention.flash_partial(
+            q, k, v, scale=0.088, causal=causal, kv_len=n, band_lo=lo,
+            name="mellum_history_window"),
+        _one(topo), ((32, 1024, 128), BF16), ((4, keys, 128), BF16),
+        ((4, keys, 128), BF16), ((4,), jnp.int32), ((4,), jnp.int32))
+    assert "%mellum_history_window" in c.as_text()
+
+
+@pytest.mark.parametrize("tokens,rows", [(32, 256), (1024, 16384)],
+                         ids=["decode", "piece"])
+def test_held_expert_ffn_sixty_four_experts(topo, monkeypatch, tokens, rows):
+    """All 64 of Mellum2's experts held (2304 x 896, top-8): the whole
+    contraction in one tile and a column tile of 256, which divides 1792
+    and 2304 (512 does not), chosen from the shapes, never timed. A decode
+    step of 32 slots keeps its 256 pairs packed; a piece of 1024 tokens is
+    8,192 pairs, ONE pass, laid out on tile boundaries: 8,192 + 64 x 128
+    static rows."""
+    moe_dispatch = importlib.import_module("paddle_tpu.kernels.moe_dispatch")
+    monkeypatch.setattr(moe_dispatch, "_mosaic", lambda: True)
+    text = _compile(
+        lambda x, g, i, v, gu, dn: moe_dispatch.held_expert_ffn(
+            x, g, i, v, gu, dn, 0),
+        _one(topo), ((tokens, 2304), BF16), ((tokens, 8), jnp.float32),
+        ((tokens, 8), jnp.int32), ((tokens,), jnp.bool_),
+        ((64, 2304, 1792), BF16), ((64, 896, 2304), BF16)).as_text()
+    calls = [ln for ln in text.splitlines() if "custom-call(" in ln
+             and "%gmm" in ln]
+    # gate|up and down, each once (one pass) over the regime's static rows
+    assert sorted(ln.split(" = ")[1].split("{")[0] for ln in calls) == [
+        f"bf16[{rows},1792]", f"bf16[{rows},2304]"], calls
+    assert "conditional(" not in text          # no second pass to choose
+
+
 def test_ragged_walk_tp2(topo):
     """The shard_mapped walk on two described devices: pools sharded on
     the KV-head axis, tables and lengths replicated."""

@@ -318,3 +318,191 @@ def test_lfm2_one_row_prefill_writes_no_weight_sized_array(topo, monkeypatch,
     assert "%lfm2_prefill_chunk" in text
     assert ("%lfm2_prefill_history" in text) == bool(history)
     assert _lfm2_big_writes(text) == []
+
+
+# -- the fourth family: window layers beside full ones ------------------------
+MEL_NB, MEL_NBW, MEL_TABLE, MEL_RING = 24577, 2113, 2112, 65
+
+
+def _mellum_shapes(topo):
+    """Mellum2's first period (three window layers and a full one) at the
+    published widths and the ``repo-offline`` cell's pools, as shapes."""
+    from paddle_tpu.models import mellum
+
+    moe_dispatch = importlib.import_module("paddle_tpu.kernels.moe_dispatch")
+    sh = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sh)
+    cfg = mellum.MellumConfig(
+        layer_types=mellum.PUBLISHED_LAYER_TYPES[:4], dtype=BF16)
+    model = cfg.served_model()
+    h, f, E, V = (cfg.hidden_size, cfg.moe_intermediate_size,
+                  cfg.num_experts, cfg.vocab_size)
+    layer = {"wq": (h, 4096), "wk": (h, 512), "wv": (h, 512),
+             "wo": (4096, h), "q_norm": (128,), "k_norm": (128,),
+             "attn_norm": (h,), "ffn_norm": (h,), "router": (h, E),
+             "e_gu": (E, h, 2 * f), "e_down": (E, f, h)}
+    params = {"embed": sds((V, h), BF16), "head": sds((V, h), BF16),
+              "final_norm": sds((h,), BF16),
+              "layers": [{n: sds(s, BF16) for n, s in layer.items()}
+                         for _ in range(4)]}
+    pools = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+            lambda: model.make_pools(MEL_NB, BS, nb_window=MEL_NBW)))
+    return model, params, pools, sds, moe_dispatch
+
+
+# a pool as it lies: the parameter's dims and layout (S(1): a window pool
+# of 69 MB prefetched whole into the chip's fast memory before its walk, in
+# the layout it has; the walks of 32 full windows read as much)
+_MEL_POOL = re.compile(r"(24577|2113),16,1024\]")
+_MEL_LIES = re.compile(r"bf16\[(1,)?(24577|2113),16,1024\]\{(3,2,1,0|2,1,0):"
+                       r"T\(8,128\)\(2,1\)(S\(1\))?\}")
+
+
+def _mellum_big_writes(text):
+    """Writes of 4 MiB or more that are neither a pool's write-back in
+    place (a pool re-laid out around a scatter has another layout than the
+    parameter's: rows of [4, 128], the dense family's layout at 4 KV
+    heads, were re-laid out whole around the prefill's scatter, which is
+    why a row holds all of a token's heads) nor a weight's prefetch."""
+    entry = _entry(text)
+    relaid = [f"{n} = {ty[:70]} {op}" for n, (ty, op, _) in entry.items()
+              if _MEL_POOL.search(ty) and not _MEL_LIES.search(ty)]
+    big = [b for dims in ((MEL_NB, BS, 1024), (MEL_NBW, BS, 1024))
+           for b in _weight_sized_writes(entry, dims)]
+    return relaid + [b for b in set(big) if big.count(b) == 2
+                     and not b.endswith(_PREFETCH)]
+
+
+def test_mellum_decode_writes_no_weight_sized_array(topo, monkeypatch):
+    """32 slots through three window layers and a full one: both walks are
+    in the program under their names, the window kind's table is an
+    operand of its own, the untied head contracts its matrix where it lies
+    (no transposed copy of 453 MB), every pool takes the step's rows in
+    place, and Mosaic accepts the walk's fourth scalar operand."""
+    N = 32
+    model, params, pools, sds, moe_dispatch = _mellum_shapes(topo)
+    monkeypatch.setattr(moe_dispatch, "_mosaic", lambda: True)
+    text = jax.jit(functools.partial(
+        engine._paged_decode, model=model, n_steps=1,
+        opts=ServeOpts(ragged=True), sample_flags=GREEDY),
+        donate_argnums=(8,)).lower(
+        params, sds((N,), I32), sds((N,), I32), sds((N,), jnp.bool_),
+        sds((N,), I32), sds((2,), jnp.uint32), sds((N,), jnp.bool_),
+        sds((N, MEL_TABLE), I32), pools, sds((N,), F32), sds((N,), I32),
+        sds((N,), F32), sds((N,), I32), sds((N, MEL_RING), I32)
+        ).compile().as_text()
+    assert text.count("%mellum_walk_full") >= 1
+    assert text.count("%mellum_walk_window") >= 3 and "%gmm" in text
+    assert _mellum_big_writes(text) == []
+
+
+@pytest.mark.parametrize("history", [0, MEL_TABLE],
+                         ids=["first", "continuing"])
+def test_mellum_one_row_prefill_writes_no_weight_sized_array(
+        topo, monkeypatch, history):
+    """A piece of 1024 tokens (the cell's bucket), alone and with a
+    history: a full layer gathers the slot's whole table (its length a
+    runtime operand), a window layer the ring's 65 blocks under the band;
+    the gathered histories (69 MB a full layer at this width) are the
+    history, not a weight or a pool, and are told apart by their dims."""
+    model, params, pools, sds, moe_dispatch = _mellum_shapes(topo)
+    monkeypatch.setattr(moe_dispatch, "_mosaic", lambda: True)
+    S = 1024
+    args = [params, sds((1, S), I32), sds((1, S // BS), I32),
+            sds((1,), I32), pools, sds((1,), F32), sds((1,), I32),
+            sds((1,), F32), sds((2,), jnp.uint32)]
+    win = {"blk_ids": sds((1, S // BS), I32)}
+    if history:
+        args += [sds((1,), I32), sds((1, history), I32)]
+        win.update(ctx_tbl=sds((1, MEL_RING), I32),
+                   ctx_start=sds((1,), I32))
+    text = jax.jit(functools.partial(
+        engine._paged_prefill, model=model, opts=ServeOpts(ragged=True),
+        sample_flags=GREEDY, prefix_nbk=history), donate_argnums=(4,)).lower(
+        *args, win=win).compile().as_text()
+    assert "%mellum_prefill_chunk" in text and "%gmm" in text
+    for name in ("%mellum_history_full", "%mellum_history_window"):
+        assert (name in text) == bool(history)
+    assert [n for n, (ty, _op, _) in _entry(text).items()
+            if _MEL_POOL.search(ty) and not _MEL_LIES.search(ty)] == []
+
+
+# -- the three older families' programs are the parent's ----------------------
+# sha256 (first 16 hex digits) of the programs' jaxpr text, kernels lowered,
+# with source positions and addresses taken out, read on the PARENT of PR 35
+# (8908c52) and equal on its change: the walk's start, the flash kernel's
+# band and the engine's window operands are absent operands for a model of
+# one kind, so its programs are the parent's to the letter. A later PR that
+# means to change one of these programs prints the new text's hash here.
+PARENT_PROGRAMS = {
+    "dense.decode": "7921a0ad28675c6f", "dense.prefill0": "1cad0efaa529c317",
+    "dense.prefill16": "53e7a3a2e58a42bd",
+    "latent.decode": "387d28af185258ef",
+    "latent.prefill0": "6ba1697f08295a2c",
+    "latent.prefill16": "c000e881670864b6",
+    "lfm2.decode": "b4d382c4e1eff7be", "lfm2.prefill0": "7e7adb40b921895e",
+    "lfm2.prefill16": "112dd12a4b2ea3e4"}
+
+
+def _small_family(name):
+    """(model, params as shapes): a family at widths the Mosaic grouped
+    matmul takes (multiples of 128), small enough to trace in a second."""
+    from benchmark import manifest
+
+    if name == "dense":
+        cfg = llama.LlamaConfig(
+            vocab_size=512, hidden_size=256, intermediate_size=512,
+            num_layers=2, num_heads=2, num_kv_heads=1, head_dim=128,
+            dtype=BF16)
+        return cfg.served_model(), jax.eval_shape(
+            lambda: jax.tree_util.tree_map(
+                lambda a: a.astype(BF16),
+                llama.init_params(cfg, jax.random.PRNGKey(0))))
+    family = {"latent": "deepseek_v2", "lfm2": "lfm2_moe"}[name]
+    fam = manifest.load_family(family)
+    man = manifest.Manifest()
+    doc = next(d for d in (man.config(c["name"]) for c in man.doc["configs"])
+               if d["family"] == family)
+    m = {**doc, **fam.tiny(doc), "moe_intermediate_size": 128,
+         "hidden_size": 256}
+    return fam.program_config(m).served_model(), jax.eval_shape(
+        lambda: fam.make_params(m, jax.random.PRNGKey(0), BF16))
+
+
+@pytest.mark.parametrize("program", sorted(PARENT_PROGRAMS))
+def test_the_older_families_programs_are_the_parents(program, monkeypatch):
+    import hashlib
+
+    family, which = program.split(".")
+    moe_dispatch = importlib.import_module("paddle_tpu.kernels.moe_dispatch")
+    monkeypatch.setattr(moe_dispatch, "_mosaic", lambda: True)
+    model, params = _small_family(family)
+    N, NB_, MB = 4, 65, 16
+    sds = jax.ShapeDtypeStruct
+    pools = jax.eval_shape(lambda: {
+        **model.make_pools(NB_, BS),
+        **(model.make_state(N) if model.state_entries else {})})
+    if which == "decode":
+        text = jax.make_jaxpr(functools.partial(
+            engine._paged_decode, model=model, n_steps=1,
+            opts=ServeOpts(ragged=True), sample_flags=GREEDY))(
+            params, sds((N,), I32), sds((N,), I32), sds((N,), jnp.bool_),
+            sds((N,), I32), sds((2,), jnp.uint32), sds((N,), jnp.bool_),
+            sds((N, MB), I32), pools, sds((N,), F32), sds((N,), I32),
+            sds((N,), F32), sds((N,), I32))
+    else:
+        hist, S = int(which[len("prefill"):]), 128
+        args = [params, sds((1, S), I32), sds((1, S // BS), I32),
+                sds((1,), I32), pools, sds((1,), F32), sds((1,), I32),
+                sds((1,), F32), sds((2,), jnp.uint32)]
+        args += [sds((1,), I32), sds((1, hist), I32)] if hist else []
+        if model.state_entries:
+            args += ([] if hist else [None, None]) + [sds((1,), I32)]
+        text = jax.make_jaxpr(functools.partial(
+            engine._paged_prefill, model=model, opts=ServeOpts(ragged=True),
+            sample_flags=GREEDY, prefix_nbk=hist))(*args)
+    text = re.sub(r" at [^\s\]\)]+:\d+", "", str(text))
+    text = re.sub(r"0x[0-9a-f]+", "0x", text)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+        PARENT_PROGRAMS[program]
