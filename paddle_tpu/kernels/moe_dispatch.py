@@ -1033,9 +1033,12 @@ def sigmoid_bias_routing(scores, bias, top_k: int, scale: float = 1.0,
     return w * scale, idx.astype(jnp.int32)
 
 
-# pairs up to which a call gathers all of them at once; a wider wave walks
-# its sorted pairs in passes of about one row a token
-_HELD_PASS_ROWS = 8192
+# tokens of a call up to which ALL its pairs are gathered at once, whatever
+# the top-k: a prefill piece with the decode rows that ride in its program
+# (Mellum2's 1,024 + 32 tokens x 8 = 8,448 pairs), where k times the call is
+# still small; a wider wave walks its sorted pairs in passes of about one
+# row a token, so that memory is bounded by the wave and not by k times it
+_HELD_PASS_TOKENS = 2048
 # pairs of a pass up to which a call is a decode step: its pairs stay packed
 # one after another; over it (a prefill piece) every held expert's rows start
 # on a row-tile boundary of the grouped matmul
@@ -1130,12 +1133,16 @@ def held_expert_ffn(x, gates, idx, valid, e_gu, e_down, first: int):
 
     The pairs are sorted by held expert (foreign ones last), the rows of
     the held pairs gathered, one grouped matmul form run over them and the
-    results combined. A call of up to ``_HELD_PASS_ROWS`` pairs gathers all
-    of them at once; a wider prefill wave walks the sorted pairs in passes
-    of about T rows, each under a ``cond`` on whether any held pair is
-    left, so that memory is bounded by the wave and not by k times it, and
-    no pair is dropped however skewed the routing (with even routing one
-    pass in k runs).
+    results combined. A call of up to ``_HELD_PASS_TOKENS`` tokens (a
+    decode step, a prefill piece, a piece with the decode rows that ride in
+    its program) gathers all its pairs at once; a wider prefill wave walks
+    the sorted pairs in passes of about T rows, each under a ``cond`` on
+    whether any held pair is left, so that memory is bounded by the wave
+    and not by k times it, and no pair is dropped however skewed the
+    routing (with even routing one pass in k runs). The bound is on the
+    call's TOKENS: a bound on its pairs (8,192 until PR 36) stood exactly
+    at Mellum2's piece of 1,024 x 8, and 32 decode rows more would have
+    sent it to ~8 passes with the whole call's gather in each.
 
     The row layout of a pass follows from its static pair count M. A
     decode step (M up to ``_HELD_SMALL_ROWS``: a few rows an expert) keeps
@@ -1172,7 +1179,7 @@ def held_expert_ffn(x, gates, idx, valid, e_gu, e_down, first: int):
     total = ends[-1]
     gate = jnp.where(held, gates, 0.0)
     tile = _row_tile()
-    M = -(-(T * k if T * k <= _HELD_PASS_ROWS else T) // tile) * tile
+    M = -(-(T * k if T <= _HELD_PASS_TOKENS else T) // tile) * tile
     aligned = M > _HELD_SMALL_ROWS
     n_pass = -(-T * k // M)
     if aligned:

@@ -302,7 +302,9 @@ class DeepseekV2Served:
                 "valid": (jnp.arange(S)[None, :]
                           < true_len[:, None]).reshape(B * S)}
 
-    def prefill_layer(self, params, l: int, x, aux, pools, opts: ServeOpts):
+    def prefill_mix(self, params, l: int, x, aux, pools, opts: ServeOpts):
+        """The token-mixing half of a piece's layer: x [B, S, h] -> (x +
+        latent attention, the layer's new latent rows)."""
         c, dt = self.config, self.dtype
         p = params["layers"][l]
         B, S, h = x.shape
@@ -348,10 +350,25 @@ class DeepseekV2Served:
         rows = (q_nope, q_rope, row)
         if aux["prefix_nbk"]:
             rows += (aux["ctx_tbl"], aux["hist_len"].astype(jnp.int32))
-        x = x + jax.lax.map(one_row, rows)
-        hn = _rms_norm(x, p["mlp_norm"], c.rms_eps)
+        return x + jax.lax.map(one_row, rows), {"c": row}
+
+    def ffn(self, params, l: int, rows, valid):
+        """The row-wise half of a layer, whatever program the rows come
+        from: rows [T, h] -> (rows + FFN(norm(rows)), counts or None). No
+        row's result depends on another's."""
+        p = params["layers"][l]
+        y, counts = self._ffn(
+            p, l, _rms_norm(rows, p["mlp_norm"], self.config.rms_eps), valid)
+        return rows + y, counts
+
+    def prefill_layer(self, params, l: int, x, aux, pools, opts: ServeOpts):
+        """``prefill_mix`` and then ``ffn``'s steps over the piece's own
+        rows, in the order that keeps the lone program's text."""
+        p = params["layers"][l]
+        B, S, h = x.shape
+        x, ent = self.prefill_mix(params, l, x, aux, pools, opts)
+        hn = _rms_norm(x, p["mlp_norm"], self.config.rms_eps)
         y, counts = self._ffn(p, l, hn.reshape(B * S, h), aux["valid"])
-        ent = {"c": row}
         if self._has_experts:
             ent["_stats"] = (counts if counts is not None
                              else jnp.zeros((5,), jnp.float32))
@@ -391,8 +408,11 @@ class DeepseekV2Served:
         return {"ang": lens.astype(jnp.float32)[:, None] * aux["inv"][None],
                 "ring_mask": (jnp.arange(S) <= t)[None, None, :]}
 
-    def decode_layer(self, params, l: int, x, aux, step, ring, t, pools,
-                     act, opts: ServeOpts):
+    def decode_mix(self, params, l: int, x, aux, step, ring, t, pools, act,
+                   opts: ServeOpts):
+        """The token-mixing half of a decode step's layer: x [N, 1, h] ->
+        (x + absorbed latent attention [N, h], the ring with this step's
+        latent row)."""
         c, dt = self.config, self.dtype
         p = params["layers"][l]
         N = x.shape[0]
@@ -435,10 +455,17 @@ class DeepseekV2Served:
                      + jnp.einsum("nhs,nsc->nhc", probs[..., P:].astype(dt),
                                   v_rng)).astype(dt)
         o = jnp.einsum("nhc,hcd->nhd", o_lat, p["w_uv"].astype(dt))
-        xa = x[:, 0] + o.reshape(N, -1) @ p["w_o"].astype(dt)
-        hn = _rms_norm(xa, p["mlp_norm"], c.rms_eps)
+        return (x[:, 0] + o.reshape(N, -1) @ p["w_o"].astype(dt),
+                dict(ring, c=rc))
+
+    def decode_layer(self, params, l: int, x, aux, step, ring, t, pools,
+                     act, opts: ServeOpts):
+        """``decode_mix`` and then ``ffn``'s steps over the slots' rows."""
+        p = params["layers"][l]
+        xa, ring = self.decode_mix(params, l, x, aux, step, ring, t, pools,
+                                   act, opts)
+        hn = _rms_norm(xa, p["mlp_norm"], self.config.rms_eps)
         y, counts = self._ffn(p, l, hn, act)
-        ring = dict(ring, c=rc)
         if counts is not None:
             ring["_stats"] = ring["_stats"] + counts
         return (xa + y)[:, None], ring

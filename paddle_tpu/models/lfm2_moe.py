@@ -311,18 +311,35 @@ class Lfm2MoeServed:
                 r, n, 2, 0))(ue, aux["true_len"].astype(jnp.int32))
         return (gate * conv) @ p["w_out"].astype(dt), {f"s{ci}": s_new}
 
-    def prefill_layer(self, params, l: int, x, aux, pools, opts: ServeOpts):
+    def prefill_mix(self, params, l: int, x, aux, pools, opts: ServeOpts):
+        """The token-mixing half of a piece's layer: x [B, S, h] -> (x +
+        convolution or attention, the layer's new entries)."""
         c = self.config
         p = params["layers"][l]
-        B, S, h = x.shape
         hn = _rms_norm(x, p["op_norm"], c.norm_eps)
         if c.layer_types[l] == "conv":
             y, ent = self._prefill_conv(p, self._conv.index(l), hn, aux)
         else:
             y, ent = self._prefill_attention(p, self._attn.index(l), hn,
                                              aux, pools, opts)
-        x = x + y
-        hn = _rms_norm(x, p["ffn_norm"], c.norm_eps)
+        return x + y, ent
+
+    def ffn(self, params, l: int, rows, valid):
+        """The row-wise half of a layer, whatever program the rows come
+        from: rows [T, h] -> (rows + FFN(norm(rows)), counts or None). No
+        row's result depends on another's."""
+        p = params["layers"][l]
+        y, counts = self._ffn(
+            p, l, _rms_norm(rows, p["ffn_norm"], self.config.norm_eps), valid)
+        return rows + y, counts
+
+    def prefill_layer(self, params, l: int, x, aux, pools, opts: ServeOpts):
+        """``prefill_mix`` and then ``ffn``'s steps over the piece's own
+        rows, in the order that keeps the lone program's text."""
+        p = params["layers"][l]
+        B, S, h = x.shape
+        x, ent = self.prefill_mix(params, l, x, aux, pools, opts)
+        hn = _rms_norm(x, p["ffn_norm"], self.config.norm_eps)
         y, counts = self._ffn(p, l, hn.reshape(B * S, h), aux["valid"])
         if self._has_experts:
             ent["_stats"] = (counts if counts is not None
@@ -396,8 +413,11 @@ class Lfm2MoeServed:
         y = (gate * conv) @ p["w_out"].astype(dt)
         return y, {**ring, f"s{ci}": s_new[None]}
 
-    def decode_layer(self, params, l: int, x, aux, step, ring, t, pools,
-                     act, opts: ServeOpts):
+    def decode_mix(self, params, l: int, x, aux, step, ring, t, pools, act,
+                   opts: ServeOpts):
+        """The token-mixing half of a decode step's layer: x [N, 1, h] ->
+        (x + convolution or attention [N, h], the ring with this step's
+        entry or state)."""
         c = self.config
         p = params["layers"][l]
         hn = _rms_norm(x[:, 0], p["op_norm"], c.norm_eps)
@@ -407,8 +427,15 @@ class Lfm2MoeServed:
         else:
             y, ring = self._decode_attention(p, self._attn.index(l), hn, aux,
                                              step, ring, t, pools, opts)
-        xa = x[:, 0] + y
-        hn = _rms_norm(xa, p["ffn_norm"], c.norm_eps)
+        return x[:, 0] + y, ring
+
+    def decode_layer(self, params, l: int, x, aux, step, ring, t, pools,
+                     act, opts: ServeOpts):
+        """``decode_mix`` and then ``ffn``'s steps over the slots' rows."""
+        p = params["layers"][l]
+        xa, ring = self.decode_mix(params, l, x, aux, step, ring, t, pools,
+                                   act, opts)
+        hn = _rms_norm(xa, p["ffn_norm"], self.config.norm_eps)
         y, counts = self._ffn(p, l, hn, act)
         if counts is not None:
             ring = dict(ring, _stats=ring["_stats"] + counts)

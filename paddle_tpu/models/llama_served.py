@@ -47,6 +47,21 @@ pool-name prefix of a draft model) and ``mesh``):
 ``ring_init(N, S, opts)``             the in-call ring of a decode call
 ``decode_begin(...)`` -> aux          what is frozen for the whole call
 ``decode_layer(params, l, x, aux, step, ring, t, pools, opts)``
+``prefill_mix``, ``decode_mix``, ``ffn``  OPTIONAL: a layer's two halves
+                                      apart, for ONE program in which a
+                                      step's last prefill piece carries the
+                                      decode rows (docs/served_models.md):
+                                      ``prefill_mix(params, l, x, aux,
+                                      pools, opts) -> (x, entries)`` and
+                                      ``decode_mix(params, l, x, aux, step,
+                                      ring, t, pools, act, opts) -> (x [N,
+                                      h], ring)`` are the token-mixing half
+                                      with its residual, ``ffn(params, l,
+                                      rows [T, h], valid) -> (rows, counts)``
+                                      the row-wise half (norm, router,
+                                      experts or dense FFN, residual: no
+                                      cross-row state). Absent here: the
+                                      dense family keeps its two programs
 ``shard(params, pools, mesh, ...)``   a tp mesh's placements
 ``spec_verify``                       llama's own extra: a model without
                                       it lists ``spec`` as unsupported

@@ -320,15 +320,31 @@ class MellumServed:
         return (o @ p["wo"].astype(self.dtype),
                 {f"kv{kind}": pack_rows(k, v)})
 
+    def prefill_mix(self, params, l: int, x, aux, pools, opts: ServeOpts):
+        """The token-mixing half of a piece's layer: x [B, S, h] -> (x +
+        attention of its kind, the layer's new entries)."""
+        p = params["layers"][l]
+        kind, a = self._kind(l)
+        hn = _rms_norm(x, p["attn_norm"], self.config.rms_eps)
+        y, ent = self._prefill_attention(p, kind, a, hn, aux, pools, opts)
+        return x + y, ent
+
+    def ffn(self, params, l: int, rows, valid):
+        """The row-wise half of a layer, whatever program the rows come
+        from: rows [T, h] -> (rows + experts(norm(rows)), counts). No
+        row's result depends on another's."""
+        p = params["layers"][l]
+        y, counts = self._ffn(
+            p, _rms_norm(rows, p["ffn_norm"], self.config.rms_eps), valid)
+        return rows + y, counts
+
     def prefill_layer(self, params, l: int, x, aux, pools, opts: ServeOpts):
-        c = self.config
+        """``prefill_mix`` and then ``ffn``'s steps over the piece's own
+        rows, in the order that keeps the lone program's text."""
         p = params["layers"][l]
         B, S, h = x.shape
-        kind, a = self._kind(l)
-        hn = _rms_norm(x, p["attn_norm"], c.rms_eps)
-        y, ent = self._prefill_attention(p, kind, a, hn, aux, pools, opts)
-        x = x + y
-        hn = _rms_norm(x, p["ffn_norm"], c.rms_eps)
+        x, ent = self.prefill_mix(params, l, x, aux, pools, opts)
+        hn = _rms_norm(x, p["ffn_norm"], self.config.rms_eps)
         y, ent["_stats"] = self._ffn(p, hn.reshape(B * S, h), aux["valid"])
         return x + y.reshape(B, S, h), ent
 
@@ -418,16 +434,25 @@ class MellumServed:
             walk=walk, dense=dense)
         return att @ p["wo"].astype(dt), {**ring, name: rkv}
 
-    def decode_layer(self, params, l: int, x, aux, step, ring, t, pools,
-                     act, opts: ServeOpts):
-        c = self.config
+    def decode_mix(self, params, l: int, x, aux, step, ring, t, pools, act,
+                   opts: ServeOpts):
+        """The token-mixing half of a decode step's layer: x [N, 1, h] ->
+        (x + attention of its kind [N, h], the ring with this step's
+        entry)."""
         p = params["layers"][l]
         kind, a = self._kind(l)
-        hn = _rms_norm(x[:, 0], p["attn_norm"], c.rms_eps)
+        hn = _rms_norm(x[:, 0], p["attn_norm"], self.config.rms_eps)
         y, ring = self._decode_attention(p, kind, a, hn, aux, step, ring, t,
                                          pools, opts)
-        xa = x[:, 0] + y
-        hn = _rms_norm(xa, p["ffn_norm"], c.rms_eps)
+        return x[:, 0] + y, ring
+
+    def decode_layer(self, params, l: int, x, aux, step, ring, t, pools,
+                     act, opts: ServeOpts):
+        """``decode_mix`` and then ``ffn``'s steps over the slots' rows."""
+        p = params["layers"][l]
+        xa, ring = self.decode_mix(params, l, x, aux, step, ring, t, pools,
+                                   act, opts)
+        hn = _rms_norm(xa, p["ffn_norm"], self.config.rms_eps)
         y, counts = self._ffn(p, hn, act)
         ring = dict(ring, _stats=ring["_stats"] + counts)
         return (xa + y)[:, None], ring

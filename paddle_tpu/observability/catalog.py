@@ -287,6 +287,18 @@ CATALOG = {
         "counter", (),
         "window-kind blocks written again in place behind the window (what "
         "a free list would have been given back and asked for again)"),
+    "serving_prefill_programs_total": (
+        "counter", ("carried",),
+        "prefill programs dispatched by an engine whose pieces carry the "
+        "decode rows (LLMEngine._piggyback): carried=rows (the step's last "
+        "piece, with one decode step of the slots in the same program) or "
+        "none (a piece that was not the step's last, or a step with no "
+        "decode rows); the two sum to the pieces dispatched"),
+    "serving_decode_steps_total": (
+        "counter", ("program",),
+        "decode steps of the slots by the program that ran them: "
+        "program=decode (their own) or piece (they rode with a prefill "
+        "piece, so the step streamed the row-wise weights once)"),
     # -- fleet observability (observability.fleet, r17) --------------------
     "serving_fleet_slo_attainment": (
         "gauge", ("replica", "slo"),

@@ -164,6 +164,8 @@ _M_STATE_SLOT_BYTES = _instrument("serving_state_bytes_per_slot")
 _M_STATE_RESETS = _instrument("serving_state_resets_total")
 _M_WINDOW_SLOT_BYTES = _instrument("serving_window_bytes_per_slot")
 _M_WINDOW_RECYCLED = _instrument("serving_window_blocks_recycled_total")
+_M_PREFILL_PROGRAMS = _instrument("serving_prefill_programs_total")
+_M_DECODE_STEPS = _instrument("serving_decode_steps_total")
 
 
 @dataclasses.dataclass
@@ -267,7 +269,7 @@ def _apply_admissions(c_last, c_len, c_done, c_rem, wave_toks, slot_of_row,
 
 def _paged_prefill(params, tokens, blk_ids, true_len, pools,
                    temps, top_ks, top_ps, key, hist_len=None,
-                   ctx_tbl=None, slot=None, win=None, *, model,
+                   ctx_tbl=None, slot=None, win=None, dec=None, *, model,
                    opts: ServeOpts = ServeOpts(),
                    sample_flags=(True, True, True), prefix_nbk: int = 0):
     """Prefill a WAVE of admissions in one compiled program: causal
@@ -346,6 +348,24 @@ def _paged_prefill(params, tokens, blk_ids, true_len, pools,
     ``ctx_start`` [B]: the blocks that hold the last ``W - 1`` tokens
     before the piece, in order, and the position of the first of them. A
     model with one kind is given no ``win`` and its program is unchanged.
+
+    The decode rows in the same program (``dec``: ``_paged_decode``'s
+    operands from ``last_tokens`` to ``eos_ids`` and, for a model with
+    window entries, ``win_table``, in its order without ``params`` and
+    ``pools``; ``LLMEngine._piggyback`` says when): one decode step of the
+    slots rides with the piece. Every layer's token-mixing half runs for
+    each kind of row as it does in its own program (``model.prefill_mix``
+    over the piece, ``model.decode_mix`` over the slots: the walk, the
+    one-token ring, the per-slot state), and its row-wise half
+    (``model.ffn``: norm, router, experts or dense FFN, residual) ONCE
+    over the piece's rows and the slots' rows laid end to end, so the
+    experts' weights stream once for both. Both write-backs follow, then
+    one head over the piece's last real position and the slots' rows.
+    Returns (first_tokens [B], emitted [1, N], last, lengths, done,
+    budgets, key, pools, stats): what both programs return, ``stats`` ONE
+    vector for the whole program. With ``active`` all false it is the lone
+    piece: the walks visit no block, no slot's row is routed, state or
+    carry moves, and the ring lands in the trash block.
     """
     B, S = tokens.shape
     x = model.embed(params, tokens)
@@ -359,14 +379,29 @@ def _paged_prefill(params, tokens, blk_ids, true_len, pools,
             n: jnp.where(carried.reshape((1, B) + (1,) * (pools[n].ndim - 2)),
                          pools[n][:, slot], 0)
             for n in model.state_entries}
+    if dec is not None:
+        return _piece_with_decode_rows(
+            params, x, aux, blk_ids, true_len, pools, temps, top_ks, top_ps,
+            key, slot, win, dec, model, opts, sample_flags)
     new = []
     for l in range(model.num_layers):
         x, ent = model.prefill_layer(params, l, x, aux, pools, opts)
         new.append(ent)
+    pools = _scatter_piece(model, opts, pools, new, blk_ids, slot, win, B, S)
 
-    # hoisted writeback: all layers' entries in ONE scatter per pool (the
-    # per-layer Pallas/XLA block appends cost ~0.6 ms of launch overhead
-    # each — 2L calls/prefill dwarfed the prefill math itself)
+    x = model.final_norm(params, x)
+    last_h = x[jnp.arange(B), jnp.maximum(true_len - 1, 0)]
+    logits = model.head(params, last_h)
+    toks = _sample_rows(logits, key, temps, top_ks, top_ps, *sample_flags)
+    stats = (sum(e["_stats"] for e in new) if "_stats" in new[-1] else None)
+    return toks, pools, stats
+
+
+def _scatter_piece(model, opts, pools, new, blk_ids, slot, win, B, S):
+    """A piece's new entries into the pools: all layers' in ONE scatter per
+    pool (the per-layer Pallas/XLA block appends cost ~0.6 ms of launch
+    overhead each — 2L calls/prefill dwarfed the prefill math itself), and
+    its per-slot state into the row's slot."""
     flat = blk_ids.reshape(-1)
     names = dict.fromkeys(n for e in new for n in e if not n.startswith("_"))
     stacked = {n: jnp.stack([e[n] for e in new if n in e]) for n in names}
@@ -381,13 +416,65 @@ def _paged_prefill(params, tokens, blk_ids, true_len, pools,
         pools[name] = pools[name].at[
             :, wflat if name in window else flat].set(
             val.reshape((val.shape[0], B * (S // bs), bs) + val.shape[3:]))
+    return pools
 
-    x = model.final_norm(params, x)
+
+def _piece_with_decode_rows(params, x, aux, blk_ids, true_len, pools, temps,
+                            top_ks, top_ps, key, slot, win, dec, model, opts,
+                            sample_flags):
+    """``_paged_prefill`` from its layers on, with one decode step of the
+    slots in the same program (its docstring says what is shared)."""
+    (last, lens0, done, rem, dkey, active, table, dtemps, dtop_ks, dtop_ps,
+     eos_ids, *win_table) = dec
+    win_table = win_table[0] if win_table else None
+    B, S, h = x.shape
+    N = last.shape[0]
+    daux = model.decode_begin(
+        params, pools, table, lens0, active, 1, opts,
+        **({} if win_table is None else {"win_table": win_table}))
+    dkey, sub = jax.random.split(dkey)
+    act = active & ~done
+    xd = model.embed(params, last)                              # [N, h]
+    step = model.decode_step_begin(daux, lens0, 0, 1)
+    ring = model.ring_init(N, 1, opts)
+    for name in model.state_entries:
+        ring[name] = pools[name][:, :N]
+    # pad positions of the piece and idle slots load no expert
+    valid = jnp.concatenate([aux["valid"], act])
+    new, stats = [], None
+    for l in range(model.num_layers):
+        x, ent = model.prefill_mix(params, l, x, aux, pools, opts)
+        xd, ring = model.decode_mix(params, l, xd[:, None], daux, step, ring,
+                                    0, pools, act, opts)
+        rows, counts = model.ffn(
+            params, l, jnp.concatenate([x.reshape(B * S, h), xd]), valid)
+        x, xd = rows[:B * S].reshape(B, S, h), rows[B * S:]
+        if counts is not None:
+            stats = counts if stats is None else stats + counts
+        new.append(ent)
+
+    # the slots' rows first: the ring's per-slot state is every slot's as it
+    # was read, moved only where ``act``, so the piece's own slot (never a
+    # decode row of this program) keeps what the piece writes after it
+    lens_end = lens0 + act.astype(lens0.dtype)
+    pools, _ = _scatter_ring(model, opts, pools, ring, table, win_table,
+                             lens0, lens_end, active, 1)
+    pools = _scatter_piece(model, opts, pools, new, blk_ids, slot, win, B, S)
+
+    # one head over the piece's last real position and the slots' rows
     last_h = x[jnp.arange(B), jnp.maximum(true_len - 1, 0)]
-    logits = model.head(params, last_h)
-    toks = _sample_rows(logits, key, temps, top_ks, top_ps, *sample_flags)
-    stats = (sum(e["_stats"] for e in new) if "_stats" in new[-1] else None)
-    return toks, pools, stats
+    logits = model.head(params, model.final_norm(
+        params, jnp.concatenate([last_h, xd])))
+    toks = _sample_rows(logits[:B], key, temps, top_ks, top_ps,
+                        *sample_flags)
+    nxt = _sample_rows(logits[B:], sub, dtemps, dtop_ks, dtop_ps,
+                       *sample_flags)
+    emitted = jnp.where(act, nxt, -1)[None]
+    rem = rem - act.astype(rem.dtype)
+    done = done | (act & (eos_ids >= 0) & (nxt == eos_ids)) \
+        | (act & (rem <= 0))
+    last = jnp.where(act, nxt, last)
+    return toks, emitted, last, lens_end, done, rem, dkey, pools, stats
 
 
 def _paged_decode(params, last_tokens, lengths, done0, budgets, key, active,
@@ -513,7 +600,18 @@ def _paged_decode(params, last_tokens, lengths, done0, budgets, key, active,
     (last_tokens, lens_end, done0, budgets, ring, key), \
         emitted = jax.lax.scan(body, init, jnp.arange(S))
 
-    # ---- writeback: the ring's valid entries → pools, one scatter -------
+    pools, stats = _scatter_ring(model, opts, pools, ring, block_table,
+                                 win_table, lens0, lens_end, active, S)
+    return (emitted, last_tokens, lens_end, done0, budgets, key, pools,
+            stats)
+
+
+def _scatter_ring(model, opts, pools, ring, block_table, win_table, lens0,
+                  lens_end, active, S):
+    """A decode call's ring into the pools, its valid entries in ONE
+    scatter per pool at (block, offset), and the per-slot state back to
+    its rows: ``(pools, the ring's "_stats" or None)``."""
+    N, MB = block_table.shape
     stats = ring.pop("_stats", None)
     state = {name: ring.pop(name) for name in model.state_entries}
     packed = model.pack_entries(ring, opts)
@@ -537,8 +635,7 @@ def _paged_decode(params, last_tokens, lengths, done0, budgets, key, active,
             :, phys_w if name in window else phys, off].set(val)
     for name, val in state.items():
         pools[name] = pools[name].at[:, :N].set(val)
-    return (emitted, last_tokens, lens_end, done0, budgets, key, pools,
-            stats)
+    return pools, stats
 
 
 # ---------------------------------------------------------------------------
@@ -1030,6 +1127,33 @@ class LLMEngine:
                     f"prefill_chunk {prefill_chunk} exceeds the largest "
                     f"prompt bucket {self.buckets[-1]}")
         self.prefill_chunk = prefill_chunk
+        # Whether a step's LAST prefill piece carries the decode rows in
+        # its own program (``_paged_prefill``'s ``dec``), so that a step
+        # with a piece streams the experts (and every row-wise weight)
+        # once and not once a program. Derived from what this engine is,
+        # never set: pieces run between decode steps (``prefill_chunk``),
+        # a decode call is one step, the ragged walk (one table shape for
+        # good), no draft model, a role that decodes, and a model that
+        # offers its layers' two halves (``prefill_mix`` / ``decode_mix`` /
+        # ``ffn``, docs/served_models.md). Such an engine has no other
+        # prefill program: a piece that carries nothing runs the same one
+        # with ``active`` all false.
+        self._piggyback = bool(
+            prefill_chunk and self.decode_steps == 1 and not self._spec_on
+            and role != "prefill" and self._decode_path() == "ragged"
+            and all(hasattr(model, half) for half in
+                    ("prefill_mix", "decode_mix", "ffn")))
+        # the step's last piece, built and waiting for the decode dispatch
+        # (``_launch_row``'s operand); None between steps
+        self._held = None
+        # a final piece that rode with the decode rows: its first token is
+        # read back with that record, and its slot joins the decode rows at
+        # the NEXT dispatch, which puts the token into the carry
+        self._joining: List = []
+        self._idle_dec = None        # a lone piece's decode operands
+        # this step's decode steps by the program that ran them, for
+        # _step_telemetry
+        self._step_decodes = {"piece": 0, "decode": 0}
         self.prefix_cache = (
             PrefixCache(block_size,
                         HostKVPool(prefix_cache_host_bytes, kind="prefix")
@@ -1171,6 +1295,7 @@ class LLMEngine:
                                  kv_int8=self.kv_int8 and not draft,
                                  numerics=(self.kv_int8 and not draft
                                            and _nm.active()),
+                                 ragged=self._piggyback,
                                  prefix="d" if draft else "",
                                  mesh=self.mesh),
                              prefix_nbk=prefix_nbk)),
@@ -1312,6 +1437,9 @@ class LLMEngine:
         # an admission whose first token was never read back dies with the
         # slot (recompute semantics: re-admission prefills and re-samples)
         self._pending_adm = [e for e in self._pending_adm if e[0] != slot]
+        self._joining = [e for e in self._joining if e[0] != slot]
+        if self._held is not None and self._held[0][0] == slot:
+            self._held = None         # a piece built and not yet dispatched
         self._pending_swapin = [e for e in self._pending_swapin
                                 if e[0] != slot]
         self._fresh_swapins.discard(slot)
@@ -1788,6 +1916,8 @@ class LLMEngine:
         state at the next dispatch."""
         self._inflight = None
         self._pending_adm = []
+        self._joining = []
+        self._held = None
         self._pending_swapin = []
         self._fresh_swapins = set()
         self._carry = None
@@ -2042,13 +2172,29 @@ class LLMEngine:
         no batch form: a row never pays for a wider wave's padding
         (prompts of 300 and 900 tokens cost 729 ms as 16 rows x 1024
         and 71 ms as their own two programs, PERF.md §6 PR 31)."""
+        wave = len(rows)
+        if self._piggyback:
+            # the step's last piece waits for the decode dispatch and goes
+            # out with the decode rows; one held before it (an earlier
+            # admission phase of this step) is no longer the last
+            self._flush_held()
+            rows, last = rows[:-1], rows[-1]
         for row in rows:
-            self._dispatch_row(row, len(rows))
+            self._launch_row(self._build_row(row, wave))
+        if self._piggyback:
+            self._held = self._build_row(last, wave)
 
-    def _dispatch_row(self, row, wave: int):
-        """One row's program: operands, the call, the draft's call
-        behind it, the host's bookkeeping. ``wave``: rows dispatched
-        with it in this step."""
+    def _flush_held(self) -> None:
+        """The held piece alone: nothing rides with it (no decode rows in
+        this step, or a later piece took its place)."""
+        if self._held is not None:
+            held, self._held = self._held, None
+            self._launch_row(held)
+
+    def _build_row(self, row, wave: int):
+        """One row's program, built: ``(row, bucket, flags, pnbk, args,
+        kw, span attrs)``. ``wave``: rows dispatched with it in this
+        step."""
         slot, req, ctx, hist, piece, final = row
         with trace_span("serving.prefill_build", wave=wave) as sp:
             bucket, flags, pnbk, args = self._prefill_operands(row)
@@ -2072,8 +2218,36 @@ class LLMEngine:
             kw["win"] = self._window_operands(row, bucket, pnbk)
             # the history tokens a window layer gathers for this piece
             attrs["hist_window"] = min(hist, self.win.W - 1)
-        with trace_span("serving.prefill", **attrs) as sp:
-            tok_dev, self.pools, stats = self._prefill_fn(
+        return row, bucket, flags, pnbk, args, kw, attrs
+
+    def _launch_row(self, built, dec=None, dec_flags=None, **dec_attrs):
+        """A built row's call, the draft's call behind it, the host's
+        bookkeeping. In an engine whose pieces carry the decode rows
+        (``_piggyback``) the ONE program takes ``dec``, the decode call's
+        operands, and returns what it returns: the carry and the emitted
+        tokens come back to ``_dispatch_decode``; a lone piece takes the
+        idle operands (``active`` all false) and its carry is dropped. The
+        program's span is ``serving.prefill`` either way, with the decode
+        rows it carried as ``decode_slots`` and their walk's
+        ``walk_blocks`` / ``kv_bytes`` (``dec_attrs``), and there is no
+        ``serving.decode`` span for the step: one pass over the weights,
+        one set of the model's counts."""
+        row, bucket, flags, pnbk, args, kw, attrs = built
+        req = row[1]
+        args[4] = self.pools          # as donated by whatever ran since
+        if self._piggyback:
+            if dec is None:
+                dec = self._idle_rows()
+                dec_attrs = dict(decode_slots=0, walk_blocks=0, kv_bytes=0)
+            else:
+                # one flag tuple a program: a branch either kind needs
+                flags = tuple(a or b for a, b in zip(flags, dec_flags))
+            kw = dict(kw, dec=dec)
+            carried = "rows" if dec_attrs["decode_slots"] else "none"
+            _M_PREFILL_PROGRAMS.inc(carried=carried)
+            self._step_decodes["piece"] += carried == "rows"
+        with trace_span("serving.prefill", **attrs, **dec_attrs) as sp:
+            tok_dev, *rest, self.pools, stats = self._prefill_fn(
                 bucket, flags, pnbk)(*args, **kw)
         if stats is not None:
             # read back with the next decode record's tokens
@@ -2088,11 +2262,28 @@ class LLMEngine:
             dargs = [self.draft_params] + args[1:8] + [dsub] + args[9:]
             dargs[4] = self.pools
             with trace_span("serving.prefill", bucket=bucket, batch=1,
-                            wave=wave, model="draft",
+                            wave=attrs["wave"], model="draft",
                             request_ids=[req.req_id]):
                 _junk, self.pools, _st = self._prefill_fn(
                     bucket, flags, pnbk, draft=True)(*dargs)
         self._prefill_dispatched(row, bucket, tok_dev)
+        return rest
+
+    def _idle_rows(self):
+        """The decode operands of a piece that carries no decode rows:
+        ``active`` all false, so no slot walks, is routed or moves; made
+        once."""
+        if self._idle_dec is None:
+            # from host arrays: a copy each, no program compiled for them
+            zi = np.zeros(self.N, np.int32)
+            off = np.zeros(self.N, bool)
+            self._idle_dec = tuple(jnp.asarray(a) for a in (
+                zi, zi, off, zi, np.zeros(2, np.uint32), off,
+                np.zeros((self.N, self.mb), np.int32),
+                np.zeros(self.N, np.float32), zi,
+                np.ones(self.N, np.float32), np.full(self.N, -1, np.int32))
+            ) + (() if self.win is None else (self._wtable_dev,))
+        return self._idle_dec
 
     def _prefill_operands(self, row):
         """A row's program variant and its operands, from the bucket
@@ -2101,7 +2292,13 @@ class LLMEngine:
         slot, req, ctx, hist, piece, final = row
         bucket = self._bucket_for(piece)
         b0 = hist // self.bs
-        pnbk = self.model.history_blocks(b0, self.mb)
+        # where pieces carry the decode rows, a row that starts its context
+        # takes the history's operands too, at a length of 0: ONE piece
+        # program a bucket to trace, load and keep, which now holds the
+        # decode rows' half as well (a kernel skips the tiles past a
+        # history's length, so the table's width is what it costs)
+        pnbk = self.model.history_blocks(max(b0, int(self._piggyback)),
+                                         self.mb)
         # only the blocks the piece occupies; the bucket's pad tail
         # scatters into the trash block (never read: causality)
         nblk = -(-(hist + piece) // self.bs) - b0
@@ -2229,9 +2426,12 @@ class LLMEngine:
 
     def _decode_slots(self):
         """Slots the decode call covers: active and not mid-chunked-
-        prefill (a chunking slot joins once its final chunk lands)."""
+        prefill (a chunking slot joins once its final chunk lands; the
+        slot of a piece held for this step's decode dispatch joins at the
+        next)."""
+        held = None if self._held is None else self._held[0][0]
         return [i for i in range(self.N) if self.slot_req[i] is not None
-                and i not in self._chunks]
+                and i not in self._chunks and i != held]
 
     def _spec_safe(self) -> bool:
         """True iff dispatching the next decode call BEFORE reading the
@@ -2248,6 +2448,14 @@ class LLMEngine:
             if req.eos_token_id is not None:
                 return False
             if rec["rem_start"][slot] - self.decode_steps <= 0:
+                return False
+        for slot, rid, _ in self._joining:
+            # a final piece rode in the in-flight program: its first
+            # token, unread, may already end its request
+            req = self.slot_req[slot]
+            if req is None or req.req_id != rid \
+                    or req.eos_token_id is not None \
+                    or req.max_new_tokens - len(req.generated) <= 1:
                 return False
         return True
 
@@ -2317,7 +2525,7 @@ class LLMEngine:
                 "carry rebuild requires a drained pipeline"
             last = np.zeros(self.N, np.int32)
             budgets = np.zeros(self.N, np.int32)
-            pend = {s for s, _, _ in self._pending_adm}
+            pend = {s for s, _, _ in self._pending_adm + self._joining}
             for i in active_slots:
                 req = self.slot_req[i]
                 # swap-in slots continue from the context tail (their KV
@@ -2333,13 +2541,14 @@ class LLMEngine:
                            jnp.asarray(self.lengths, jnp.int32),
                            jnp.zeros(self.N, bool),
                            jnp.asarray(budgets), sub)
-        if self._pending_adm:
+        if self._pending_adm or self._joining:
             # one _apply_admissions call per admitted row (usually one):
             # the row's [1] token array, everything else pinned to
             # [max_slots], so nothing here can ever compile inside the
-            # serving loop
+            # serving loop. ``_joining``: a final piece that rode with the
+            # last dispatch's decode rows, its record already made
             c_last, c_len, c_done, c_rem, c_key = self._carry
-            for s, _rid, arr in self._pending_adm:
+            for s, _rid, arr in self._pending_adm + self._joining:
                 upd = np.zeros(self.N, bool)
                 lens_new = np.zeros(self.N, np.int32)
                 rems_new = np.zeros(self.N, np.int32)
@@ -2456,7 +2665,8 @@ class LLMEngine:
         record when pipelined). ``prep``: the caller's open
         ``serving.decode_prepare`` span, ended here right at the call."""
         prev = self._inflight
-        pend = {s for s, _, _ in self._pending_adm}
+        pend = {s for s, _, _ in self._pending_adm + self._joining}
+        self._joining = []            # in the carry since _refresh_carry
         rem_start = {}
         for i in active_slots:
             req = self.slot_req[i]
@@ -2496,7 +2706,7 @@ class LLMEngine:
                                  if r.temperature > 0))
         vk = (path, flags) if ragged else (nbk, flags)
         decode = self._decode_cache.get(vk)
-        if decode is None:
+        if decode is None and self._held is None:
             # numerics gate baked per variant, like _prefill_fn (the key
             # stays ("ragged"|bucket, flags): a mid-run flag flip
             # instruments new variants only — docs/observability.md)
@@ -2564,24 +2774,41 @@ class LLMEngine:
             prep.attrs["slots"] = len(active_slots)
             prep.end()
         latent = self.model.cache_kind == "latent"
-        with trace_span("serving.decode", slots=len(active_slots),
-                        steps=self.decode_steps,
-                        walk_blocks=walk, kv_bytes=step_bytes,
-                        latent_bytes=step_bytes if latent else 0,
-                        # per-slot state a step reads and writes, of the
-                        # slots that move
-                        state_bytes=(self._state_bytes_per_slot
-                                     * len(active_slots)),
-                        # the true dispatched horizon (ragged: max real
-                        # length; bucketed: the ceiling) — matches the
-                        # serving_decode_prefix_bucket gauge, never the
-                        # full-width table shape
-                        prefix_bucket=bucket_tokens, **win_attrs,
-                        request_ids=[r.req_id for r in reqs]) as sp:
-            (toks, c_last, c_len, c_done, c_rem, c_key,
-             self.pools, stats) = decode(
-                self.params, c_last, c_len, c_done, c_rem, c_key, v_act,
-                tbl, self.pools, v_t, v_k, v_p, v_eos, *win_args)
+        held, self._held = self._held, None
+        if held is not None:
+            # ONE program for the step's last piece and the decode rows
+            # (``_launch_row``): its span is the piece's, its counts one set
+            toks, c_last, c_len, c_done, c_rem, c_key = self._launch_row(
+                held, (c_last, c_len, c_done, c_rem, c_key, v_act, tbl,
+                       v_t, v_k, v_p, v_eos, *win_args), flags,
+                decode_slots=len(active_slots), walk_blocks=walk,
+                kv_bytes=step_bytes, **win_attrs)
+            if held[0][5]:
+                # a final piece: read back with this record, in the carry
+                # and the decode mask from the next dispatch on
+                self._joining = self._pending_adm[-1:]
+                self._slots_dirty = True
+            stats = None              # on the piece's span already
+        else:
+            self._step_decodes["decode"] += 1
+            with trace_span("serving.decode", slots=len(active_slots),
+                            steps=self.decode_steps,
+                            walk_blocks=walk, kv_bytes=step_bytes,
+                            latent_bytes=step_bytes if latent else 0,
+                            # per-slot state a step reads and writes, of
+                            # the slots that move
+                            state_bytes=(self._state_bytes_per_slot
+                                         * len(active_slots)),
+                            # the true dispatched horizon (ragged: max real
+                            # length; bucketed: the ceiling) — matches the
+                            # serving_decode_prefix_bucket gauge, never the
+                            # full-width table shape
+                            prefix_bucket=bucket_tokens, **win_attrs,
+                            request_ids=[r.req_id for r in reqs]) as sp:
+                (toks, c_last, c_len, c_done, c_rem, c_key,
+                 self.pools, stats) = decode(
+                    self.params, c_last, c_len, c_done, c_rem, c_key, v_act,
+                    tbl, self.pools, v_t, v_k, v_p, v_eos, *win_args)
         self._carry = (c_last, c_len, c_done, c_rem, c_key)
         if stats is not None:
             self._pending_stats.append((stats, sp.attrs))
@@ -2973,6 +3200,11 @@ class LLMEngine:
         """What a step owes the registry and the request timelines once
         its work is done (the ``serving.telemetry`` span)."""
         _M_STEP_SECONDS.observe(dt)
+        # the step's programs: its decode rows rode a piece or ran alone
+        for program, n in self._step_decodes.items():
+            if n:
+                _M_DECODE_STEPS.inc(n, program=program)
+                self._step_decodes[program] = 0
         # the host's own share: the step less its waits on the device
         _M_STEP_HOST_SECONDS.observe(max(0.0, dt - self._wait_s))
         if emitted:
@@ -3029,6 +3261,7 @@ class LLMEngine:
             # freed are allocatable THIS step; staged payloads meet their
             # restore)
             self._offload_tick()
+        self._flush_held()        # left by a step that raised, if at all
         # one chunk per mid-prefill slot BEFORE admission/decode: the
         # chunk program and this step's decode wave share the step, so a
         # long prefill never monopolizes it (bounded TTFT for the slots
@@ -3057,6 +3290,7 @@ class LLMEngine:
             self._admit_phase()    # freed slots: refill before dispatching
         active = self._decode_slots()
         if not active:
+            self._flush_held()        # a piece and no decode rows to carry
             if self._inflight is not None:
                 emitted += self._process_inflight()
             return emitted
@@ -3066,6 +3300,7 @@ class LLMEngine:
             emitted += self._back_or_preempt()
             active = self._decode_slots()
             if not active:
+                self._flush_held()
                 return emitted
             self._refresh_carry(active)
             prev = self._dispatch_decode(active, prep)

@@ -262,13 +262,17 @@ def _lfm2_big_writes(text):
     scatter has another) nor a prefetch of a weight into the chip's fast
     memory in slices."""
     entry = _entry(text)
-    lies = re.compile(r"bf16\[(1,)?12289,16,1024\]\{(3,2,1,0|2,1,0):"
-                      r"T\(8,128\)\(2,1\)\}")
-    relaid = [f"{n} = {ty[:70]} {op}" for n, (ty, op, _) in entry.items()
-              if "12289,16,1024]" in ty and not lies.search(ty)]
-    return relaid + [
+    return _lfm2_relaid(entry) + [
         b for b in _weight_sized_writes(entry, (LFM2_NB, BS, 1024))
         if not b.endswith(_PREFETCH)]
+
+
+def _lfm2_relaid(entry):
+    """A pool in another layout than the parameter's."""
+    lies = re.compile(r"bf16\[(1,)?12289,16,1024\]\{(3,2,1,0|2,1,0):"
+                      r"T\(8,128\)\(2,1\)\}")
+    return [f"{n} = {ty[:70]} {op}" for n, (ty, op, _) in entry.items()
+            if "12289,16,1024]" in ty and not lies.search(ty)]
 
 
 def test_lfm2_decode_writes_no_weight_sized_array(topo, monkeypatch):
@@ -318,6 +322,61 @@ def test_lfm2_one_row_prefill_writes_no_weight_sized_array(topo, monkeypatch,
     assert "%lfm2_prefill_chunk" in text
     assert ("%lfm2_prefill_history" in text) == bool(history)
     assert _lfm2_big_writes(text) == []
+
+
+def _dec_operands(sds, N, table, ring=None):
+    """``_paged_prefill``'s ``dec``: the decode call's operands as shapes."""
+    dec = (sds((N,), I32), sds((N,), I32), sds((N,), jnp.bool_),
+           sds((N,), I32), sds((2,), jnp.uint32), sds((N,), jnp.bool_),
+           sds((N, table), I32), sds((N,), F32), sds((N,), I32),
+           sds((N,), F32), sds((N,), I32))
+    return dec + ((sds((N, ring), I32),) if ring else ())
+
+
+def _gmm_calls(text):
+    return [n for n, (_ty, op, _) in _entry(text).items()
+            if op == "custom-call" and n.startswith("gmm")]
+
+
+@pytest.mark.parametrize("history", [0, TABLE // 2],
+                         ids=["first", "continuing"])
+def test_lfm2_piece_with_the_decode_rows_is_one_pass_over_the_experts(
+        topo, monkeypatch, history):
+    """The ONE program of a step that has a piece (PR 36): a piece of 1024
+    tokens (the cell's bucket) and a decode step of the cell's 64 slots.
+    Both kinds' kernels are in it under their names, every expert layer has
+    ONE grouped-matmul pair over 1,088 x 4 pairs on tile boundaries (4,352 +
+    32 x 128 rows), and now that two write-backs share a program no pool is
+    re-laid out around either scatter, no weight-sized array is written."""
+    N = 64
+    model, params, pools, sds, moe_dispatch = _lfm2_shapes(topo, N)
+    monkeypatch.setattr(moe_dispatch, "_mosaic", lambda: True)
+    S = 1024
+    args = [params, sds((1, S), I32), sds((1, S // BS), I32),
+            sds((1,), I32), pools, sds((1,), F32), sds((1,), I32),
+            sds((1,), F32), sds((2,), jnp.uint32)]
+    if history:
+        args += [sds((1,), I32), sds((1, history), I32)]
+    text = jax.jit(functools.partial(
+        engine._paged_prefill, model=model, opts=ServeOpts(ragged=True),
+        sample_flags=GREEDY, prefix_nbk=history), donate_argnums=(4,)).lower(
+        *args, slot=sds((1,), I32),
+        dec=_dec_operands(sds, N, TABLE)).compile().as_text()
+    assert "%lfm2_prefill_chunk" in text and "%lfm2_ragged_walk" in text
+    assert ("%lfm2_prefill_history" in text) == bool(history)
+    entry = _entry(text)
+    gmm = _gmm_calls(text)
+    assert len(gmm) == 2 * 2, gmm            # gate|up and down, two layers
+    assert all("bf16[8448," in entry[n][0] for n in gmm), \
+        [entry[n][0] for n in gmm]
+    # (a piece of 1024 x 2048 is itself 4 MiB, the reader's weight size:
+    # what it writes of that size is read at a piece of 128, above)
+    assert _lfm2_relaid(entry) == []
+    stacks = {ty.split("{")[0] for ty, _op, _ in entry.values()} & {
+        "bf16[32,2048,3584]", "bf16[32,1792,2048]", "bf16[65536,2048]"}
+    assert stacks == set() or all(
+        op in _NO_WRITE + _PREFETCH for ty, op, _ in entry.values()
+        if ty.split("{")[0] in stacks)
 
 
 # -- the fourth family: window layers beside full ones ------------------------
@@ -428,13 +487,61 @@ def test_mellum_one_row_prefill_writes_no_weight_sized_array(
             if _MEL_POOL.search(ty) and not _MEL_LIES.search(ty)] == []
 
 
-# -- the three older families' programs are the parent's ----------------------
+@pytest.mark.parametrize("history", [0, MEL_TABLE],
+                         ids=["first", "continuing"])
+def test_mellum_piece_with_the_decode_rows_is_one_pass_over_the_experts(
+        topo, monkeypatch, history):
+    """The ONE program of a step that has a piece (PR 36) at the
+    ``repo-offline`` cell's shapes: a piece of 1024 tokens and a decode
+    step of 32 slots. Both kinds' walks and the piece's kernels are in it,
+    every layer has ONE grouped-matmul pair over 1,056 x 8 = 8,448 pairs in
+    ONE pass (8,448 + 64 x 128 rows: over the old bound of 8,192 pairs), and
+    neither kind's pool is re-laid out around the two write-backs."""
+    N = 32
+    model, params, pools, sds, moe_dispatch = _mellum_shapes(topo)
+    monkeypatch.setattr(moe_dispatch, "_mosaic", lambda: True)
+    S = 1024
+    args = [params, sds((1, S), I32), sds((1, S // BS), I32),
+            sds((1,), I32), pools, sds((1,), F32), sds((1,), I32),
+            sds((1,), F32), sds((2,), jnp.uint32)]
+    win = {"blk_ids": sds((1, S // BS), I32)}
+    if history:
+        args += [sds((1,), I32), sds((1, history), I32)]
+        win.update(ctx_tbl=sds((1, MEL_RING), I32),
+                   ctx_start=sds((1,), I32))
+    text = jax.jit(functools.partial(
+        engine._paged_prefill, model=model, opts=ServeOpts(ragged=True),
+        sample_flags=GREEDY, prefix_nbk=history), donate_argnums=(4,)).lower(
+        *args, win=win,
+        dec=_dec_operands(sds, N, MEL_TABLE, MEL_RING)).compile().as_text()
+    assert "%mellum_prefill_chunk" in text
+    assert text.count("%mellum_walk_full") >= 1
+    assert text.count("%mellum_walk_window") >= 3
+    for name in ("%mellum_history_full", "%mellum_history_window"):
+        assert (name in text) == bool(history)
+    entry = _entry(text)
+    gmm = _gmm_calls(text)
+    assert len(gmm) == 2 * 4, gmm            # gate|up and down, four layers
+    assert all("bf16[16640," in entry[n][0] for n in gmm), \
+        [entry[n][0] for n in gmm]
+    assert [n for n, (ty, _op, _) in entry.items()
+            if _MEL_POOL.search(ty) and not _MEL_LIES.search(ty)] == []
+
+
+# -- the families' programs are the parent's ----------------------------------
 # sha256 (first 16 hex digits) of the programs' jaxpr text, kernels lowered,
 # with source positions and addresses taken out, read on the PARENT of PR 35
 # (8908c52) and equal on its change: the walk's start, the flash kernel's
 # band and the engine's window operands are absent operands for a model of
 # one kind, so its programs are the parent's to the letter. A later PR that
 # means to change one of these programs prints the new text's hash here.
+# PR 36 (a step's last piece carries the decode rows): every hash above the
+# ``piece+rows`` entries is its parent's (c8c7cc0), Mellum2's read there for
+# the first time: the layers' split into ``prefill_mix`` / ``decode_mix`` /
+# ``ffn`` keeps the order of every operation of the lone programs, and
+# ``_paged_prefill`` without ``dec`` is the program it was. The
+# ``piece+rows`` entries are the ONE program of a step that has a piece (a
+# history's operands always: the engine runs no other form), new in PR 36.
 PARENT_PROGRAMS = {
     "dense.decode": "7921a0ad28675c6f", "dense.prefill0": "1cad0efaa529c317",
     "dense.prefill16": "53e7a3a2e58a42bd",
@@ -442,7 +549,13 @@ PARENT_PROGRAMS = {
     "latent.prefill0": "6ba1697f08295a2c",
     "latent.prefill16": "c000e881670864b6",
     "lfm2.decode": "b4d382c4e1eff7be", "lfm2.prefill0": "7e7adb40b921895e",
-    "lfm2.prefill16": "112dd12a4b2ea3e4"}
+    "lfm2.prefill16": "112dd12a4b2ea3e4",
+    "mellum.decode": "e0f446c50b3116c4",
+    "mellum.prefill0": "48f89d29978be3e1",
+    "mellum.prefill16": "b975e92f011023d6",
+    "latent.piece+rows16": "d60e2a4ce78df50e",
+    "lfm2.piece+rows16": "6e654ec074807ea7",
+    "mellum.piece+rows16": "5fb9e04168110144"}
 
 
 def _small_family(name):
@@ -459,7 +572,8 @@ def _small_family(name):
             lambda: jax.tree_util.tree_map(
                 lambda a: a.astype(BF16),
                 llama.init_params(cfg, jax.random.PRNGKey(0))))
-    family = {"latent": "deepseek_v2", "lfm2": "lfm2_moe"}[name]
+    family = {"latent": "deepseek_v2", "lfm2": "lfm2_moe",
+              "mellum": "mellum"}[name]
     fam = manifest.load_family(family)
     man = manifest.Manifest()
     doc = next(d for d in (man.config(c["name"]) for c in man.doc["configs"])
@@ -471,37 +585,47 @@ def _small_family(name):
 
 
 @pytest.mark.parametrize("program", sorted(PARENT_PROGRAMS))
-def test_the_older_families_programs_are_the_parents(program, monkeypatch):
+def test_the_families_programs_are_the_parents(program, monkeypatch):
     import hashlib
 
     family, which = program.split(".")
     moe_dispatch = importlib.import_module("paddle_tpu.kernels.moe_dispatch")
     monkeypatch.setattr(moe_dispatch, "_mosaic", lambda: True)
     model, params = _small_family(family)
-    N, NB_, MB = 4, 65, 16
+    N, NB_, MB, RING = 4, 65, 16, 3
     sds = jax.ShapeDtypeStruct
+    window = bool(getattr(model, "window_entries", ()))
     pools = jax.eval_shape(lambda: {
-        **model.make_pools(NB_, BS),
+        **(model.make_pools(NB_, BS, nb_window=N * RING + 1) if window
+           else model.make_pools(NB_, BS)),
         **(model.make_state(N) if model.state_entries else {})})
+    dec = _dec_operands(lambda shape, dt: sds(shape, dt), N, MB,
+                        RING if window else None)
     if which == "decode":
         text = jax.make_jaxpr(functools.partial(
             engine._paged_decode, model=model, n_steps=1,
             opts=ServeOpts(ragged=True), sample_flags=GREEDY))(
-            params, sds((N,), I32), sds((N,), I32), sds((N,), jnp.bool_),
-            sds((N,), I32), sds((2,), jnp.uint32), sds((N,), jnp.bool_),
-            sds((N, MB), I32), pools, sds((N,), F32), sds((N,), I32),
-            sds((N,), F32), sds((N,), I32))
+            params, *dec[:7], pools, *dec[7:])
     else:
-        hist, S = int(which[len("prefill"):]), 128
+        rows = which.startswith("piece+rows")
+        hist, S = int(which[len("piece+rows" if rows else "prefill"):]), 128
         args = [params, sds((1, S), I32), sds((1, S // BS), I32),
                 sds((1,), I32), pools, sds((1,), F32), sds((1,), I32),
                 sds((1,), F32), sds((2,), jnp.uint32)]
         args += [sds((1,), I32), sds((1, hist), I32)] if hist else []
+        kw = {}
         if model.state_entries:
-            args += ([] if hist else [None, None]) + [sds((1,), I32)]
+            kw["slot"] = sds((1,), I32)
+        if window:
+            kw["win"] = {"blk_ids": sds((1, S // BS), I32)}
+            if hist:
+                kw["win"].update(ctx_tbl=sds((1, RING), I32),
+                                 ctx_start=sds((1,), I32))
+        if rows:
+            kw["dec"] = dec
         text = jax.make_jaxpr(functools.partial(
             engine._paged_prefill, model=model, opts=ServeOpts(ragged=True),
-            sample_flags=GREEDY, prefix_nbk=hist))(*args)
+            sample_flags=GREEDY, prefix_nbk=hist))(*args, **kw)
     text = re.sub(r" at [^\s\]\)]+:\d+", "", str(text))
     text = re.sub(r"0x[0-9a-f]+", "0x", text)
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == \

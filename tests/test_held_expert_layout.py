@@ -117,7 +117,7 @@ def test_the_aligned_layout_equals_the_dense_sum(name):
     gates = jnp.asarray(rng.uniform(0.1, 1.0, size=(T, k)), F32)
     e_gu, e_down = _weights(experts=experts)
     idx_j, valid_j = jnp.asarray(idx, jnp.int32), jnp.asarray(valid)
-    M = T * k if T * k <= md._HELD_PASS_ROWS else T
+    M = T * k if T <= md._HELD_PASS_TOKENS else T
     assert M > md._HELD_SMALL_ROWS                     # the prefill regime
     with jax.default_matmul_precision("highest"):
         y, counts = jax.jit(md.held_expert_ffn, static_argnums=6)(
@@ -215,6 +215,38 @@ def test_a_piece_lays_its_experts_out_on_tile_boundaries(monkeypatch):
     assert ("gather", (1024, 4, 2048)) in seen
     assert not any(name.startswith("scatter") and shape == (1024, 2048)
                    for name, shape in seen)
+
+
+@pytest.mark.parametrize("T,k,experts,h,f", [
+    (1056, 8, 64, 2304, 896), (1088, 4, 32, 2048, 1792),
+    (1048, 6, 20, 5120, 1536)],
+    ids=["mellum2-1024+32", "lfm2-1024+64", "deepseek-v2-1024+24"])
+def test_a_piece_with_the_decode_rows_is_one_aligned_pass(monkeypatch, T, k,
+                                                          experts, h, f):
+    """A piece of 1,024 tokens with the engine's slots riding in its
+    program: the one-pass bound is on the call's TOKENS, so Mellum2's 1,056
+    x 8 = 8,448 pairs (over the old bound of 8,192 pairs, which stood
+    exactly at its piece) still gather at once, on tile boundaries, under
+    no ``cond``; and each token takes its k rows of the result back."""
+    monkeypatch.setattr(md, "_mosaic", lambda: True)
+    seen = _operations(lambda *a: md.held_expert_ffn(*a, 0),
+                       *_chip_specs(T, k, experts, h, f))
+    rows = -(-T * k // 128) * 128 + experts * 128
+    assert {shape[0] for name, shape in seen if name == "gather"
+            and len(shape) == 2 and shape[1] == h} == {rows}
+    assert ("gather", (T, k, h)) in seen
+    assert not any(name == "cond" for name, _ in seen)
+    assert not any(name.startswith("scatter") and shape == (T, h)
+                   for name, shape in seen)
+
+
+def test_a_decode_step_of_sixty_four_experts_stays_packed(monkeypatch):
+    """Mellum2's decode call, 32 slots x 8: 256 packed rows, as LFM2's 64
+    x 4 and DeepSeek-V2's 24 x 6 (above)."""
+    monkeypatch.setattr(md, "_mosaic", lambda: True)
+    assert _gathered_rows(lambda *a: md.held_expert_ffn(*a, 0),
+                          *_chip_specs(32, 8, 64, 2304, 896),
+                          width=2304) == {256}
 
 
 def test_the_engine_carries_the_tiles_to_a_counter_and_the_spans():
