@@ -299,6 +299,31 @@ CATALOG = {
         "decode steps of the slots by the program that ran them: "
         "program=decode (their own) or piece (they rode with a prefill "
         "piece, so the step streamed the row-wise weights once)"),
+    "serving_device_starved_seconds_total": (
+        "counter", ("phase",),
+        "seconds in which the engine KNEW the device had nothing of its "
+        "own queued (the newest program it dispatched had been read back, "
+        "the next dispatching call had not returned) while it held "
+        "requests, by the step phase the host was in, under the spans' "
+        "names (serving.readback, serving.admit, serving.prefill_build, "
+        "serving.decode_prepare, serving.prefill, serving.decode, "
+        "serving.step, between_steps, ...); a lower bound on the device's "
+        "idle time: the copy inside a readback, launch latency and the "
+        "seams between operations are not in it"),
+    "serving_engine_no_work_seconds_total": (
+        "counter", (),
+        "seconds in which the device was known empty and the engine held "
+        "no request at all (no queue, no slot): spare capacity, not "
+        "starvation; flushed by the next step that runs"),
+    "serving_pipeline_drains_total": (
+        "counter", ("reason",),
+        "times the step thread read the in-flight decode record back "
+        "BEFORE dispatching the next program, by why: may_finish (a slot "
+        "of the record may end inside it: last token, an eos to trip on, "
+        "a joining piece's first token, or the slot is gone), spec_wave, "
+        "no_active (nothing decodes), backing (the pool is short and "
+        "preemption needs exact lengths), run_end (a defensive drain "
+        "outside a step)"),
     # -- fleet observability (observability.fleet, r17) --------------------
     "serving_fleet_slo_attainment": (
         "gauge", ("replica", "slo"),

@@ -166,6 +166,9 @@ _M_WINDOW_SLOT_BYTES = _instrument("serving_window_bytes_per_slot")
 _M_WINDOW_RECYCLED = _instrument("serving_window_blocks_recycled_total")
 _M_PREFILL_PROGRAMS = _instrument("serving_prefill_programs_total")
 _M_DECODE_STEPS = _instrument("serving_decode_steps_total")
+_M_STARVED = _instrument("serving_device_starved_seconds_total")
+_M_NO_WORK = _instrument("serving_engine_no_work_seconds_total")
+_M_DRAINS = _instrument("serving_pipeline_drains_total")
 
 
 @dataclasses.dataclass
@@ -1028,6 +1031,22 @@ class LLMEngine:
         # counts (an expert layer's routed/assigned pairs) are not read
         # yet; attached to the next dispatch record like _pending_adm
         self._pending_stats: List = []
+        # -- the starved-time ledger (``_device_get``) ---------------------
+        # programs the step thread has dispatched, and of those the newest
+        # whose first token waits in ``_pending_adm``; a record remembers
+        # the count at its making (``seq``)
+        self._seq = 0
+        self._adm_seq = 0
+        # since when the device is known empty and unaccounted for (None:
+        # something this engine dispatched may still run), the phase the
+        # step thread is in, and whether the engine held no request then
+        self._starved_t: Optional[float] = None
+        self._phase = "between_steps"
+        self._idle = False
+        # seconds accrued since the last flush (``_step_telemetry``)
+        self._starved: Dict[str, float] = {}
+        self._no_work_s = 0.0
+        self._step_drain: Optional[str] = None   # this step's first drain
         # observability: add_request wall time per req awaiting its first
         # host-visible token (TTFT); entries die with the request
         self._obs_t_add: Dict[int, float] = {}
@@ -1236,6 +1255,8 @@ class LLMEngine:
                                   shed_reason=reason)
                 raise ShedError(reason, rid)
         self.queue.append(req)
+        if self._starved_t is not None:
+            self._mark(self._phase)     # an empty engine has work again
         if req.t_deadline is not None:
             self._deadline_live += 1
         if _obs.enabled():
@@ -1257,7 +1278,7 @@ class LLMEngine:
         while self.has_work():
             self.step()
         if self._inflight is not None:      # defensive: step() drains first
-            self._process_inflight()
+            self._process_inflight("run_end")
         self.drain_offload()                # land stragglers: in_flight→0
         return self.results
 
@@ -1676,7 +1697,8 @@ class LLMEngine:
         emitted = []
         if self._pending_adm:
             adm, self._pending_adm = self._pending_adm, []
-            emitted += self._flush_adm(adm)
+            emitted += self._flush_adm(adm, self._adm_seq)
+            self._mark("serving.step")
         for slot in self._decode_slots():
             if self.slot_req[slot] is not None:
                 self._handoff(slot)
@@ -1915,6 +1937,7 @@ class LLMEngine:
         swap tier is bypassed). The device carry is rebuilt from host
         state at the next dispatch."""
         self._inflight = None
+        self._starved_t = None       # what was dispatched may still run
         self._pending_adm = []
         self._joining = []
         self._held = None
@@ -2127,10 +2150,12 @@ class LLMEngine:
         nothing queued and no chunk due is not worth a span."""
         if not self.queue and not (chunks and self._chunks):
             return
+        self._mark("serving.admit")
         with trace_span("serving.admit", queue=len(self.queue)) as sp:
             if chunks:
                 self._advance_chunks()
             sp.attrs["wave"] = self._admit()
+        self._mark("serving.step")
 
     def _advance_chunks(self):
         """Feed every mid-prefill slot its next chunk — ONE chunk per
@@ -2196,9 +2221,11 @@ class LLMEngine:
         kw, span attrs)``. ``wave``: rows dispatched with it in this
         step."""
         slot, req, ctx, hist, piece, final = row
+        self._mark("serving.prefill_build")
         with trace_span("serving.prefill_build", wave=wave) as sp:
             bucket, flags, pnbk, args = self._prefill_operands(row)
             sp.attrs.update(bucket=bucket, batch=1)
+        self._mark("serving.admit")
         # tokens: the row's real tokens in THIS program; start: what of
         # the row is already cached (a chunk is not a whole prompt)
         attrs = dict(bucket=bucket, batch=1, wave=wave,
@@ -2246,9 +2273,11 @@ class LLMEngine:
             carried = "rows" if dec_attrs["decode_slots"] else "none"
             _M_PREFILL_PROGRAMS.inc(carried=carried)
             self._step_decodes["piece"] += carried == "rows"
+        self._mark("serving.prefill")
         with trace_span("serving.prefill", **attrs, **dec_attrs) as sp:
             tok_dev, *rest, self.pools, stats = self._prefill_fn(
                 bucket, flags, pnbk)(*args, **kw)
+            seq = self._dispatched()
         if stats is not None:
             # read back with the next decode record's tokens
             self._pending_stats.append((stats, sp.attrs))
@@ -2266,7 +2295,8 @@ class LLMEngine:
                             request_ids=[req.req_id]):
                 _junk, self.pools, _st = self._prefill_fn(
                     bucket, flags, pnbk, draft=True)(*dargs)
-        self._prefill_dispatched(row, bucket, tok_dev)
+                self._dispatched()
+        self._prefill_dispatched(row, bucket, tok_dev, seq)
         return rest
 
     def _idle_rows(self):
@@ -2339,9 +2369,10 @@ class LLMEngine:
             win["ctx_start"] = jnp.asarray([start], jnp.int32)
         return win
 
-    def _prefill_dispatched(self, row, bucket, tok_dev):
+    def _prefill_dispatched(self, row, bucket, tok_dev, seq: int):
         """Host bookkeeping of a dispatched row: lengths, the pending
-        first token, chunk state, timelines, prefix-cache adoption."""
+        first token (``seq``: its program's number), chunk state,
+        timelines, prefix-cache adoption."""
         slot, req, ctx, hist, piece, final = row
         self.lengths[slot] = hist + piece
         if self.win is not None:
@@ -2354,6 +2385,7 @@ class LLMEngine:
             # the row's [1] first-token array: the readback fetches all
             # of a record's arrays in one call
             self._pending_adm.append((slot, req.req_id, tok_dev))
+            self._adm_seq = seq
         else:
             if slot not in self._chunks:
                 self._slots_dirty = True   # leaves the decode mask
@@ -2483,7 +2515,7 @@ class LLMEngine:
                     break
                 if self._inflight is not None:
                     # exact lengths before evicting anyone
-                    emitted += self._process_inflight()
+                    emitted += self._process_inflight("backing")
                     if self.slot_req[slot] is None:
                         break
                     continue
@@ -2791,6 +2823,7 @@ class LLMEngine:
             stats = None              # on the piece's span already
         else:
             self._step_decodes["decode"] += 1
+            self._mark("serving.decode")
             with trace_span("serving.decode", slots=len(active_slots),
                             steps=self.decode_steps,
                             walk_blocks=walk, kv_bytes=step_bytes,
@@ -2809,6 +2842,7 @@ class LLMEngine:
                  self.pools, stats) = decode(
                     self.params, c_last, c_len, c_done, c_rem, c_key, v_act,
                     tbl, self.pools, v_t, v_k, v_p, v_eos, *win_args)
+                self._dispatched()
         self._carry = (c_last, c_len, c_done, c_rem, c_key)
         if stats is not None:
             self._pending_stats.append((stats, sp.attrs))
@@ -2821,6 +2855,9 @@ class LLMEngine:
                          for i in active_slots],
             "adm": self._pending_adm,
             "rem_start": rem_start,
+            # the newest program dispatched: once this record is back and
+            # the count has not moved, the device is empty (_device_get)
+            "seq": self._seq,
         }
         self._pending_adm = []
         self._pending_stats = []
@@ -2918,7 +2955,8 @@ class LLMEngine:
             adm, self._pending_adm = self._pending_adm, []
             with guarded("serving-spec-readback"), \
                     trace_span("serving.readback"):
-                emitted += self._flush_adm(adm)
+                emitted += self._flush_adm(adm, self._adm_seq)
+            self._mark("serving.step")
         # swap-in carry lanes are host-known state; the spec wave reads
         # host state directly and invalidates the chained device carry
         self._pending_swapin = []
@@ -2970,6 +3008,7 @@ class LLMEngine:
         act_j = jnp.asarray(act)
         rids = [self.slot_req[i].req_id for i in active]
         draft_fn = self._spec_draft_fn(path)
+        self._mark("serving.spec_draft")
         with trace_span("serving.spec_draft", slots=len(active), k=k,
                         request_ids=rids):
             (demitted, _dl, _dn, _dd, _db, _dk, self.pools,
@@ -2979,12 +3018,14 @@ class LLMEngine:
                 tbl_d, self.pools, jnp.zeros(N, jnp.float32),
                 jnp.zeros(N, jnp.int32), jnp.ones(N, jnp.float32),
                 jnp.full(N, -1, jnp.int32))
+            self._dispatched()
         verify_fn = self._spec_verify_fn(nbk)
         with trace_span("serving.spec_verify", slots=len(active), k=k,
                         prefix_bucket=nbk * self.bs, request_ids=rids):
             vtoks, self.pools = verify_fn(
                 self.params, tbl_v, last_j, demitted, lens_j, act_j,
                 self.pools)
+            seq = self._dispatched()
         if self.injector is not None and \
                 self.injector.fires("spec_verify_fail", self._step_idx):
             # chaos surface: a crash between the verify dispatch and
@@ -2999,7 +3040,7 @@ class LLMEngine:
         with guarded("serving-spec-readback"), \
                 trace_span("serving.readback"):
             d_host, v_host = self._device_get(
-                (demitted, vtoks))                  # [k, N], [N, k+1]
+                (demitted, vtoks), seq)             # [k, N], [N, k+1]
         wave_prop = wave_acc = wave_commit = 0
         for i in active:
             req = self.slot_req[i]
@@ -3027,6 +3068,7 @@ class LLMEngine:
                 self._step_emitted.append((rid, tok))
                 if self._emit(i, tok):
                     break                   # eos/budget mid-wave
+        self._mark("serving.step")      # the commits were the readback's
         self.spec_waves += 1
         self.spec_verify_calls += 1
         self.spec_draft_steps += k
@@ -3076,26 +3118,86 @@ class LLMEngine:
             raise SimulatedCrash(
                 f"injected readback failure at serving step "
                 f"{self._step_idx}")
+        outer = self._phase
         with guarded("serving-decode-readback"), \
                 trace_span("serving.readback"):
-            return self._process_guarded(rec)
+            emitted = self._process_guarded(rec)
+        self._mark(outer)
+        return emitted
 
-    def _device_get(self, tree):
+    def _device_get(self, tree, seq: Optional[int] = None):
         """The engine's blocking host sync: ``tree``'s arrays on the
         host. The wait is a ``serving.readback_wait`` span of its own
         inside ``serving.readback`` and adds to ``_wait_s``, which
-        ``serving_step_host_seconds`` takes off the step's wall time."""
+        ``serving_step_host_seconds`` takes off the step's wall time.
+
+        It is also where the starved-time ledger starts. The step thread
+        numbers every compiled program it dispatches (``_dispatched``) and
+        programs chain through the donated pools, so they end in order:
+        when the arrays of program ``seq`` are here and ``seq`` is still
+        the newest dispatched, the device has nothing of this engine's
+        left to run, and stays so until the next dispatching call returns.
+        A readback that returns while a later program is in flight (the
+        pipelined case; a drain behind a lone piece dispatched earlier in
+        the step) starts nothing. From then on the time goes to the phase
+        the step thread is in (``_mark``), under the spans' names, and
+        while the engine holds no request to
+        ``serving_engine_no_work_seconds_total`` instead; a step's total
+        closes its ``serving.step`` span as ``starved_ms`` / ``starved``
+        and ``_step_telemetry`` flushes it to
+        ``serving_device_starved_seconds_total{phase}``.
+
+        A LOWER bound on the trace's idle time by construction. It cannot
+        see: the device-to-host copy inside the wait after the last
+        operation ended, the launch latency after a dispatching call
+        returned, the seams between operations, a device that ran dry
+        behind a program dispatched earlier in the step. Not numbered,
+        because they run for microseconds or in no benchmarked shape: the
+        carry's ``_apply_admissions`` and the swap tier's gathers and
+        scatters (while those run the ledger reads a little high)."""
         with trace_span("serving.readback_wait") as sp:
             host = jax.device_get(tree)
         self._wait_s += sp.seconds
+        if seq == self._seq and self._starved_t is None and _obs.enabled():
+            self._starved_t = time.perf_counter()
+            self._idle = False       # it held this record's requests
+            self._phase = "serving.readback"
         return host
 
-    def _flush_adm(self, adm):
+    def _dispatched(self) -> int:
+        """A dispatching call just returned: the program's number, and
+        the end of a starved stretch if one was open."""
+        self._seq += 1
+        if self._starved_t is not None:
+            self._mark(self._phase)
+            self._starved_t = None
+        return self._seq
+
+    def _mark(self, phase: str) -> None:
+        """The step thread enters ``phase``. While the device is known
+        empty, the time since the last mark goes to the phase it leaves
+        (or to no work, if the engine held no request through it): one
+        clock read and one add. Otherwise one attribute read."""
+        t = self._starved_t
+        if t is not None:
+            now = time.perf_counter()
+            if self._idle:
+                self._no_work_s += now - t
+            else:
+                self._starved[self._phase] = \
+                    self._starved.get(self._phase, 0.0) + (now - t)
+            self._starved_t = now
+            # has_work(), cheaper: admit_order holds the slots in use
+            self._idle = not (self.queue or self.admit_order)
+        self._phase = phase
+
+    def _flush_adm(self, adm, seq: Optional[int] = None):
         """Read back a list of pending-admission first tokens
         ((slot, rid, [1] token array) tuples) and commit them host-side
-        — ONE readback for all of them, not one per admission."""
+        — ONE readback for all of them, not one per admission. ``seq``:
+        the newest program among them, where no record carries them."""
         emitted = []
-        host = self._device_get([arr for _, _, arr in adm])
+        host = self._device_get([arr for _, _, arr in adm], seq)
         for (slot, rid, _), h in zip(adm, host):
             req = self.slot_req[slot]
             if req is None or req.req_id != rid:
@@ -3112,15 +3214,16 @@ class LLMEngine:
     def _process_guarded(self, rec):
         emitted = []
         if rec["adm"]:
+            # first tokens of programs BEFORE the record's newest: no seq
             emitted += self._flush_adm(rec["adm"])
         stats = rec.get("stats") or []
         if stats:
             # one blocking sync for the tokens and the counts together
             toks_host, stats_host = self._device_get(
-                (rec["toks"], [a for a, _ in stats]))
+                (rec["toks"], [a for a, _ in stats]), rec["seq"])
             self._note_stats(stats_host, [at for _, at in stats])
         else:
-            toks_host = self._device_get(rec["toks"])        # [K, N]
+            toks_host = self._device_get(rec["toks"], rec["seq"])   # [K, N]
         for slot, rid in rec["snapshot"]:
             req = self.slot_req[slot]
             if req is None or req.req_id != rid:
@@ -3159,8 +3262,16 @@ class LLMEngine:
             if assigned:
                 _M_MOE_LOAD.set(fullest / assigned)
 
-    def _process_inflight(self):
+    def _process_inflight(self, reason: str = "run_end"):
+        """Drain the depth-1 pipeline: read the in-flight record back
+        with nothing dispatched behind it, counted by why
+        (``serving_pipeline_drains_total{reason}``; the step's first is
+        its span's ``drain``)."""
         rec, self._inflight = self._inflight, None
+        if _obs.enabled():
+            _M_DRAINS.inc(reason=reason)
+            if self._step_drain is None:
+                self._step_drain = reason
         return self._process(rec)
 
     def step(self):
@@ -3175,8 +3286,10 @@ class LLMEngine:
         (decode_steps tokens per slot).
 
         Observability (FLAGS_obs_enabled): each call lands a
-        ``serving.step`` span (prefill/decode/readback nested inside),
-        a step-duration + tokens/sec observation, TTFT (with a
+        ``serving.step`` span (prefill/decode/readback nested inside;
+        it closes with ``starved_ms`` / ``starved`` / ``drain`` where the
+        device was known empty in it or the pipeline drained:
+        ``_device_get``), a step-duration + tokens/sec observation, TTFT (with a
         request_id exemplar) for requests whose first token became
         visible, a per-request decode tick on the timeline, and the
         queue/slot/KV-pool gauges. Disabled, this wrapper costs one
@@ -3188,9 +3301,18 @@ class LLMEngine:
         if not _obs.enabled():
             return self._step_inner()
         self._wait_s = 0.0
+        self._step_drain = None
         t0 = time.perf_counter()
-        with trace_span("serving.step"):
+        with trace_span("serving.step") as sp:
+            self._mark("serving.step")
             emitted = self._step_inner()
+            self._mark("between_steps")
+            if self._starved:
+                # with what accrued between steps since the last flush
+                ms = {p: 1e3 * s for p, s in self._starved.items()}
+                sp.attrs.update(starved_ms=sum(ms.values()), starved=ms)
+            if self._step_drain is not None:
+                sp.attrs["drain"] = self._step_drain
         now = time.perf_counter()
         with trace_span("serving.telemetry"):
             self._step_telemetry(emitted, now, now - t0)
@@ -3205,6 +3327,13 @@ class LLMEngine:
             if n:
                 _M_DECODE_STEPS.inc(n, program=program)
                 self._step_decodes[program] = 0
+        # the starved-time ledger since the last step's flush
+        for phase, s in self._starved.items():
+            _M_STARVED.inc(s, phase=phase)
+        self._starved.clear()
+        if self._no_work_s:
+            _M_NO_WORK.inc(self._no_work_s)
+            self._no_work_s = 0.0
         # the host's own share: the step less its waits on the device
         _M_STEP_HOST_SECONDS.observe(max(0.0, dt - self._wait_s))
         if emitted:
@@ -3252,6 +3381,7 @@ class LLMEngine:
         # admission: an injected squeeze shapes this step's block
         # budget, and an expired or disconnected request must not
         # occupy the slot a live one could take
+        self._mark("serving.housekeeping")
         with trace_span("serving.housekeeping"):
             self._apply_faults()
             self._expire_deadlines()
@@ -3261,6 +3391,7 @@ class LLMEngine:
             # freed are allocatable THIS step; staged payloads meet their
             # restore)
             self._offload_tick()
+        self._mark("serving.step")
         self._flush_held()        # left by a step that raised, if at all
         # one chunk per mid-prefill slot BEFORE admission/decode: the
         # chunk program and this step's decode wave share the step, so a
@@ -3280,22 +3411,23 @@ class LLMEngine:
                 # exact), re-admit into any slots that freed, then run
                 # draft → verify → commit
                 if self._inflight is not None:
-                    emitted += self._process_inflight()
+                    emitted += self._process_inflight("spec_wave")
                     self._admit_phase()
                     active = self._decode_slots()
                 if active and self._spec_eligible(active):
                     return emitted + self._spec_wave(active)
         if self._inflight is not None and not self._spec_safe():
-            emitted += self._process_inflight()
+            emitted += self._process_inflight("may_finish")
             self._admit_phase()    # freed slots: refill before dispatching
         active = self._decode_slots()
         if not active:
             self._flush_held()        # a piece and no decode rows to carry
             if self._inflight is not None:
-                emitted += self._process_inflight()
+                emitted += self._process_inflight("no_active")
             return emitted
         # everything the host does for this decode call before it is
         # enqueued; _dispatch_decode ends the span right at the call
+        self._mark("serving.decode_prepare")
         with trace_span("serving.decode_prepare") as prep:
             emitted += self._back_or_preempt()
             active = self._decode_slots()

@@ -510,6 +510,7 @@ def test_integration_p99_exemplar_resolves_slow_request_over_http(
         eng.add_request(rng.integers(1, 64, size=n).tolist(),
                         max_new_tokens=k)
     eng.step()
+    eng.step()
     eng.add_request(rng.integers(1, 64, size=5).tolist(),
                     max_new_tokens=4)
     eng.run()
@@ -520,6 +521,12 @@ def test_integration_p99_exemplar_resolves_slow_request_over_http(
     fast = [eng.add_request(rng.integers(1, 64, size=n).tolist(),
                             max_new_tokens=k)
             for n, k in ((3, 4), (7, 6))]
+    eng.step()
+    # the token stream lags the chip by one call: only the SECOND step
+    # makes the fast requests' first tokens host-visible. Without it their
+    # TTFT would hold the sleep below too (257.47 ms against the slow
+    # request's 257.82 when this was found), and the ranking at the end
+    # would turn on a third of a millisecond
     eng.step()
     # ...then the seeded-slow request queues behind them and waits
     slow = eng.add_request(rng.integers(1, 64, size=5).tolist(),
