@@ -319,11 +319,19 @@ CATALOG = {
         "counter", ("reason",),
         "times the step thread read the in-flight decode record back "
         "BEFORE dispatching the next program, by why: may_finish (a slot "
-        "of the record may end inside it: last token, an eos to trip on, "
-        "a joining piece's first token, or the slot is gone), spec_wave, "
-        "no_active (nothing decodes), backing (the pool is short and "
-        "preemption needs exact lengths), run_end (a defensive drain "
-        "outside a step)"),
+        "of the record may end inside it at a step the host cannot count, "
+        "an eos to trip on; or the slot is gone; or its counted ends "
+        "leave too few lanes to run a call for, every lane ending "
+        "included), spec_wave, no_active (nothing decodes), backing (the "
+        "pool is short and preemption needs exact lengths), run_end (a "
+        "defensive drain outside a step)"),
+    "serving_counted_finishes_total": (
+        "counter", ("drained",),
+        "lanes whose last token the host counted ahead (a budget that "
+        "ends inside the in-flight call, no eos_token_id): drained=no, "
+        "the record was read back BEHIND the next dispatch and the lane "
+        "took no part in it; drained=yes, it was read back before one "
+        "all the same (serving_pipeline_drains_total says why)"),
     # -- fleet observability (observability.fleet, r17) --------------------
     "serving_fleet_slo_attainment": (
         "gauge", ("replica", "slo"),

@@ -169,6 +169,7 @@ _M_DECODE_STEPS = _instrument("serving_decode_steps_total")
 _M_STARVED = _instrument("serving_device_starved_seconds_total")
 _M_NO_WORK = _instrument("serving_engine_no_work_seconds_total")
 _M_DRAINS = _instrument("serving_pipeline_drains_total")
+_M_COUNTED_ENDS = _instrument("serving_counted_finishes_total")
 
 
 @dataclasses.dataclass
@@ -808,12 +809,13 @@ class LLMEngine:
         entirely (no draft pools, byte-identical engine).
 
         Pipelining caveat: the engine dispatches call k+1 before reading
-        call k's tokens only when every in-flight slot is GUARANTEED
-        alive through call k (``_spec_safe``) — which requires
-        ``eos_token_id`` unset, since an eos can finish a slot at any
-        step. Workloads where every request carries an eos run with a
-        synchronous readback between decode calls instead;
-        ``decode_steps`` remains the amortization lever there.
+        call k's tokens only when nothing in call k can surprise the
+        host (``_spec_safe``) — which requires ``eos_token_id`` unset,
+        since an eos can finish a slot at any step (a budget's end can
+        be counted ahead and drains nothing). Workloads where every
+        request carries an eos run with a synchronous readback between
+        decode calls instead; ``decode_steps`` remains the amortization
+        lever there.
         Speculative waves are the exception either way: acceptance is a
         host decision, so a spec wave DRAINS the pipeline and syncs
         once per wave — the draft/verify pair replaces multi-step
@@ -1047,6 +1049,7 @@ class LLMEngine:
         self._starved: Dict[str, float] = {}
         self._no_work_s = 0.0
         self._step_drain: Optional[str] = None   # this step's first drain
+        self._step_carried = 0    # counted ends this step read behind a call
         # observability: add_request wall time per req awaiting its first
         # host-visible token (TTFT); entries die with the request
         self._obs_t_add: Dict[int, float] = {}
@@ -2460,35 +2463,66 @@ class LLMEngine:
         """Slots the decode call covers: active and not mid-chunked-
         prefill (a chunking slot joins once its final chunk lands; the
         slot of a piece held for this step's decode dispatch joins at the
-        next)."""
+        next), less the lanes that the in-flight record counted to their
+        end (its ``ends``): no block is backed for them, no record behind
+        it names them and no span counts them."""
         held = None if self._held is None else self._held[0][0]
+        # a lane whose last token the unread call emits takes no part in
+        # the call behind it (``_spec_safe``)
+        ends = () if self._inflight is None else self._inflight["ends"]
         return [i for i in range(self.N) if self.slot_req[i] is not None
-                and i not in self._chunks and i != held]
+                and i not in self._chunks and i != held and i not in ends]
 
     def _spec_safe(self) -> bool:
-        """True iff dispatching the next decode call BEFORE reading the
-        in-flight one cannot waste work: every slot in the in-flight
-        snapshot is guaranteed still alive when it ends — no eos token to
-        trip on, and budget strictly beyond the call's horizon. Otherwise
-        the engine syncs first (cheaper than risking an all-done call or
-        starving admission of a freed slot)."""
+        """True iff the step may dispatch its program BEFORE reading the
+        in-flight record: nothing in that record can surprise the host.
+
+        A budget's end is no surprise. With no ``eos_token_id`` on its
+        request, the ``rem_start`` that says a lane's budget ends inside
+        the in-flight call also says at which step, and the device's
+        carry holds the lane ``done`` from there on (``_paged_decode``:
+        it emits -1 and writes nothing in the call behind). The record
+        names such lanes at its making (``ends``: ``_dispatch_decode``),
+        ``_decode_slots`` leaves them out of everything the next dispatch
+        does, and the record comes back through that dispatch's ``prev``,
+        which emits the last token and frees the slot; the NEXT step's
+        admission refills it while the device runs. What that costs is
+        the freed slot's lane for one call (``decode_steps`` lane-steps),
+        where a drain leaves EVERY live lane without a program for about
+        a step's time (PERF.md section 6, PR 38: ~12 ms of a 23.5 ms step
+        in ``rag-offline``).
+
+        The engine still syncs first where the end is not the host's to
+        count, or where nothing would run behind the record: a lane with
+        an ``eos_token_id`` (any step may be its last); a lane that is
+        gone or holds another request (cancelled, expired, preempted); a
+        speculating engine (its waves read host state); and where the
+        ended slots would sit out more lane-steps than the lanes that go
+        on have in one step, ``len(ends) * decode_steps > live`` (``live``:
+        the decode rows of the next dispatch, and a held piece). Every
+        lane ending with no piece held is the case ``live == 0``: no
+        all-done call is ever dispatched."""
         rec = self._inflight
+        ends = rec["ends"]
         for slot, rid in rec["snapshot"]:
             req = self.slot_req[slot]
             if req is None or req.req_id != rid:
                 return False
             if req.eos_token_id is not None:
                 return False
-            if rec["rem_start"][slot] - self.decode_steps <= 0:
-                return False
+            if slot not in ends \
+                    and rec["rem_start"][slot] <= self.decode_steps:
+                return False      # a speculating engine counts no end
         for slot, rid, _ in self._joining:
             # a final piece rode in the in-flight program: its first
-            # token, unread, may already end its request
+            # token, unread, may be an eos
             req = self.slot_req[slot]
             if req is None or req.req_id != rid \
-                    or req.eos_token_id is not None \
-                    or req.max_new_tokens - len(req.generated) <= 1:
+                    or req.eos_token_id is not None:
                 return False
+        if ends:
+            live = len(self._decode_slots()) + (self._held is not None)
+            return len(ends) * self.decode_steps <= live
         return True
 
     def _back_or_preempt(self, steps: Optional[int] = None):
@@ -2714,6 +2748,16 @@ class LLMEngine:
             else:
                 rem_start[i] = req.max_new_tokens - len(req.generated) \
                     - len(self.slot_out[i])
+        # the lanes whose LAST token this call emits, by the host's own
+        # count: a budget that ends inside the call and no eos that could
+        # end it sooner (``_spec_safe``; a speculating engine counts none)
+        ends = set() if self._spec_on else {
+            i for i in active_slots if rem_start[i] <= self.decode_steps
+            and self.slot_req[i].eos_token_id is None}
+        if prev is not None and prev["ends"] and _obs.enabled():
+            # ends carried: ``prev`` is read back behind this dispatch
+            self._step_carried = len(prev["ends"])
+            _M_COUNTED_ENDS.inc(self._step_carried, drained="no")
         path = self._decode_path()
         ragged = path == "ragged"
         # ragged: the table ships at FULL width — one static shape
@@ -2820,6 +2864,10 @@ class LLMEngine:
                 # and the decode mask from the next dispatch on
                 self._joining = self._pending_adm[-1:]
                 self._slots_dirty = True
+                req = held[0][1]
+                if req.eos_token_id is None \
+                        and req.max_new_tokens - len(req.generated) <= 1:
+                    ends.add(held[0][0])   # its first token is its last
             stats = None              # on the piece's span already
         else:
             self._step_decodes["decode"] += 1
@@ -2855,6 +2903,7 @@ class LLMEngine:
                          for i in active_slots],
             "adm": self._pending_adm,
             "rem_start": rem_start,
+            "ends": ends,
             # the newest program dispatched: once this record is back and
             # the count has not moved, the device is empty (_device_get)
             "seq": self._seq,
@@ -3272,6 +3321,8 @@ class LLMEngine:
             _M_DRAINS.inc(reason=reason)
             if self._step_drain is None:
                 self._step_drain = reason
+            if rec["ends"]:
+                _M_COUNTED_ENDS.inc(len(rec["ends"]), drained="yes")
         return self._process(rec)
 
     def step(self):
@@ -3279,9 +3330,9 @@ class LLMEngine:
         (req_id, token) pairs that became host-visible this call.
 
         Pipelined: decode call k+1 is dispatched BEFORE call k's tokens
-        are read whenever no in-flight slot can finish mid-call
-        (``_spec_safe``), so the readback latency overlaps the next
-        call's compute. The
+        are read whenever no in-flight slot can finish at a step the host
+        cannot count ahead (``_spec_safe``), so the readback latency
+        overlaps the next call's compute. The
         token stream therefore lags the chip by up to one call
         (decode_steps tokens per slot).
 
@@ -3302,6 +3353,7 @@ class LLMEngine:
             return self._step_inner()
         self._wait_s = 0.0
         self._step_drain = None
+        self._step_carried = 0
         t0 = time.perf_counter()
         with trace_span("serving.step") as sp:
             self._mark("serving.step")
@@ -3313,6 +3365,8 @@ class LLMEngine:
                 sp.attrs.update(starved_ms=sum(ms.values()), starved=ms)
             if self._step_drain is not None:
                 sp.attrs["drain"] = self._step_drain
+            if self._step_carried:
+                sp.attrs["counted_ends"] = self._step_carried
         now = time.perf_counter()
         with trace_span("serving.telemetry"):
             self._step_telemetry(emitted, now, now - t0)
@@ -3416,9 +3470,18 @@ class LLMEngine:
                     active = self._decode_slots()
                 if active and self._spec_eligible(active):
                     return emitted + self._spec_wave(active)
-        if self._inflight is not None and not self._spec_safe():
-            emitted += self._process_inflight("may_finish")
-            self._admit_phase()    # freed slots: refill before dispatching
+        if self._inflight is not None:
+            if not self._spec_safe():
+                emitted += self._process_inflight("may_finish")
+                self._admit_phase()    # freed slots: refill before dispatching
+            elif self._inflight["ends"]:
+                # counted ends, carried: the lanes leave the decode mask
+                # (uploaded behind the running call) and a piece whose
+                # first token was its last never joins
+                ends = self._inflight["ends"]
+                self._joining = [e for e in self._joining
+                                 if e[0] not in ends]
+                self._slots_dirty = True
         active = self._decode_slots()
         if not active:
             self._flush_held()        # a piece and no decode rows to carry

@@ -5,10 +5,11 @@
 ``starved_ms`` / ``starved`` / ``drain``, on float32 toy engines on the CPU.
 
 - pipelined steps accrue nothing and carry neither attribute;
-- a slot on its last token drains (``may_finish``): the stretch from that
-  readback's return to the next dispatch lands on the phases in order,
-  their sum is the step's ``starved_ms``, and the counter moved by the sum
-  over the steps;
+- a slot that may trip on its eos drains (``may_finish``; a budget's end
+  alone no longer does, PR 38: ``tests/test_counted_ends.py``): the stretch
+  from that readback's return to the next dispatch lands on the phases in
+  order, their sum is the step's ``starved_ms``, and the counter moved by
+  the sum over the steps;
 - the sequence rule: a drain behind a lone piece dispatched earlier in the
   step starts nothing; the drain of the record that reads that piece does;
 - an engine with no request moves the no-work counter and no phase;
@@ -143,13 +144,13 @@ def test_pipelined_steps_accrue_nothing(model, obs_on):
     assert eng._inflight["seq"] == eng._seq
 
 
-def test_a_last_token_drains_and_the_stretch_lands_on_the_phases(model,
-                                                                 obs_on):
+def test_an_eos_drains_and_the_stretch_lands_on_the_phases(model, obs_on):
     eng = _engine(model)
     # three requests on two slots: the first to end frees the slot that
-    # the third is admitted into, on a drained pipeline
+    # the third is admitted into, on a drained pipeline (each may trip on
+    # its eos at any step, which the host cannot count ahead)
     for n, k in ((3, 6), (7, 9), (20, 5)):
-        eng.add_request(_prompt(n, n), max_new_tokens=k)
+        eng.add_request(_prompt(n, n), max_new_tokens=k, eos_token_id=0)
     eng.run()
     steps = _steps()
     drained = [s for s in steps if s.attrs.get("drain") == "may_finish"]
@@ -278,7 +279,7 @@ def test_an_engine_with_no_request_moves_no_work_and_no_phase(model, obs_on):
 # ---------------------------------------------------------------------------
 def _drain_may_finish(model):
     eng = _engine(model)
-    eng.add_request(_prompt(3), max_new_tokens=4)
+    eng.add_request(_prompt(3), max_new_tokens=4, eos_token_id=0)
     eng.run()
     return eng
 

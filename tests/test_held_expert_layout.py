@@ -263,6 +263,7 @@ def test_the_engine_carries_the_tiles_to_a_counter_and_the_spans():
     prompt = rng.integers(0, 256, size=600).tolist()
     obs.enable()
     try:
+        obs.get_tracer().clear()     # an earlier file's spans in this worker
         before = obs.snapshot()
         from paddle_tpu.serving import LLMEngine
         eng = LLMEngine(t._params(), cfg, max_slots=2, block_size=8,
