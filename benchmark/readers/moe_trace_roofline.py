@@ -101,9 +101,12 @@ def read(rec, what, program=None, op=None, op_lacks=None):
         load = traced_decode_load(rec)
         if not load:
             return None
-        steps, slots, live = load
+        steps, slots, live, decoded = load
         if what == "latent_walk":
+            # the kernel walks in every program that decodes, a piece that
+            # carries the decode rows too
             flops, nbytes = costs.decode_attention_cost(m, slots, live)
+            steps = decoded
         else:
             ex = _experts(decodes)
             if ex is None:

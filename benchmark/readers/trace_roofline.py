@@ -38,11 +38,13 @@ def read(rec, what, program=None, op=None, op_lacks=None):
         load = traced_decode_load(rec)
         if not load:
             return None
-        steps, slots, live = load
-        fn = (costs.decode_step_cost if what == "decode"
-              else costs.decode_attention_cost)
+        steps, slots, live, decoded = load
+        # the program ran in the pure decode steps; the kernel walks in
+        # every program that decodes
+        fn, n = ((costs.decode_step_cost, steps) if what == "decode"
+                 else (costs.decode_attention_cost, decoded))
         flops, nbytes = fn(m, slots, live)
-        flops, nbytes = flops * steps, nbytes * steps
+        flops, nbytes = flops * n, nbytes * n
     elif what in ("prefill", "flash"):
         rows = [t for r in traced_prefill_rows(rec) for t in r]
         if not rows:

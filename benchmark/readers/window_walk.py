@@ -24,6 +24,7 @@ the attributes, or a trace without the kernel, reads nothing. A share over
 leave part of it out."""
 from benchmark import trace
 from benchmark.manifest import family_of
+from benchmark.readers_util import decoding_steps
 
 
 def _decode_spans(rec, t0, t1):
@@ -32,13 +33,15 @@ def _decode_spans(rec, t0, t1):
 
 
 def _traced_load(rec, window):
-    """(decode steps, slot-steps, visible tokens of a full layer, of a
-    window layer) while tracing, the last three summed over the tokens
-    the client received there."""
+    """(pure decode steps, every step that decoded, slot-steps, visible
+    tokens of a full layer, of a window layer) while tracing, the last
+    three summed over the tokens the client received there: from every
+    step that decoded, a piece that carried the decode rows too
+    (``readers_util.decoding_steps``)."""
     span = rec.get("trace_span")
     if not span or "client" not in rec:
         return None
-    steps = len(_decode_spans(rec, *span))
+    steps, decoded = decoding_steps(rec, *span)
     if not steps:
         return None
     t_open = rec["t_open"]
@@ -49,7 +52,7 @@ def _traced_load(rec, window):
                 toks += 1
                 full += s["prompt_len"] + i
                 win += min(s["prompt_len"] + i, window)
-    return steps, toks, full, win
+    return steps, decoded, toks, full, win
 
 
 def read(rec, what, kind=None, program=None, op=None):
@@ -74,7 +77,7 @@ def read(rec, what, kind=None, program=None, op=None):
     load = _traced_load(rec, m["sliding_window"])
     if not seconds or not load:
         return None
-    steps, toks, full, win = load
+    steps, decoded, toks, full, win = load
     if what == "walk":
         flops, nbytes = costs.walk_cost(m, kind,
                                         full if kind == "full" else win)
@@ -82,12 +85,14 @@ def read(rec, what, kind=None, program=None, op=None):
         have = [a for a in _decode_spans(rec, *span) if "expert_rows" in a]
         if not have:
             return None
-        # per step, as the costs function counts one step
+        # per step, as the costs function counts one step: the load a
+        # mean over every step that decoded, the experts' counts and the
+        # work over the decode program's own steps
         flops, nbytes = costs.decode_step_cost(
-            m, toks / steps, full / steps,
+            m, toks / decoded, full / decoded,
             expert_rows=sum(a["expert_rows"] for a in have) / steps,
             experts_hit=sum(a["experts_hit"] for a in have) / steps,
-            window_tokens=win / steps)
+            window_tokens=win / decoded)
         flops, nbytes = flops * steps, nbytes * steps
     else:
         raise ValueError(f"unknown work {what!r}")

