@@ -1008,16 +1008,25 @@ def group_limited_routing(probs, n_group: int, topk_group: int, top_k: int,
     T, E = probs.shape
     per = E // n_group
     group = probs.reshape(T, n_group, per).max(axis=-1)
-    _, keep = jax.lax.top_k(group, topk_group)                    # [T, g]
-    kept = jnp.zeros((T, n_group), bool).at[
-        jnp.arange(T)[:, None], keep].set(True)
-    masked = jnp.where(jnp.repeat(kept, per, axis=1), probs, 0.0)
+    masked = jnp.where(_kept_groups(group, topk_group, per), probs, 0.0)
     w, idx = jax.lax.top_k(masked, top_k)
     return w * scale, idx.astype(jnp.int32)
 
 
+def _kept_groups(group, topk_group: int, per: int):
+    """[T, n_group * per] bool: the experts of each row's best
+    ``topk_group`` groups by ``group`` [T, n_group] (ties to the lower
+    index)."""
+    T, n_group = group.shape
+    _, keep = jax.lax.top_k(group, topk_group)                    # [T, g]
+    kept = jnp.zeros((T, n_group), bool).at[
+        jnp.arange(T)[:, None], keep].set(True)
+    return jnp.repeat(kept, per, axis=1)
+
+
 def sigmoid_bias_routing(scores, bias, top_k: int, scale: float = 1.0,
-                         renorm: bool = True):
+                         renorm: bool = True, n_group: int = 1,
+                         topk_group: int = 1):
     """Sigmoid routing with a selection bias (LFM2's, and the
     auxiliary-loss-free balancing it comes from): ``scores`` [T, E] are
     the router's sigmoids in float32, each expert's own; ``bias`` [E]
@@ -1025,8 +1034,23 @@ def sigmoid_bias_routing(scores, bias, top_k: int, scale: float = 1.0,
     bias``, ties to the lower index) and enters no weight; the gates are
     the chosen experts' unbiased scores, divided by their sum plus 1e-6
     where ``renorm``, times ``scale``. Returns (gates [T, top_k] f32,
-    idx [T, top_k] int32)."""
-    _, idx = jax.lax.top_k(scores + bias[None, :].astype(scores.dtype), top_k)
+    idx [T, top_k] int32).
+
+    ``n_group`` > 1 limits the selection to groups first (DeepSeek-V3's
+    ``noaux_tc``): the experts form ``n_group`` groups of consecutive
+    ones, a group scores the SUM OF ITS TOP TWO biased scores, the best
+    ``topk_group`` groups stay (ties to the lower index) and the ``top_k``
+    are chosen among their experts only. The sum that renormalises runs
+    over all the chosen, wherever they are held."""
+    biased = scores + bias[None, :].astype(scores.dtype)
+    if n_group > 1:
+        T, E = scores.shape
+        per = E // n_group
+        top2, _ = jax.lax.top_k(biased.reshape(T, n_group, per), 2)
+        biased = jnp.where(
+            _kept_groups(jnp.sum(top2, axis=-1), topk_group, per), biased,
+            -jnp.inf)
+    _, idx = jax.lax.top_k(biased, top_k)
     w = jnp.take_along_axis(scores, idx, axis=-1)
     if renorm:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)

@@ -55,7 +55,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -82,7 +82,7 @@ class DeepseekV2Config:
     moe_intermediate_size: int = 1536     # one routed expert's FFN
     num_layers: int = 60
     num_heads: int = 128
-    q_lora_rank: int = 1536
+    q_lora_rank: Optional[int] = 1536     # None: ``w_q`` straight from h
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
@@ -154,10 +154,13 @@ def from_published(layer: Dict, c: DeepseekV2Config) -> Dict:
                      c.v_head_dim)
     r = c.kv_lora_rank
     perm = jnp.concatenate([jnp.arange(0, dr, 2), jnp.arange(1, dr, 2)])
+    # the queries' matrix: ``w_uq`` behind the low-rank ``w_dq``, or (a
+    # model with no query compression, ``q_lora_rank`` None) ``w_q``
+    wq = "w_q" if c.q_lora_rank is None else "w_uq"
     out = {k: v for k, v in layer.items()
-           if k not in ("w_uq", "w_dkv", "w_ukv", "e_gate", "e_up")}
-    w_uq = layer["w_uq"].reshape(-1, H, dn + dr)
-    out["w_uq"] = jnp.concatenate(
+           if k not in (wq, "w_dkv", "w_ukv", "e_gate", "e_up")}
+    w_uq = layer[wq].reshape(-1, H, dn + dr)
+    out[wq] = jnp.concatenate(
         [w_uq[..., :dn], w_uq[..., dn:][..., perm]], -1).reshape(
             -1, H * (dn + dr))
     w_dkv = layer["w_dkv"]
@@ -251,8 +254,12 @@ class DeepseekV2Served:
         c, dt = self.config, self.dtype
         H, dn, dr = c.num_heads, c.qk_nope_head_dim, c.qk_rope_head_dim
         r = c.kv_lora_rank
-        cq = _rms_norm(hn @ p["w_dq"].astype(dt), p["q_norm"], c.rms_eps)
-        q = (cq @ p["w_uq"].astype(dt)).reshape(hn.shape[:-1] + (H, dn + dr))
+        if c.q_lora_rank is None:
+            q = hn @ p["w_q"].astype(dt)
+        else:
+            cq = _rms_norm(hn @ p["w_dq"].astype(dt), p["q_norm"], c.rms_eps)
+            q = cq @ p["w_uq"].astype(dt)
+        q = q.reshape(hn.shape[:-1] + (H, dn + dr))
         q_nope = q[..., :dn]
         q_rope = _rope(q[..., dn:], ang[..., None, :], mscale)
         ckv = hn @ p["w_dkv"].astype(dt)
@@ -317,8 +324,12 @@ class DeepseekV2Served:
         qk_pad = -(dn + dr) % 128
         pool = pools[f"{opts.prefix}c{l}"][0]
 
+        gated = "w_g" in p     # a head-wise output gate (arXiv:2505.06708)
+
         def one_row(args):
             qn, qr, rw = args[:3]                  # [S,H,dn] [S,H,dr] [S,W]
+            if gated:
+                *args, gate = args
             lat, k_r = rw[:, :r], rw[:, r:r + dr]
             # the chunk itself, expanded: q/k padded with zero columns
             k_nope = jnp.einsum("sc,hdc->hsd", lat, p["w_uk"].astype(dt))
@@ -334,7 +345,7 @@ class DeepseekV2Served:
             if aux["prefix_nbk"]:
                 # the earlier chunks, absorbed: all heads of all the
                 # chunk's tokens against the latent rows as they lie
-                tbl, n_hist = args[3:]
+                tbl, n_hist = args[3:5]
                 hist = pool[tbl].reshape(1, -1, c.latent_width)
                 q_abs = self._absorb(p, qn, qr).reshape(
                     1, S * H, c.latent_width)
@@ -345,11 +356,15 @@ class DeepseekV2Served:
                                  p["w_uv"].astype(dt))
                 o = combine_partials(o, jnp.swapaxes(lse, 0, 1), o_h,
                                      lse_h.reshape(S, H))
+            if gated:
+                o = o * gate[..., None]
             return o.reshape(S, H * dv) @ p["w_o"].astype(dt)
 
         rows = (q_nope, q_rope, row)
         if aux["prefix_nbk"]:
             rows += (aux["ctx_tbl"], aux["hist_len"].astype(jnp.int32))
+        if gated:
+            rows += (jax.nn.sigmoid(hn @ p["w_g"].astype(dt)),)
         return x + jax.lax.map(one_row, rows), {"c": row}
 
     def ffn(self, params, l: int, rows, valid):
@@ -455,6 +470,8 @@ class DeepseekV2Served:
                      + jnp.einsum("nhs,nsc->nhc", probs[..., P:].astype(dt),
                                   v_rng)).astype(dt)
         o = jnp.einsum("nhc,hcd->nhd", o_lat, p["w_uv"].astype(dt))
+        if "w_g" in p:
+            o = o * jax.nn.sigmoid(hn @ p["w_g"].astype(dt))[..., None]
         return (x[:, 0] + o.reshape(N, -1) @ p["w_o"].astype(dt),
                 dict(ring, c=rc))
 

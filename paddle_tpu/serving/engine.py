@@ -441,8 +441,7 @@ def _piece_with_decode_rows(params, x, aux, blk_ids, true_len, pools, temps,
     xd = model.embed(params, last)                              # [N, h]
     step = model.decode_step_begin(daux, lens0, 0, 1)
     ring = model.ring_init(N, 1, opts)
-    for name in model.state_entries:
-        ring[name] = pools[name][:, :N]
+    _state_into_ring(model, ring, pools, N)
     # pad positions of the piece and idle slots load no expert
     valid = jnp.concatenate([aux["valid"], act])
     new, stats = [], None
@@ -598,8 +597,7 @@ def _paged_decode(params, last_tokens, lengths, done0, budgets, key, active,
     # per-slot state rides in the carry beside the ring: the model advances
     # it only where ``act`` (an idle, mid-chunk or finished slot's must not
     # move), and it is written back once, below
-    for name in model.state_entries:
-        ring[name] = pools[name][:, :N]
+    _state_into_ring(model, ring, pools, N)
     init = (last_tokens, lengths, done0, budgets, ring, key)
     (last_tokens, lens_end, done0, budgets, ring, key), \
         emitted = jax.lax.scan(body, init, jnp.arange(S))
@@ -608,6 +606,19 @@ def _paged_decode(params, last_tokens, lengths, done0, budgets, key, active,
                                  win_table, lens0, lens_end, active, S)
     return (emitted, last_tokens, lens_end, done0, budgets, key, pools,
             stats)
+
+
+def _state_into_ring(model, ring, pools, N):
+    """A decode call's per-slot state beside the ring: the slots' rows of
+    each entry, copied in here and written back whole by ``_scatter_ring``
+    (kilobytes a slot). An entry that the model advances IN PLACE
+    (``model.state_in_place``: megabytes a slot, which no call can copy in
+    and out) is the pools' own donated buffer, trash row and all: the
+    model's kernel aliases it in and out and touches the active slots'
+    rows only, and what it returns IS the pools' entry."""
+    in_place = getattr(model, "state_in_place", ())
+    for name in model.state_entries:
+        ring[name] = pools[name] if name in in_place else pools[name][:, :N]
 
 
 def _scatter_ring(model, opts, pools, ring, block_table, win_table, lens0,
@@ -637,8 +648,10 @@ def _scatter_ring(model, opts, pools, ring, block_table, win_table, lens0,
     for name, val in packed.items():
         pools[name] = pools[name].at[
             :, phys_w if name in window else phys, off].set(val)
+    in_place = getattr(model, "state_in_place", ())
     for name, val in state.items():
-        pools[name] = pools[name].at[:, :N].set(val)
+        pools[name] = (val if name in in_place
+                       else pools[name].at[:, :N].set(val))
     return pools, stats
 
 
@@ -2241,6 +2254,11 @@ class LLMEngine:
             # hist_len; counted here by why the row starts over)
             kw["slot"] = jnp.asarray([slot], jnp.int32)
             attrs["state_in"] = hist > 0
+            # the row's own state: read where carried, written once
+            attrs["state_bytes"] = self._state_bytes_per_slot
+            if getattr(self.model, "scan_layers", 0):
+                # real tokens x the layers whose state a scan advances
+                attrs["scan_tokens"] = piece * self.model.scan_layers
             if not hist:
                 _M_STATE_RESETS.inc(
                     reason="preempt" if req.generated else "admit")
@@ -2273,6 +2291,10 @@ class LLMEngine:
                 # one flag tuple a program: a branch either kind needs
                 flags = tuple(a or b for a, b in zip(flags, dec_flags))
             kw = dict(kw, dec=dec)
+            if "state_bytes" in attrs:
+                # and the state of the decode rows that move with it
+                attrs = dict(attrs, state_bytes=attrs["state_bytes"] * (
+                    1 + dec_attrs["decode_slots"]))
             carried = "rows" if dec_attrs["decode_slots"] else "none"
             _M_PREFILL_PROGRAMS.inc(carried=carried)
             self._step_decodes["piece"] += carried == "rows"

@@ -1,8 +1,9 @@
 """A step's last prefill piece carries the decode rows in ONE program
 (``serving/engine.py`` ``_paged_prefill(dec=)``, ``LLMEngine._piggyback``),
-float32 on the CPU with the walk kernels interpreted, over the three
-families that chunk their prefill (LFM2, Mellum2, DeepSeek-V2 through the
-one interface: ``prefill_mix`` / ``decode_mix`` / ``ffn``):
+float32 on the CPU with the walk kernels interpreted, over the four
+families that chunk their prefill (LFM2, Mellum2, DeepSeek-V2 and
+Ling-3.0-flash, whose matrix state the programs advance in place, through
+the one interface: ``prefill_mix`` / ``decode_mix`` / ``ffn``):
 
 - the program against today's pair, a piece's program and then a decode
   step, run one behind the other from the same pools: the piece's first
@@ -31,7 +32,8 @@ from paddle_tpu.serving import LLMEngine, engine as eng_mod
 F32 = jnp.float32
 GREEDY = (False, False, False)
 FAMILIES = {"lfm2": "test_lfm2_moe", "mellum": "test_mellum",
-            "deepseek_v2": "test_deepseek_v2_served"}
+            "deepseek_v2": "test_deepseek_v2_served",
+            "ling_hybrid": "test_ling_hybrid"}
 # (top-k, expert layers) of the tiny models whose experts are ALL held
 ALL_HELD = {"lfm2": (2, 2), "mellum": (2, 4)}
 N, BS, MML, CHUNK = 3, 8, 128, 16
