@@ -1070,6 +1070,9 @@ _HELD_SMALL_ROWS = 1024
 # the longest side of an expert's matrices up to which the whole contraction
 # is one tile (DeepSeek-V2's 5120 x 1536 lies above it and keeps its tiles)
 _HELD_NARROW = 4096
+# the fast memory a Mosaic kernel may take on a v5e unless its caller raises
+# the limit, which ``megablox.gmm`` offers no argument for
+_GMM_VMEM = 16 << 20
 
 
 def _mosaic() -> bool:
@@ -1093,46 +1096,100 @@ def _static_gmm(xs, w, gs):
     expert, and a piece's experts each start on a tile boundary
     (``held_expert_ffn``), so a taller tile only adds rows of padding for
     the MXU to work through. What the tile's other sides are follows from
-    the expert's width. Read on the chip (PRs 28, 32, 33; scratch
-    ``output/chip_micro*.py``), the kernel alone:
+    the expert's width. Read on the chip (PRs 28, 32, 33, 41; scratch
+    ``output/chip*_micro*.py``):
 
     - a narrow expert (no side of its matrices over 4096: LFM2's 2048 x
-      1792, Mellum2's 2304 x 896): the whole contraction in one tile, so
-      an expert's weights stream once and no accumulator is revisited,
-      and 512 columns, or the widest of (512, 256, 128) that divides the
-      output side (Mellum2's 1792 and 2304: 256). ``[rows, 2048] x [32,
-      2048, 3584]`` + ``[rows, 1792] x [32, 1792, 2048]``: a decode step's
-      256 packed rows 0.68 + 0.37 ms (0.92 + 0.46 at (128, 512, 1024)),
-      84% of the weights' bytes; a piece's 2,948 real pairs 0.95 + 0.50 ms
-      on 8,192 aligned rows, against 1.02 + 0.54 packed in 4,096 rows at a
-      256-row tile and 1.04 + 0.56 packed at 128: the kernel is bound by
-      the bytes it moves (the weights once, x's tiles once a column tile),
-      not by the MXU's passes over masked rows, and the layout wins a
-      tenth of it. (128, 512, 3584) and (128, 1792, 2048) read 0.83 + 0.44
-      alone and nothing in the whole function (1.44 against 1.45 ms), so
-      the one rule stays.
+      1792, Mellum2's 2304 x 896, Ling's 2560 x 768): the whole contraction
+      in one tile, so an expert's weights stream once and no accumulator is
+      revisited, and the widest column tile that divides the output side
+      and fits (``_column_tile``). The kernel's grid has the column tile
+      OUTERMOST: every row tile of ``x`` is read again for every column
+      tile (``_gmm_bytes``), which a decode step's two row tiles do not
+      feel and a piece's hundred do. Until PR 41 the column tile was the
+      widest of (512, 256, 128) that divides the side, 256 for Mellum2's
+      1792 and 2304: seven and nine passes over a piece's rows. Read on the
+      chip, ms a call as the mean of 30 dispatched back to back (my chip
+      runs, PR 41, ``output/chip41_micro.py``; a single call waited for
+      reads 0.6 to 1.0 ms more, as PR 35's 2.16 / 1.63 did), the old
+      rule's tile -> this one's, gate|up + down alone and then the WHOLE
+      ``held_expert_ffn`` of a layer:
+      Mellum2, a piece (13,440 rows visited of 16,640): 1.608 + 1.049 ->
+      1.225 + 0.676, the function 2.90 -> 2.49; a decode step (256 packed
+      rows): 0.766 + 0.501 -> 0.761 + 0.405, the function 1.23 -> 1.14.
+      LFM2, a piece (7,040 of 8,448): 1.243 + 0.678 -> 1.137 + 0.610, the
+      function 2.02 -> 1.97; a decode step (256): 0.717 + 0.382 -> 0.696 +
+      0.382, the function 1.045 -> 1.050 (no gain, inside the noise).
+      Ling, a piece (16,384 of 25,088): 1.844 + 1.493 -> 1.740 + 1.300, the
+      function 4.14 -> 3.97; a decode step (512): 0.875 + 0.497 -> 0.886 +
+      0.462, the function 1.36 -> 1.34. Alone the kernel follows its bytes
+      to the digit; in the function it gains about half of that, because
+      XLA keeps the gathered rows and the activation in the chip's fast
+      memory (``S(1)`` in the compiled text), so the re-reads saved were
+      not all HBM's. One rule serves a piece and a decode step: no branch
+      on the row count. PR 33 read LFM2's (128, 512, 3584) and (128, 1792,
+      2048) as 0.83 + 0.44 alone against 0.95 + 0.50 and as nothing in the
+      whole function; the second reading stands (2.08 ms against 2.02 at
+      the old rule), the first's gate|up half does not repeat (1.251
+      against 1.243). Earlier readings of the same kernel (PRs 32, 33): a
+      decode step's 256 rows 0.68 + 0.37 ms with the whole contraction in
+      one tile against 0.92 + 0.46 at (128, 512, 1024); a piece's 2,948
+      real pairs 0.95 + 0.50 on 8,192 aligned rows against 1.04 + 0.56
+      packed: the layout wins a tenth.
     - any other (DeepSeek-V2's 5120 x 1536): (128, 512, 1024), or what of
       it divides the expert's sides. A decode step's 144 pairs: 4.2 ms of
-      bytes a step against 11.7 at a 512-row tile (PR 28). A piece's ~840 real pairs over 20 experts:
-      1.06 + 0.65 ms aligned against 1.99 + 1.10 packed under
-      ``heuristic_tilings``' (512, 512, 1024), where every one of ~21
-      visits works through 512 rows; (128, 512, 512) 1.26 + 0.76."""
+      bytes a step against 11.7 at a 512-row tile (PR 28). A piece's ~840
+      real pairs over 20 experts: 1.06 + 0.65 ms aligned against 1.99 +
+      1.10 packed under ``heuristic_tilings``' (512, 512, 1024), where
+      every one of ~21 visits works through 512 rows; (128, 512, 512) 1.26
+      + 0.76."""
     m, k = xs.shape
     n = w.shape[-1]
     if _mosaic():
         if k % 128 or n % 128:
             raise ValueError(f"no static gmm tiling for rows={m} k={k} n={n}")
         if max(k, n) <= _HELD_NARROW:
-            # the widest column tile that divides the output side, so that
-            # no side is padded in HBM (Mellum2's 1792 and 2304 take 256)
-            tile = (_row_tile(), k,
-                    next(t for t in (512, 256, 128) if n % t == 0))
+            tile = (_row_tile(), k, _column_tile(k, n, xs.dtype.itemsize))
         else:
             tile = (_row_tile(),
                     next(t for t in (512, 256, 128) if k % t == 0),
                     next(t for t in (1024, 512, 256, 128) if n % t == 0))
         return _gmm_tuned(xs, w, gs, (tile, tile, tile), False)
     return jax.lax.ragged_dot(xs, w, gs)
+
+
+def _gmm_footprint(k: int, tn: int, item: int) -> int:
+    """Bytes of the chip's fast memory that ``megablox.gmm`` holds at a tile
+    of ``(128, k, tn)``, reckoned from the kernel's text: the weight block
+    and the row tile and the output tile twice each (the pipeline fetches
+    the next while this one is worked on), the float32 accumulator, and the
+    masked store's two float32 temporaries of the accumulator's size (the
+    accumulator as read and the output tile widened to be selected
+    against)."""
+    tm = 128
+    return 2 * item * (k * tn + tm * k + tm * tn) + 3 * 4 * tm * tn
+
+
+def _column_tile(k: int, n: int, item: int) -> int:
+    """The widest multiple of 128 that divides the output side ``n`` and
+    whose tile, the whole contraction ``k`` deep, fits ``_GMM_VMEM``: the
+    kernel reads every row tile of ``x`` again for every column tile, so
+    the fewest column tiles move the fewest bytes, and a divisor leaves no
+    side padded in HBM. The MXU asks for a multiple of 128, not for a
+    power of two (Mellum2's 1792 takes 896 and its 2304 goes whole)."""
+    return max(t for t in range(128, n + 1, 128)
+               if n % t == 0 and _gmm_footprint(k, t, item) <= _GMM_VMEM)
+
+
+def _gmm_bytes(rows: int, groups: int, k: int, n: int, tn: int,
+               item: int = 2) -> int:
+    """Bytes a call of the grouped matmul moves by ``megablox.gmm``'s own
+    ``cost_estimate``, for ``rows`` rows in the row tiles it visits and
+    ``groups`` groups with a row: the column tile is the grid's OUTERMOST
+    side, so ``x`` is read once a column tile, a group's weights once (the
+    whole contraction is one tile) and the output written once. Used by no
+    program: it holds the arithmetic behind ``_column_tile``."""
+    return item * (rows * k * (n // tn) + groups * k * n + rows * n)
 
 
 def _tile_visits(gs, tile: int):

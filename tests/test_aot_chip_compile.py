@@ -285,8 +285,9 @@ def test_flash_partial_with_a_band(topo, keys, causal):
                          ids=["decode", "piece"])
 def test_held_expert_ffn_sixty_four_experts(topo, monkeypatch, tokens, rows):
     """All 64 of Mellum2's experts held (2304 x 896, top-8): the whole
-    contraction in one tile and a column tile of 256, which divides 1792
-    and 2304 (512 does not), chosen from the shapes, never timed. A decode
+    contraction in one tile and the widest column tile that divides the
+    side and fits the kernel's fast memory (896 of 1792, all of 2304; 256
+    until PR 41), chosen from the shapes, never timed. A decode
     step of 32 slots keeps its 256 pairs packed; a piece of 1024 tokens is
     8,192 pairs, ONE pass, laid out on tile boundaries: 8,192 + 64 x 128
     static rows."""

@@ -287,14 +287,16 @@ def test_lfm2_decode_writes_no_weight_sized_array(topo, monkeypatch):
     N = 32
     model, params, pools, sds, moe_dispatch = _lfm2_shapes(topo, N)
     monkeypatch.setattr(moe_dispatch, "_mosaic", lambda: True)
-    text = jax.jit(functools.partial(
+    traced = jax.jit(functools.partial(
         engine._paged_decode, model=model, n_steps=1,
         opts=ServeOpts(ragged=True), sample_flags=GREEDY),
-        donate_argnums=(8,)).lower(
+        donate_argnums=(8,)).trace(
         params, sds((N,), I32), sds((N,), I32), sds((N,), jnp.bool_),
         sds((N,), I32), sds((2,), jnp.uint32), sds((N,), jnp.bool_),
         sds((N, TABLE), I32), pools, sds((N,), F32), sds((N,), I32),
-        sds((N,), F32), sds((N,), I32)).compile().as_text()
+        sds((N,), F32), sds((N,), I32))
+    assert _gmm_tiles(traced) == LFM2_TILES
+    text = traced.lower().compile().as_text()
     assert "%lfm2_ragged_walk" in text and "%gmm" in text
     assert _lfm2_big_writes(text) == []
 
@@ -338,6 +340,30 @@ def _gmm_calls(text):
             if op == "custom-call" and n.startswith("gmm")]
 
 
+_GMM_KERNEL = re.compile(
+    r"Ref\{bf16\[128,(\d+)\]\}\s+\w+:Ref\{bf16\[(\d+),(\d+)\]\}\s+"
+    r"\w+:Ref\{bf16\[128,(\d+)\]\}\s+\w+:Ref<vmem>\{f32\[128,(\d+)\]\}")
+
+
+def _gmm_tiles(traced):
+    """{(contraction tile, column tile)} of a traced program's grouped
+    matmuls, read from the kernel's own operands in the jaxpr (the compiled
+    text holds the kernel as bytes): a row tile ``[128, tk]``, a weight
+    block ``[tk, tn]``, an output tile and an accumulator ``[128, tn]``."""
+    tiles = set()
+    for tk, wk, wn, tn, acc in _GMM_KERNEL.findall(str(traced.jaxpr)):
+        assert tk == wk and wn == tn == acc
+        tiles.add((int(tk), int(tn)))
+    return tiles
+
+
+# what ``_static_gmm``'s rule answers at the published widths (PR 41): the
+# whole contraction, and the widest column tile that divides the side and
+# fits; a tile that did not fit would fail these compiles
+LFM2_TILES = {(2048, 896), (1792, 1024)}
+MEL_TILES = {(2304, 896), (896, 2304)}
+
+
 @pytest.mark.parametrize("history", [0, TABLE // 2],
                          ids=["first", "continuing"])
 def test_lfm2_piece_with_the_decode_rows_is_one_pass_over_the_experts(
@@ -357,11 +383,12 @@ def test_lfm2_piece_with_the_decode_rows_is_one_pass_over_the_experts(
             sds((1,), F32), sds((2,), jnp.uint32)]
     if history:
         args += [sds((1,), I32), sds((1, history), I32)]
-    text = jax.jit(functools.partial(
+    traced = jax.jit(functools.partial(
         engine._paged_prefill, model=model, opts=ServeOpts(ragged=True),
-        sample_flags=GREEDY, prefix_nbk=history), donate_argnums=(4,)).lower(
-        *args, slot=sds((1,), I32),
-        dec=_dec_operands(sds, N, TABLE)).compile().as_text()
+        sample_flags=GREEDY, prefix_nbk=history), donate_argnums=(4,)).trace(
+        *args, slot=sds((1,), I32), dec=_dec_operands(sds, N, TABLE))
+    assert _gmm_tiles(traced) == LFM2_TILES
+    text = traced.lower().compile().as_text()
     assert "%lfm2_prefill_chunk" in text and "%lfm2_ragged_walk" in text
     assert ("%lfm2_prefill_history" in text) == bool(history)
     entry = _entry(text)
@@ -442,15 +469,16 @@ def test_mellum_decode_writes_no_weight_sized_array(topo, monkeypatch):
     N = 32
     model, params, pools, sds, moe_dispatch = _mellum_shapes(topo)
     monkeypatch.setattr(moe_dispatch, "_mosaic", lambda: True)
-    text = jax.jit(functools.partial(
+    traced = jax.jit(functools.partial(
         engine._paged_decode, model=model, n_steps=1,
         opts=ServeOpts(ragged=True), sample_flags=GREEDY),
-        donate_argnums=(8,)).lower(
+        donate_argnums=(8,)).trace(
         params, sds((N,), I32), sds((N,), I32), sds((N,), jnp.bool_),
         sds((N,), I32), sds((2,), jnp.uint32), sds((N,), jnp.bool_),
         sds((N, MEL_TABLE), I32), pools, sds((N,), F32), sds((N,), I32),
-        sds((N,), F32), sds((N,), I32), sds((N, MEL_RING), I32)
-        ).compile().as_text()
+        sds((N,), F32), sds((N,), I32), sds((N, MEL_RING), I32))
+    assert _gmm_tiles(traced) == MEL_TILES
+    text = traced.lower().compile().as_text()
     assert text.count("%mellum_walk_full") >= 1
     assert text.count("%mellum_walk_window") >= 3 and "%gmm" in text
     assert _mellum_big_writes(text) == []
@@ -509,11 +537,12 @@ def test_mellum_piece_with_the_decode_rows_is_one_pass_over_the_experts(
         args += [sds((1,), I32), sds((1, history), I32)]
         win.update(ctx_tbl=sds((1, MEL_RING), I32),
                    ctx_start=sds((1,), I32))
-    text = jax.jit(functools.partial(
+    traced = jax.jit(functools.partial(
         engine._paged_prefill, model=model, opts=ServeOpts(ragged=True),
-        sample_flags=GREEDY, prefix_nbk=history), donate_argnums=(4,)).lower(
-        *args, win=win,
-        dec=_dec_operands(sds, N, MEL_TABLE, MEL_RING)).compile().as_text()
+        sample_flags=GREEDY, prefix_nbk=history), donate_argnums=(4,)).trace(
+        *args, win=win, dec=_dec_operands(sds, N, MEL_TABLE, MEL_RING))
+    assert _gmm_tiles(traced) == MEL_TILES
+    text = traced.lower().compile().as_text()
     assert "%mellum_prefill_chunk" in text
     assert text.count("%mellum_walk_full") >= 1
     assert text.count("%mellum_walk_window") >= 3
@@ -542,6 +571,11 @@ def test_mellum_piece_with_the_decode_rows_is_one_pass_over_the_experts(
 # ``_paged_prefill`` without ``dec`` is the program it was. The
 # ``piece+rows`` entries are the ONE program of a step that has a piece (a
 # history's operands always: the engine runs no other form), new in PR 36.
+# PR 41 (the grouped matmul's column tile by what divides and fits) leaves
+# every hash: the dense family runs no grouped matmul, the latent one takes
+# the other branch, and at these widths (sides of 256) the old rule and the
+# new answer one column tile alike. What the rule answers at the published
+# widths is held above (``LFM2_TILES``, ``MEL_TILES``).
 PARENT_PROGRAMS = {
     "dense.decode": "7921a0ad28675c6f", "dense.prefill0": "1cad0efaa529c317",
     "dense.prefill16": "53e7a3a2e58a42bd",

@@ -33,7 +33,7 @@ BASE = {k: PUBLISHED[k] for k in (
 MODEL = {**BASE, **FAM.tiny(BASE), "sliding_window": 32}
 W, BS = 32, 8
 KEY = weights.seed_key(7)
-F32 = jnp.float32
+F32, BF16 = jnp.float32, jnp.bfloat16
 # contexts of 0.5, 1, 1.5 and 5 windows, and one under a block
 PROMPTS = (16, 32, 48, 160, 5)
 
@@ -476,28 +476,84 @@ def test_the_reference_s_pairs_by_expert_are_every_expert_masked(quant):
     assert np.abs(got - want).max() < 2e-5
 
 
-@pytest.mark.parametrize("k,n,tile", [
-    (2304, 1792, (128, 2304, 256)),      # Mellum2's gate and up
-    (896, 2304, (128, 896, 256)),        # its down projection
-    (2048, 3584, (128, 2048, 512)),      # LFM2's: what they were
-    (1792, 2048, (128, 1792, 512)),
+# every expert shape a cell runs: (contraction, output side, the rule's tile)
+GMM_TILES = [
+    (2304, 1792, (128, 2304, 896)),      # Mellum2's gate and up: 7 -> 2 tiles
+    (896, 2304, (128, 896, 2304)),       # its down projection: 9 -> 1
+    (2048, 3584, (128, 2048, 896)),      # LFM2's: 7 -> 4
+    (1792, 2048, (128, 1792, 1024)),     # 4 -> 2
     (5120, 3072, (128, 512, 1024)),      # DeepSeek-V2's: what they were
-    (1536, 5120, (128, 512, 1024))])
-def test_the_grouped_matmul_s_tile_by_rule(k, n, tile, monkeypatch):
-    """``_static_gmm``'s rule at the three expert shapes: no side padded
-    (the column tile divides the output side), the older families' tiles
-    unchanged; and off a TPU the function is ``ragged_dot``."""
+    (1536, 5120, (128, 512, 1024)),
+    (2560, 1536, (128, 2560, 768)),      # Ling's: 3 -> 2
+    (768, 2560, (128, 768, 2560))]       # 5 -> 1
+
+
+def _rule_s_tile(k, n, monkeypatch):
+    """The tilings ``_static_gmm`` hands the Mosaic kernel for bf16 rows."""
     seen = []
     monkeypatch.setattr(md, "_mosaic", lambda: True)
     monkeypatch.setattr(md, "_gmm_tuned", lambda xs, w, gs, tiles, full:
                         seen.append(tiles) or jnp.zeros((xs.shape[0], n)))
-    gs = jnp.asarray([3, 5], jnp.int32)
-    md._static_gmm(jnp.zeros((8, k)), jnp.zeros((2, k, n)), gs)
-    assert seen == [(tile, tile, tile)]
-    assert n % tile[2] == 0 and k % tile[1] == 0
+    md._static_gmm(jnp.zeros((8, k), BF16), jnp.zeros((2, k, n), BF16),
+                   jnp.asarray([3, 5], jnp.int32))
     monkeypatch.undo()
+    (tiles,) = seen
+    assert tiles[0] == tiles[1] == tiles[2]
+    return tiles[0]
+
+
+@pytest.mark.parametrize("k,n,tile", GMM_TILES)
+def test_the_grouped_matmul_s_tile_by_rule(k, n, tile, monkeypatch):
+    """``_static_gmm``'s rule at every expert shape a cell runs: the widest
+    column tile that divides the output side and fits (no side padded),
+    DeepSeek-V2's tiles unchanged; and off a TPU the function is
+    ``ragged_dot``."""
+    assert _rule_s_tile(k, n, monkeypatch) == tile
+    assert n % tile[2] == 0 and k % tile[1] == 0
+    gs = jnp.asarray([3, 5], jnp.int32)
     rng = np.random.default_rng(0)
     xs = jnp.asarray(rng.standard_normal((8, k)), F32)
     w = jnp.asarray(rng.standard_normal((2, k, n)), F32)
     assert np.array_equal(np.asarray(md._static_gmm(xs, w, gs)),
                           np.asarray(jax.lax.ragged_dot(xs, w, gs)))
+
+
+@pytest.mark.parametrize("k,n", [(k, n) for k, n, _ in GMM_TILES])
+def test_the_column_tile_is_the_widest_that_fits(k, n, monkeypatch):
+    """The rule's own properties: the column tile is a multiple of 128 that
+    divides ``n``; on the narrow branch the contraction is whole, the
+    reckoned footprint is under the kernel's memory and no wider divisor
+    would fit."""
+    tm, tk, tn = _rule_s_tile(k, n, monkeypatch)
+    assert tm == 128 and tn % 128 == 0 and n % tn == 0 and k % tk == 0
+    if max(k, n) > md._HELD_NARROW:
+        return
+    assert tk == k and tn == md._column_tile(k, n, 2)
+    assert md._gmm_footprint(k, tn, 2) <= md._GMM_VMEM
+    wider = [t for t in range(tn + 128, n + 1, 128) if n % t == 0]
+    assert all(md._gmm_footprint(k, t, 2) > md._GMM_VMEM for t in wider)
+    # float32 rows take twice the bytes a block: a narrower tile, by the
+    # same rule
+    tn4 = md._column_tile(k, n, 4)
+    assert tn4 <= tn and n % tn4 == 0
+
+
+@pytest.mark.parametrize("groups,rows,shapes,old,new", [
+    # Mellum2's piece: 8,448 pairs + half a tile an expert, a layer
+    (64, 12544, ((2304, 1792, 256, 896), (896, 2304, 256, 2304)),
+     1.50e9, 1.03e9),
+    # LFM2's: 4,352 pairs + half a tile an expert
+    (32, 6400, ((2048, 3584, 512, 896), (1792, 2048, 512, 1024)),
+     1.05e9, 0.93e9)], ids=["mellum2", "lfm2"])
+def test_a_piece_s_rows_are_read_once_a_column_tile(groups, rows, shapes,
+                                                    old, new):
+    """The arithmetic the column tile rests on, by ``megablox.gmm``'s own
+    ``cost_estimate``: ``x`` once a column tile, the weights once a group,
+    the output once. A layer of Mellum2's piece moves 1.50 GB at the old
+    tiles and 1.03 at the rule's, LFM2's 1.05 and 0.93."""
+    moved = lambda which: sum(
+        md._gmm_bytes(rows, groups, k, n, (t_old, t_new)[which])
+        for k, n, t_old, t_new in shapes)
+    assert abs(moved(0) - old) < 0.005e9 and abs(moved(1) - new) < 0.005e9
+    assert all(t_new == md._column_tile(k, n, 2)
+               for k, n, _t_old, t_new in shapes)
