@@ -1026,15 +1026,16 @@ def _kept_groups(group, topk_group: int, per: int):
 
 def sigmoid_bias_routing(scores, bias, top_k: int, scale: float = 1.0,
                          renorm: bool = True, n_group: int = 1,
-                         topk_group: int = 1):
+                         topk_group: int = 1, eps: float = 1e-6):
     """Sigmoid routing with a selection bias (LFM2's, and the
     auxiliary-loss-free balancing it comes from): ``scores`` [T, E] are
     the router's sigmoids in float32, each expert's own; ``bias`` [E]
     float32 is added for the SELECTION only (``top_k`` of ``scores +
     bias``, ties to the lower index) and enters no weight; the gates are
-    the chosen experts' unbiased scores, divided by their sum plus 1e-6
-    where ``renorm``, times ``scale``. Returns (gates [T, top_k] f32,
-    idx [T, top_k] int32).
+    the chosen experts' unbiased scores, divided by their sum plus
+    ``eps`` (LFM2's and Ling's 1e-6; Trinity's published 1e-20) where
+    ``renorm``, times ``scale``. Returns (gates [T, top_k] f32, idx [T,
+    top_k] int32).
 
     ``n_group`` > 1 limits the selection to groups first (DeepSeek-V3's
     ``noaux_tc``): the experts form ``n_group`` groups of consecutive
@@ -1053,7 +1054,7 @@ def sigmoid_bias_routing(scores, bias, top_k: int, scale: float = 1.0,
     _, idx = jax.lax.top_k(biased, top_k)
     w = jnp.take_along_axis(scores, idx, axis=-1)
     if renorm:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + eps)
     return w * scale, idx.astype(jnp.int32)
 
 

@@ -2,7 +2,8 @@
 heads and then all of its key heads, side by side (``[V | K]``, one pool a
 layer: ``docs/served_models.md`` says why): a prefill piece's blockwise
 attention over [history ; piece] and a decode step's over [pool ; in-call
-ring]. ``models/lfm2_moe.py`` and ``models/mellum.py`` share it; what
+ring]. ``models/lfm2_moe.py`` and, through ``models/window_kv.py``,
+``models/mellum.py`` and ``models/afmoe.py`` share it; what
 differs between them and between Mellum's two kinds of layer (a band, a
 start, the kernels' names in a trace) comes in as arguments that are absent
 by default, so a model without them compiles the program it always had.

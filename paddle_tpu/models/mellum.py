@@ -12,7 +12,10 @@ nothing from here): pre-norm residual layers ``x <- x + Attn_l(rms(x))``,
 plain rope on the window layers and YaRN on the full ones (its attention
 factor on cos and sin, ``models/rope.py``); a final norm and the head.
 
-What the engine sees (the interface of ``models/llama_served.py``):
+What the engine sees (the interface of ``models/llama_served.py``; the
+cache half of it, which Trinity shares, is ``models/window_kv.py``
+``TwoKindCache``: this file keeps the projections, both kinds' rope, the
+router and the kernels' names):
 
 - **two kinds of per-token cache entry in one manager.** A layer's pool
   row holds all of a token's heads, its 4 value heads and then its 4 key
@@ -61,11 +64,10 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels.moe_dispatch import held_expert_ffn, routing_from_logits
-from ..kernels.paged_attention import ragged_tpu_refusal
-from .flat_kv_attention import decode_attention, pack_rows, prefill_attention
 from .llama import _rms_norm
 from .llama_served import ServeOpts
 from .rope import rope_half, yarn_frequencies
+from .window_kv import TwoKindCache
 
 __all__ = ["MellumConfig", "MellumServed", "from_published",
            "PUBLISHED_LAYER_TYPES"]
@@ -115,30 +117,8 @@ def from_published(layer: Dict, c: MellumConfig) -> Dict:
     return out
 
 
-def _history_pad(tokens: int) -> int:
-    """A gathered history's width: a multiple of the flash kernel's key
-    tile (512, or 128 for a short one), so that no tile is narrower than
-    the MXU; the rows past the history are masked by its length."""
-    m = 512 if tokens > 512 else 128 if tokens > 128 else 1
-    return -(-tokens // m) * m
-
-
-def ring_positions(lens0, width: int, bs: int):
-    """For a ring table gathered dense ([N, width * bs] rows, column c of
-    the ring first): the position each row holds for a slot whose context
-    is ``lens0`` tokens, -1 where the column was never written. Column c
-    holds the newest logical block ``b <= (lens0 - 1) // bs`` with ``b %
-    width == c`` (``serving/window_ledger.py``)."""
-    newest = (lens0.astype(jnp.int32) - 1) // bs                   # [N]
-    c = jnp.arange(width, dtype=jnp.int32)[None, :]
-    b = newest[:, None] - jnp.mod(newest[:, None] - c, width)      # [N, w]
-    pos = b[:, :, None] * bs + jnp.arange(bs, dtype=jnp.int32)[None, None, :]
-    return jnp.where((b >= 0)[:, :, None], pos, -1).reshape(
-        lens0.shape[0], width * bs)
-
-
-class MellumServed:
-    cache_kind = "kv"
+class MellumServed(TwoKindCache):
+    trace_name = "mellum"
     state_entries = ()       # nothing is kept per slot beside the cache
     unsupported = {
         "spec": "there is no draft of this family and spec_verify is "
@@ -158,12 +138,6 @@ class MellumServed:
 
     def __init__(self, config: MellumConfig):
         c = config
-        bad = set(c.layer_types) - {"sliding_attention", "full_attention"}
-        if bad:
-            raise ValueError(f"unknown layer types {sorted(bad)}")
-        if c.num_heads % c.num_kv_heads or c.head_dim % 2:
-            raise ValueError(f"{c.num_heads} heads on {c.num_kv_heads} KV "
-                             f"heads of {c.head_dim}")
         if not c.norm_topk_prob:
             raise ValueError("the router renormalises its top-k "
                              "(routing_from_logits): norm_topk_prob false "
@@ -172,38 +146,7 @@ class MellumServed:
         self.num_layers = c.num_layers
         self.vocab_size = c.vocab_size
         self.dtype = c.dtype
-        self.window = int(c.sliding_window)
-        # a layer's index among the layers of its own kind: which plane of
-        # its kind's pools it writes
-        self._full = [l for l, t in enumerate(c.layer_types)
-                      if t == "full_attention"]
-        self._win = [l for l, t in enumerate(c.layer_types)
-                     if t == "sliding_attention"]
-        if not self._full or not self._win:
-            raise ValueError("both kinds of layer are expected: the dense "
-                             "family serves a model of one kind")
-        # the pool entries of the WINDOW kind: a ring a slot in the engine
-        self.window_entries = tuple(f"kvw{a}" for a in range(len(self._win)))
-
-    # -- the cache -----------------------------------------------------------
-    def make_pools(self, nb: int, bs: int, kv_int8: bool = False,
-                   prefix: str = "", nb_window: int = 0) -> Dict:
-        c = self.config
-        row = (bs, 2 * c.num_kv_heads * c.head_dim)              # [V | K]
-        return {f"{prefix}kv{kind}{a}": jnp.zeros((1, n) + row, c.dtype)
-                for kind, ls, n in (("f", self._full, nb),
-                                    ("w", self._win, nb_window))
-                for a in range(len(ls))}
-
-    def ragged_refusal(self, kv_int8: bool):
-        c = self.config                              # rows of 1024 lanes
-        return ragged_tpu_refusal(2 * c.num_kv_heads * c.head_dim, kv_int8)
-
-    @staticmethod
-    def history_blocks(hist_blocks: int, mb: int) -> int:
-        """Full width or none: the history kernels take a row's length as
-        a runtime operand and skip the tiles past it."""
-        return mb if hist_blocks else 0
+        self._init_kinds()
 
     # -- top of the model ----------------------------------------------------
     def embed(self, params, tokens):
@@ -237,16 +180,12 @@ class MellumServed:
                                 c.rope_beta_slow)
         return {"w": (plain, 1.0), "f": (yarn, c.rope_attention_factor)}
 
-    def _kind(self, l: int) -> Tuple[str, int]:
-        """("f" | "w", the layer's plane in its kind's pools)."""
-        if self.config.layer_types[l] == "full_attention":
-            return "f", self._full.index(l)
-        return "w", self._win.index(l)
-
-    def _qkv(self, hn, p, ang, mscale):
+    def _qkv(self, hn, p, ang):
         """Normed, roped queries [..., H, D] and keys [..., Hkv, D], and
-        values. The barrier holds the three products [..., out] in the
-        compiled program (``LlamaServed._qkv``, PR 29)."""
+        values; ``ang`` the kind's (angles, factor on cos and sin). The
+        barrier holds the three products [..., out] in the compiled
+        program (``LlamaServed._qkv``, PR 29)."""
+        ang, mscale = ang
         c, dt = self.config, self.dtype
         q, k, v = jax.lax.optimization_barrier(
             tuple(hn @ p[w].astype(dt) for w in ("wq", "wk", "wv")))
@@ -259,6 +198,9 @@ class MellumServed:
         k = rope_half(_rms_norm(k, p["k_norm"], c.rms_eps), ang, mscale)
         return q, k, v
 
+    def _attn_out(self, p, o, hn):
+        return o @ p["wo"].astype(self.dtype)
+
     def _ffn(self, p, x, valid):
         """x [T, h] -> (y, counts): every layer is sparse."""
         c, dt = self.config, self.dtype
@@ -269,57 +211,6 @@ class MellumServed:
                                p["e_gu"], p["e_down"], 0)
 
     # -- prefill -------------------------------------------------------------
-    def prefill_begin(self, params, pools, tokens, true_len, hist_len,
-                      ctx_tbl, prefix_nbk: int, opts: ServeOpts, win=None):
-        B, S = tokens.shape
-        start = (jnp.zeros((B,), jnp.float32) if hist_len is None
-                 else hist_len.astype(jnp.float32))
-        pos = start[:, None] + jnp.arange(S, dtype=jnp.float32)[None, :]
-        aux = {"ang": {k: (pos[:, :, None] * f[None, None, :], m)
-                       for k, (f, m) in self._freqs().items()},
-               "prefix_nbk": prefix_nbk, "hist_len": hist_len,
-               "ctx_tbl": ctx_tbl,
-               # pad positions of a row and pad rows are not routed
-               "valid": (jnp.arange(S)[None, :]
-                         < true_len[:, None]).reshape(B * S)}
-        if prefix_nbk:
-            # the window layers' history: the ring's blocks that hold the
-            # last W - 1 tokens before the piece, in order, padded with
-            # the trash block to a width the flash kernel tiles well
-            tbl = win["ctx_tbl"]
-            bs = pools[f"{opts.prefix}kvw0"].shape[2]
-            width = _history_pad(tbl.shape[1] * bs) // bs
-            aux["win_tbl"] = jnp.pad(tbl, ((0, 0), (0, width - tbl.shape[1])))
-            aux["win_len"] = hist_len.astype(jnp.int32) - win["ctx_start"]
-        return aux
-
-    def _prefill_attention(self, p, kind: str, a: int, hn, aux, pools, opts):
-        """Attention of a piece over [history ; piece]: both parts
-        blockwise, one softmax. A window layer's history is the last W - 1
-        tokens under the band ``i - j < W``; a full layer's is all of it."""
-        B, S, _ = hn.shape
-        Hkv, W = self.config.num_kv_heads, self.window
-        q, k, v = self._qkv(hn, p, *aux["ang"][kind])
-        # inside a piece the band cuts nothing unless the bucket is longer
-        # than the window (a static fact of the program)
-        band = (jnp.full((B * Hkv,), 1 - W, jnp.int32)
-                if kind == "w" and S > W else None)
-        history = None
-        pool = pools[f"{opts.prefix}kv{kind}{a}"]
-        if aux["prefix_nbk"] and kind == "f":
-            history = (pool, aux["ctx_tbl"], aux["hist_len"], None,
-                       "mellum_history_full")
-        elif aux["prefix_nbk"]:
-            # gathered key j is position ctx_start + j, query row i
-            # position hist_len + i: i - j < W in the rows' own indices
-            history = (pool, aux["win_tbl"], aux["win_len"],
-                       jnp.repeat(aux["win_len"] - W + 1, Hkv),
-                       "mellum_history_window")
-        o = prefill_attention(q, k, v, chunk_name="mellum_prefill_chunk",
-                              chunk_band=band, history=history)
-        return (o @ p["wo"].astype(self.dtype),
-                {f"kv{kind}": pack_rows(k, v)})
-
     def prefill_mix(self, params, l: int, x, aux, pools, opts: ServeOpts):
         """The token-mixing half of a piece's layer: x [B, S, h] -> (x +
         attention of its kind, the layer's new entries)."""
@@ -348,92 +239,7 @@ class MellumServed:
         y, ent["_stats"] = self._ffn(p, hn.reshape(B * S, h), aux["valid"])
         return x + y.reshape(B, S, h), ent
 
-    def pack_entries(self, new: Dict, opts: ServeOpts) -> Dict:
-        """Rows stacked over a kind's layers [L_kind, ..., 2 * Hkv * D],
-        as each layer's own pool."""
-        return {f"{opts.prefix}{n}{a}": rows[a:a + 1]
-                for n, rows in new.items() for a in range(rows.shape[0])}
-
     # -- decode --------------------------------------------------------------
-    def ring_init(self, N: int, S: int, opts: ServeOpts) -> Dict:
-        c = self.config
-        row = (N, S, 2 * c.num_kv_heads * c.head_dim)
-        ring = {f"kv{kind}": jnp.zeros((len(ls),) + row, c.dtype)
-                for kind, ls in (("f", self._full), ("w", self._win))}
-        ring["_stats"] = jnp.zeros((5,), jnp.float32)
-        return ring
-
-    def decode_begin(self, params, pools, block_table, lens0, active,
-                     n_steps: int, opts: ServeOpts, win_table=None):
-        c = self.config
-        N, MB = block_table.shape
-        Hkv, D = c.num_kv_heads, c.head_dim
-        aux = {"freqs": self._freqs(), "block_table": block_table,
-               "win_table": win_table, "lens0": lens0.astype(jnp.int32)}
-        if opts.ragged:
-            # slots outside the decode set walk zero blocks
-            aux["walk_lens"] = jnp.where(active, lens0.astype(jnp.int32), 0)
-            return aux
-        # off a TPU: one dense gather of every slot's frozen prefix, the
-        # full kind's through its table, the window kind's ring as it lies
-        # with the position each of its rows holds
-        px = opts.prefix
-        bs = pools[px + "kvf0"].shape[2]
-        for kind, ls, tbl in (("f", self._full, block_table),
-                              ("w", self._win, win_table)):
-            dense = [pools[f"{px}kv{kind}{a}"][0][tbl].reshape(
-                N, -1, 2, Hkv, D) for a in range(len(ls))]
-            aux[f"kd{kind}"] = [r[:, :, 1] for r in dense]
-            aux[f"vd{kind}"] = [r[:, :, 0] for r in dense]
-        aux["pos_f"] = jnp.broadcast_to(
-            jnp.arange(MB * bs, dtype=jnp.int32)[None, :], (N, MB * bs))
-        aux["pos_w"] = ring_positions(lens0, win_table.shape[1], bs)
-        return aux
-
-    def decode_step_begin(self, aux, lens, t, S: int):
-        W = self.window
-        lens = lens.astype(jnp.int32)
-        lens0 = aux["lens0"]
-        # what a window layer may see of a query at position ``lens``:
-        # [lens - W + 1, lens], of which the pool holds [.., lens0)
-        start = jnp.maximum(lens - W + 1, 0)
-        ring_pos = lens0[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
-        in_call = (jnp.arange(S) <= t)[None, :]
-        step = {"ang": {k: (lens.astype(jnp.float32)[:, None] * f[None, :], m)
-                        for k, (f, m) in aux["freqs"].items()},
-                "start": start,
-                "ring_mask": {
-                    "f": in_call[:, None, None, :],
-                    "w": (in_call & (ring_pos >= start[:, None])
-                          )[:, None, None, :]}}
-        if "pos_w" in aux:
-            held = lambda pos: (pos >= 0) & (pos < lens0[:, None])
-            step["pre_mask"] = {
-                "f": held(aux["pos_f"])[:, None, None, :],
-                "w": (held(aux["pos_w"]) & (aux["pos_w"] >= start[:, None])
-                      )[:, None, None, :]}
-        return step
-
-    def _decode_attention(self, p, kind: str, a: int, hn, aux, step, ring,
-                          t, pools, opts):
-        dt = self.dtype
-        name = f"kv{kind}"
-        q, kk, vv = self._qkv(hn, p, *step["ang"][kind])
-        walk = dense = None
-        if opts.ragged and kind == "f":
-            walk = (pools[f"{opts.prefix}{name}{a}"], aux["block_table"],
-                    aux["walk_lens"], None, "mellum_walk_full")
-        elif opts.ragged:
-            walk = (pools[f"{opts.prefix}{name}{a}"], aux["win_table"],
-                    aux["walk_lens"], step["start"], "mellum_walk_window")
-        else:
-            dense = (aux[f"kd{kind}"][a], aux[f"vd{kind}"][a],
-                     step["pre_mask"][kind])
-        att, rkv = decode_attention(
-            q, kk, vv, ring[name], a, t, step["ring_mask"][kind], dt,
-            walk=walk, dense=dense)
-        return att @ p["wo"].astype(dt), {**ring, name: rkv}
-
     def decode_mix(self, params, l: int, x, aux, step, ring, t, pools, act,
                    opts: ServeOpts):
         """The token-mixing half of a decode step's layer: x [N, 1, h] ->

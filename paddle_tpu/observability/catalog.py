@@ -287,6 +287,13 @@ CATALOG = {
         "counter", (),
         "window-kind blocks written again in place behind the window (what "
         "a free list would have been given back and asked for again)"),
+    "serving_window_bounded_tokens_total": (
+        "counter", (),
+        "generated tokens whose context (prompt + tokens so far, the token "
+        "itself among them) had passed the window: their window layers read "
+        "a ring that recycles, not a whole context; over "
+        "serving_tokens_total it is the share of the decoding that the "
+        "window bounds (any model with window entries)"),
     "serving_prefill_programs_total": (
         "counter", ("carried",),
         "prefill programs dispatched by an engine whose pieces carry the "

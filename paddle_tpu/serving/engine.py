@@ -164,6 +164,7 @@ _M_STATE_SLOT_BYTES = _instrument("serving_state_bytes_per_slot")
 _M_STATE_RESETS = _instrument("serving_state_resets_total")
 _M_WINDOW_SLOT_BYTES = _instrument("serving_window_bytes_per_slot")
 _M_WINDOW_RECYCLED = _instrument("serving_window_blocks_recycled_total")
+_M_WINDOW_BOUNDED = _instrument("serving_window_bounded_tokens_total")
 _M_PREFILL_PROGRAMS = _instrument("serving_prefill_programs_total")
 _M_DECODE_STEPS = _instrument("serving_decode_steps_total")
 _M_STARVED = _instrument("serving_device_starved_seconds_total")
@@ -1036,6 +1037,7 @@ class LLMEngine:
         self._table_dirty = True
         self._table_dev = {}         # prefix-bucket (blocks) → device table
         self._win_recycled = 0       # of win.recycled, what the counter has
+        self._win_bounded = 0        # tokens emitted past the window, unflushed
         # the dispatched-but-unread decode call (pipeline depth 1): its
         # tokens are fetched while the NEXT call occupies the chip
         self._inflight = None
@@ -2440,6 +2442,10 @@ class LLMEngine:
         req = self.slot_req[slot]
         self.slot_out[slot].append(tok)
         n_gen = len(req.generated) + len(self.slot_out[slot])
+        if self.win is not None and len(req.prompt) + n_gen > self.win.W:
+            # the token's context had passed the window: its window layers
+            # read a ring that is being written again, not a whole context
+            self._win_bounded += 1
         done = (req.eos_token_id is not None and tok == req.eos_token_id) \
             or n_gen >= req.max_new_tokens
         if done:
@@ -3442,6 +3448,8 @@ class LLMEngine:
                                      * self.win.width)
             _M_WINDOW_RECYCLED.inc(self.win.recycled - self._win_recycled)
             self._win_recycled = self.win.recycled
+            _M_WINDOW_BOUNDED.inc(self._win_bounded)
+            self._win_bounded = 0
         if self.prefix_cache is not None:
             self.prefix_cache.update_gauges()
         # time-series sampler (r20): throttled by FLAGS_obs_ts_interval_s,

@@ -29,7 +29,7 @@ Stale rows: the columns of a block that is being written again hold, past
 the newest token, rows of the block ``width`` before, and a slot taken by a
 new request holds its predecessor's rows. Every reader masks by position:
 the walk and the banded history by ``[start, length)``, the dense gather
-off a TPU by the position each row of the ring holds (``models/mellum.py``
+off a TPU by the position each row of the ring holds (``models/window_kv.py``
 ``ring_positions``).
 """
 from __future__ import annotations

@@ -33,7 +33,7 @@ F32 = jnp.float32
 GREEDY = (False, False, False)
 FAMILIES = {"lfm2": "test_lfm2_moe", "mellum": "test_mellum",
             "deepseek_v2": "test_deepseek_v2_served",
-            "ling_hybrid": "test_ling_hybrid"}
+            "ling_hybrid": "test_ling_hybrid", "afmoe": "test_afmoe"}
 # (top-k, expert layers) of the tiny models whose experts are ALL held
 ALL_HELD = {"lfm2": (2, 2), "mellum": (2, 4)}
 N, BS, MML, CHUNK = 3, 8, 128, 16
