@@ -18,9 +18,11 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -329,6 +331,158 @@ def flash_attention_fwd(q, k, v, causal: bool = False,
 # group, and the log-sum-exp returned so that two calls combine
 # ---------------------------------------------------------------------------
 
+# the fast memory a Mosaic kernel may take on a v5e unless it raises the limit
+_FLASH_VMEM = 16 << 20
+# the widest key tile the rule answers, without a band and under one
+_FLASH_KV_MAX, _FLASH_KV_MAX_BANDED = 1024, 512
+# scores in a causal call's square tile (all the group's rows by its keys)
+_FLASH_CAUSAL_TILE = 1 << 20
+
+
+def _flash_footprint(groups: int, bq: int, bkv: int, Dk: int, Dv: int,
+                     item: int, v_cols: bool = False,
+                     cut: bool = False) -> int:
+    """Bytes of the chip's fast memory a ``flash_partial`` step holds at a
+    tile of ``groups * bq`` rows by ``bkv`` keys, reckoned against what
+    Mosaic reports (``Scoped allocation with size ...`` in a
+    described-topology compile; 14 tiles of the published shapes read in
+    PR 43: the reckoning from 0.03 MiB under to a few MiB over, so a tile
+    it admits at the limit's edge is held by the compiles in
+    ``tests/test_aot_chip_compile.py``): the operand and output
+    blocks twice each (the pipeline fetches the next while this one is
+    worked on; a [rows, 1] block takes a lane tile a row), the float32
+    accumulator and the two statistics, the tile's scores in float32, two
+    temporaries of a statistic's size, the value columns where they are
+    sliced from the keys, and under a diagonal or a band (``cut``) the
+    edge tiles' row and column indices and their mask, a POSITION's."""
+    rows = groups * bq
+    lanes = lambda d: -(-d // STATS) * STATS
+    kv = lanes(Dk) + (0 if v_cols else lanes(Dv))
+    blocks = 2 * (item * (rows * (lanes(Dk) + lanes(Dv)) + bkv * kv)
+                  + 4 * rows * STATS)
+    scratch = 4 * rows * (lanes(Dv) + 2 * STATS)
+    return (blocks + scratch + 4 * rows * bkv + 2 * 4 * rows * STATS
+            + (item * bkv * lanes(Dv) if v_cols else 0)
+            + (4 * bq * bkv if cut else 0))
+
+
+def flash_tiles(groups: int, S: int, T: int, Dk: int, Dv: int,
+                item: int = 2, causal: bool = False,
+                v_cols: bool = False, banded: bool = False
+                ) -> Tuple[int, int]:
+    """``flash_partial``'s tile ``(block_q, block_kv)`` from what the call
+    can see of its operands, never from a model's name: ``block_q``
+    positions of all ``groups`` heads (a power of two from 1024 down to
+    128 that divides S) by ``block_kv`` keys (a multiple of 128 that
+    divides T: the MXU asks for no power of two, and a divisor leaves no
+    side padded), such that the step fits the kernel's fast memory
+    (``_flash_footprint``). Read on the chip, the kernel alone at the
+    published shapes (``PERF.md`` section 6, PR 43):
+
+    - Keys every row may see but for a length and a band (a history):
+      the TALLEST ``block_q`` that fits beside a key tile of 512, then
+      the widest key tile that still fits beside it. Taller first: at
+      equal scores a step the taller tile won every shape read (Trinity's
+      8k history 1.75 ms at (256, 1024), 1.85 at (256, 512), 1.97 at (128,
+      1024)): a key tile is fetched once a ``block_q``. The key tile is
+      at most 1,024 wide, 512 under a band (a band cuts two edges of
+      every query tile's keys, and what an edge tile wastes grows with
+      its width: 4,608 banded keys 0.83 ms at 512, 0.98 at 768), and lines
+      up with S (divides it or is a multiple of it): a history is whole
+      pieces, so such a tile's edge falls on its length and no tile is
+      masked (Mellum2's 8k: 1.17 ms at 512, 1.25 at 768). Wider halves
+      the tiles past the length, which a table of 34,816 keys has 52 of
+      68 of at 8k of history.
+    - A causal call over its own keys (a chunk): a SQUARE tile, the
+      largest of at most ``_FLASH_CAUSAL_TILE`` scores. The diagonal's
+      tiles are half wasted whatever their size, ``1 + 1 / n`` of the
+      triangle's work at n tiles a side, against a step's fixed cost n (n
+      + 1) / 2 times (a group of 1 at 256 keys wide takes 1024: 0.76 ms
+      against 0.93 at 512; groups of 4 to 8 read the same at every tile
+      tried).
+
+    Sides that no 128 divides (tests, short buckets) take ``_pick_block``'s
+    answer, as before."""
+    fits = lambda bq, bkv: _flash_footprint(
+        groups, bq, bkv, Dk, Dv, item, v_cols, causal or banded) \
+        <= _FLASH_VMEM
+    qs = [b for b in (1024, 512, 256, 128) if S % b == 0] \
+        or [_pick_block(S, 512)]
+    if causal and S == T:
+        square = [b for b in qs if fits(b, b)
+                  and groups * b * b <= _FLASH_CAUSAL_TILE]
+        if square:
+            return square[0], square[0]
+    widest = _FLASH_KV_MAX_BANDED if banded else _FLASH_KV_MAX
+    ks = [t for t in range(128, min(T, widest) + 1, 128)
+          if T % t == 0 and (S % t == 0 or t % S == 0)] \
+        or [_pick_block(T, 512)]
+    base = max(t for t in ks if t <= 512)
+    bq = next((b for b in qs if fits(b, base)), qs[-1])
+    return bq, max([t for t in ks if t >= base and fits(bq, t)] or [base])
+
+
+def _tile_kind(q0, c0, bq: int, bkv: int, kv_len, lo, causal: bool):
+    """What a tile of ``bq`` positions from ``q0`` by ``bkv`` keys from
+    column ``c0`` is, from scalars alone, before the tile is touched:
+    ``run``: some (row, column) of it is inside every mask; ``whole``:
+    every one is (an interior tile). ``lo`` None: no band. The kernel
+    calls it on traced scalars, ``flash_tile_counts`` on numpy arrays."""
+    run = c0 < kv_len
+    whole = c0 + bkv <= kv_len
+    if causal:
+        # a tile wholly above the diagonal contributes nothing; one whose
+        # last column is the first row's own lies wholly under it
+        run = run & (c0 <= q0 + bq - 1)
+        whole = whole & (c0 + bkv - 1 <= q0)
+    if lo is not None:
+        # row r sees no key before column r + lo: a tile whose last
+        # column lies before the first row's bound is outside the band,
+        # one whose first column is the last row's bound wholly inside
+        run = run & (c0 + bkv - 1 >= q0 + lo)
+        whole = whole & (c0 >= q0 + bq - 1 + lo)
+    return run, whole
+
+
+def flash_tile_counts(S: int, T: int, kv_len: int, band_lo=None,
+                      causal: bool = False, *, bq: int, bkv: int
+                      ) -> Tuple[int, int, int]:
+    """``(interior, edge, skipped)``: the grid steps of one KV head's
+    ``flash_partial`` call by what the kernel does in them (``_tile_kind``,
+    its own rule): the unmasked branch, the masked one, nothing. A step's
+    tile holds every query head of the group, so the counts are a KV
+    head's whatever the group's size."""
+    q0 = np.arange(0, S, bq, dtype=np.int64)[:, None]
+    c0 = np.arange(0, T, bkv, dtype=np.int64)[None, :]
+    run, whole = _tile_kind(q0, c0, bq, bkv, int(kv_len),
+                            None if band_lo is None else int(band_lo),
+                            causal)
+    run = np.broadcast_to(run, (q0.size, c0.size))
+    interior = int((run & whole).sum())
+    edge = int(run.sum()) - interior
+    return interior, edge, run.size - interior - edge
+
+
+def flash_call_tiles(groups: int, S: int, T: int, Dk: int, Dv: int,
+                     kv_len=None, band_lo=None, causal: bool = False,
+                     item: int = 2, v_cols: bool = False
+                     ) -> Tuple[int, int, int]:
+    """``flash_tile_counts`` of a call's one KV head at the tile
+    ``flash_partial`` itself chooses for those operands."""
+    bq, bkv = flash_tiles(groups, S, T, Dk, Dv, item, causal, v_cols,
+                          band_lo is not None)
+    return flash_tile_counts(S, T, T if kv_len is None else kv_len, band_lo,
+                             causal, bq=bq, bkv=bkv)
+
+
+def _lanes(x, n: int):
+    """x [rows, STATS], a row's value in every lane, at n lanes."""
+    if n % STATS == 0:
+        return x if n == STATS else pltpu.repeat(x, n // STATS, 1)
+    return x[:, :n] if n < STATS else jnp.broadcast_to(
+        x[:, :1], (x.shape[0], n))
+
+
 def _partial_kernel(len_ref, *rest, scale, causal, block_q, block_kv,
                     groups, v_cols, banded=False):
     if banded:
@@ -338,9 +492,14 @@ def _partial_kernel(len_ref, *rest, scale, causal, block_q, block_kv,
         v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
     else:
         o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
-    i, j = pl.program_id(1), pl.program_id(2)
+    g, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     nj = pl.num_programs(2)
-    kv_len = len_ref[pl.program_id(0) // groups]
+    kv_len = len_ref[g]
+    # the tile: ``block_q`` positions of EVERY query head of KV head g
+    # (rows h * block_q + r: head h of the group, position q0 + r) against
+    # ``block_kv`` keys from column c0
+    rows = groups * block_q
+    q0, c0 = i * block_q, j * block_kv
 
     @pl.when(j == 0)
     def _():
@@ -348,60 +507,67 @@ def _partial_kernel(len_ref, *rest, scale, causal, block_q, block_kv,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    run = j * block_kv < kv_len
-    if causal:
-        # tile fully above the diagonal contributes nothing
-        run &= (j * block_kv) <= (i * block_q + block_q - 1)
-    if banded:
-        # row r sees no key before column r + lo: a tile whose last
-        # column lies before the first row's bound is outside the band
-        lo = lo_ref[pl.program_id(0) // groups]
-        run &= (j * block_kv + block_kv - 1) >= (i * block_q + lo)
+    lo = lo_ref[g] if banded else None
+    run, whole = _tile_kind(q0, c0, block_q, block_kv, kv_len, lo, causal)
 
-    @pl.when(run)
-    def _():
-        q = q_ref[0]
+    def tile(cut: bool):
+        q = q_ref[0].reshape(rows, q_ref.shape[-1])
         k = k_ref[0]
         v = k[:, :v_cols] if v_cols is not None else v_ref[0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + j * block_kv
-        keep = col < kv_len
-        if causal:
-            row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) \
-                + i * block_q
-            keep &= row >= col
-        if banded:
-            row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) \
-                + i * block_q
-            keep &= col >= row + lo
-        s = jnp.where(keep, s, jnp.float32(NEG_INF))
-        m_prev = m_ref[:, :1]
+        if cut:
+            # one mask a POSITION, shared by the group's heads
+            shape = (block_q, block_kv)
+            col = jax.lax.broadcasted_iota(jnp.int32, shape, 1) + c0
+            keep = col < kv_len
+            if causal or banded:
+                row = jax.lax.broadcasted_iota(jnp.int32, shape, 0) + q0
+            if causal:
+                keep &= row >= col
+            if banded:
+                keep &= col >= row + lo
+            masked = lambda x, fill: jnp.where(
+                keep[None], x.reshape(groups, block_q, block_kv),
+                jnp.float32(fill)).reshape(rows, block_kv)
+            s = masked(s, NEG_INF)
+        # the statistics stay as they lie in their scratch, a row's value
+        # in every one of its 128 lanes: a [rows, 1] column is spread over
+        # the lanes twice a tile (the tile's max, the tile's sum) and
+        # nowhere else
+        m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        # a row with no key yet keeps m = NEG_INF: exp(s - m) would be 1
-        p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
-        l_new = l_ref[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        p = jnp.exp(s - _lanes(m_new, block_kv))
+        if cut:
+            # a row with no key yet keeps m = NEG_INF: exp(s - m) would be
+            # 1. (An interior tile gives every one of its rows block_kv
+            # keys, so m_new is finite there and a row that had seen none
+            # takes alpha = 0: nothing to select.)
+            p = masked(p, 0.0)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
         pv = jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * alpha + pv
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        acc_ref[...] = acc_ref[...] * _lanes(alpha, pv.shape[-1]) + pv
+        m_ref[...] = m_new
+
+    pl.when(run & whole)(functools.partial(tile, False))
+    pl.when(run & jnp.logical_not(whole))(functools.partial(tile, True))
 
     @pl.when(j == nj - 1)
     def _():
         l = l_ref[:, :1]
-        o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-30)).astype(
+            o_ref.dtype).reshape(o_ref.shape[1:])
         lse_ref[0] = jnp.where(l > 0, m_ref[:, :1] + jnp.log(
-            jnp.maximum(l, 1e-30)), NEG_INF)
+            jnp.maximum(l, 1e-30)), NEG_INF).reshape(lse_ref.shape[1:])
 
 
 def flash_partial(q, k, v=None, *, scale: float, causal: bool = False,
-                  kv_len=None, v_cols=None, block_q: int = 512,
-                  block_kv: int = 512, name: str = "flash_partial",
-                  band_lo=None):
+                  kv_len=None, v_cols=None, block_q=None, block_kv=None,
+                  name: str = "flash_partial", band_lo=None):
     """Blockwise softmax attention in partial form, forward only.
 
     q: [G, S, Dk]; k: [Gk, T, Dk] with G a multiple of Gk (group g reads
@@ -418,6 +584,19 @@ def flash_partial(q, k, v=None, *, scale: float, causal: bool = False,
     start ``off`` positions before the queries: ``off - W + 1``), and key
     tiles wholly before a query tile's band are neither fetched nor
     computed.
+
+    The tile. A grid step takes ``block_q`` query POSITIONS of all the
+    ``G // Gk`` heads that read one KV head (``groups * block_q`` rows of
+    one matmul) against ``block_kv`` keys: a K and a V tile are named, and
+    a step's fixed cost and every tile past the length paid, once a group
+    and not once a query head. From scalars alone a step knows its tile as
+    *skipped* (no (row, column) inside the masks), *interior* (every one
+    inside the length, under the diagonal and inside the band: no iota, no
+    compare, no select is computed) or *edge* (the rest: one mask a
+    position, shared by the group's heads): ``flash_tile_counts``. The
+    sizes follow from the operands' shapes (``flash_tiles``); ``block_q``
+    / ``block_kv`` override them, for tests.
+
     Returns ``(o [G, S, Dv] in q's dtype, lse [G, S] f32)``: normalised
     output and log-sum-exp, -1e30 where a row saw no key, so that
     ``combine_partials`` merges calls over disjoint key sets. Nothing of
@@ -428,7 +607,12 @@ def flash_partial(q, k, v=None, *, scale: float, causal: bool = False,
     assert G % Gk == 0 and (v is None) != (v_cols is None), (G, Gk, v_cols)
     groups = G // Gk
     Dv = v_cols if v is None else v.shape[-1]
-    bq, bkv = _pick_block(S, block_q), _pick_block(T, block_kv)
+    bq, bkv = flash_tiles(groups, S, T, Dk, Dv, q.dtype.itemsize, causal,
+                          v is None, band_lo is not None)
+    if block_q is not None:
+        bq = _pick_block(S, block_q)
+    if block_kv is not None:
+        bkv = _pick_block(T, block_kv)
     if kv_len is None:
         kv_len = jnp.full((Gk,), T, jnp.int32)
 
@@ -437,24 +621,25 @@ def flash_partial(q, k, v=None, *, scale: float, causal: bool = False,
         def kv_map(g, i, j, lens, lo):
             # before the band and past the length the nearest tile inside
             # is named again: no new copy
-            last = jnp.maximum((lens[g // groups] + bkv - 1) // bkv - 1, 0)
-            first = jnp.clip((i * bq + lo[g // groups]) // bkv, 0, last)
-            return (g // groups, jnp.clip(j, first, last), 0)
+            last = jnp.maximum((lens[g] + bkv - 1) // bkv - 1, 0)
+            first = jnp.clip((i * bq + lo[g]) // bkv, 0, last)
+            return (g, jnp.clip(j, first, last), 0)
 
-        q_map = lambda g, i, j, lens, lo: (g, i, 0)
+        q_map = lambda g, i, j, lens, lo: (g, 0, i, 0)
         scalars = [kv_len.astype(jnp.int32), band_lo.astype(jnp.int32)]
     else:
         def kv_map(g, i, j, lens):
             # past the length the same tile is named again: no new copy
-            last = jnp.maximum((lens[g // groups] + bkv - 1) // bkv - 1, 0)
-            return (g // groups, jnp.minimum(j, last), 0)
+            last = jnp.maximum((lens[g] + bkv - 1) // bkv - 1, 0)
+            return (g, jnp.minimum(j, last), 0)
 
-        q_map = lambda g, i, j, lens: (g, i, 0)
+        q_map = lambda g, i, j, lens: (g, 0, i, 0)
         scalars = [kv_len.astype(jnp.int32)]
 
-    in_specs = [pl.BlockSpec((1, bq, Dk), q_map),
+    # the heads of a group are neighbours in q: a view, no copy
+    in_specs = [pl.BlockSpec((1, groups, bq, Dk), q_map),
                 pl.BlockSpec((1, bkv, Dk), kv_map)]
-    operands = [q, k]
+    operands = [q.reshape(Gk, groups, S, Dk), k]
     if v is not None:
         in_specs.append(pl.BlockSpec((1, bkv, Dv), kv_map))
         operands.append(v)
@@ -463,21 +648,22 @@ def flash_partial(q, k, v=None, *, scale: float, causal: bool = False,
                                v_cols=v_cols)
     if banded:
         kernel = functools.partial(kernel, banded=True)
+    rows = groups * bq
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(scalars), grid=(G, S // bq, T // bkv),
+            num_scalar_prefetch=len(scalars), grid=(Gk, S // bq, T // bkv),
             in_specs=in_specs,
-            out_specs=[pl.BlockSpec((1, bq, Dv), q_map),
-                       pl.BlockSpec((1, bq, 1), q_map)],
-            scratch_shapes=[pltpu.VMEM((bq, Dv), jnp.float32),
-                            pltpu.VMEM((bq, STATS), jnp.float32),
-                            pltpu.VMEM((bq, STATS), jnp.float32)]),
-        out_shape=[jax.ShapeDtypeStruct((G, S, Dv), q.dtype),
-                   jax.ShapeDtypeStruct((G, S, 1), jnp.float32)],
+            out_specs=[pl.BlockSpec((1, groups, bq, Dv), q_map),
+                       pl.BlockSpec((1, groups, bq, 1), q_map)],
+            scratch_shapes=[pltpu.VMEM((rows, Dv), jnp.float32),
+                            pltpu.VMEM((rows, STATS), jnp.float32),
+                            pltpu.VMEM((rows, STATS), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((Gk, groups, S, Dv), q.dtype),
+                   jax.ShapeDtypeStruct((Gk, groups, S, 1), jnp.float32)],
         interpret=_interpret(), name=name,
     )(*scalars, *operands)
-    return out, lse[..., 0]
+    return out.reshape(G, S, Dv), lse.reshape(G, S)
 
 
 def combine_partials(o1, lse1, o2, lse2):

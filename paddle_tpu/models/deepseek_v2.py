@@ -62,7 +62,8 @@ import jax.numpy as jnp
 
 from ..kernels.moe_dispatch import group_limited_routing, held_expert_ffn
 from ..kernels.paged_attention import latent_decode_partial
-from ..kernels.pallas_attention import combine_partials, flash_partial
+from ..kernels.pallas_attention import (combine_partials, flash_call_tiles,
+                                        flash_partial)
 from .llama import _rms_norm
 from .llama_served import ServeOpts
 from .rope import rope_half as _rope
@@ -366,6 +367,24 @@ class DeepseekV2Served:
         if gated:
             rows += (jax.nn.sigmoid(hn @ p["w_g"].astype(dt)),)
         return x + jax.lax.map(one_row, rows), {"c": row}
+
+    def piece_flash_tiles(self, S: int, hist: int, pnbk: int, bs: int):
+        """The grid steps of a piece's blockwise attention by kernel and
+        kind, over the layers: the chunk's keys are expanded a head (a
+        group of one, a call's KV heads the query heads), the history's
+        heads are rows of one group against the latent rows as they
+        lie, ``pnbk`` blocks wide of which ``hist`` tokens are real."""
+        c = self.config
+        L, H = self.num_layers, c.num_heads
+        dqk = c.qk_nope_head_dim + c.qk_rope_head_dim
+        chunk = flash_call_tiles(1, S, S, dqk + -dqk % 128, c.v_head_dim,
+                                 causal=True)
+        out = {"mla_prefill_chunk": tuple(L * H * t for t in chunk)}
+        if pnbk:
+            out["mla_prefill_history"] = tuple(L * t for t in flash_call_tiles(
+                1, S * H, pnbk * bs, c.latent_width, c.kv_lora_rank,
+                kv_len=hist, v_cols=True))
+        return out
 
     def ffn(self, params, l: int, rows, valid):
         """The row-wise half of a layer, whatever program the rows come

@@ -17,9 +17,11 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels.paged_attention import flat_decode_partial
-from ..kernels.pallas_attention import combine_partials, flash_partial
+from ..kernels.pallas_attention import (combine_partials, flash_call_tiles,
+                                        flash_partial)
 
-__all__ = ["prefill_attention", "decode_attention", "pack_rows"]
+__all__ = ["prefill_attention", "prefill_attention_tiles",
+           "decode_attention", "pack_rows"]
 
 
 def pack_rows(k, v):
@@ -57,6 +59,23 @@ def prefill_attention(q, k, v, *, chunk_name: str, chunk_band=None,
             kv_len=n_hist, band_lo=band, name=name)
         o = combine_partials(o, lse, o_h, lse_h)
     return jnp.swapaxes(o.reshape(B, H, S, D), 1, 2).reshape(B, S, H * D)
+
+
+def prefill_attention_tiles(S: int, H: int, Hkv: int, D: int, *,
+                            chunk_band=None, history=None):
+    """``prefill_attention``'s two calls counted, host side and from
+    numbers alone: ``(the chunk's, the history's or None)``, each the
+    ``(interior, edge, skipped)`` grid steps of ONE KV head
+    (``kernels.pallas_attention.flash_tile_counts``). ``chunk_band``: the
+    band's lower bound inside the piece; ``history``: ``(keys gathered,
+    keys real, lower bound or None)``."""
+    chunk = flash_call_tiles(H // Hkv, S, S, D, D, band_lo=chunk_band,
+                             causal=True)
+    if history is None:
+        return chunk, None
+    T, n_hist, band = history
+    return chunk, flash_call_tiles(H // Hkv, S, T, D, D, kv_len=n_hist,
+                                   band_lo=band)
 
 
 def decode_attention(q, k, v, ring, a: int, t, ring_mask, dt, *,

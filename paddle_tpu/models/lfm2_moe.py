@@ -69,7 +69,8 @@ import jax.numpy as jnp
 
 from ..kernels.moe_dispatch import held_expert_ffn, sigmoid_bias_routing
 from ..kernels.paged_attention import ragged_tpu_refusal
-from .flat_kv_attention import decode_attention, pack_rows, prefill_attention
+from .flat_kv_attention import (decode_attention, pack_rows,
+                                prefill_attention, prefill_attention_tiles)
 from .deepseek_v2 import _rope, _swiglu
 from .llama import _rms_norm
 from .llama_served import ServeOpts
@@ -293,6 +294,20 @@ class Lfm2MoeServed:
                      aux["hist_len"], None, "lfm2_prefill_history")
             if aux["prefix_nbk"] else None)
         return o @ p["wo"].astype(self.dtype), {"kv": pack_rows(k, v)}
+
+    def piece_flash_tiles(self, S: int, hist: int, pnbk: int, bs: int):
+        """The grid steps of a piece's blockwise attention by kind, over
+        the attention layers and their KV heads: a bucket of ``S`` after
+        ``hist`` cached tokens gathered ``pnbk`` blocks wide."""
+        c = self.config
+        n = len(self._attn) * c.num_kv_heads
+        chunk, history = prefill_attention_tiles(
+            S, c.num_heads, c.num_kv_heads, c.head_dim,
+            history=(pnbk * bs, hist, None) if pnbk else None)
+        out = {"lfm2_prefill_chunk": tuple(n * t for t in chunk)}
+        if history:
+            out["lfm2_prefill_history"] = tuple(n * t for t in history)
+        return out
 
     def _prefill_conv(self, p, ci: int, hn, aux):
         """The gated short convolution of a piece from the state its

@@ -232,6 +232,10 @@ class LingHybridServed:
 
     history_blocks = staticmethod(deepseek_v2.DeepseekV2Served.history_blocks)
 
+    def piece_flash_tiles(self, S: int, hist: int, pnbk: int, bs: int):
+        """The one MLA layer's: the KDA layers run no blockwise softmax."""
+        return self._mla.piece_flash_tiles(S, hist, pnbk, bs)
+
     # -- top of the model ----------------------------------------------------
     def embed(self, params, tokens):
         return params["embed"].astype(self.dtype)[tokens]

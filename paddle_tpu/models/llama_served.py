@@ -62,6 +62,12 @@ pool-name prefix of a draft model) and ``mesh``):
                                       experts or dense FFN, residual: no
                                       cross-row state). Absent here: the
                                       dense family keeps its two programs
+``piece_flash_tiles(S, hist, pnbk, bs)``  OPTIONAL (the chunked families):
+                                      ``{kernel: (interior, edge, skipped)}``,
+                                      the grid steps of a piece's blockwise
+                                      attention by what the kernel does in
+                                      them, from numbers the engine holds
+                                      (``serving_flash_tiles_total``)
 ``shard(params, pools, mesh, ...)``   a tp mesh's placements
 ``spec_verify``                       llama's own extra: a model without
                                       it lists ``spec`` as unsupported

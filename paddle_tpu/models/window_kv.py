@@ -31,7 +31,8 @@ from typing import Dict, Tuple
 import jax.numpy as jnp
 
 from ..kernels.paged_attention import ragged_tpu_refusal
-from .flat_kv_attention import decode_attention, pack_rows, prefill_attention
+from .flat_kv_attention import (decode_attention, pack_rows,
+                                prefill_attention, prefill_attention_tiles)
 from .llama_served import ServeOpts
 
 __all__ = ["TwoKindCache", "ring_positions", "history_pad"]
@@ -179,6 +180,35 @@ class TwoKindCache:
             q, k, v, chunk_name=f"{self.trace_name}_prefill_chunk",
             chunk_band=band, history=history)
         return self._attn_out(p, o, hn), {f"kv{kind}": pack_rows(k, v)}
+
+    def piece_flash_tiles(self, S: int, hist: int, pnbk: int, bs: int):
+        """The grid steps of a piece's blockwise attention by kernel and
+        kind, over both kinds' layers and their KV heads: a bucket of
+        ``S`` after ``hist`` cached tokens, of which a full layer gathers
+        ``pnbk`` blocks and a window layer its ring (``prefill_begin``,
+        ``serving/window_ledger.py``'s width and ``history``)."""
+        c, W = self.config, self.window
+        dims = (S, c.num_heads, c.num_kv_heads, c.head_dim)
+        out = {}
+        for kind, layers in (("full", self._full), ("window", self._win)):
+            history = None
+            if pnbk and kind == "full":
+                history = (pnbk * bs, hist, None)
+            elif pnbk:
+                n_win = hist - max(0, hist - W + 1) // bs * bs
+                history = (history_pad((-(-W // bs) + 1) * bs), n_win,
+                           n_win - W + 1)
+            counts = prefill_attention_tiles(
+                *dims, chunk_band=1 - W if kind == "window" and S > W
+                else None, history=history)
+            n = len(layers) * c.num_kv_heads
+            for name, got in zip(("prefill_chunk", f"history_{kind}"),
+                                 counts):
+                if got:
+                    name = f"{self.trace_name}_{name}"
+                    out[name] = tuple(a + n * t for a, t in zip(
+                        out.get(name, (0, 0, 0)), got))
+        return out
 
     # -- decode --------------------------------------------------------------
     def ring_init(self, N: int, S: int, opts: ServeOpts) -> Dict:

@@ -266,6 +266,18 @@ CATALOG = {
                        "for each); serving_moe_assigned_total over this "
                        "times the tile's rows is the share of the MXU's "
                        "rows that are real"),
+    "serving_flash_tiles_total": (
+        "counter", ("kernel", "kind"),
+        "grid steps of the prefill pieces' blockwise attention "
+        "(kernels/pallas_attention.flash_partial) by the kernel's name in "
+        "a trace and by what the step does: interior (every (row, column) "
+        "of the tile inside the length, under the diagonal and inside the "
+        "band: no mask is computed), edge (a mask cuts the tile) or "
+        "skipped (nothing of it is inside: neither fetched nor computed); "
+        "counted on the host from a piece's bucket and history length, "
+        "over its layers and KV heads (a step's tile holds a KV head's "
+        "whole query group); interior over interior + edge is how often "
+        "the unmasked branch engages"),
     "serving_state_bytes_per_slot": (
         "gauge", (), "bytes of per-slot state a served model keeps beside "
                      "the paged cache (a short convolution's last inputs, "

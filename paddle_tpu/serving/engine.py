@@ -160,6 +160,7 @@ _M_MOE_ROUTED = _instrument("serving_moe_routed_total")
 _M_MOE_ASSIGNED = _instrument("serving_moe_assigned_total")
 _M_MOE_LOAD = _instrument("serving_moe_load_max_over_mean")
 _M_MOE_TILES = _instrument("serving_moe_row_tiles_total")
+_M_FLASH_TILES = _instrument("serving_flash_tiles_total")
 _M_STATE_SLOT_BYTES = _instrument("serving_state_bytes_per_slot")
 _M_STATE_RESETS = _instrument("serving_state_resets_total")
 _M_WINDOW_SLOT_BYTES = _instrument("serving_window_bytes_per_slot")
@@ -2268,6 +2269,13 @@ class LLMEngine:
             kw["win"] = self._window_operands(row, bucket, pnbk)
             # the history tokens a window layer gathers for this piece
             attrs["hist_window"] = min(hist, self.win.W - 1)
+        if _obs.enabled() and hasattr(self.model, "piece_flash_tiles"):
+            # what the piece's blockwise attention will do with its tiles,
+            # from the bucket and the history length alone
+            for kernel, counts in self.model.piece_flash_tiles(
+                    bucket, hist, pnbk, self.bs).items():
+                for kind, n in zip(("interior", "edge", "skipped"), counts):
+                    _M_FLASH_TILES.inc(n, kernel=kernel, kind=kind)
         return row, bucket, flags, pnbk, args, kw, attrs
 
     def _launch_row(self, built, dec=None, dec_flags=None, **dec_attrs):

@@ -281,6 +281,34 @@ def test_flash_partial_with_a_band(topo, keys, causal):
     assert "%mellum_history_window" in c.as_text()
 
 
+@pytest.mark.parametrize("name,heads,keys,causal,band", [
+    ("afmoe_prefill_chunk", (48, 8), 1024, True, False),
+    ("afmoe_history_window", (48, 8), 4608, False, True),
+    ("afmoe_history_full", (48, 8), 34816, False, False),
+    ("mellum_history_full", (32, 4), 33792, False, False)],
+    ids=["trinity-chunk", "trinity-window", "trinity-full", "mellum-full"])
+def test_flash_partial_with_a_query_group_in_the_tile(topo, name, heads,
+                                                      keys, causal, band):
+    """The tile that holds a KV head's whole query group, at the tile the
+    rule chooses from the shapes (``flash_tiles``), for the tallest
+    groups served: Trinity's 6 query heads a KV head (a piece of 1024,
+    causal; the window's 4,608 gathered keys under a band; the full
+    layers' table of 34,816 keys) and Mellum2's 8 over 33,792 keys.
+    Mosaic takes the stacked tile (the heads' rows merged into one
+    matmul's, split again under the edge tiles' mask) and its fast-memory
+    footprint; the kernel keeps the name the trace readers match."""
+    G, Gk = heads
+    specs = [((G, 1024, 128), BF16), ((Gk, keys, 128), BF16),
+             ((Gk, keys, 128), BF16), ((Gk,), jnp.int32)]
+    if band:
+        specs.append(((Gk,), jnp.int32))
+    c = _compile(
+        lambda q, k, v, n, lo=None: pallas_attention.flash_partial(
+            q, k, v, scale=0.088, causal=causal, kv_len=n, band_lo=lo,
+            name=name), _one(topo), *specs)
+    assert "%" + name in c.as_text()
+
+
 @pytest.mark.parametrize("tokens,rows", [(32, 256), (1024, 16384)],
                          ids=["decode", "piece"])
 def test_held_expert_ffn_sixty_four_experts(topo, monkeypatch, tokens, rows):
