@@ -337,11 +337,12 @@ _FLASH_VMEM = 16 << 20
 _FLASH_KV_MAX, _FLASH_KV_MAX_BANDED = 1024, 512
 # scores in a causal call's square tile (all the group's rows by its keys)
 _FLASH_CAUSAL_TILE = 1 << 20
+# the widest key tile of a latent history (``latent_history_tiles``)
+_LATENT_KV_MAX = 512
 
 
 def _flash_footprint(groups: int, bq: int, bkv: int, Dk: int, Dv: int,
-                     item: int, v_cols: bool = False,
-                     cut: bool = False) -> int:
+                     item: int, cut: bool = False) -> int:
     """Bytes of the chip's fast memory a ``flash_partial`` step holds at a
     tile of ``groups * bq`` rows by ``bkv`` keys, reckoned against what
     Mosaic reports (``Scoped allocation with size ...`` in a
@@ -352,23 +353,33 @@ def _flash_footprint(groups: int, bq: int, bkv: int, Dk: int, Dv: int,
     blocks twice each (the pipeline fetches the next while this one is
     worked on; a [rows, 1] block takes a lane tile a row), the float32
     accumulator and the two statistics, the tile's scores in float32, two
-    temporaries of a statistic's size, the value columns where they are
-    sliced from the keys, and under a diagonal or a band (``cut``) the
-    edge tiles' row and column indices and their mask, a POSITION's."""
+    temporaries of a statistic's size, and under a diagonal or a band
+    (``cut``) the edge tiles' row and column indices and their mask, a
+    POSITION's."""
     rows = groups * bq
     lanes = lambda d: -(-d // STATS) * STATS
-    kv = lanes(Dk) + (0 if v_cols else lanes(Dv))
-    blocks = 2 * (item * (rows * (lanes(Dk) + lanes(Dv)) + bkv * kv)
+    blocks = 2 * (item * (rows + bkv) * (lanes(Dk) + lanes(Dv))
                   + 4 * rows * STATS)
     scratch = 4 * rows * (lanes(Dv) + 2 * STATS)
     return (blocks + scratch + 4 * rows * bkv + 2 * 4 * rows * STATS
-            + (item * bkv * lanes(Dv) if v_cols else 0)
             + (4 * bq * bkv if cut else 0))
 
 
+def _tile_sides(S: int, T: int, widest: int):
+    """The sides a tile may take: ``block_q`` a power of two that divides
+    S, tallest first; ``block_kv`` a multiple of 128 up to ``widest`` that
+    divides T and lines up with S (divides it or is a multiple of it).
+    Sides that no 128 divides take ``_pick_block``'s answer."""
+    qs = [b for b in (1024, 512, 256, 128) if S % b == 0] \
+        or [_pick_block(S, 512)]
+    ks = [t for t in range(128, min(T, widest) + 1, 128)
+          if T % t == 0 and (S % t == 0 or t % S == 0)] \
+        or [_pick_block(T, 512)]
+    return qs, ks
+
+
 def flash_tiles(groups: int, S: int, T: int, Dk: int, Dv: int,
-                item: int = 2, causal: bool = False,
-                v_cols: bool = False, banded: bool = False
+                item: int = 2, causal: bool = False, banded: bool = False
                 ) -> Tuple[int, int]:
     """``flash_partial``'s tile ``(block_q, block_kv)`` from what the call
     can see of its operands, never from a model's name: ``block_q``
@@ -404,19 +415,14 @@ def flash_tiles(groups: int, S: int, T: int, Dk: int, Dv: int,
     Sides that no 128 divides (tests, short buckets) take ``_pick_block``'s
     answer, as before."""
     fits = lambda bq, bkv: _flash_footprint(
-        groups, bq, bkv, Dk, Dv, item, v_cols, causal or banded) \
-        <= _FLASH_VMEM
-    qs = [b for b in (1024, 512, 256, 128) if S % b == 0] \
-        or [_pick_block(S, 512)]
+        groups, bq, bkv, Dk, Dv, item, causal or banded) <= _FLASH_VMEM
+    qs, ks = _tile_sides(
+        S, T, _FLASH_KV_MAX_BANDED if banded else _FLASH_KV_MAX)
     if causal and S == T:
         square = [b for b in qs if fits(b, b)
                   and groups * b * b <= _FLASH_CAUSAL_TILE]
         if square:
             return square[0], square[0]
-    widest = _FLASH_KV_MAX_BANDED if banded else _FLASH_KV_MAX
-    ks = [t for t in range(128, min(T, widest) + 1, 128)
-          if T % t == 0 and (S % t == 0 or t % S == 0)] \
-        or [_pick_block(T, 512)]
     base = max(t for t in ks if t <= 512)
     bq = next((b for b in qs if fits(b, base)), qs[-1])
     return bq, max([t for t in ks if t >= base and fits(bq, t)] or [base])
@@ -465,11 +471,10 @@ def flash_tile_counts(S: int, T: int, kv_len: int, band_lo=None,
 
 def flash_call_tiles(groups: int, S: int, T: int, Dk: int, Dv: int,
                      kv_len=None, band_lo=None, causal: bool = False,
-                     item: int = 2, v_cols: bool = False
-                     ) -> Tuple[int, int, int]:
+                     item: int = 2) -> Tuple[int, int, int]:
     """``flash_tile_counts`` of a call's one KV head at the tile
     ``flash_partial`` itself chooses for those operands."""
-    bq, bkv = flash_tiles(groups, S, T, Dk, Dv, item, causal, v_cols,
+    bq, bkv = flash_tiles(groups, S, T, Dk, Dv, item, causal,
                           band_lo is not None)
     return flash_tile_counts(S, T, T if kv_len is None else kv_len, band_lo,
                              causal, bq=bq, bkv=bkv)
@@ -483,15 +488,50 @@ def _lanes(x, n: int):
         x[:, :1], (x.shape[0], n))
 
 
+def _init_softmax(acc_ref, m_ref, l_ref):
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def _softmax_step(s, v, acc_ref, m_ref, l_ref, masked=None):
+    """One key tile of the online softmax: scores s [rows, keys] f32
+    (an edge tile's already ``masked(s, NEG_INF)``), values v [keys, Dv]."""
+    # the statistics stay as they lie in their scratch, a row's value
+    # in every one of its 128 lanes: a [rows, 1] column is spread over
+    # the lanes twice a tile (the tile's max, the tile's sum) and
+    # nowhere else
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - _lanes(m_new, s.shape[-1]))
+    if masked is not None:
+        # a row with no key yet keeps m = NEG_INF: exp(s - m) would be
+        # 1. (An interior tile gives every one of its rows block_kv
+        # keys, so m_new is finite there and a row that had seen none
+        # takes alpha = 0: nothing to select.)
+        p = masked(p, 0.0)
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    pv = jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    acc_ref[...] = acc_ref[...] * _lanes(alpha, pv.shape[-1]) + pv
+    m_ref[...] = m_new
+
+
+def _write_partial(o_ref, lse_ref, acc_ref, m_ref, l_ref):
+    l = l_ref[:, :1]
+    o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-30)).astype(
+        o_ref.dtype).reshape(o_ref.shape[1:])
+    lse_ref[0] = jnp.where(l > 0, m_ref[:, :1] + jnp.log(
+        jnp.maximum(l, 1e-30)), NEG_INF).reshape(lse_ref.shape[1:])
+
+
 def _partial_kernel(len_ref, *rest, scale, causal, block_q, block_kv,
-                    groups, v_cols, banded=False):
+                    groups, banded=False):
     if banded:
         lo_ref, *rest = rest
-    q_ref, k_ref, *rest = rest
-    if v_cols is None:
-        v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
-    else:
-        o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
+    q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
     g, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     nj = pl.num_programs(2)
     kv_len = len_ref[g]
@@ -501,22 +541,18 @@ def _partial_kernel(len_ref, *rest, scale, causal, block_q, block_kv,
     rows = groups * block_q
     q0, c0 = i * block_q, j * block_kv
 
-    @pl.when(j == 0)
-    def _():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    pl.when(j == 0)(functools.partial(_init_softmax, acc_ref, m_ref, l_ref))
 
     lo = lo_ref[g] if banded else None
     run, whole = _tile_kind(q0, c0, block_q, block_kv, kv_len, lo, causal)
 
     def tile(cut: bool):
         q = q_ref[0].reshape(rows, q_ref.shape[-1])
-        k = k_ref[0]
-        v = k[:, :v_cols] if v_cols is not None else v_ref[0]
+        k, v = k_ref[0], v_ref[0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
+        masked = None
         if cut:
             # one mask a POSITION, shared by the group's heads
             shape = (block_q, block_kv)
@@ -532,48 +568,22 @@ def _partial_kernel(len_ref, *rest, scale, causal, block_q, block_kv,
                 keep[None], x.reshape(groups, block_q, block_kv),
                 jnp.float32(fill)).reshape(rows, block_kv)
             s = masked(s, NEG_INF)
-        # the statistics stay as they lie in their scratch, a row's value
-        # in every one of its 128 lanes: a [rows, 1] column is spread over
-        # the lanes twice a tile (the tile's max, the tile's sum) and
-        # nowhere else
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - _lanes(m_new, block_kv))
-        if cut:
-            # a row with no key yet keeps m = NEG_INF: exp(s - m) would be
-            # 1. (An interior tile gives every one of its rows block_kv
-            # keys, so m_new is finite there and a row that had seen none
-            # takes alpha = 0: nothing to select.)
-            p = masked(p, 0.0)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * _lanes(alpha, pv.shape[-1]) + pv
-        m_ref[...] = m_new
+        _softmax_step(s, v, acc_ref, m_ref, l_ref, masked)
 
     pl.when(run & whole)(functools.partial(tile, False))
     pl.when(run & jnp.logical_not(whole))(functools.partial(tile, True))
 
-    @pl.when(j == nj - 1)
-    def _():
-        l = l_ref[:, :1]
-        o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-30)).astype(
-            o_ref.dtype).reshape(o_ref.shape[1:])
-        lse_ref[0] = jnp.where(l > 0, m_ref[:, :1] + jnp.log(
-            jnp.maximum(l, 1e-30)), NEG_INF).reshape(lse_ref.shape[1:])
+    pl.when(j == nj - 1)(functools.partial(
+        _write_partial, o_ref, lse_ref, acc_ref, m_ref, l_ref))
 
 
-def flash_partial(q, k, v=None, *, scale: float, causal: bool = False,
-                  kv_len=None, v_cols=None, block_q=None, block_kv=None,
+def flash_partial(q, k, v, *, scale: float, causal: bool = False,
+                  kv_len=None, block_q=None, block_kv=None,
                   name: str = "flash_partial", band_lo=None):
     """Blockwise softmax attention in partial form, forward only.
 
     q: [G, S, Dk]; k: [Gk, T, Dk] with G a multiple of Gk (group g reads
-    keys g // (G // Gk)); v: [Gk, T, Dv] with any Dv, or None with
-    ``v_cols``: the values are then the first ``v_cols`` columns of the
-    keys, sliced in VMEM (a latent row is key and value at once).
+    keys g // (G // Gk)); v: [Gk, T, Dv] with any Dv.
     ``kv_len`` [Gk] int32 masks keys at or past it, a runtime operand: key
     tiles past it are neither fetched again nor computed. ``causal``
     compares row and column indices as they are (S and T start together).
@@ -600,15 +610,17 @@ def flash_partial(q, k, v=None, *, scale: float, causal: bool = False,
     Returns ``(o [G, S, Dv] in q's dtype, lse [G, S] f32)``: normalised
     output and log-sum-exp, -1e30 where a row saw no key, so that
     ``combine_partials`` merges calls over disjoint key sets. Nothing of
-    size S x T is materialised. Dk, Dv and v_cols are multiples of 128 on
-    a TPU (Mosaic's lanes): pad with zero columns, which add 0.0."""
+    size S x T is materialised. Dk and Dv are multiples of 128 on a TPU
+    (Mosaic's lanes): pad with zero columns, which add 0.0. (Keys and
+    values that are ONE latent row a token, shared by every head, go
+    through ``latent_history_partial``.)"""
     G, S, Dk = q.shape
     Gk, T, _ = k.shape
-    assert G % Gk == 0 and (v is None) != (v_cols is None), (G, Gk, v_cols)
+    assert G % Gk == 0, (G, Gk)
     groups = G // Gk
-    Dv = v_cols if v is None else v.shape[-1]
+    Dv = v.shape[-1]
     bq, bkv = flash_tiles(groups, S, T, Dk, Dv, q.dtype.itemsize, causal,
-                          v is None, band_lo is not None)
+                          band_lo is not None)
     if block_q is not None:
         bq = _pick_block(S, block_q)
     if block_kv is not None:
@@ -636,16 +648,8 @@ def flash_partial(q, k, v=None, *, scale: float, causal: bool = False,
         q_map = lambda g, i, j, lens: (g, 0, i, 0)
         scalars = [kv_len.astype(jnp.int32)]
 
-    # the heads of a group are neighbours in q: a view, no copy
-    in_specs = [pl.BlockSpec((1, groups, bq, Dk), q_map),
-                pl.BlockSpec((1, bkv, Dk), kv_map)]
-    operands = [q.reshape(Gk, groups, S, Dk), k]
-    if v is not None:
-        in_specs.append(pl.BlockSpec((1, bkv, Dv), kv_map))
-        operands.append(v)
     kernel = functools.partial(_partial_kernel, scale=scale, causal=causal,
-                               block_q=bq, block_kv=bkv, groups=groups,
-                               v_cols=v_cols)
+                               block_q=bq, block_kv=bkv, groups=groups)
     if banded:
         kernel = functools.partial(kernel, banded=True)
     rows = groups * bq
@@ -653,7 +657,10 @@ def flash_partial(q, k, v=None, *, scale: float, causal: bool = False,
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars), grid=(Gk, S // bq, T // bkv),
-            in_specs=in_specs,
+            # the heads of a group are neighbours in q: a view, no copy
+            in_specs=[pl.BlockSpec((1, groups, bq, Dk), q_map),
+                      pl.BlockSpec((1, bkv, Dk), kv_map),
+                      pl.BlockSpec((1, bkv, Dv), kv_map)],
             out_specs=[pl.BlockSpec((1, groups, bq, Dv), q_map),
                        pl.BlockSpec((1, groups, bq, 1), q_map)],
             scratch_shapes=[pltpu.VMEM((rows, Dv), jnp.float32),
@@ -662,8 +669,131 @@ def flash_partial(q, k, v=None, *, scale: float, causal: bool = False,
         out_shape=[jax.ShapeDtypeStruct((Gk, groups, S, Dv), q.dtype),
                    jax.ShapeDtypeStruct((Gk, groups, S, 1), jnp.float32)],
         interpret=_interpret(), name=name,
-    )(*scalars, *operands)
+    )(*scalars, q.reshape(Gk, groups, S, Dk), k, v)
     return out.reshape(G, S, Dv), lse.reshape(G, S)
+
+
+# ---------------------------------------------------------------------------
+# the same partial over a LATENT history: one row a token, shared by every
+# head, expanded to a head's keys and values a key tile at a time
+# ---------------------------------------------------------------------------
+
+def latent_history_tiles(S: int, T: int) -> Tuple[int, int]:
+    """``latent_history_partial``'s tile ``(block_q, block_kv)``: a head's
+    WHOLE piece where S allows (a key tile is expanded once a ``block_q``,
+    2 x block_kv x rank x (dn + dv) FLOPs that ``block_q`` rows share), by
+    the widest key tile up to ``_LATENT_KV_MAX`` (``_tile_sides``)."""
+    qs, ks = _tile_sides(S, T, _LATENT_KV_MAX)
+    return qs[0], max(ks)
+
+
+def _latent_history_kernel(len_ref, q_ref, c_ref, uk_ref, uv_ref, o_ref,
+                           lse_ref, acc_ref, m_ref, l_ref, *, scale,
+                           block_q, block_kv, rope):
+    j, nj = pl.program_id(2), pl.num_programs(2)
+    kv_len, c0 = len_ref[0], j * block_kv
+    dn, r = uk_ref.shape[1:]
+
+    pl.when(j == 0)(functools.partial(_init_softmax, acc_ref, m_ref, l_ref))
+
+    # every row of a piece sees every key of its history: a tile is cut by
+    # the length alone
+    run, whole = _tile_kind(0, c0, block_q, block_kv, kv_len, None, False)
+
+    def tile(cut: bool):
+        q, rows = q_ref[0], c_ref[0]
+        lat, dt = rows[:, :r], rows.dtype
+        nt = lambda a, b: jax.lax.dot_general(
+            a, b, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        # this head's keys and values of the tile, from the latent rows:
+        # f32 accumulation, rounded as the chunk's own are
+        k_nope = nt(lat, uk_ref[0]).astype(dt)
+        v = jnp.dot(lat, uv_ref[0],
+                    preferred_element_type=jnp.float32).astype(dt)
+        s = (nt(q[:, :dn], k_nope)
+             + nt(q[:, dn:dn + rope], rows[:, r:r + rope])) * scale
+        masked = None
+        if cut:
+            keep = jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_kv), 1) + c0 < kv_len
+            masked = lambda x, fill: jnp.where(keep, x, jnp.float32(fill))
+            s = masked(s, NEG_INF)
+        _softmax_step(s, v, acc_ref, m_ref, l_ref, masked)
+
+    pl.when(run & whole)(functools.partial(tile, False))
+    pl.when(run & jnp.logical_not(whole))(functools.partial(tile, True))
+
+    pl.when(j == nj - 1)(functools.partial(
+        _write_partial, o_ref, lse_ref, acc_ref, m_ref, l_ref))
+
+
+def latent_history_partial(q, hist, w_uk, w_uv, *, scale: float, kv_len,
+                           block_q=None, block_kv=None,
+                           name: str = "latent_history_partial"):
+    """``flash_partial`` over a history kept as LATENT rows, in the
+    expanded form: a head's keys and values of a key tile are made inside
+    the kernel, in the chip's fast memory, and never lie in HBM.
+
+    q: [H, S, Dq], a head's ``[nope dn ; rope ; zeros]`` as the chunk's own
+    call takes it; hist: [1, T, W], a token's ``[latent r ; roped key ;
+    zeros]`` as the pool keeps it; w_uk: [H, dn, r]; w_uv: [H, r, Dv];
+    ``kv_len`` [1] int32, a runtime operand: key tiles past it are neither
+    fetched again nor computed. A grid step takes ``block_q`` positions of
+    ONE head against ``block_kv`` history rows: ``k_nope = lat . w_uk[h]^T``
+    and ``v = lat . w_uv[h]`` (f32 accumulation, rounded to the rows'
+    dtype), ``s = q_nope . k_nope^T + q_rope . k_rope^T`` and the online
+    softmax of ``flash_partial``, its interior / edge / skipped tiles by the
+    length (``flash_tile_counts``). Per (query, head, key) that is 2 x (dn
+    + rope + Dv) FLOPs and the tile's expansion, 2 x r x (dn + Dv), shared
+    by ``block_q`` queries (``latent_history_tiles``: a head's whole
+    piece), where the absorbed form (queries carried into the latent's
+    coordinates: the decode walk's) pays 2 x (r + rope + r) whatever S:
+    the absorbed form wins only under r x (dn + Dv) / (2 r - dn - Dv)
+    queries a head (~170 at the published widths), which no piece is.
+
+    The rope columns are taken ``min(Dq - dn, W - r)`` wide: past the rope
+    both sides hold zeros, so on a TPU (Dq 256, W 640) both slices are
+    whole lane tiles. Returns ``(o [H, S, Dv], lse [H, S] f32)`` as
+    ``flash_partial`` does."""
+    H, S, Dq = q.shape
+    _, T, W = hist.shape
+    _, dn, r = w_uk.shape
+    Dv = w_uv.shape[-1]
+    bq, bkv = latent_history_tiles(S, T)
+    if block_q is not None:
+        bq = _pick_block(S, block_q)
+    if block_kv is not None:
+        bkv = _pick_block(T, block_kv)
+
+    def row_map(h, i, j, lens):
+        # past the length the same tile is named again: no new copy
+        last = jnp.maximum((lens[0] + bkv - 1) // bkv - 1, 0)
+        return (0, jnp.minimum(j, last), 0)
+
+    q_map = lambda h, i, j, lens: (h, i, 0)
+    w_map = lambda h, i, j, lens: (h, 0, 0)
+    kernel = functools.partial(
+        _latent_history_kernel, scale=scale, block_q=bq, block_kv=bkv,
+        rope=min(Dq - dn, W - r))
+    o, lse = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(H, S // bq, T // bkv),
+            in_specs=[pl.BlockSpec((1, bq, Dq), q_map),
+                      pl.BlockSpec((1, bkv, W), row_map),
+                      pl.BlockSpec((1, dn, r), w_map),
+                      pl.BlockSpec((1, r, Dv), w_map)],
+            out_specs=[pl.BlockSpec((1, bq, Dv), q_map),
+                       pl.BlockSpec((1, bq, 1), q_map)],
+            scratch_shapes=[pltpu.VMEM((bq, Dv), jnp.float32),
+                            pltpu.VMEM((bq, STATS), jnp.float32),
+                            pltpu.VMEM((bq, STATS), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((H, S, Dv), q.dtype),
+                   jax.ShapeDtypeStruct((H, S, 1), jnp.float32)],
+        interpret=_interpret(), name=name,
+    )(kv_len.astype(jnp.int32), q, hist, w_uk, w_uv)
+    return o, lse[..., 0]
 
 
 def combine_partials(o1, lse1, o2, lse2):

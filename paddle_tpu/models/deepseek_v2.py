@@ -28,14 +28,20 @@ What the engine sees (the interface of ``models/llama_served.py``):
   off a TPU), ``o = (softmax . c_kv) . W_UV``. Keys and values are never
   expanded.
 - **prefill in two parts** that one softmax joins
-  (``pallas_attention.combine_partials``): a chunk's own tokens in the
-  expanded form (q/k 192 wide padded to 256, v 128), causal, blockwise;
-  the tokens of earlier chunks in the absorbed form straight against the
-  gathered latent rows — expanding a 16k history's keys and values for a
-  padded wave would take tens of GB, the absorbed form takes the rows as
-  they lie, at 3.6 times the FLOPs per pair. Both run one row at a time
-  (``lax.map``; the engine's programs take one row each): the expanded
-  operands of several rows at once would not fit either.
+  (``pallas_attention.combine_partials``), both in the expanded form
+  (q/k 192 wide padded to 256, v 128), blockwise: a chunk's own tokens
+  against keys and values expanded before the call, causal
+  (``flash_partial``); the tokens of earlier chunks against the gathered
+  latent rows as they lie, a head's keys and values of a 512-key tile
+  made INSIDE the kernel (``latent_history_partial``: ``W_UK`` and
+  ``W_UV`` a head are 128 KiB each, and a piece's 1,024 positions of the
+  head share the tile's expansion). Expanding a 16k history's keys and
+  values in HBM for a padded wave would take tens of GB; in the chip's
+  fast memory it costs 256 FLOPs a (query, head, key) beside the 640 of
+  the scores and the weighted sum, where the absorbed form (decode's)
+  pays 2,304. Both run one row at a time (``lax.map``; the engine's
+  programs take one row each): the expanded operands of several rows at
+  once would not fit.
 - **the chip's share of an expert layer**: the router scores all
   ``n_routed_experts``, the routing rule runs over all groups, and this
   chip computes the pairs that fell on the experts it holds
@@ -63,7 +69,9 @@ import jax.numpy as jnp
 from ..kernels.moe_dispatch import group_limited_routing, held_expert_ffn
 from ..kernels.paged_attention import latent_decode_partial
 from ..kernels.pallas_attention import (combine_partials, flash_call_tiles,
-                                        flash_partial)
+                                        flash_partial, flash_tile_counts,
+                                        latent_history_partial,
+                                        latent_history_tiles)
 from .llama import _rms_norm
 from .llama_served import ServeOpts
 from .rope import rope_half as _rope
@@ -342,21 +350,17 @@ class DeepseekV2Served:
                 [k_nope, jnp.broadcast_to(k_r[None], (H, S, dr)), zq], -1)
             o, lse = flash_partial(qf, kf, v, scale=scale, causal=True,
                                    name="mla_prefill_chunk")
-            o = jnp.swapaxes(o, 0, 1)                           # [S, H, dv]
             if aux["prefix_nbk"]:
-                # the earlier chunks, absorbed: all heads of all the
-                # chunk's tokens against the latent rows as they lie
+                # the earlier chunks, expanded too, but a key tile and a
+                # head at a time inside the kernel, from the latent rows
+                # as they lie
                 tbl, n_hist = args[3:5]
-                hist = pool[tbl].reshape(1, -1, c.latent_width)
-                q_abs = self._absorb(p, qn, qr).reshape(
-                    1, S * H, c.latent_width)
-                o_lat, lse_h = flash_partial(
-                    q_abs, hist, None, scale=scale, kv_len=n_hist[None],
-                    v_cols=r, name="mla_prefill_history")
-                o_h = jnp.einsum("shc,hcd->shd", o_lat.reshape(S, H, r),
-                                 p["w_uv"].astype(dt))
-                o = combine_partials(o, jnp.swapaxes(lse, 0, 1), o_h,
-                                     lse_h.reshape(S, H))
+                o_h, lse_h = latent_history_partial(
+                    qf, pool[tbl].reshape(1, -1, c.latent_width),
+                    p["w_uk"].astype(dt), p["w_uv"].astype(dt), scale=scale,
+                    kv_len=n_hist[None], name="mla_prefill_history")
+                o = combine_partials(o, lse, o_h, lse_h)
+            o = jnp.swapaxes(o, 0, 1)                           # [S, H, dv]
             if gated:
                 o = o * gate[..., None]
             return o.reshape(S, H * dv) @ p["w_o"].astype(dt)
@@ -370,21 +374,21 @@ class DeepseekV2Served:
 
     def piece_flash_tiles(self, S: int, hist: int, pnbk: int, bs: int):
         """The grid steps of a piece's blockwise attention by kernel and
-        kind, over the layers: the chunk's keys are expanded a head (a
-        group of one, a call's KV heads the query heads), the history's
-        heads are rows of one group against the latent rows as they
-        lie, ``pnbk`` blocks wide of which ``hist`` tokens are real."""
+        kind, over the layers: both halves' keys are expanded a head (a
+        group of one, a call's KV heads the query heads), the chunk's
+        before the call and the history's a key tile at a time inside it,
+        from latent rows ``pnbk`` blocks wide of which ``hist`` tokens are
+        real."""
         c = self.config
         L, H = self.num_layers, c.num_heads
         dqk = c.qk_nope_head_dim + c.qk_rope_head_dim
-        chunk = flash_call_tiles(1, S, S, dqk + -dqk % 128, c.v_head_dim,
-                                 causal=True)
-        out = {"mla_prefill_chunk": tuple(L * H * t for t in chunk)}
+        out = {"mla_prefill_chunk": flash_call_tiles(
+            1, S, S, dqk + -dqk % 128, c.v_head_dim, causal=True)}
         if pnbk:
-            out["mla_prefill_history"] = tuple(L * t for t in flash_call_tiles(
-                1, S * H, pnbk * bs, c.latent_width, c.kv_lora_rank,
-                kv_len=hist, v_cols=True))
-        return out
+            bq, bkv = latent_history_tiles(S, pnbk * bs)
+            out["mla_prefill_history"] = flash_tile_counts(
+                S, pnbk * bs, hist, bq=bq, bkv=bkv)
+        return {k: tuple(L * H * t for t in v) for k, v in out.items()}
 
     def ffn(self, params, l: int, rows, valid):
         """The row-wise half of a layer, whatever program the rows come

@@ -586,18 +586,26 @@ def test_mellum_piece_with_the_decode_rows_is_one_pass_over_the_experts(
 # (e87d884): the dense family's prefill is ``paged_prefill``'s and no decode
 # program calls the kernel, which is this test's word that the bypassing
 # cells' programs did not move.
+# PR 44 (a latent piece's history in the expanded form: ``latent_history_
+# partial`` in place of ``_absorb``, ``flash_partial(v_cols=)`` and the
+# ``W_UV`` einsum after it) means to change the latent family's two programs
+# with a history, ``latent.prefill16`` and ``latent.piece+rows16``, read on
+# its tree. The other thirteen are the parent's (caf9c12), ``latent.
+# prefill0`` and the six of LFM2 and Mellum2 among them, though ``flash_
+# partial`` lost its ``v_cols`` branch and shares its softmax step with the
+# new kernel: a static Python branch's going leaves their text as it was.
 PARENT_PROGRAMS = {
     "dense.decode": "7921a0ad28675c6f", "dense.prefill0": "1cad0efaa529c317",
     "dense.prefill16": "53e7a3a2e58a42bd",
     "latent.decode": "387d28af185258ef",
     "latent.prefill0": "983d6d18051b9133",
-    "latent.prefill16": "43cd3b55967aec1d",
+    "latent.prefill16": "06e7ddbc8fe9f377",
     "lfm2.decode": "b4d382c4e1eff7be", "lfm2.prefill0": "448f46f78c37c4c9",
     "lfm2.prefill16": "e9e2632e826ea0d5",
     "mellum.decode": "e0f446c50b3116c4",
     "mellum.prefill0": "628fb8245afb434c",
     "mellum.prefill16": "8a0ea42f56e669f5",
-    "latent.piece+rows16": "97066cb566148b29",
+    "latent.piece+rows16": "e6b4cc88ea56074b",
     "lfm2.piece+rows16": "6864cc65ce4c6c1b",
     "mellum.piece+rows16": "e8b6cddd43734101"}
 

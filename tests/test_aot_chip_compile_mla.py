@@ -6,6 +6,7 @@ padded to 640, q/k 192 padded to 256, v 128, experts 5120 x 1536, blocks of
 16, 24 slots, a table 1152 wide)."""
 import importlib
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -74,14 +75,25 @@ def test_prefill_chunk_attention_at_unequal_widths(topo):
     assert "%mla_prefill_chunk" in text
 
 
-def test_prefill_history_attention_over_latent_rows(topo):
+@pytest.mark.parametrize("heads,keys", [(128, 18432), (32, 25600)],
+                         ids=["deepseek-v2", "ling-3.0-flash"])
+def test_prefill_history_attention_over_latent_rows(topo, heads, keys):
+    """The history in the expanded form, a key tile's K and V made a head
+    inside the kernel, at both published shapes (a 1,024-token piece of
+    ``longdoc-offline`` against its table of 18,432 keys, of
+    ``reason-offline`` against 25,600): the fast memory the compiled call
+    scopes, as its own configuration says it, is under the kernel's 16
+    MiB (a step that asked for more would have failed the compile)."""
     text = _compile(
-        lambda q, k, n: pallas_attention.flash_partial(
-            q, k, None, scale=SCALE, kv_len=n, v_cols=512,
-            name="mla_prefill_history"),
-        topo, ((1, 1024 * 128, 640), BF16), ((1, 18432, 640), BF16),
-        ((1,), I32))
-    assert "%mla_prefill_history" in text
+        lambda q, c, uk, uv, n: pallas_attention.latent_history_partial(
+            q, c, uk, uv, scale=SCALE, kv_len=n, name="mla_prefill_history"),
+        topo, ((heads, 1024, 256), BF16), ((1, keys, 640), BF16),
+        ((heads, 128, 512), BF16), ((heads, 512, 128), BF16), ((1,), I32))
+    call, = [ln for ln in text.splitlines()
+             if "custom-call(" in ln and "%mla_prefill_history" in ln]
+    scoped = [int(n) for n in re.findall(
+        r'"memory_space":"1","offset":"\d+","size":"(\d+)"', call)]
+    assert scoped and 0 < sum(scoped) <= pallas_attention._FLASH_VMEM, scoped
 
 
 @pytest.mark.parametrize("tokens,rows", [

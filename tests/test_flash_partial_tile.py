@@ -2,7 +2,10 @@
 query group, and computes a mask only where a mask cuts the tile. The
 kernel, interpreted, against the plain softmax over the same mask; the
 tile's classification and ``flash_tile_counts`` against a brute-force
-count over the mask itself; the rule that chooses the tile's sizes."""
+count over the mask itself; the rule that chooses the tile's sizes. And
+``latent_history_partial`` (PR 44): the same partial over a history of
+LATENT rows, a head's keys and values made a key tile at a time inside
+the kernel."""
 import itertools
 
 import jax.numpy as jnp
@@ -13,7 +16,9 @@ from paddle_tpu.kernels import pallas_attention as fl
 from paddle_tpu.kernels.pallas_attention import (combine_partials,
                                                  flash_partial,
                                                  flash_tile_counts,
-                                                 flash_tiles)
+                                                 flash_tiles,
+                                                 latent_history_partial,
+                                                 latent_history_tiles)
 
 BQ, BKV = 16, 32            # unequal, so that rows and columns cannot swap
 # a KV head a length: none, one key, a multiple of the key tile, one short
@@ -92,28 +97,111 @@ def test_the_stacked_tile_against_the_plain_softmax(groups, mode):
     _check(q, k, v, o, lse, kv_len, lo, causal, 0.1)
 
 
-@pytest.mark.parametrize("groups,D,v_cols", [(1, 256, 128), (4, 256, 128),
-                                             (4, 64, None), (8, 64, None)],
+# a latent family at the tests' size: nope 32 and rope 16 in a query row of
+# 128, rank 128 and the roped key in a pool row of 256, values of 32
+DN, DR, R, DV, DQ, W = 32, 16, 128, 32, 128, 256
+
+
+def _expand(rows, w_uk, w_uv):
+    """Latent rows [T, W] as every head's keys [H, T, DN + DR] and values
+    [H, T, DV]: the published expansion, in float32."""
+    lat, k_r = rows[:, :R], rows[:, R:R + DR]
+    k_nope = np.einsum("tc,hdc->htd", lat, w_uk)
+    return (np.concatenate([k_nope, np.broadcast_to(
+        k_r, k_nope.shape[:2] + (DR,))], -1),
+        np.einsum("tc,hcd->htd", lat, w_uv))
+
+
+def _latent_operands(seed, H, S, T):
+    """q [H, S, DQ] and the history [1, T, W], zeros past the rope as the
+    model pads them, and a head's ``w_uk`` [DN, R] and ``w_uv`` [R, DV]."""
+    rng = np.random.default_rng(seed)
+    f = lambda *shape: rng.standard_normal(shape).astype(np.float32)
+    q, hist = np.zeros((H, S, DQ), np.float32), np.zeros((1, T, W), np.float32)
+    q[..., :DN + DR], hist[..., :R + DR] = f(H, S, DN + DR), f(1, T, R + DR)
+    return q, hist, f(H, DN, R) / R ** 0.5, f(H, R, DV) / R ** 0.5
+
+
+@pytest.mark.parametrize("groups,D,heads", [(1, 256, 1), (4, 256, 4),
+                                            (4, 64, None), (8, 64, None)],
                          ids=["latent-rows", "latent-rows-grouped",
                               "head-dim-64-of-4", "head-dim-64-of-8"])
-def test_the_stacked_tile_at_other_widths(groups, D, v_cols):
-    """Values that are the keys' first columns (a latent row), and heads
-    of 64 as they are, causal and then over a history with a length."""
+def test_the_stacked_tile_at_other_widths(groups, D, heads):
+    """A history of latent rows, expanded a head inside the kernel, under
+    two lengths; and heads of 64 as they are, causal and then over a
+    history with a length."""
     Gk, S, T = 2, 32, 64
-    q, k, v = _operands(7, Gk * groups, Gk, S, T, D,
-                        None if v_cols else D)
-    vv = k[:, :, :v_cols] if v_cols else v
-    kw = dict(scale=0.07, block_q=BQ, block_kv=BKV, v_cols=v_cols)
     kv_len = np.asarray([T - 1, 33])
-    o, lse = flash_partial(jnp.asarray(q), jnp.asarray(k),
-                           None if v_cols else jnp.asarray(v),
+    if heads:
+        q, hist, w_uk, w_uv = _latent_operands(7, heads, S, T)
+        k, v = _expand(hist[0], w_uk, w_uv)
+        for n in kv_len:
+            o, lse = latent_history_partial(
+                *map(jnp.asarray, (q, hist, w_uk, w_uv)), scale=0.07,
+                kv_len=jnp.asarray([n]), block_q=BQ, block_kv=BKV)
+            _check(q[..., :DN + DR], k, v, o, lse, np.full((heads,), n),
+                   None, False, 0.07)
+        return
+    q, k, v = _operands(7, Gk * groups, Gk, S, T, D, D)
+    kw = dict(scale=0.07, block_q=BQ, block_kv=BKV)
+    o, lse = flash_partial(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
                            kv_len=jnp.asarray(kv_len), **kw)
-    _check(q, k, vv, o, lse, kv_len, None, False, 0.07)
+    _check(q, k, v, o, lse, kv_len, None, False, 0.07)
     o, lse = flash_partial(jnp.asarray(q), jnp.asarray(k[:, :S]),
-                           None if v_cols else jnp.asarray(v[:, :S]),
-                           causal=True, **kw)
-    _check(q, k[:, :S], vv[:, :S], o, lse, np.full((Gk,), S), None, True,
+                           jnp.asarray(v[:, :S]), causal=True, **kw)
+    _check(q, k[:, :S], v[:, :S], o, lse, np.full((Gk,), S), None, True,
            0.07)
+
+
+@pytest.mark.parametrize("heads", [4, 32])
+@pytest.mark.parametrize("n_hist", [0, BKV + 1, 2 * BKV, 3 * BKV],
+                         ids=["none", "one-past-a-tile", "whole-tiles",
+                              "the-table-s-width"])
+def test_a_latent_piece_against_the_plain_softmax(n_hist, heads):
+    """A piece as the latent family runs it: the chunk's own keys expanded
+    before the call (``flash_partial``, causal), the history's inside
+    ``latent_history_partial``, one softmax joining them: against the plain
+    float32 softmax over [history ; chunk], and against the ABSORBED form
+    of the history (queries carried into the latent's coordinates, the rows
+    as keys and values, ``W_UV`` after) on the same operands."""
+    S, T, scale = 32, 3 * BKV, 0.11
+    q, hist, w_uk, w_uv = _latent_operands(n_hist + heads, heads, S, T)
+    own = _latent_operands(1, heads, S, S)[1][0]    # the chunk's own rows
+    (k_h, v_h), (k_c, v_c) = (_expand(x, w_uk, w_uv) for x in (hist[0], own))
+    pad = lambda x: np.concatenate(
+        [x, np.zeros(x.shape[:-1] + (DQ - DN - DR,), np.float32)], -1)
+    o_c, lse_c = flash_partial(jnp.asarray(q), jnp.asarray(pad(k_c)),
+                               jnp.asarray(v_c), scale=scale, causal=True,
+                               block_q=BQ, block_kv=BQ)
+    o_h, lse_h = latent_history_partial(
+        *map(jnp.asarray, (q, hist, w_uk, w_uv)), scale=scale,
+        kv_len=jnp.asarray([n_hist]), block_q=BQ, block_kv=BKV)
+    got = np.asarray(combine_partials(o_c, lse_c, o_h, lse_h))
+    # the plain softmax over the history's real keys and then the chunk's
+    qq = q[..., :DN + DR]
+    keep = np.concatenate([np.broadcast_to(np.arange(T) < n_hist, (S, T)),
+                           np.tril(np.ones((S, S), bool))], 1)
+    s = np.where(keep, np.einsum("hsd,htd->hst", qq, np.concatenate(
+        [k_h, k_c], 1)) * scale, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    want = np.einsum("hst,htd->hsd", p / p.sum(-1, keepdims=True),
+                     np.concatenate([v_h, v_c], 1))
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    # the absorbed history: scores in the latent's coordinates
+    if n_hist:
+        q_abs = np.concatenate([np.einsum("hsd,hdc->hsc", q[..., :DN], w_uk),
+                                q[..., DN:DN + DR]], -1)
+        s_a = np.einsum("hsw,tw->hst", q_abs, hist[0, :n_hist, :R + DR]) \
+            * scale
+        p_a = np.exp(s_a - s_a.max(-1, keepdims=True))
+        o_a = np.einsum("hsc,hcd->hsd", np.einsum(
+            "hst,tc->hsc", p_a / p_a.sum(-1, keepdims=True),
+            hist[0, :n_hist, :R]), w_uv)
+        np.testing.assert_allclose(np.asarray(o_h), o_a, atol=2e-5)
+        np.testing.assert_allclose(
+            np.asarray(lse_h), s_a.max(-1) + np.log(p_a.sum(-1)), atol=2e-5)
+    else:
+        assert (np.asarray(lse_h) <= -1e29).all()
 
 
 def test_the_tile_the_rule_chooses_gives_what_any_other_gives():
@@ -180,7 +268,7 @@ def test_the_counts_at_the_published_shapes():
     (6, 1024, 1024, 128, 128), (6, 1024, 4608, 128, 128),
     (6, 1024, 34816, 128, 128), (8, 1024, 33792, 128, 128),
     (8, 1024, 1536, 128, 128), (4, 1024, 9216, 64, 64),
-    (1, 1024, 1024, 256, 128), (1, 131072, 18432, 640, 512),
+    (1, 1024, 1024, 256, 128), (6, 1024, 2048, 128, 128),
     (2, 32, 48, 128, 128), (1, 64, 96, 64, 64)])
 def test_the_tile_follows_from_the_shapes(groups, S, T, Dk, Dv):
     """The rule's tile divides both sides, is a multiple of the MXU's 128
@@ -194,6 +282,18 @@ def test_the_tile_follows_from_the_shapes(groups, S, T, Dk, Dv):
     if S % 128 == 0 and T % 128 == 0:
         assert fl._flash_footprint(groups, bq, bkv, Dk, Dv, 2) \
             <= fl._FLASH_VMEM
+
+
+def test_a_latent_history_s_tile_follows_from_the_shapes():
+    """A head's whole piece by the widest key tile of at most 512 that
+    divides the table and lines up with the piece; sides that no 128
+    divides take ``_pick_block``'s answer."""
+    assert latent_history_tiles(1024, 18432) == (1024, 512)
+    assert latent_history_tiles(1024, 25600) == (1024, 512)
+    assert latent_history_tiles(2048, 18432) == (1024, 512)
+    assert latent_history_tiles(256, 1280) == (256, 256)
+    assert latent_history_tiles(64, 4096) == (64, 512)
+    assert latent_history_tiles(32, 96) == (32, 96)
 
 
 # -- the counter ---------------------------------------------------------------
@@ -294,3 +394,12 @@ def test_every_chunked_family_says_what_its_pieces_tile(family):
         assert all(n >= 0 and n == int(n) for n in counts)
     # a history 100 tokens long in a table of 4,096: most of it is skipped
     assert later[names[1]][2] > 0
+    if names[1] == "mla_prefill_history":
+        # the latent history's own grid: a head a step by key tiles, in
+        # every latent layer, of which the 100 real keys fill one tile
+        mla = getattr(model, "_mla", model)
+        bq, bkv = latent_history_tiles(64, 4096)
+        heads = mla.num_layers * mla.config.num_heads
+        assert later[names[1]] == (0, heads, heads * (4096 // bkv - 1))
+        assert model.piece_flash_tiles(64, 2 * bkv, 512, 8)[names[1]] == (
+            2 * heads, 0, heads * (4096 // bkv - 2))
