@@ -3,8 +3,8 @@
 Exercises the full hybrid-parallel recipe: a (pp, dp, sp, tp) device mesh,
 fsdp/tp/sp sharded parameters, flash attention, remat, optional 1F1B
 pipeline schedule, chunked cross-entropy, and the fused
-fwd+bwd+clip+optimizer train step. On one chip it is the bench.py
-configuration; on a pod slice raise --tp/--pp/--dp to the mesh you have.
+fwd+bwd+clip+optimizer train step. One chip runs it unsharded; on a
+pod slice raise --tp/--pp/--dp to the mesh you have.
 
 Run (one chip, ~740M):   python examples/llama_pretrain.py --size 740m
 Run (8-virtual-CPU dev): JAX_PLATFORMS=cpu python examples/llama_pretrain.py \

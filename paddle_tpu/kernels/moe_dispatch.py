@@ -235,8 +235,8 @@ def clear_plan_cache() -> None:
 #
 # r04 made the dense-base staging form the static default on the strength
 # of a forward-only MXU measurement; under the full train step it lost
-# ~7% to the grouped-GEMM form at the bench shape (BENCH_r05 0.925x,
-# docs/moe.md postmortem). Shape heuristics keep getting this wrong, so
+# ~7% to the grouped-GEMM form at a 16-expert top-2 train shape (round
+# 5's chip runs). Shape heuristics keep getting this wrong, so
 # the form is now MEASURED once per routing shape on TPU — fwd+bwd, the
 # quantity the bench actually pays — and the winner is persisted through
 # the jit artifact cache. The static default ("fused") is always among

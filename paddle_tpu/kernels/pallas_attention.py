@@ -310,7 +310,7 @@ def flash_attention_fwd(q, k, v, causal: bool = False,
     repeated K/V (and their expanded dK/dV) never touch HBM.
     Differentiable (custom FA2 backward). Default 1024-blocks measured
     fastest on v5e (2.6B train step: 6.89k vs 6.52k tok/s at 512-blocks,
-    bench.py runs); _pick_block shrinks them for shorter sequences."""
+    round 4's chip runs); _pick_block shrinks them for shorter sequences."""
     B, S, H, D = q.shape
     Hkv = k.shape[2]
     assert H % Hkv == 0, (H, Hkv)

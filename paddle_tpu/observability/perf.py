@@ -6,7 +6,7 @@ one-off benchmark:
 - **MFU** — FLOPs one call executes come from XLA's cost analysis of the
   LOWERED program (:func:`flops_of`; no second compile — ``lower()`` is
   a trace), divided by measured step time x the per-device-kind peak
-  from :data:`DEVICE_SPECS` (bench.py reuses this table). Off an
+  from :data:`DEVICE_SPECS` (published peaks, each with its source). Off an
   accelerator MFU is undefined: :func:`mfu` returns ``None`` on the CPU
   and the gauges stay unset. A device kind the table does not list is
   an error, never another chip's peak.

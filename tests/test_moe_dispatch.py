@@ -838,43 +838,3 @@ def test_int8_ep_sharded_lowering_smoke():
         lowered = jax.jit(
             lambda p, t: moe.loss_fn(p, t, cfg)).lower(params, tokens)
     assert "psum" in lowered.as_text() or len(lowered.as_text()) > 0
-
-
-# ---------------------------------------------------------------------------
-# phase-breakdown harness + bisect CLI (the r05 evidence tooling)
-# ---------------------------------------------------------------------------
-
-def test_moe_phase_breakdown_sums_to_step_time():
-    """The per-phase decomposition accounts for the measured layer time:
-    the breakdown that bench.py attaches to the MoE row (phase_ms) must
-    sum to ~the fwd+bwd layer wall-clock on the CPU mini-config."""
-    sys.path.insert(0, os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))))
-    from bench import moe_phase_breakdown
-
-    out = moe_phase_breakdown(moe.tiny_moe(), 2, 64)
-    assert set(out["phase_ms"]) == {"routing", "gmm_fwd", "gmm_bwd",
-                                    "combine", "collective"}
-    total = sum(out["phase_ms"].values())
-    assert out["layer_ms"] > 0
-    ratio = total / out["layer_ms"]
-    assert 0.4 <= ratio <= 1.6, (out, ratio)
-
-
-def test_moe_tune_bisect_cli_smoke(tmp_path):
-    """--bisect runs end to end on the CPU lane: the lever-delta table,
-    the phase breakdown, and the JSON artifact."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               PADDLE_TPU_CACHE_DIR=str(tmp_path))
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    out_json = tmp_path / "bisect.json"
-    proc = subprocess.run(
-        [sys.executable, os.path.join(root, "tools", "moe_tune.py"),
-         "--bisect", "--preset", "tiny", "--levers", "gmm",
-         "--out", str(out_json)],
-        env=env, cwd=root, capture_output=True, text=True, timeout=300)
-    assert proc.returncode == 0, proc.stderr[-1000:]
-    assert "vs base" in proc.stdout
-    assert "per-phase breakdown" in proc.stdout
-    doc = json.loads(out_json.read_text())
-    assert doc["levers"] and "phase_ms" in doc

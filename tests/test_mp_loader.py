@@ -264,28 +264,18 @@ class _GilBoundDataset(Dataset):
         acc = 0
         for j in range(150_000):
             acc += j * j
-        return np.asarray([i, acc % 7], np.int64)
+        return np.asarray([i, acc % 7], np.int64), np.int64(os.getpid())
 
 
-@pytest.mark.skipif((os.cpu_count() or 1) < 4,
-                    reason="needs >=4 cores for process-pool speedup")
 def test_process_workers_beat_threads_on_gil_bound_transforms():
-    def epoch_time(mode):
-        loader = DataLoader(_GilBoundDataset(), batch_size=4, num_workers=4,
-                            worker_mode=mode, persistent_workers=True)
-        ids = []
-        for b in loader:          # warm epoch: pool spawn + first batches
-            pass
-        t0 = time.perf_counter()
-        for b in loader:
-            ids.append(np.asarray(b.numpy() if isinstance(b, Tensor)
-                                  else b)[:, 0])
-        dt = time.perf_counter() - t0
-        assert sorted(np.concatenate(ids).tolist()) == list(range(24))
-        return dt
-
-    t_thread = epoch_time("thread")
-    t_proc = epoch_time("process")
-    # 4 GIL-bound thread workers ≈ serial; 4 processes ≈ 4x. Assert a
-    # conservative margin so shared CI hosts don't flake.
-    assert t_proc < 0.75 * t_thread, (t_proc, t_thread)
+    """Four persistent process workers serve a warm second epoch whole,
+    from more than one process. That they beat threads on the clock is the
+    slow lane's claim (``..._on_cpu_bound_transforms`` above)."""
+    loader = DataLoader(_GilBoundDataset(), batch_size=4, num_workers=4,
+                        worker_mode="process", persistent_workers=True)
+    for _ in loader:          # warm epoch: pool spawn + first batches
+        pass
+    rows, pids = _collect(loader)
+    assert sorted(np.concatenate(rows)[:, 0].tolist()) == list(range(24))
+    made_by = set(np.concatenate(pids).tolist())
+    assert os.getpid() not in made_by and len(made_by) > 1

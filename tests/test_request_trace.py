@@ -1,5 +1,5 @@
 """r7 per-request observability: timelines, exemplars, SLO audit, the
-on-demand profiling control plane, and the bench regression sentinel.
+on-demand profiling control plane.
 
 Contracts under test:
 - a served request's timeline is COMPLETE (queued -> admitted ->
@@ -13,9 +13,7 @@ Contracts under test:
   exemplars (the disabled-path guard);
 - the profiling controller windows a jax.profiler capture to N step
   boundaries, mirrors trace_span into TraceAnnotations only while
-  live, and logs the capture to the flight recorder;
-- tools/bench_diff.py on the REAL r04/r05 files exits nonzero naming
-  moe-dropless_pretrain (r04 failed -> anchors on r03).
+  live, and logs the capture to the flight recorder.
 """
 import dataclasses
 import json
@@ -548,130 +546,6 @@ def test_integration_p99_exemplar_resolves_slow_request_over_http(
     assert listing["requests"][0]["request_id"] == slow
     assert listing["exemplar_quantiles"][
         "serving_ttft_seconds"]["p99"]["request_id"] == slow
-
-
-def _run_bench_diff(*argv):
-    return subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "bench_diff.py"),
-         *argv],
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, timeout=60,
-        cwd=REPO)
-
-
-_TPS = "_pretrain_tokens_per_sec_per_chip"
-
-
-def _write_moe_rounds(tmp_path):
-    """Rounds 3-5 as the driver recorded them, written into ``tmp_path``
-    (the r03 record itself is deleted): a usable round, a failed one
-    (no parsed metrics), then one whose MoE row is 7.3% down."""
-    def round_(n, rc, values):
-        doc = {"n": n, "rc": rc, "parsed": values and {"metrics": [
-            {"metric": m + _TPS, "value": v, "unit": "tokens/s"}
-            for m, v in values.items()]}}
-        path = tmp_path / f"BENCH_r{n:02d}.json"
-        path.write_text(json.dumps(doc))
-        return str(path)
-
-    return (round_(3, 0, {"llama-5.2b-layerwise": 3594.0,
-                          "llama-2.6b@8k": 5472.5,
-                          "moe-dropless": 18268.5}),
-            round_(4, 1, None),
-            round_(5, 0, {"llama-5.2b-layerwise": 3595.5,
-                          "llama-2.6b@8k": 5450.5,
-                          "moe-dropless": 16937.6}))
-
-
-def test_bench_diff_flags_moe_regression_across_failed_round(tmp_path):
-    """The sentinel that would have caught MoE 0.92x at r05: r04 failed
-    (no parsed metrics), so it anchors on r03 and flags the -7.3%."""
-    _r03, r04, r05 = _write_moe_rounds(tmp_path)
-    proc = _run_bench_diff(r04, r05)
-    out = proc.stdout.decode()
-    assert proc.returncode == 1, out
-    assert "moe-dropless_pretrain" in out
-    assert "REGRESSION" in out
-    assert "BENCH_r03.json" in out            # the walk-back is explicit
-
-
-def test_bench_diff_auto_mode_latest_pair(tmp_path):
-    _write_moe_rounds(tmp_path)
-    proc = _run_bench_diff("--dir", str(tmp_path))
-    out = proc.stdout.decode()
-    assert proc.returncode == 1, out           # latest pair is r04/r05
-    assert "moe-dropless_pretrain" in out
-
-
-def test_bench_diff_ok_within_band_and_band_knob(tmp_path):
-    a = {"n": 1, "rc": 0, "parsed": {"metrics": [
-        {"metric": "m1", "value": 100.0}, {"metric": "m2", "value": 50.0}]}}
-    b = {"n": 2, "rc": 0, "parsed": {"metrics": [
-        {"metric": "m1", "value": 98.0}, {"metric": "m2", "value": 51.0}]}}
-    pa, pb = tmp_path / "BENCH_r01.json", tmp_path / "BENCH_r02.json"
-    pa.write_text(json.dumps(a))
-    pb.write_text(json.dumps(b))
-    proc = _run_bench_diff(str(pa), str(pb))
-    assert proc.returncode == 0, proc.stdout.decode()
-    # tighten the band below the -2% delta: now it regresses
-    proc = _run_bench_diff(str(pa), str(pb), "--band", "1.5")
-    out = proc.stdout.decode()
-    assert proc.returncode == 1 and "m1" in out
-
-
-def test_bench_diff_failed_new_round_is_a_regression(tmp_path):
-    pa = tmp_path / "BENCH_r01.json"
-    pb = tmp_path / "BENCH_r02.json"
-    pa.write_text(json.dumps(
-        {"n": 1, "rc": 0,
-         "parsed": {"metrics": [{"metric": "m1", "value": 100.0}]}}))
-    pb.write_text(json.dumps({"n": 2, "rc": 1, "parsed": None}))
-    proc = _run_bench_diff(str(pa), str(pb))
-    out = proc.stdout.decode()
-    assert proc.returncode == 1 and "no parsed metrics" in out
-
-
-def test_bench_diff_check_next_committed_round_is_armed():
-    """The tier-1 sentinel: --check against the NEXT bench round in the
-    repo. Today the file does not exist, so the check reports pending
-    and passes; the moment BENCH_r06.json is committed this same test
-    diffs it against the newest earlier usable round and fails the
-    suite on any regression beyond the band — a 0.92x can no longer sit
-    unnoticed for two rounds."""
-    proc = _run_bench_diff("--check", os.path.join(REPO, "BENCH_r06.json"))
-    out = proc.stdout.decode()
-    assert proc.returncode == 0, out
-    # whichever state the repo is in, the check made a decision
-    assert ("pending" in out or "no regression" in out
-            or "first usable round" in out), out
-
-
-def test_bench_diff_check_flags_the_r05_regression(tmp_path):
-    """--check on r05 anchors on the newest earlier usable round and
-    flags the MoE regression — proof the armed mode actually bites once
-    the round exists."""
-    _r03, _r04, r05 = _write_moe_rounds(tmp_path)
-    proc = _run_bench_diff("--check", r05)
-    out = proc.stdout.decode()
-    assert proc.returncode == 1, out
-    assert "moe-dropless_pretrain" in out and "REGRESSION" in out
-
-
-def test_bench_diff_check_first_round_and_band(tmp_path):
-    pa = tmp_path / "BENCH_r01.json"
-    pa.write_text(json.dumps(
-        {"n": 1, "rc": 0,
-         "parsed": {"metrics": [{"metric": "m1", "value": 100.0}]}}))
-    proc = _run_bench_diff("--check", str(pa))
-    assert proc.returncode == 0
-    assert "first usable round" in proc.stdout.decode()
-    pb = tmp_path / "BENCH_r02.json"
-    pb.write_text(json.dumps(
-        {"n": 2, "rc": 0,
-         "parsed": {"metrics": [{"metric": "m1", "value": 98.0}]}}))
-    proc = _run_bench_diff("--check", str(pb))
-    assert proc.returncode == 0, proc.stdout.decode()   # inside ±3%
-    proc = _run_bench_diff("--check", str(pb), "--band", "1.5")
-    assert proc.returncode == 1                         # band bites
 
 
 # ---------------------------------------------------------------------------

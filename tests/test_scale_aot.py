@@ -3,7 +3,7 @@ llama3-8b (32 layers, 4096 hidden, 128256 vocab) over a (pp=2, dp=2, tp=2)
 mesh — the pod-slice recipe — without materializing any 8B-sized buffer
 (``jit(...).lower(abstract_args).compile()``).
 
-Single-chip bench covers 2.6B (bench.py); the 8B target runs on a pod slice.
+One chip holds a model of about 2.6B to train; the 8B target runs on a pod slice.
 This test proves the sharded 1F1B train step for the 8B config compiles end
 to end: GSPMD partitioning, the 1F1B shard_map schedule, collective layout —
 everything except the physical chips. Reference scale target:
